@@ -159,10 +159,8 @@ def load(fname: str) -> Tuple[List[NDArray], List[str]]:
 
 def wait_all():
     """MXNDArrayWaitAll/MXEngineWaitAll."""
-    import jax
-    from . import engine as _engine
-    _engine.get().wait_for_all()
-    jax.effects_barrier()
+    from .ndarray.ndarray import waitall
+    waitall()
 
 
 def random_seed(seed: int):

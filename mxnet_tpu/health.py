@@ -111,29 +111,55 @@ CAUSES = ("compute_bound", "input_bound", "sync_bound", "compile_bound",
 
 # -- peak FLOPS model (shared with bench.py) --------------------------------
 
-# Per-platform dense peaks in TFLOP/s.  The tpu column is the v5e-class
-# figure bench.py has used since r02; cpu is a dev-box ballpark that keeps
-# the live gauge finite without pretending the host is a chip.  Override
-# with MXNET_HEALTH_PEAK_TFLOPS (or bench's BENCH_PEAK_TFLOPS).
+# Dense matmul peaks of one device in TFLOP/s, keyed by the ``device_kind``
+# jax reports, each row with its published source.  A kind or dtype that is
+# not here is an error, never a default: add the row with its source.
+# Override with MXNET_HEALTH_PEAK_TFLOPS (or bench's BENCH_PEAK_TFLOPS).
 _PEAK_TFLOPS = {
-    "tpu": {"bfloat16": 197.0, "float16": 197.0, "float32": 99.0},
-    "gpu": {"bfloat16": 312.0, "float16": 312.0, "float32": 19.5},
-    "cpu": {"bfloat16": 0.25, "float16": 0.25, "float32": 0.25},
+    "TPU v5 lite": {
+        "source": 'Google Cloud documentation, "TPU v5e": 197 TFLOP/s '
+                  "bf16 and 393 TOP/s int8 per chip",
+        "tflops": {"bfloat16": 197.0, "int8": 393.0},
+    },
+    "cpu": {
+        "source": "not a device peak: a convention that keeps the live "
+                  "MFU gauge finite on CPU test runs",
+        "tflops": {None: 0.25},
+    },
 }
 
 
-def peak_tflops(dtype="bfloat16", platform=None):
-    """Per-platform peak in TFLOP/s for ``dtype`` (env-overridable).
+#: MFU is quoted against the device's bf16 matmul peak whatever dtype a
+#: program stores in: it is the dense figure the sources publish, and the
+#: convention model-FLOP/s utilization is compared under
+MFU_DTYPE = "bfloat16"
 
-    ``platform=None`` keeps bench.py's historical convention: quote MFU
-    against the tpu peak even when measuring on another backend (so CPU
-    container numbers stay comparable across rounds)."""
+
+def peak_tflops(dtype=MFU_DTYPE, device_kind=None):
+    """Published peak in TFLOP/s of one ``device_kind`` device for
+    ``dtype`` (env-overridable).  ``device_kind=None`` asks jax for the
+    first device's kind.  Raises ``KeyError`` for a kind or dtype the
+    table has no sourced row for."""
     for key in ("MXNET_HEALTH_PEAK_TFLOPS", "BENCH_PEAK_TFLOPS"):
         raw = os.environ.get(key)
         if raw:
             return float(raw)
-    table = _PEAK_TFLOPS.get(platform or "tpu", _PEAK_TFLOPS["tpu"])
-    return table.get(str(dtype), table["float32"])
+    if device_kind is None:
+        device_kind = _device_kind()
+    row = _PEAK_TFLOPS.get(device_kind)
+    if row is None:
+        raise KeyError(
+            "no published peak for device_kind %r (known: %s); add a "
+            "sourced row to health._PEAK_TFLOPS or set "
+            "MXNET_HEALTH_PEAK_TFLOPS" % (device_kind,
+                                          sorted(_PEAK_TFLOPS)))
+    tflops = row["tflops"]
+    if None in tflops:
+        return tflops[None]
+    if str(dtype) not in tflops:
+        raise KeyError("no published %s peak for device_kind %r (%s)"
+                       % (dtype, device_kind, row["source"]))
+    return tflops[str(dtype)]
 
 
 def achieved_tflops(rate, flops_per_item):
@@ -156,11 +182,13 @@ def mfu_impossible(mfu, platform):
 
 
 def _platform():
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    import jax
+    return jax.devices()[0].platform
+
+
+def _device_kind():
+    import jax
+    return jax.devices()[0].device_kind
 
 
 # -- program cost accounting ------------------------------------------------
@@ -384,7 +412,6 @@ class StepMonitor(object):
 
     def __init__(self):
         self._lock = threading.Lock()
-        self.dtype = None  # MFU dtype; resolved per-platform when unset
         self.reset()
 
     def reset(self):
@@ -456,10 +483,7 @@ class StepMonitor(object):
         flops = program_flops_total(program)
         mfu = None
         if flops > 0:
-            plat = _platform()
-            dtype = self.dtype or ("bfloat16" if plat == "tpu"
-                                   else "float32")
-            peak = peak_tflops(dtype, platform=plat)
+            peak = peak_tflops(MFU_DTYPE)
             if peak > 0:
                 mfu = 100.0 * flops / (dt * peak * 1e12)
                 _MFU.set(mfu)
@@ -640,14 +664,13 @@ workers = WorkerTable()
 
 def statusz():
     """JSON-able health snapshot served by telemetry/export.py."""
-    plat = _platform()
-    dtype = monitor.dtype or ("bfloat16" if plat == "tpu" else "float32")
     from . import program_cache as _program_cache
     return {
         "enabled": enabled,
-        "platform": plat,
-        "peak_tflops": peak_tflops(dtype, platform=plat),
-        "peak_dtype": dtype,
+        "platform": _platform(),
+        "device_kind": _device_kind(),
+        "peak_tflops": peak_tflops(MFU_DTYPE),
+        "peak_dtype": MFU_DTYPE,
         "programs": {n: pc.as_dict() for n, pc in programs().items()},
         "step": monitor.snapshot(),
         "workers": workers.snapshot(),

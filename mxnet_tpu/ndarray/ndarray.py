@@ -732,10 +732,17 @@ def onehot_encode(indices, out):
 
 
 def waitall():
-    """Block until all async work completes (ref: MXNDArrayWaitAll)."""
+    """Block until all async work completes (ref: MXNDArrayWaitAll): the
+    host engine's queue, ordered host effects, and every computation the
+    device still owes a live array.  ``effects_barrier`` alone waits only
+    for side-effecting programs, so a chain of pure train steps would
+    still be running when it returned."""
     from .. import engine as _engine
     _engine.get().wait_for_all()
-    (jax.effects_barrier if hasattr(jax, "effects_barrier") else lambda: None)()
+    jax.effects_barrier()
+    for arr in jax.live_arrays():
+        if not arr.is_deleted():
+            arr.block_until_ready()
 
 
 # --------------------------------------------------------------------------

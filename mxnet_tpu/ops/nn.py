@@ -654,11 +654,10 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
             out = flash(q, k, v)
             path = "flash_interpret"
         else:
-            # platform resolved at LOWERING time where the jax version
-            # supports branch pruning (advisor r03), trace time otherwise
-            from ..parallel._compat import platform_dependent
-            out = platform_dependent(q, k, v, tpu=flash,
-                                     default=lambda q, k, v: ref(q, k, v))
+            # platform resolved at LOWERING time (advisor r03): the
+            # branch that does not match the target is pruned
+            out = jax.lax.platform_dependent(
+                q, k, v, tpu=flash, default=lambda q, k, v: ref(q, k, v))
             path = "flash"
     else:
         out = ref(q, k, v)

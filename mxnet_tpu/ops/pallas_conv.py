@@ -18,7 +18,7 @@ tap (dh, dw) — so each tap is ONE contiguous row-slice matmul
 with no im2col materialization in HBM and zero in-kernel relayouts.
 Images are laid out on a common 8-aligned frame stride L so NB of them
 stack into one grid step (small-spatial shapes keep the MXU fed); the
-input BlockSpec is element-indexed (``pl.unblocked``) because tap halos
+input BlockSpec is element-indexed (``pl.Element``) because tap halos
 overlap tiles.
 
 Backward is a ``custom_vjp`` whose both arms are also Pallas kernels
@@ -195,8 +195,8 @@ def _conv_s1(x, w_taps, pads: _PadsT, KH, KW, plan: _Plan = None):
         grid=(p.G,),
         in_specs=[
             # element-indexed: tap halos make consecutive slabs overlap
-            pl.BlockSpec((p.SLAB, C), lambda g, _p=p: (g * _p.TILE, 0),
-                         indexing_mode=pl.unblocked),
+            pl.BlockSpec((pl.Element(p.SLAB), pl.Element(C)),
+                         lambda g, _p=p: (g * _p.TILE, 0)),
             pl.BlockSpec((KH * KW, C, O), lambda g: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((p.TILE, O), lambda g: (g, 0)),
@@ -230,8 +230,8 @@ def _wgrad_s1(x, g, pads: _PadsT, KH, KW, plan: _Plan = None):
         kern,
         grid=(p.G,),
         in_specs=[
-            pl.BlockSpec((p.SLAB, C), lambda g_, _p=p: (g_ * _p.TILE, 0),
-                         indexing_mode=pl.unblocked),
+            pl.BlockSpec((pl.Element(p.SLAB), pl.Element(C)),
+                         lambda g_, _p=p: (g_ * _p.TILE, 0)),
             pl.BlockSpec((p.TILE, O), lambda g_: (g_, 0)),
         ],
         out_specs=pl.BlockSpec((KH * KW, C, O), lambda g_: (0, 0, 0)),

@@ -124,7 +124,6 @@ def moe_ffn(x, params: MoEParams, mesh: Optional[Mesh] = None,
         return jnp.einsum("tec,ecd->td", combine.astype(x.dtype),
                           expert_out)
 
-    from ._compat import shard_map
     n = mesh.shape[axis]
     if E % n:
         raise ValueError("num_experts %d not divisible by %s=%d"
@@ -147,10 +146,10 @@ def moe_ffn(x, params: MoEParams, mesh: Optional[Mesh] = None,
         return jnp.einsum("tec,ecd->td", combine.astype(xs.dtype),
                           expert_out)
 
-    f = shard_map(sharded, mesh=mesh,
-                  in_specs=(P(axis, None), P(axis, None, None),
-                            P(axis, None, None)),
-                  out_specs=P(axis, None))
+    f = jax.shard_map(sharded, mesh=mesh,
+                      in_specs=(P(axis, None), P(axis, None, None),
+                                P(axis, None, None)),
+                      out_specs=P(axis, None))
     return f(x, params.w1, params.w2)
 
 

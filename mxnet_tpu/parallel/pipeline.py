@@ -74,8 +74,8 @@ class Pipeline:
         outputs0 = jnp.zeros((m,) + mb_shape, micro_in.dtype)
         prev0 = jnp.zeros(mb_shape, micro_in.dtype)
         # carries vary per stage: mark them device-varying for shard_map
-        from ._compat import pvary
-        outputs0, prev0 = pvary((outputs0, prev0), (self.axis,))
+        outputs0, prev0 = lax.pcast((outputs0, prev0), (self.axis,),
+                                    to="varying")
         (outputs, _), _ = lax.scan(tick, (outputs0, prev0),
                                    jnp.arange(ticks))
         return outputs
@@ -90,7 +90,6 @@ def pipeline_apply(mesh, axis: str, stage_fn: Callable, stage_params,
     ``num_stages`` (leaf shape (S, ...)); each stage sees its own slice.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ._compat import shard_map
 
     s = mesh.shape[axis]
     n = x.shape[0]
@@ -109,7 +108,7 @@ def pipeline_apply(mesh, axis: str, stage_fn: Callable, stage_params,
         keep = (lax.axis_index(axis) == s - 1).astype(outs.dtype)
         return lax.psum(outs * keep, axis)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P())
     out = fn(stage_params, micro)
     return out.reshape((n,) + out.shape[2:])

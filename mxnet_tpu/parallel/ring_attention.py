@@ -78,10 +78,8 @@ def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
             if pa.INTERPRET:   # test hook: force the interpreter on CPU
                 return flash(q, k, v)
             # platform resolved at LOWERING time: CPU-committed arrays on
-            # a TPU host get the scan branch, never Mosaic (advisor r03);
-            # jax versions without branch pruning resolve at trace time
-            from ._compat import platform_dependent
-            return platform_dependent(
+            # a TPU host get the scan branch, never Mosaic (advisor r03)
+            return jax.lax.platform_dependent(
                 q, k, v, tpu=flash,
                 default=partial(blockwise_attention, block_size=block_size,
                                 causal=causal, scale=scale,
@@ -153,8 +151,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     def _pvary(*xs):
         # carries become device-varying after the first ppermute, so the
         # initial values must be marked varying over the ring axis too
-        from ._compat import pvary
-        return pvary(xs, (axis,))
+        return jax.lax.pcast(xs, (axis,), to="varying")
 
     def per_shard_scan(qs, ks, vs):
         idx = jax.lax.axis_index(axis)
@@ -315,28 +312,16 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     def per_shard(qs, ks, vs):
         if pa.INTERPRET:        # test hook: force the interpreter on CPU
             return _ring_flash(qs, ks, vs)
-        from ._compat import platform_dependent
-        return platform_dependent(
+        return jax.lax.platform_dependent(
             qs, ks, vs, tpu=_ring_flash, default=per_shard_scan)
 
-    from ._compat import shard_map
     spec = P(None, None, axis, None)
-    kw = {}
-    if use_flash:
-        # pallas_call inside shard_map is not vma-checkable (the per-shard
-        # kernel's internal slices are unvarying); exactness vs the
-        # checked scan formulation is pinned by tests.  Older jax spells
-        # the flag check_rep — probe the signature instead of catching
-        # TypeError, which would mask real errors.
-        import inspect
-        params = inspect.signature(shard_map).parameters
-        flag = ("check_vma" if "check_vma" in params
-                else "check_rep" if "check_rep" in params else None)
-        if flag:
-            kw = {flag: False}
-    f = shard_map(per_shard if use_flash else per_shard_scan,
-                  mesh=mesh, in_specs=(spec, spec, spec),
-                  out_specs=spec, **kw)
+    # pallas_call inside shard_map is not vma-checkable (the per-shard
+    # kernel's internal slices are unvarying); exactness vs the checked
+    # scan formulation is pinned by tests
+    f = jax.shard_map(per_shard if use_flash else per_shard_scan,
+                      mesh=mesh, in_specs=(spec, spec, spec),
+                      out_specs=spec, check_vma=not use_flash)
     return f(q, k, v)
 
 
@@ -344,8 +329,6 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp",
                       causal: bool = False, scale: Optional[float] = None):
     """Ulysses/DeepSpeed-style: all-to-all so each chip gets ALL sequence for
     a subset of heads, runs full attention locally, then all-to-alls back."""
-    from ._compat import shard_map
-
     n = mesh.shape[axis]
 
     def per_shard(qs, ks, vs):
@@ -359,6 +342,6 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp",
                                   tiled=True)
 
     spec = P(None, None, axis, None)
-    f = shard_map(per_shard, mesh=mesh, in_specs=(spec, spec, spec),
-                  out_specs=spec)
+    f = jax.shard_map(per_shard, mesh=mesh, in_specs=(spec, spec, spec),
+                      out_specs=spec)
     return f(q, k, v)

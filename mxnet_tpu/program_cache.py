@@ -29,11 +29,11 @@ Layered keying:
   jax/jaxlib version + devices).  The env flags are *baked into the
   traced HLO*, so a flag flip changes the traced program and therefore
   the disk key — stale programs cannot be served by construction.
-- **environment fingerprint**: entries live under a
-  ``fp-<digest>`` namespace directory derived from jax/jaxlib versions,
-  backend platform, and device topology, and every entry embeds the
-  digest.  An artifact shipped from a mismatched environment quarantines
-  instead of deserializing.
+- **environment fingerprint**: every entry embeds a digest of the
+  jax/jaxlib versions, backend platform and device topology.  An
+  artifact shipped from a mismatched environment quarantines instead of
+  deserializing.  Entries sit directly in the cache directory: the
+  directory is the caller's to place, and this module never moves it.
 
 Entry format (``*.mxpc``): ``b"MXPC1\\0"`` magic + 16-byte fingerprint
 digest + 32-byte SHA-256 of the payload + payload (jax's compressed
@@ -43,14 +43,23 @@ to ``quarantine/``, counts ``program_cache_errors_total{kind}``, and
 falls back to a fresh compile — a poisoned artifact can never take a
 run down.
 
-Activation: set ``MXNET_PROGRAM_CACHE_DIR`` (the compile sites call
-:func:`ensure_enabled` lazily on their first miss) or call
-:func:`enable` directly.  ``MXNET_PROGRAM_CACHE_MAX_BYTES`` (default
-4 GiB) bounds the namespace with LRU eviction (mtime = recency, bumped
-on every hit).  ``MXNET_PROGRAM_CACHE=0`` force-disables even when the
-dir is set.  Deploy prefill: ``tools/cache_prefill.py`` compiles a
-model's bucket ladder + training step into the cache dir once; ship the
-directory with the model artifact and every replica restarts warm.
+Placement: :func:`resolve_dir` is the one function that decides where
+the cache lives.  ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it
+stands and wins over ``MXNET_PROGRAM_CACHE_DIR``; with neither set the
+cache sits at one fixed path inside the checkout (:data:`DEFAULT_DIR`).
+
+Activation: the program's entry points (``chip_smoke.py``, ``bench.py``,
+the ``main`` of each tool, the serving tools among them; a user's own
+server script does the same) call :func:`place` before their first
+compile.  A process that only imports the library — the test suite, or
+``ModelServer`` used as a class — gets a cache only if ``MXNET_PROGRAM_CACHE_DIR`` is set (the compile
+sites call :func:`ensure_enabled` lazily on their first miss) or it calls
+:func:`enable` itself.  ``MXNET_PROGRAM_CACHE_MAX_BYTES`` (default
+4 GiB) bounds the directory with LRU eviction (mtime = recency, bumped
+on every hit).  ``MXNET_PROGRAM_CACHE=0`` force-disables everywhere.
+Deploy prefill: ``tools/cache_prefill.py`` compiles a model's bucket
+ladder + training step into the cache dir once; ship the directory with
+the model artifact and every replica restarts warm.
 """
 from __future__ import annotations
 
@@ -58,6 +67,7 @@ import atexit
 import hashlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Optional
@@ -67,9 +77,16 @@ from . import telemetry as _telemetry
 
 __all__ = ["enable", "disable", "enabled", "ensure_enabled", "stats",
            "note_memory_hit", "fingerprint", "fingerprint_info",
-           "cache_dir", "DiskProgramCache"]
+           "cache_dir", "resolve_dir", "place", "DiskProgramCache"]
 
 ENV_DIR = "MXNET_PROGRAM_CACHE_DIR"
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+#: where the cache lives when no environment variable places it: one fixed
+#: path inside the checkout (the path is part of jax's cache key, so a
+#: directory that moved would never hit)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 ENV_MAX_BYTES = "MXNET_PROGRAM_CACHE_MAX_BYTES"
 ENV_GATE = "MXNET_PROGRAM_CACHE"
 
@@ -105,9 +122,9 @@ _COMPILES = _telemetry.counter(
     "Fresh XLA compiles persisted while the program cache was enabled "
     "(zero across a warm restart is the deploy-prefill contract)")
 _BYTES = _telemetry.gauge(
-    "program_cache_bytes", "Bytes in the program-cache namespace on disk")
+    "program_cache_bytes", "Bytes in the program-cache directory on disk")
 _ENTRIES = _telemetry.gauge(
-    "program_cache_entries", "Entries in the program-cache namespace")
+    "program_cache_entries", "Entries in the program-cache directory")
 
 
 def fingerprint_info() -> Dict[str, Any]:
@@ -146,14 +163,14 @@ def _digest_of(info: Dict[str, Any]) -> bytes:
 
 
 def fingerprint() -> Optional[str]:
-    """Hex fingerprint of the active cache namespace (None when
+    """Hex fingerprint of the active cache's environment (None when
     disabled)."""
     c = _state.cache
     return c.fingerprint_hex if c is not None else None
 
 
 def cache_dir() -> Optional[str]:
-    """The active namespace directory (None when disabled)."""
+    """The active cache directory (None when disabled)."""
     c = _state.cache
     return c.directory if c is not None else None
 
@@ -323,8 +340,7 @@ class _State:
     def __init__(self) -> None:
         self.cache: Optional[DiskProgramCache] = None
         self.resolved = False          # env config read once
-        self.mode: Optional[str] = None  # "native" | "config"
-        self.root: Optional[str] = None
+        self.prior_dir: Optional[str] = None  # jax's dir before enable()
         self.info: Optional[Dict[str, Any]] = None
         self.memory_hits = 0
         self.atexit_registered = False
@@ -358,95 +374,109 @@ def note_memory_hit() -> None:
     _REQS.labels(tier="memory").inc()
 
 
+def resolve_dir() -> str:
+    """THE placement decision, made in one place: the directory compiled
+    programs persist in.  ``JAX_COMPILATION_CACHE_DIR`` is used exactly as
+    given (no sub-directory) and wins over ``MXNET_PROGRAM_CACHE_DIR``,
+    with a line on stderr saying so; with neither set it is
+    :data:`DEFAULT_DIR`, fixed inside the checkout."""
+    jax_dir = os.environ.get(JAX_ENV_DIR)
+    own_dir = os.environ.get(ENV_DIR)
+    if jax_dir:
+        if own_dir and os.path.abspath(own_dir) != os.path.abspath(jax_dir):
+            sys.stderr.write(
+                "program_cache: %s=%s wins; %s=%s is ignored\n"
+                % (JAX_ENV_DIR, jax_dir, ENV_DIR, own_dir))
+        return jax_dir
+    return own_dir or DEFAULT_DIR
+
+
+def place() -> Optional[str]:
+    """Switch the persistent cache on at :func:`resolve_dir`.  Entry
+    points call this before their first compile; returns the directory
+    in use (None when ``MXNET_PROGRAM_CACHE=0``).  Idempotent."""
+    if not get_env(ENV_GATE, True, bool):
+        return None
+    cache = enable(resolve_dir())
+    return cache.directory if cache is not None else None
+
+
 def ensure_enabled() -> bool:
-    """Resolve the env config once and enable the cache if
-    ``MXNET_PROGRAM_CACHE_DIR`` names a directory.  Called lazily from
-    every whole-graph compile site on its miss path — i.e. right before
-    jax is about to trace+compile, so touching the backend here is
-    safe."""
+    """The library's lazy switch: enable the cache if
+    ``MXNET_PROGRAM_CACHE_DIR`` asks for one (at :func:`resolve_dir`, so
+    jax's own variable still wins).  Called from every whole-graph
+    compile site on its miss path — i.e. right before jax is about to
+    trace+compile, so touching the backend here is safe.  Without that
+    variable an importing process gets no cache from this module."""
     if _state.resolved:
         return _state.cache is not None
     with _lock:
         if _state.resolved:
             return _state.cache is not None
-        root = os.environ.get(ENV_DIR)
-        if not root or not get_env(ENV_GATE, True, bool):
+        if not os.environ.get(ENV_DIR) or not get_env(ENV_GATE, True, bool):
             _state.resolved = True
             return False
     # enable() takes _lock itself and sets resolved
-    return enable(root) is not None
+    return enable(resolve_dir()) is not None
 
 
-def _install_into_jax(cache: DiskProgramCache, namespace: str) -> str:
-    """Point jax's persistent compilation cache at ``cache``.
-
-    Preferred ("native") mode replaces the module-level cache object in
-    ``jax._src.compilation_cache`` so every ``compile_or_get_cached``
-    lookup flows through our checksum/quarantine/LRU layer.  If those
-    internals ever move, fall back to the public config knobs alone
-    ("config" mode — jax's own LRUCache over the same namespace dir:
-    still a working persistent cache, minus validation/telemetry).
-    """
+def _install_into_jax(cache: DiskProgramCache) -> None:
+    """Point jax's persistent compilation cache at ``cache``: replace the
+    module-level cache object in ``jax._src.compilation_cache`` so every
+    ``compile_or_get_cached`` lookup flows through our checksum/
+    quarantine/LRU layer.  One behaviour: if those internals move, this
+    raises and the move is repaired here."""
     import jax
+    from jax._src import compilation_cache as _cc
     jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", namespace)
+    if jax.config.jax_compilation_cache_dir != cache.directory:
+        # never reached when JAX_COMPILATION_CACHE_DIR placed the cache:
+        # jax read that variable itself and the directory is the same
+        jax.config.update("jax_compilation_cache_dir", cache.directory)
     # persist everything: whole-step programs on CPU can compile in
     # <1s, and tiny glue programs (broadcasts, transfers) must load too
     # for the zero-compile contract to hold
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        from jax._src import compilation_cache as _cc
-        with _cc._cache_initialized_mutex:
-            _cc._cache = cache
-            _cc._cache_initialized = True
-            # re-evaluate the one-shot "is the cache used" verdict in
-            # case compiles already happened before enable()
-            _cc._cache_checked = False
-            _cc._cache_used = False
-        return "native"
-    except Exception:
-        return "config"
+    with _cc._cache_initialized_mutex:
+        _cc._cache = cache
+        _cc._cache_initialized = True
+        # re-evaluate the one-shot "is the cache used" verdict in
+        # case compiles already happened before enable()
+        _cc._cache_checked = False
+        _cc._cache_used = False
 
 
-def _uninstall_from_jax() -> None:
+def _uninstall_from_jax(restore_dir: Optional[str]) -> None:
     import jax
-    try:
-        from jax._src import compilation_cache as _cc
-        with _cc._cache_initialized_mutex:
-            _cc._cache = None
-            _cc._cache_initialized = False
-            _cc._cache_checked = False
-            _cc._cache_used = False
-    except Exception:
-        pass
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
+    from jax._src import compilation_cache as _cc
+    with _cc._cache_initialized_mutex:
+        _cc._cache = None
+        _cc._cache_initialized = False
+        _cc._cache_checked = False
+        _cc._cache_used = False
+    if jax.config.jax_compilation_cache_dir != restore_dir:
+        jax.config.update("jax_compilation_cache_dir", restore_dir)
 
 
 def enable(root: Optional[str] = None,
            max_bytes: Optional[int] = None) -> Optional[DiskProgramCache]:
-    """Enable the persistent program cache under ``root`` (default
-    ``MXNET_PROGRAM_CACHE_DIR``).  Idempotent: returns the live cache if
-    already enabled.  Returns None when no directory is configured."""
+    """Enable the persistent program cache in ``root`` (default:
+    :func:`resolve_dir`).  Idempotent: returns the live cache if already
+    enabled.  Returns None when the directory is unusable."""
     with _lock:
         if _state.cache is not None:
             _state.resolved = True
             return _state.cache
-        root = root or os.environ.get(ENV_DIR)
+        root = root or resolve_dir()
         _state.resolved = True
-        if not root:
-            return None
         if max_bytes is None:
             max_bytes = get_env(ENV_MAX_BYTES, 4 * 1024 ** 3, int)
         info = fingerprint_info()
         digest = _digest_of(info)
-        namespace = os.path.join(root, "fp-%s" % digest.hex())
         try:
-            os.makedirs(namespace, exist_ok=True)
-            manifest = os.path.join(namespace, "manifest.json")
+            os.makedirs(root, exist_ok=True)
+            manifest = os.path.join(root, "manifest.json")
             if not os.path.exists(manifest):
                 tmp = manifest + ".tmp.%d" % os.getpid()
                 with open(tmp, "w", encoding="utf-8") as f:
@@ -454,14 +484,15 @@ def enable(root: Optional[str] = None,
                                "created": round(time.time(), 3)}, f,
                               indent=1, sort_keys=True)
                 os.replace(tmp, manifest)
-            cache = DiskProgramCache(namespace, digest, int(max_bytes))
+            cache = DiskProgramCache(root, digest, int(max_bytes))
         except OSError:
             # unusable directory: stay disabled rather than crash
             _ERRORS.labels(kind="io").inc()
             return None
-        _state.mode = _install_into_jax(cache, namespace)
+        import jax
+        _state.prior_dir = jax.config.jax_compilation_cache_dir
+        _install_into_jax(cache)
         _state.cache = cache
-        _state.root = root
         _state.info = info
         if not _state.atexit_registered:
             _state.atexit_registered = True
@@ -469,9 +500,8 @@ def enable(root: Optional[str] = None,
     try:
         from . import runlog as _runlog
         _runlog.event("program_cache_start", dir=root,
-                      namespace=namespace, fingerprint=digest.hex(),
-                      mode=_state.mode, max_bytes=int(max_bytes),
-                      info=info)
+                      fingerprint=digest.hex(),
+                      max_bytes=int(max_bytes), info=info)
     except Exception:
         pass
     return _state.cache
@@ -487,10 +517,9 @@ def disable() -> None:
             _state.memory_hits = 0
             return
         _log_summary()
-        _uninstall_from_jax()
+        _uninstall_from_jax(_state.prior_dir)
         _state.cache = None
-        _state.mode = None
-        _state.root = None
+        _state.prior_dir = None
         _state.info = None
         _state.resolved = False
         _state.memory_hits = 0
@@ -515,8 +544,7 @@ def stats() -> Dict[str, Any]:
         pass
     out.update(c.stats)
     out.update({
-        "dir": _state.root, "namespace": c.directory,
-        "fingerprint": c.fingerprint_hex, "mode": _state.mode,
+        "dir": c.directory, "fingerprint": c.fingerprint_hex,
         "max_bytes": c.max_bytes,
         "bytes": sum(entries), "entries": len(entries),
     })

@@ -205,16 +205,16 @@ def enable(path: Optional[str] = None,
         _log = RunLog(p, run_id=run_id)
         _topology_noted = False
         log = _log
-    # cache identity without forcing jax backend init: dir comes from the
-    # env; the fingerprint is known only once program_cache.enable() ran
-    # (which then also logs a full "program_cache_start" event)
+    # cache identity without forcing jax backend init: directory and
+    # fingerprint are known only once program_cache.enable() ran (which
+    # then also logs a full "program_cache_start" event)
     from . import program_cache as _program_cache
     log.event("run_start",
               argv=list(sys.argv),
               env=_env_snapshot(),
               python="%d.%d.%d" % sys.version_info[:3],
               pid=os.getpid(),
-              program_cache_dir=os.environ.get("MXNET_PROGRAM_CACHE_DIR"),
+              program_cache_dir=_program_cache.cache_dir(),
               program_cache_fingerprint=_program_cache.fingerprint())
     return log
 

@@ -43,21 +43,44 @@ def _clean():
 # shared MFU helpers (the code bench.py's two hand-rolled blocks became)
 # ---------------------------------------------------------------------------
 class TestHelpers:
-    def test_peak_table(self, monkeypatch):
+    @pytest.mark.parametrize("dtype,kind,want", [
+        ("bfloat16", "TPU v5 lite", 197.0),     # published rows
+        ("int8", "TPU v5 lite", 393.0),
+        ("float32", "cpu", 0.25),               # CPU row: any dtype, a
+        ("bfloat16", "cpu", 0.25),              # convention, not a peak
+        ("bfloat16", None, 0.25),               # None -> jax's kind (cpu)
+    ])
+    def test_peak_table(self, monkeypatch, dtype, kind, want):
         monkeypatch.delenv("MXNET_HEALTH_PEAK_TFLOPS", raising=False)
         monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
-        # platform=None keeps bench.py's historical quote-against-tpu-peak
-        assert health.peak_tflops("bfloat16") == 197.0
-        assert health.peak_tflops("float32") == 99.0
-        assert health.peak_tflops("int8") == 99.0       # unknown -> f32
-        assert health.peak_tflops("float32", platform="cpu") == 0.25
+        assert health.peak_tflops(dtype, device_kind=kind) == want
 
-    def test_peak_env_overrides(self, monkeypatch):
-        monkeypatch.setenv("BENCH_PEAK_TFLOPS", "123.0")
-        assert health.peak_tflops("bfloat16") == 123.0
+    @pytest.mark.parametrize("dtype,kind", [
+        ("bfloat16", "TPU v9 imaginary"),       # unknown kind: no default
+        ("bfloat16", "tpu"),                    # a platform is not a kind
+        ("float32", "TPU v5 lite"),             # no published f32 figure
+    ])
+    def test_peak_unknown_raises(self, monkeypatch, dtype, kind):
+        monkeypatch.delenv("MXNET_HEALTH_PEAK_TFLOPS", raising=False)
+        monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
+        with pytest.raises(KeyError):
+            health.peak_tflops(dtype, device_kind=kind)
+
+    def test_peak_rows_name_their_source(self):
+        for kind, row in health._PEAK_TFLOPS.items():
+            assert row["source"], kind
+
+    @pytest.mark.parametrize("env,want", [
+        ({"BENCH_PEAK_TFLOPS": "123.0"}, 123.0),
         # the health-specific knob wins over the bench one
-        monkeypatch.setenv("MXNET_HEALTH_PEAK_TFLOPS", "7.5")
-        assert health.peak_tflops("bfloat16") == 7.5
+        ({"BENCH_PEAK_TFLOPS": "123.0",
+          "MXNET_HEALTH_PEAK_TFLOPS": "7.5"}, 7.5),
+    ])
+    def test_peak_env_overrides(self, monkeypatch, env, want):
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        # the override answers even for a kind the table does not know
+        assert health.peak_tflops("bfloat16", "TPU v9 imaginary") == want
 
     def test_achieved_and_fraction(self):
         # 1000 items/s at 1 GFLOP/item = 1 TFLOP/s; 50% of a 2-TFLOP peak

@@ -1,0 +1,129 @@
+"""The chip's compiler, asked without the chip: every Pallas entry point of
+the main path must compile for a described TPU v5e at the widths
+``chip_smoke.py`` and the benchmark run them at.
+
+A compile that passes here is not a chip run and says nothing about
+results or times; it catches what interpret-mode tests cannot (a slice
+off the tiling, too much VMEM, an API spelling the installed jax dropped).
+
+This is the only file that describes a topology.  It does so inside a
+module-scoped, non-autouse fixture, never at import, in a ``skipif`` or in
+``parametrize``: only the process that runs these tests loads the TPU
+library, and it compiles in-process.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "cannot"
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without the chip; keep it out of the way."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _custom_calls(fn, *avals):
+    """Compile ``fn`` for the device the avals are placed on; the number
+    of Mosaic kernels in the result."""
+    return jax.jit(fn).lower(*avals).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def _sq(fn):
+    """Scalar loss over every output of ``fn`` (for ``jax.grad``)."""
+    def loss(*args):
+        return sum(jnp.sum(o.astype(jnp.float32) ** 2)
+                   for o in jax.tree_util.tree_leaves(fn(*args)))
+    return loss
+
+
+def test_described_device_is_the_v5e(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    from mxnet_tpu import health
+    assert health.peak_tflops("bfloat16", topo.devices[0].device_kind) == 197.0
+
+
+@pytest.mark.parametrize("direction", ["forward", "forward+backward"])
+def test_flash_attention_compiles(one_chip, direction):
+    from mxnet_tpu.ops import pallas_attention as pa
+    B, H, T, D = 8, 12, 2048, 64          # chip_smoke's attention width
+    assert pa.flash_attention_available(B, H, T, T, D, jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
+    fn = functools.partial(pa.flash_attention, causal=True)
+    if direction == "forward":
+        assert _custom_calls(fn, q, q, q) == 1
+    else:       # forward-with-lse, dq, dk/dv
+        assert _custom_calls(jax.grad(_sq(fn), (0, 1, 2)), q, q, q) == 3
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_attention_ring_variant_compiles(one_chip, direction):
+    """The stats-emitting kernel ring attention runs per shard, and the
+    backward it feeds with full-sequence stats."""
+    from mxnet_tpu.ops import pallas_attention as pa
+    B, H, T, D = 8, 12, 2048, 64
+    q = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((B, H, T), jnp.float32, sharding=one_chip)
+    if direction == "forward":
+        fn = functools.partial(pa.flash_attention_stats, causal=True,
+                               scale=D ** -0.5)
+        assert _custom_calls(fn, q, q, q) == 1
+    else:
+        fn = functools.partial(pa.flash_attention_bwd, causal=False,
+                               scale=D ** -0.5)
+        assert _custom_calls(fn, q, q, q, q, stat, stat) == 2
+
+
+@pytest.mark.parametrize("direction", ["forward", "forward+backward"])
+def test_lstm_scan_compiles(one_chip, direction):
+    from mxnet_tpu.ops import pallas_rnn
+    T, B, H = 35, 128, 650                # the LSTM-LM benchmark width
+    assert pallas_rnn.lstm_scan_available(B, H, jnp.bfloat16)
+
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    args = (aval(T, B, 4 * H), aval(B, H), aval(B, H), aval(4 * H, H),
+            aval(4 * H))
+    if direction == "forward":
+        assert _custom_calls(pallas_rnn.lstm_scan, *args) == 1
+    else:
+        grad = jax.grad(_sq(pallas_rnn.lstm_scan), (0, 1, 2, 3, 4))
+        assert _custom_calls(grad, *args) == 2
+
+
+def test_conv3x3_compiles(one_chip):
+    """One ResNet-50 stage shape past the lane gate (stage 3: 256 channels,
+    14 px, batch 128): forward, dgrad and wgrad are all Pallas."""
+    from mxnet_tpu.ops import pallas_conv
+    N, C, HW = 128, 256, 14
+    assert pallas_conv._plan(N, HW, HW, C, C, 3, 3, ((1, 1), (1, 1)), 2)
+    x = jax.ShapeDtypeStruct((N, C, HW, HW), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((C, C, 3, 3), jnp.bfloat16, sharding=one_chip)
+    grad = jax.grad(_sq(pallas_conv.conv3x3_same), (0, 1))
+    assert _custom_calls(grad, x, w) == 3

@@ -126,6 +126,8 @@ def bench_ps(iters: int):
 
 
 def main():
+    from mxnet_tpu import program_cache
+    program_cache.place()       # the one decision on where compiles persist
     ap = argparse.ArgumentParser()
     ap.add_argument("--size-mb", type=float, default=64.0)
     ap.add_argument("--iters", type=int, default=10)

@@ -380,6 +380,8 @@ def canonical_round(doc, round_name, source):
 
 
 def main():
+    from mxnet_tpu import program_cache
+    program_cache.place()       # the one decision on where compiles persist
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--in-dim", type=int, default=64)
     ap.add_argument("--hidden", type=int, default=256)

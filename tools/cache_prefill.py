@@ -2,11 +2,15 @@
 """Deploy prefill for the persistent compiled-program cache.
 
 Compiles a model's serving bucket ladder and (optionally) its fused
-training step ONCE into ``MXNET_PROGRAM_CACHE_DIR``, so the cache
+training step ONCE into the program-cache directory, so the cache
 directory can ship with the model artifact and every replica restarts
 warm: ready-to-serve / step-1 with **zero** XLA compiles, just disk
 reads (see mxnet_tpu/program_cache.py and docs/serving.md "Deploy
-prefill").
+prefill").  The directory is ``--cache-dir``, else wherever
+``program_cache.resolve_dir()`` places it; a ``JAX_COMPILATION_CACHE_DIR``
+in the environment wins over ``--cache-dir`` too, and the workers say so.
+This process never touches jax: the cold and the warm worker run one
+after the other and each has the device to itself.
 
 Modes:
 
@@ -16,7 +20,9 @@ Modes:
                    fresh subprocess and assert zero fresh XLA compiles
                    (``program_cache`` puts == misses == 0); reports
                    cold/warm seconds and the speedup.
-- ``--smoke``    — CI probe: tiny MLP, throwaway cache dir under /tmp,
+- ``--smoke``    — CI probe: tiny MLP, throwaway cache dir under /tmp
+                   (a cold cache is what it tests, so jax's placement
+                   variable is dropped from the workers' environment),
                    CPU pinned, prefill + verify + assertions; prints
                    ``{"probe": "cache_prefill", "ok": true, ...}``.
 - ``--worker``   — internal: the subprocess entry that actually runs the
@@ -112,7 +118,7 @@ def _train_step(args):
     data_shape = (batch,) + example["data"]
     mod = mx.mod.Module(sym, data_names=("data",),
                         label_names=("softmax_label",),
-                        context=[mx.cpu()])
+                        context=[mx.current_context()])
     mod.bind(data_shapes=[("data", data_shape)],
              label_shapes=[("softmax_label", (batch,))])
     mx.random.seed(7)
@@ -158,7 +164,9 @@ def run_worker(args):
         jax.config.update("jax_platforms", args.platform)
     from mxnet_tpu import program_cache, telemetry
     telemetry.enable()
-    out = {"cache_dir": os.environ.get(program_cache.ENV_DIR)}
+    out = {"cache_dir": program_cache.place(),
+           "platform": jax.devices()[0].platform,
+           "device_kind": jax.devices()[0].device_kind}
     if args.serve:
         out["serving_warmup_seconds"] = round(_serve_ladder(args), 6)
     if args.train:
@@ -200,10 +208,9 @@ def _spawn(args, extra_env, tag):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--cache-dir",
-                    default=os.environ.get("MXNET_PROGRAM_CACHE_DIR"),
-                    help="program-cache directory to prefill "
-                         "(default: $MXNET_PROGRAM_CACHE_DIR)")
+    ap.add_argument("--cache-dir", default=None,
+                    help="program-cache directory to prefill (default: "
+                         "where program_cache.resolve_dir() places it)")
     ap.add_argument("--model", choices=("mlp", "resnet50"), default="mlp")
     ap.add_argument("--buckets", default="1,2,4,8",
                     help="serving bucket ladder (comma-separated)")
@@ -246,16 +253,16 @@ def main(argv=None):
         args.buckets, args.bucket_list = "1,2", (1, 2)
         tmp = tempfile.mkdtemp(prefix="mxpc_smoke_")
         args.cache_dir = tmp
-    if not args.cache_dir:
-        ap.error("--cache-dir (or $MXNET_PROGRAM_CACHE_DIR) is required")
-    os.makedirs(args.cache_dir, exist_ok=True)
-
-    wenv = {"MXNET_PROGRAM_CACHE_DIR": args.cache_dir}
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    wenv = {}
+    if args.cache_dir:
+        os.makedirs(args.cache_dir, exist_ok=True)
+        wenv["MXNET_PROGRAM_CACHE_DIR"] = args.cache_dir
     try:
         cold = _spawn(args, wenv, "prefill")
         doc = {"tool": "cache_prefill", "model": args.model,
                "buckets": list(args.bucket_list),
-               "cache_dir": args.cache_dir, "cold": cold}
+               "cache_dir": cold["cache_dir"], "cold": cold}
         if args.verify:
             warm = _spawn(args, wenv, "verify")
             doc["warm"] = warm

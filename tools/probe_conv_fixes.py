@@ -77,6 +77,8 @@ def stem_s2d_weights(w):
 
 
 def main():
+    from mxnet_tpu import program_cache
+    program_cache.place()       # the one decision on where compiles persist
     N = 128
     rng = np.random.default_rng(0)
 
@@ -205,4 +207,8 @@ def main():
 
 
 if __name__ == "__main__":
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     main()

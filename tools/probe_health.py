@@ -2,9 +2,11 @@
 """Probe: run ``bench.py`` with the health monitor on and validate the
 exported health evidence.
 
-``--smoke`` shrinks the bench (tiny batch/image, few iters, no LSTM /
-phase-breakdown satellites) so the probe finishes in a couple of minutes
-on a CPU dev box; without it the full resnet50 bench runs.  Asserts the
+``--smoke`` runs ``bench.py --smoke`` (tiny batch/image, few iters, no
+LSTM / phase-breakdown satellites) pinned to CPU so the probe finishes in
+a couple of minutes on a dev box; without it the full resnet50 bench runs
+and needs the TPU.  This process never touches jax: the one child owns the
+device.  Asserts the
 acceptance contract of the health PR: the bench JSON carries a nested
 ``health`` object with live XLA-counted ``program_flops`` /
 ``program_hbm_bytes``, a ``step_mfu_pct`` gauge value, a verdict cause,
@@ -30,14 +32,12 @@ def main(argv):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["BENCH_HEALTH"] = "1"
+    cmd = [sys.executable, os.path.join(repo, "bench.py")]
     if smoke:
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        env.update({"BENCH_BATCH": "8", "BENCH_IMAGE": "64",
-                    "BENCH_ITERS": "3", "BENCH_WARMUP": "2",
-                    "BENCH_LSTM": "0", "BENCH_PHASES": "0"})
+        env["JAX_PLATFORMS"] = "cpu"
+        cmd.append("--smoke")
     proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        env=env, cwd=repo, capture_output=True, text=True,
+        cmd, env=env, cwd=repo, capture_output=True, text=True,
         timeout=900 if smoke else 3000)
     if proc.returncode != 0:
         print("bench failed (rc=%d)\n--- stdout ---\n%s\n--- stderr ---\n%s"

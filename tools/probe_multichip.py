@@ -1,8 +1,11 @@
 #!/usr/bin/env python
 """Probe: run ``bench.py --multichip`` and validate the emitted JSON.
 
-``--smoke`` uses the tiny MLP model so the probe finishes in ~1 min on a
-dev box (virtual CPU devices); without it the real resnet50 workload runs.
+``--smoke`` runs ``bench.py --multichip --smoke`` (tiny MLP) on eight
+virtual CPU devices so the probe finishes in ~1 min on a dev box; without
+it the real resnet50 workload runs over every visible TPU device and fails
+with fewer than two.  This process never touches jax: the one child owns
+the devices.
 Asserts the record carries the multichip contract keys — the driver and
 docs/perf_analysis.md both key on ``img_per_sec`` and
 ``scaling_efficiency`` — and that the mesh-fused path actually dispatched.
@@ -29,11 +32,13 @@ def main(argv):
     out.close()
     env = dict(os.environ)
     env["MULTICHIP_OUT"] = out.name
+    cmd = [sys.executable, os.path.join(repo, "bench.py"), "--multichip"]
     if smoke:
-        env["BENCH_MULTICHIP_MODEL"] = "mlp"
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        cmd.append("--smoke")
     proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--multichip"],
-        env=env, cwd=repo, capture_output=True, text=True,
+        cmd, env=env, cwd=repo, capture_output=True, text=True,
         timeout=600 if smoke else 3000)
     if proc.returncode != 0:
         print("bench --multichip failed (rc=%d)\n--- stdout ---\n%s\n"

@@ -165,6 +165,8 @@ def probe_shape(name, N, C, O, HW, stride, smoke):
 
 
 def main(argv):
+    from mxnet_tpu import program_cache
+    program_cache.place()       # the one decision on where compiles persist
     smoke = "--smoke" in argv
     import jax
     from mxnet_tpu.ops import pallas_conv as pc

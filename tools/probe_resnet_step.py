@@ -40,6 +40,8 @@ def time_chain(step, x0, chain):
 
 
 def main():
+    from mxnet_tpu import program_cache
+    program_cache.place()       # the one decision on where compiles persist
     N = 128
     rng = np.random.default_rng(0)
 
@@ -138,4 +140,8 @@ def main():
 
 
 if __name__ == "__main__":
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     main()

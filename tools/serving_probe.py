@@ -62,6 +62,8 @@ def build_int_mlp(seed):
 
 
 def main(argv):
+    from mxnet_tpu import program_cache
+    program_cache.place()       # the one decision on where compiles persist
     smoke = "--smoke" in argv
     import jax
     from mxnet_tpu import health, telemetry
