@@ -26,6 +26,7 @@ donated buffer is ever double-used.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 
 import jax
@@ -35,6 +36,7 @@ from . import atlas as _atlas
 from . import telemetry as _telemetry
 from . import health as _health
 from . import memwatch as _memwatch
+from . import profiler as _profiler
 
 __all__ = ["enabled", "mesh_enabled", "ModuleFusedStep",
            "TrainerFusedUpdate", "TrainerMeshUpdate", "DonationPool",
@@ -52,6 +54,36 @@ STEP_TIME = _telemetry.histogram(
     "step_update_seconds",
     "Wall time of the train-step update phase (fused path: the whole "
     "fwd+bwd+update program; eager path: the per-param update loop)")
+
+
+DONATION_COPIES = _telemetry.counter(
+    "donation_copies_total",
+    "Buffers DonationPool copied before donating them (a handle the pool "
+    "did not own: every leaf on the first fused step and after set_params, "
+    "none in steady state), by the step path that took them",
+    ("path",))
+DONATION_COPY_BYTES = _telemetry.counter(
+    "donation_copy_bytes_total",
+    "Bytes of the buffers counted by donation_copies_total",
+    ("path",))
+
+
+def _span(name, args=None):
+    """One phase of the step timeline (docs/observability.md "Step
+    timeline"): the same names on every Module fused path."""
+    return _profiler.span(name, "step", args=args)
+
+
+@contextlib.contextmanager
+def _gather_span(pool):
+    """``Step::gather``, with what ``pool`` copied inside it in its args;
+    the caller fills in ``leaves``."""
+    args = {"leaves": 0}
+    copies0, bytes0 = pool.copies, pool.copy_bytes
+    with _span("Step::gather", args):
+        yield args
+        args["copies"] = pool.copies - copies0
+        args["copy_bytes"] = pool.copy_bytes - bytes0
 
 
 def enabled():
@@ -90,14 +122,29 @@ class DonationPool:
     (externally written handles may share their buffer with caller-held
     arrays via no-op device_put/astype/broadcast_to).  ``give`` writes a
     program output back into the handle and records it as pool-owned.
+    Every copy is counted (``copies``, ``copy_bytes``, and the telemetry
+    pair ``donation_copies_total`` / ``donation_copy_bytes_total``): a copy
+    in steady state costs a step's worth of HBM traffic and would show
+    nowhere else.
     """
 
     def __init__(self):
         self._own = {}
+        self.copies = 0
+        self.copy_bytes = 0
+
+    def count_copy(self, path, src):
+        nbytes = int(getattr(src, "nbytes", 0))
+        self.copies += 1
+        self.copy_bytes += nbytes
+        if _telemetry.enabled:
+            DONATION_COPIES.labels(path=path).inc()
+            DONATION_COPY_BYTES.labels(path=path).inc(nbytes)
 
     def take(self, slot, handle):
         cur = handle._data
         if self._own.get(slot) is not cur:
+            self.count_copy("fused", cur)
             cur = jnp.array(cur)
         return cur
 
@@ -112,6 +159,7 @@ class DonationPool:
         if self._own.get(slot) is cur and \
                 getattr(cur, "sharding", None) == sharding:
             return cur
+        self.count_copy("mesh_fused", cur)
         return jax.device_put(jnp.array(cur), sharding)
 
     def give(self, slot, handle, new_data):
@@ -207,6 +255,7 @@ class ModuleFusedStep:
         self._mesh_cache = None      # (key, (mesh, rules, dp_axis)|None)
         self._meshed = False         # handles currently hold mesh globals
         self._mesh_outputs = None    # full-batch outputs of the last step
+        self.steps = 0               # fused steps since bind (Step::update)
         # program closures capture the optimizer binding; a new driver
         # (new init_optimizer / rebind) must not reuse a predecessor's
         for ex in self._eg.execs:
@@ -305,29 +354,14 @@ class ModuleFusedStep:
         dispatch path taken ("fused" / "mesh_fused", both truthy) or False
         (after replaying the batch eagerly) when the updater state turns
         out not to be fusable, so Module.update can run the eager loop."""
-        m = self._mod
-        opt_ = m._optimizer
         ndev = len(self._eg.execs)
-        arity = opt_.fused_state_arity()
-        # validate any pre-existing (e.g. preloaded) updater states before
-        # touching counts or consuming the pending feed.  Expected layout
-        # is per-slot: a low-precision weight's state carries the
-        # master-fp32 leaf on top of the optimizer's own arity.
-        from . import optimizer as _opt
-        states = m._updater.states
-        for slot, st in states.items():
-            i, k = divmod(slot, ndev)
-            if not (0 <= i < len(m._param_names) and k < ndev):
-                self._unsupported = True
-                self.flush_eager()
-                return False
-            w = self._eg.execs[k].arg_dict.get(m._param_names[i])
-            mp = w is not None and opt_.fused_mp(w)
-            leaves = _opt.fused_state_leaves(st, mp)
-            if leaves is None or len(leaves) != arity + (1 if mp else 0):
-                self._unsupported = True
-                self.flush_eager()
-                return False
+        with _span("Step::validate"):
+            ok = self._states_fusable(ndev)
+        if not ok:
+            self._unsupported = True
+            self.flush_eager()
+            return False
+        self.steps += 1
         if ndev == 1:
             self._step_single()
             return "fused"
@@ -336,10 +370,32 @@ class ModuleFusedStep:
         self._demesh()
         staged, self._pending = self._pending, None
         if staged is not None:
-            for ex, feed in zip(self._eg.execs, staged.feeds()):
+            with _span("Step::feed"):
+                feeds = staged.feeds()
+            for ex, feed in zip(self._eg.execs, feeds):
                 ex.forward_backward(**feed)
         self._update_multi()
         return "fused"
+
+    def _states_fusable(self, ndev):
+        """Validate any pre-existing (e.g. preloaded) updater states before
+        touching counts or consuming the pending feed.  Expected layout is
+        per-slot: a low-precision weight's state carries the master-fp32
+        leaf on top of the optimizer's own arity."""
+        from . import optimizer as _opt
+        m = self._mod
+        opt_ = m._optimizer
+        arity = opt_.fused_state_arity()
+        for slot, st in m._updater.states.items():
+            i, k = divmod(slot, ndev)
+            if not (0 <= i < len(m._param_names) and k < ndev):
+                return False
+            w = self._eg.execs[k].arg_dict.get(m._param_names[i])
+            mp = w is not None and opt_.fused_mp(w)
+            leaves = _opt.fused_state_leaves(st, mp)
+            if leaves is None or len(leaves) != arity + (1 if mp else 0):
+                return False
+        return True
 
     def _slots_for_device(self, ex, k, ndev):
         """Create-missing-state + count + capture per-slot scalars, in the
@@ -370,20 +426,27 @@ class ModuleFusedStep:
                 else opt_.fused_update for s in slots]
 
     def _gather_update_inputs(self, ex, k, slots):
-        """Pool-guarded param/state buffers + per-slot scalar arrays."""
+        """Pool-guarded param/state buffers + the scalar arrays."""
         m = self._mod
         pool = self._pools[k]
         states = m._updater.states
         pvals, svals = [], []
-        for name, slot, _, _, _ in slots:
-            pvals.append(pool.take(("w", name), ex.arg_dict[name]))
-            leaves = self._slot_leaves(ex, name, states[slot])
-            svals.append(tuple(pool.take(("s", slot, j), leaf)
-                               for j, leaf in enumerate(leaves)))
-        lrs = jnp.asarray([s[2] for s in slots], jnp.float32)
-        wds = jnp.asarray([s[3] for s in slots], jnp.float32)
-        ts = jnp.asarray([s[4] for s in slots], jnp.float32)
-        return pvals, svals, lrs, wds, ts
+        with _gather_span(pool) as args:
+            for name, slot, _, _, _ in slots:
+                pvals.append(pool.take(("w", name), ex.arg_dict[name]))
+                leaves = self._slot_leaves(ex, name, states[slot])
+                svals.append(tuple(pool.take(("s", slot, j), leaf)
+                                   for j, leaf in enumerate(leaves)))
+            args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
+            scalars = self._slot_scalars(slots)
+        return (pvals, svals) + scalars
+
+    def _slot_scalars(self, slots):
+        """(lrs, wds, ts, rescale): four small host-to-device copies."""
+        return (jnp.asarray([s[2] for s in slots], jnp.float32),
+                jnp.asarray([s[3] for s in slots], jnp.float32),
+                jnp.asarray([s[4] for s in slots], jnp.float32),
+                jnp.asarray(self._mod._optimizer.rescale_grad, jnp.float32))
 
     def _writeback(self, ex, k, slots, new_p, new_s):
         pool = self._pools[k]
@@ -395,93 +458,105 @@ class ModuleFusedStep:
                 pool.give(("s", slot, j), leaf, arr)
 
     def _step_single(self):
-        from . import profiler as _profiler
         from .ndarray.ndarray import NDArray
-        m = self._mod
-        opt_ = m._optimizer
         ex = self._eg.execs[0]
         staged, self._pending = self._pending, None
-        feeds = staged.feeds() if staged is not None else None
-        for kname, v in (feeds[0] if feeds else {}).items():
-            dst = ex.arg_dict[kname]
-            if isinstance(v, NDArray):
-                # adopt pre-placed producer batches as-is (PrefetchingIter
-                # device double buffering): no re-put, no same-dtype astype
-                src = v._data
-                dst._data = src if src.dtype == dst.dtype \
-                    else src.astype(dst.dtype)
-            else:
-                dst._data = jnp.asarray(v, dst.dtype)
-        slots = self._slots_for_device(ex, 0, 1)
-        pvals, svals, lrs, wds, ts = self._gather_update_inputs(ex, 0, slots)
-        rescale = jnp.asarray(opt_.rescale_grad, jnp.float32)
-        others = [ex.arg_dict[n]._data for n in ex.arg_names
-                  if n not in self._pset]
-        auxs = [ex.aux_dict[n]._data for n in ex.aux_names]
-        plan = ex._plan(True)
-        keys = ex._keys(plan)
-        ex._last_keys = keys
-        ogs = ex._default_ograds()
-        update_fns = self._update_fns(ex, slots)
-        first_run = ex._step_key() not in ex._jitted
-        fn = ex.step_program([s[0] for s in slots], update_fns)
-        if first_run and _health.enabled:
-            # lowering-only analysis — the dispatch below still owns the
-            # one and only compilation of this program
-            _health.register_program(
-                "step", fn, (pvals, svals, others, auxs, keys, ogs, lrs,
-                             wds, ts, rescale), donated=True,
-                env=ex._program_env(plan))
-        with _profiler.span("Executor::FusedStep", "executor",
-                            args={"first_run": first_run}):
+        with _span("Step::feed"):
+            feeds = staged.feeds() if staged is not None else None
+            for kname, v in (feeds[0] if feeds else {}).items():
+                dst = ex.arg_dict[kname]
+                if isinstance(v, NDArray):
+                    # adopt pre-placed producer batches as-is
+                    # (PrefetchingIter device double buffering): no re-put,
+                    # no same-dtype astype
+                    src = v._data
+                    dst._data = src if src.dtype == dst.dtype \
+                        else src.astype(dst.dtype)
+                else:
+                    dst._data = jnp.asarray(v, dst.dtype)
+            others = [ex.arg_dict[n]._data for n in ex.arg_names
+                      if n not in self._pset]
+            auxs = [ex.aux_dict[n]._data for n in ex.aux_names]
+        with _span("Step::slots", {"params": len(self._pnames)}):
+            slots = self._slots_for_device(ex, 0, 1)
+        pvals, svals, lrs, wds, ts, rescale = \
+            self._gather_update_inputs(ex, 0, slots)
+        run = {}
+        with _span("Step::program", run):
+            plan = ex._plan(True)
+            keys = ex._keys(plan)
+            ex._last_keys = keys
+            ogs = ex._default_ograds()
+            update_fns = self._update_fns(ex, slots)
+            first_run = run["first_run"] = ex._step_key() not in ex._jitted
+            fn = ex.step_program([s[0] for s in slots], update_fns)
+            if first_run and _health.enabled:
+                # lowering-only analysis — the dispatch below still owns
+                # the one and only compilation of this program
+                _health.register_program(
+                    "step", fn, (pvals, svals, others, auxs, keys, ogs, lrs,
+                                 wds, ts, rescale), donated=True,
+                    env=ex._program_env(plan))
+        with _span("Step::launch", run):
             new_p, new_s, outs, new_aux = fn(
                 pvals, svals, others, auxs, keys, ogs, lrs, wds, ts, rescale)
         if first_run and _health.enabled:
             _health.audit_donation("step", (pvals, svals))
-        self._writeback(ex, 0, slots, new_p, new_s)
-        ex._writeback_aux(new_aux)
-        ex._wrap_outputs(outs)
+        with _span("Step::writeback"):
+            self._writeback(ex, 0, slots, new_p, new_s)
+            ex._writeback_aux(new_aux)
+            ex._wrap_outputs(outs)
+            # the donated inputs' (now dead) array objects go here and not
+            # at the function's end, outside every phase: a thousand leaves
+            # take a millisecond to drop
+            del pvals, svals, new_p, new_s
 
     def _update_multi(self):
-        from . import profiler as _profiler
+        """Per-device update programs (multi-device without a mesh): the
+        timeline's spans from ``Step::gather`` on come once per device."""
         m = self._mod
-        opt_ = m._optimizer
         execs = self._eg.execs
         ndev = len(execs)
         reduce_grads = m._kvstore is not None
         # eager count order is param-major, device-minor: interleave the
         # per-device slot capture accordingly
         per_dev = [[] for _ in range(ndev)]
-        for i, name in enumerate(m._param_names):
-            if name not in self._pset:
-                continue
-            for k, ex in enumerate(execs):
-                per_dev[k].extend(self._slots_for_device_one(ex, i, k, ndev))
+        with _span("Step::slots", {"params": len(self._pnames)}):
+            for i, name in enumerate(m._param_names):
+                if name not in self._pset:
+                    continue
+                for k, ex in enumerate(execs):
+                    per_dev[k].extend(
+                        self._slots_for_device_one(ex, i, k, ndev))
         for k, ex in enumerate(execs):
             slots = per_dev[k]
-            pvals, svals, lrs, wds, ts = \
+            pvals, svals, lrs, wds, ts, rescale = \
                 self._gather_update_inputs(ex, k, slots)
-            dev = ex._ctx.jax_device
-            gvals = []
-            for name, _, _, _, _ in slots:
-                if reduce_grads:
-                    gvals.append([jax.device_put(e.grad_dict[name]._data, dev)
-                                  for e in execs])
-                else:
-                    gvals.append([ex.grad_dict[name]._data])
-            rescale = jnp.asarray(opt_.rescale_grad, jnp.float32)
-            first_run = ex._update_key() not in ex._jitted
-            fn = ex.update_program(self._update_fns(ex, slots))
-            if first_run and k == 0 and _health.enabled:
-                _health.register_program(
-                    "update", fn, (pvals, svals, gvals, lrs, wds, ts,
-                                   rescale), donated=True,
-                    env=ex._program_env())
-            with _profiler.span("Executor::FusedUpdate", "executor"):
+            run = {}
+            with _span("Step::program", run):
+                dev = ex._ctx.jax_device
+                gvals = []
+                for name, _, _, _, _ in slots:
+                    if reduce_grads:
+                        gvals.append(
+                            [jax.device_put(e.grad_dict[name]._data, dev)
+                             for e in execs])
+                    else:
+                        gvals.append([ex.grad_dict[name]._data])
+                first_run = run["first_run"] = \
+                    ex._update_key() not in ex._jitted
+                fn = ex.update_program(self._update_fns(ex, slots))
+                if first_run and k == 0 and _health.enabled:
+                    _health.register_program(
+                        "update", fn, (pvals, svals, gvals, lrs, wds, ts,
+                                       rescale), donated=True,
+                        env=ex._program_env())
+            with _span("Step::launch", run):
                 new_p, new_s = fn(pvals, svals, gvals, lrs, wds, ts, rescale)
             if first_run and k == 0 and _health.enabled:
                 _health.audit_donation("update", (pvals, svals))
-            self._writeback(ex, k, slots, new_p, new_s)
+            with _span("Step::writeback"):
+                self._writeback(ex, k, slots, new_p, new_s)
 
     def _slots_for_device_one(self, ex, i, k, ndev):
         """Single-param slot capture (multi-device interleaving order)."""
@@ -598,12 +673,9 @@ class ModuleFusedStep:
         return pool.take_sharded(slot, handles[0], sharding)
 
     def _step_mesh(self):
-        from . import optimizer as _opt
-        from . import profiler as _profiler
         from .ndarray.ndarray import NDArray
         from jax.sharding import NamedSharding, PartitionSpec as P
         m = self._mod
-        opt_ = m._optimizer
         eg = self._eg
         execs = eg.execs
         ex = execs[0]
@@ -618,84 +690,91 @@ class ModuleFusedStep:
             return repl
 
         staged, self._pending = self._pending, None
-        full = staged.full() if staged is not None else {}
         states = m._updater.states
         pool = self._pools[0]
-        slots = self._slots_for_mesh(ex, ndev)
+        with _span("Step::feed"):
+            full = staged.full() if staged is not None else {}
+            batch_names = set(eg.data_names) | set(eg.label_names)
+            others, full_shapes = [], {}
+            for n in ex.arg_names:
+                if n in self._pset:
+                    full_shapes[n] = ex.arg_dict[n].shape
+                    continue
+                if n in batch_names:
+                    v = full.get(n)
+                    if v is None:       # replayed without a staged batch
+                        v = ex.arg_dict[n]._data
+                    dt = ex.arg_dict[n].dtype
+                    if v.dtype != dt:
+                        v = v.astype(dt)
+                    if getattr(v, "sharding", None) != bsh:
+                        # producer-prefetched batches (PrefetchingIter with
+                        # sharding=batch_sharding()) arrive pre-sharded: the
+                        # H2D + shard already happened during the PREVIOUS
+                        # step
+                        v = jax.device_put(v, bsh)
+                    others.append(v)
+                    full_shapes[n] = tuple(v.shape)
+                else:
+                    others.append(jax.device_put(ex.arg_dict[n]._data, repl))
+                    full_shapes[n] = ex.arg_dict[n].shape
+            auxs = [jax.device_put(ex.aux_dict[n]._data, repl)
+                    for n in ex.aux_names]
+        with _span("Step::slots", {"params": len(self._pnames)}):
+            slots = self._slots_for_mesh(ex, ndev)
         pvals, svals = [], []
-        for name, slot, _, _, _ in slots:
-            sh = psh(name, ex.arg_dict[name].shape)
-            pvals.append(self._take_mesh(
-                ("w", name), [e.arg_dict[name] for e in execs], sh))
-            # mp slots: leaf 0 is the master-fp32 copy — same shape as the
-            # param, so it inherits the param's sharding like every moment
-            leaves = self._slot_leaves(ex, name, states[slot])
-            svals.append(tuple(
-                pool.take_sharded(("s", slot, j), leaf, sh)
-                for j, leaf in enumerate(leaves)))
-        lrs = jnp.asarray([s[2] for s in slots], jnp.float32)
-        wds = jnp.asarray([s[3] for s in slots], jnp.float32)
-        ts = jnp.asarray([s[4] for s in slots], jnp.float32)
-        rescale = jnp.asarray(opt_.rescale_grad, jnp.float32)
-        batch_names = set(eg.data_names) | set(eg.label_names)
-        others, full_shapes = [], {}
-        for n in ex.arg_names:
-            if n in self._pset:
-                full_shapes[n] = ex.arg_dict[n].shape
-                continue
-            if n in batch_names:
-                v = full.get(n)
-                if v is None:       # replayed without a staged batch
-                    v = ex.arg_dict[n]._data
-                dt = ex.arg_dict[n].dtype
-                if v.dtype != dt:
-                    v = v.astype(dt)
-                if getattr(v, "sharding", None) != bsh:
-                    # producer-prefetched batches (PrefetchingIter with
-                    # sharding=batch_sharding()) arrive pre-sharded: the
-                    # H2D + shard already happened during the PREVIOUS step
-                    v = jax.device_put(v, bsh)
-                others.append(v)
-                full_shapes[n] = tuple(v.shape)
-            else:
-                others.append(jax.device_put(ex.arg_dict[n]._data, repl))
-                full_shapes[n] = ex.arg_dict[n].shape
-        auxs = [jax.device_put(ex.aux_dict[n]._data, repl)
-                for n in ex.aux_names]
-        plan = ex._plan(True)
-        keys = ex._keys(plan)
-        ex._last_keys = keys
-        ogs = ex._ograds_for(full_shapes)
-        pshardings = [psh(s[0], ex.arg_dict[s[0]].shape) for s in slots]
-        mesh_sig = (tuple(sorted(mesh.shape.items())),
-                    tuple(str(sh.spec) for sh in pshardings))
-        update_fns = self._update_fns(ex, slots)
-        first_run = ex._step_key(mesh_sig) not in ex._jitted
-        fn = ex.step_program([s[0] for s in slots], update_fns,
-                             mesh_sig=mesh_sig, param_shardings=pshardings)
-        if first_run and _health.enabled:
-            _health.register_program(
-                "mesh_step", fn, (pvals, svals, others, auxs, keys, ogs,
-                                  lrs, wds, ts, rescale), donated=True,
-                env=ex._program_env(plan))
-        with _profiler.span("Mesh::Step", "executor",
-                            args={"first_run": first_run,
-                                  "mesh": str(dict(mesh.shape))}):
+        with _gather_span(pool) as args:
+            for name, slot, _, _, _ in slots:
+                sh = psh(name, ex.arg_dict[name].shape)
+                pvals.append(self._take_mesh(
+                    ("w", name), [e.arg_dict[name] for e in execs], sh))
+                # mp slots: leaf 0 is the master-fp32 copy — same shape as
+                # the param, so it inherits the param's sharding like every
+                # moment
+                leaves = self._slot_leaves(ex, name, states[slot])
+                svals.append(tuple(
+                    pool.take_sharded(("s", slot, j), leaf, sh)
+                    for j, leaf in enumerate(leaves)))
+            args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
+            lrs, wds, ts, rescale = self._slot_scalars(slots)
+        run = {"mesh": str(dict(mesh.shape))}
+        with _span("Step::program", run):
+            plan = ex._plan(True)
+            keys = ex._keys(plan)
+            ex._last_keys = keys
+            ogs = ex._ograds_for(full_shapes)
+            pshardings = [psh(s[0], ex.arg_dict[s[0]].shape) for s in slots]
+            mesh_sig = (tuple(sorted(mesh.shape.items())),
+                        tuple(str(sh.spec) for sh in pshardings))
+            update_fns = self._update_fns(ex, slots)
+            first_run = run["first_run"] = \
+                ex._step_key(mesh_sig) not in ex._jitted
+            fn = ex.step_program([s[0] for s in slots], update_fns,
+                                 mesh_sig=mesh_sig,
+                                 param_shardings=pshardings)
+            if first_run and _health.enabled:
+                _health.register_program(
+                    "mesh_step", fn, (pvals, svals, others, auxs, keys, ogs,
+                                      lrs, wds, ts, rescale), donated=True,
+                    env=ex._program_env(plan))
+        with _span("Step::launch", run):
             new_p, new_s, outs, new_aux = fn(
                 pvals, svals, others, auxs, keys, ogs, lrs, wds, ts, rescale)
         if first_run and _health.enabled:
             _health.audit_donation("mesh_step", (pvals, svals))
-        for (name, slot, _, _, _), w, st in zip(slots, new_p, new_s):
-            pool.give(("w", name), ex.arg_dict[name], w)
-            for e in execs[1:]:
-                e.arg_dict[name]._data = w
-            leaves = self._slot_leaves(ex, name, states[slot])
-            for j, (leaf, arr) in enumerate(zip(leaves, st)):
-                pool.give(("s", slot, j), leaf, arr)
-        for n, v in zip(ex.aux_names, new_aux):
-            for e in execs:
-                e.aux_dict[n]._data = v
-        self._mesh_outputs = [NDArray(o, ex._ctx) for o in outs]
+        with _span("Step::writeback"):
+            for (name, slot, _, _, _), w, st in zip(slots, new_p, new_s):
+                pool.give(("w", name), ex.arg_dict[name], w)
+                for e in execs[1:]:
+                    e.arg_dict[name]._data = w
+                leaves = self._slot_leaves(ex, name, states[slot])
+                for j, (leaf, arr) in enumerate(zip(leaves, st)):
+                    pool.give(("s", slot, j), leaf, arr)
+            for n, v in zip(ex.aux_names, new_aux):
+                for e in execs:
+                    e.aux_dict[n]._data = v
+            self._mesh_outputs = [NDArray(o, ex._ctx) for o in outs]
+            del pvals, svals, new_p, new_s      # as in _step_single
         self._meshed = True
         return "mesh_fused"
 
@@ -780,7 +859,6 @@ class TrainerFusedUpdate:
 
     def step(self):
         from . import optimizer as _opt
-        from . import profiler as _profiler
         tr = self._tr
         opt_ = tr._optimizer
         live = [(i, p) for i, p in enumerate(tr._params)
@@ -963,7 +1041,6 @@ class TrainerMeshUpdate:
 
     def step(self):
         from . import optimizer as _opt
-        from . import profiler as _profiler
         from jax.sharding import NamedSharding, PartitionSpec as P
         tr = self._tr
         opt_ = tr._optimizer
@@ -1036,9 +1113,8 @@ class TrainerMeshUpdate:
                  jnp.asarray(ts, jnp.float32),
                  jnp.asarray(opt_.rescale_grad, jnp.float32)), donated=True,
                 env=_env_dict())
-        with _profiler.span("Mesh::Step", "executor",
-                            args={"path": "trainer",
-                                  "mesh": str(dict(mesh.shape))}):
+        with _profiler.span("Trainer::MeshUpdate", "executor",
+                            args={"mesh": str(dict(mesh.shape))}):
             new_p, new_s = fn(
                 pvals, svals, gvals,
                 jnp.asarray(lrs, jnp.float32), jnp.asarray(wds, jnp.float32),
@@ -1066,6 +1142,7 @@ class TrainerMeshUpdate:
         if all(self._pools[k]._own.get(slot) is datas[k]
                for k in range(len(datas))):
             return _adopt(datas[0].shape, sharding, datas)
+        self._pools[0].count_copy("mesh_fused", datas[0])
         return jax.device_put(jnp.array(datas[0]), sharding)
 
     def _scatter(self, handles, global_arr):

@@ -8,7 +8,6 @@ optimizer either locally or on the kvstore (``update_on_kvstore``).
 from __future__ import annotations
 
 import logging
-import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -20,6 +19,7 @@ from .. import kvstore as kvs
 from .. import fused_step as _fused
 from .. import telemetry as _telemetry
 from .. import health as _health
+from .. import profiler as _profiler
 from ..context import Context, cpu, current_context
 from ..initializer import InitDesc
 from .base_module import BaseModule
@@ -269,9 +269,10 @@ class Module(BaseModule):
             # un-consumed previous batch forces an eager replay — an
             # unconditional flush would also de-mesh between every pair
             # of mesh steps, breaking the donation chain
-            if fs.pending:
-                fs.flush_eager()
-            fs.stage(data_batch)
+            with _profiler.span("Step::stage", "step"):
+                if fs.pending:
+                    fs.flush_eager()
+                fs.stage(data_batch)
             return
         if fs is not None:
             fs.flush_eager()
@@ -284,23 +285,35 @@ class Module(BaseModule):
         dispatches the fused whole-step program staged by
         forward_backward; the per-param loop below is the OFF fallback and
         parity oracle.  Note the fused path does not materialize gradients
-        in grad_dict (they live only inside the program)."""
+        in grad_dict (they live only inside the program).
+
+        The whole of it is the span ``Step::update`` (``args.path``: the
+        path that served the step), which also feeds
+        ``step_update_seconds``: one timing path."""
         assert self.optimizer_initialized
-        tel = _telemetry.enabled
-        t0 = time.perf_counter() if tel else 0.0
+        args = {}
+        with _profiler.span("Step::update", "step",
+                            histogram=_fused.STEP_TIME, args=args):
+            path = args["path"] = self._update()
+            if path != "eager":
+                args["step"] = self._fused_step.steps
+        if _telemetry.enabled:
+            _fused.STEP_DISPATCH.labels(path=path).inc()
+        if _health.enabled:
+            _health.monitor.on_step(
+                "mesh_step" if path == "mesh_fused" else
+                ("fwdbwd",) if path == "eager" else
+                ("step" if len(self._context) == 1
+                 else ("fwdbwd", "update")))
+
+    def _update(self):
+        """The body of ``update``; returns the path taken (``fused``,
+        ``mesh_fused`` or ``eager``)."""
         fs = self._fused()
         if fs is not None and fs.pending and fs.eligible():
             path = fs.step()
             if path:
-                if tel:
-                    _fused.STEP_DISPATCH.labels(path=path).inc()
-                    _fused.STEP_TIME.observe(time.perf_counter() - t0)
-                if _health.enabled:
-                    _health.monitor.on_step(
-                        "mesh_step" if path == "mesh_fused" else
-                        ("step" if len(self._context) == 1
-                         else ("fwdbwd", "update")))
-                return
+                return path
         if fs is not None:
             fs.flush_eager()
         eg = self._exec_group
@@ -340,11 +353,7 @@ class Module(BaseModule):
             for grads in eg.grad_arrays:
                 for g in grads or ():
                     _memwatch.tag("activations", g)
-        if tel:
-            _fused.STEP_DISPATCH.labels(path="eager").inc()
-            _fused.STEP_TIME.observe(time.perf_counter() - t0)
-        if _health.enabled:
-            _health.monitor.on_step(("fwdbwd",))
+        return "eager"
 
     def get_outputs(self, merge_multi_context=True):
         fs = self._fused()

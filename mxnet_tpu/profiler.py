@@ -21,6 +21,8 @@ import time
 from collections import defaultdict
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import telemetry as _telemetry
 from .base import get_env
 
@@ -62,6 +64,12 @@ _jax_trace_active = False
 # set by mxnet_tpu.tracing at import: its FlightRecorder, fed every span that
 # goes through record_span even when the profiler is stopped
 _flight = None
+
+
+# span categories that are also written into jax's own trace (host plane,
+# event name "mx:<span>"): there they lie on the clock of the device's
+# ``XLA Ops`` lines, so a device idle gap can be laid to a program span
+_ANNOTATED = frozenset(("step", "executor"))
 
 
 def _now_us():
@@ -107,22 +115,34 @@ class span:
 
     ``histogram`` (a telemetry Histogram or bound child) receives the same
     wall-clock measurement in seconds when telemetry is enabled, so one
-    timing path feeds both the Chrome trace and the metrics registry."""
+    timing path feeds both the Chrome trace and the metrics registry.
+    ``args`` is read at exit: a caller may fill the dict inside the span.
 
-    __slots__ = ("name", "cat", "begin", "hist", "args")
+    Spans of the categories ``step`` and ``executor`` also enter a
+    ``jax.profiler.TraceAnnotation("mx:" + name)``: while any jax trace is
+    on they are events of its host plane.  Nothing here asks whether one
+    is; the annotation's own idle cost (under a microsecond) is the cost."""
+
+    __slots__ = ("name", "cat", "begin", "hist", "args", "note")
 
     def __init__(self, name, category="operator", histogram=None, args=None):
         self.name = name
         self.cat = category
         self.hist = histogram
         self.args = args
+        self.note = None
 
     def __enter__(self):
+        if self.cat in _ANNOTATED:
+            self.note = _TraceAnnotation("mx:" + self.name)
+            self.note.__enter__()
         self.begin = _now_us()
         return self
 
     def __exit__(self, *exc):
         end = _now_us()
+        if self.note is not None:
+            self.note.__exit__(None, None, None)
         record_span(self.name, self.begin, end, self.cat, args=self.args)
         if self.hist is not None and _telemetry.enabled:
             self.hist.observe((end - self.begin) * 1e-6)

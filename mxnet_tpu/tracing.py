@@ -33,8 +33,10 @@ by a single attribute check (``tracing.enabled`` / ``flight.enabled``) on
 the disabled path.  Tracing is off by default (``MXNET_TRACING=1`` turns
 it on; events are collected while the profiler runs).  The flight
 recorder defaults ON because its steady-state cost is one ring append per
-*recorded* span — and nothing records spans unless the profiler or
-tracing is active, except the recorder's own crash markers.
+*recorded* span: the executor's dispatch spans and the step timeline
+(``Step::*``, about a dozen a training step) always record; per-op and
+engine spans only while the profiler or tracing is active.
+``flight.records()`` reads the ring in-process, on ``perf_counter``'s clock.
 
 Env knobs (see docs/observability.md "Tracing"): ``MXNET_TRACING``,
 ``MXNET_TRACE_DIR``, ``MXNET_FLIGHT_RECORDER``,
@@ -44,6 +46,7 @@ Env knobs (see docs/observability.md "Tracing"): ``MXNET_TRACING``,
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import signal
@@ -59,7 +62,7 @@ from .base import get_env
 
 __all__ = ["enabled", "enable", "disable", "span", "server_span",
            "current", "engine_push", "flight", "FlightRecorder",
-           "dump_process_trace"]
+           "FlightRecord", "dump_process_trace"]
 
 #: single-attribute gate read by every built-in instrumentation site
 enabled = False
@@ -319,6 +322,17 @@ def engine_push(name, const_vars=(), mutable_vars=()) -> _EngineFlow:
 # ---------------------------------------------------------------------------
 # flight recorder
 # ---------------------------------------------------------------------------
+class FlightRecord(NamedTuple):
+    """One record of the ring, as :meth:`FlightRecorder.records` hands it
+    out: begin and end in seconds on ``time.perf_counter()``'s clock."""
+    name: str
+    cat: str
+    begin_s: float
+    end_s: float
+    tid: int
+    args: Optional[dict]
+
+
 class FlightRecorder:
     """Fixed-size ring of the last N span records, always warm.
 
@@ -332,6 +346,7 @@ class FlightRecorder:
         self.enabled = get_env("MXNET_FLIGHT_RECORDER", True, bool)
         size = max(16, get_env("MXNET_FLIGHT_RECORDER_SIZE", 1024, int))
         self._ring = collections.deque(maxlen=size)
+        self._seq = itertools.count()   # a record's number since clear()
         self._dump_lock = threading.Lock()
         self._last_error_dump = 0.0
         self.error_debounce = get_env(
@@ -340,11 +355,37 @@ class FlightRecorder:
     # -- recording ---------------------------------------------------------
     def record(self, name, category, begin_us, end_us, args=None):
         self._ring.append((begin_us, end_us - begin_us, name, category,
-                           _tid(), args))
+                           _tid(), args, next(self._seq)))
 
     def clear(self):
         self._ring.clear()
+        self._seq = itertools.count()
         self._last_error_dump = 0.0
+
+    # -- reading -----------------------------------------------------------
+    def records(self, names=None, since_s=None):
+        """``(records, wrapped)``: the ring's records, oldest first, as
+        :class:`FlightRecord` — those named in ``names`` (all when None)
+        that begin at or after ``since_s`` (``time.perf_counter()`` seconds;
+        all when None).  ``wrapped`` says that the answer may be partial:
+        the ring has dropped records, and the oldest it still holds ended
+        at or after ``since_s`` (records are appended as they end, so what
+        was dropped may have begun inside the stretch asked for)."""
+        ring = list(self._ring)
+        t0 = _profiler._t0
+        wrapped = bool(ring) and ring[0][6] > 0 and (
+            since_s is None
+            or t0 + (ring[0][0] + ring[0][1]) * 1e-6 >= since_s)
+        if names is not None:
+            names = frozenset(names)
+        out = []
+        for ts, dur, name, cat, tid, args, _ in ring:
+            begin = t0 + ts * 1e-6
+            if (names is None or name in names) and \
+                    (since_s is None or begin >= since_s):
+                out.append(FlightRecord(name, cat, begin,
+                                        begin + dur * 1e-6, tid, args))
+        return out, wrapped
 
     def __len__(self):
         return len(self._ring)
@@ -362,10 +403,12 @@ class FlightRecorder:
         _FLIGHT_DUMPS.labels(reason=reason).inc()
         with self._dump_lock:
             try:
-                events = [{"ts_us": ts, "dur_us": dur, "name": name,
-                           "cat": cat, "tid": tid, "args": args}
-                          for (ts, dur, name, cat, tid, args)
-                          in list(self._ring)]
+                t0 = _profiler._t0
+                events = [{"ts_us": (r.begin_s - t0) * 1e6,
+                           "dur_us": (r.end_s - r.begin_s) * 1e6,
+                           "name": r.name, "cat": r.cat, "tid": r.tid,
+                           "args": r.args}
+                          for r in self.records()[0]]
                 doc = {"reason": reason,
                        "unix_time": time.time(),
                        "pid": os.getpid(),
@@ -442,9 +485,8 @@ class FlightRecorder:
         args = {"error": "%s: %s" % (type(exc).__name__, exc)}
         if wait_on:
             args["wait_on"] = list(wait_on)
-        self._ring.append((_profiler._now_us(), 0.0,
-                           "CRASH " + (name or "engine_op"), "crash",
-                           _tid(), args))
+        now = _profiler._now_us()
+        self.record("CRASH " + (name or "engine_op"), "crash", now, now, args)
         self.dump("engine_crash")
 
     def _on_mxnet_error(self, exc):
@@ -455,8 +497,8 @@ class FlightRecorder:
         if now - self._last_error_dump < self.error_debounce:
             return
         self._last_error_dump = now
-        self._ring.append((_profiler._now_us(), 0.0, "MXNetError", "error",
-                           _tid(), {"error": str(exc)}))
+        now = _profiler._now_us()
+        self.record("MXNetError", "error", now, now, {"error": str(exc)})
         self.dump("mxnet_error")
 
 

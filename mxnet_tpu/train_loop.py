@@ -25,6 +25,8 @@ import os
 from collections import deque
 from typing import Callable, Iterable, Optional
 
+from . import profiler as _profiler
+
 __all__ = ["OverlappedLoop", "default_overlap_depth", "run_epoch"]
 
 
@@ -44,6 +46,9 @@ class OverlappedLoop:
     the host blocks on step N-depth while the device still has steps
     N-depth+1..N queued.  FIFO order means side effects (metric updates,
     callbacks) run in exact step order, just late.
+
+    Each tail runs inside the span ``Loop::wait``: the time the host spent
+    blocked on the device.  Near zero means the host is the limit.
     """
 
     def __init__(self, depth: Optional[int] = None):
@@ -60,15 +65,20 @@ class OverlappedLoop:
         self._pending.append(blocker)
         out = None
         while len(self._pending) > self.depth:
-            out = self._pending.popleft()()
+            out = self._run_oldest()
         return out
 
     def drain(self):
         """Run every pending tail (epoch end); returns the last result."""
         out = None
         while self._pending:
-            out = self._pending.popleft()()
+            out = self._run_oldest()
         return out
+
+    def _run_oldest(self):
+        with _profiler.span("Loop::wait", "step",
+                            args={"depth": self.depth}):
+            return self._pending.popleft()()
 
 
 def run_epoch(data_iter: Iterable, step_fn: Callable,

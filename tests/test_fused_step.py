@@ -291,3 +291,123 @@ class TestResolver:
         assert o._get_lr(opt.Optimizer.slot_index(0, ndev, 1)) == \
             pytest.approx(0.2)
         assert o._get_wd(opt.Optimizer.slot_index(1, ndev, 2)) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the step timeline: spans where the work happens, donation copies counted
+# ---------------------------------------------------------------------------
+PHASES = ["Step::validate", "Step::feed", "Step::slots", "Step::gather",
+          "Step::program", "Step::launch", "Step::writeback"]
+
+
+def timeline_of_one_step(mod, batch):
+    """The ``step`` records one staged + fused step leaves in the ring,
+    in the order they began."""
+    from mxnet_tpu import tracing
+    tracing.flight.clear()
+    mod.forward_backward(batch)
+    mod.update()
+    records, wrapped = tracing.flight.records()
+    assert not wrapped
+    return sorted((r for r in records if r.cat == "step"),
+                  key=lambda r: r.begin_s)
+
+
+def assert_one_timeline(recs, path):
+    """Each span of the table exactly once, in order, the phases inside
+    ``Step::update`` and no longer than it together."""
+    assert [r.name for r in recs] == ["Step::stage", "Step::update"] + PHASES
+    stage, update = recs[0], recs[1]
+    assert stage.end_s <= update.begin_s
+    assert update.args["path"] == path and update.args["step"] >= 1
+    phases = recs[2:]
+    for a, b in zip(phases, phases[1:]):
+        assert a.end_s <= b.begin_s
+    assert update.begin_s <= phases[0].begin_s
+    assert phases[-1].end_s <= update.end_s
+    assert sum(r.end_s - r.begin_s for r in phases) <= \
+        update.end_s - update.begin_s
+    by = {r.name: r for r in recs}
+    assert by["Step::slots"].args["params"] > 0
+    assert by["Step::launch"].args["first_run"] == \
+        by["Step::program"].args["first_run"]
+    return by
+
+
+class TestStepTimeline:
+    def _module(self, monkeypatch):
+        monkeypatch.setenv(fused.ENV_FLAG, "1")
+        mod = _build_module()
+        mod.init_optimizer(
+            optimizer="sgd",
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9})
+        return mod
+
+    def test_each_span_once_in_order(self, monkeypatch):
+        mod = self._module(monkeypatch)
+        first = assert_one_timeline(
+            timeline_of_one_step(mod, _batch(0)), "fused")
+        assert first["Step::launch"].args["first_run"] is True
+        second = assert_one_timeline(
+            timeline_of_one_step(mod, _batch(1)), "fused")
+        assert second["Step::launch"].args["first_run"] is False
+        assert second["Step::update"].args["step"] == 2
+
+    def test_donation_copies_first_step_and_after_set_params(
+            self, monkeypatch):
+        telemetry.enable()
+        try:
+            n0 = telemetry.value("donation_copies_total", path="fused")
+            b0 = telemetry.value("donation_copy_bytes_total", path="fused")
+            mod = self._module(monkeypatch)
+            def gather_args(i):
+                recs = timeline_of_one_step(mod, _batch(i))
+                return [r for r in recs if r.name == "Step::gather"][0].args
+
+            g = gather_args(0)
+            # 4 weights, each with its momentum: every leaf is copied once
+            assert g["leaves"] == 8 and g["copies"] == 8
+            assert g["copy_bytes"] == 2 * 4 * (16 * 10 + 16 + 4 * 16 + 4)
+            assert telemetry.value("donation_copies_total",
+                                   path="fused") == n0 + 8
+            assert telemetry.value("donation_copy_bytes_total",
+                                   path="fused") == b0 + g["copy_bytes"]
+            g = gather_args(1)
+            assert g["leaves"] == 8 and g["copies"] == 0 \
+                and g["copy_bytes"] == 0
+            # handles written from outside are not the pool's any more
+            args, auxs = mod.get_params()
+            mod.set_params(args, auxs)
+            g = gather_args(2)
+            assert 0 < g["copies"] <= 8
+            assert telemetry.value("donation_copies_total",
+                                   path="fused") == n0 + 8 + g["copies"]
+        finally:
+            telemetry.disable()
+
+    def test_recorder_off_records_nothing_and_trains_alike(
+            self, monkeypatch):
+        from mxnet_tpu import profiler, tracing
+        monkeypatch.setenv("MXNET_FLIGHT_RECORDER", "0")
+        assert tracing.FlightRecorder().enabled is False
+
+        def three_steps():
+            mod = self._module(monkeypatch)
+            for i in range(3):
+                mod.forward_backward(_batch(i))
+                mod.update()
+            return {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+
+        on = three_steps()
+        assert len(tracing.flight) > 0
+        monkeypatch.setattr(tracing.flight, "enabled", False)
+        tracing.flight.clear()
+        assert not profiler.is_running()
+        with profiler._lock:
+            events0 = len(profiler._events)
+        off = three_steps()
+        assert len(tracing.flight) == 0
+        with profiler._lock:
+            assert len(profiler._events) == events0
+        for k in on:
+            assert np.array_equal(on[k], off[k]), k

@@ -386,3 +386,45 @@ class TestIoSharding:
         y = mx.nd.array(rs.randint(0, 4, (16,)).astype(np.float32))
         loss = tr.step({"data": x, "softmax_label": y})
         assert np.isfinite(float(loss))
+
+
+# ---------------------------------------------------------------------------
+# the step timeline on the mesh path (4 virtual devices, as the four-chip
+# cell of the benchmark binds them)
+# ---------------------------------------------------------------------------
+class TestMeshStepTimeline:
+    def _step(self, mod, seed):
+        """The ``step`` records one more mesh step leaves in the ring."""
+        from test_fused_step import timeline_of_one_step
+        rs = np.random.RandomState(seed)
+        return timeline_of_one_step(mod, _Batch(
+            rs.randint(0, 2, (8, 4)).astype(np.float32),
+            rs.randint(-1, 2, (8, 2)).astype(np.float32)))
+
+    def test_each_span_once_in_order_and_copies(self, monkeypatch):
+        from test_fused_step import assert_one_timeline
+        telemetry.enable()
+        try:
+            n0 = telemetry.value("donation_copies_total", path="mesh_fused")
+            mod = _run(monkeypatch, CTX8[:4], "sgd",
+                       {"learning_rate": 0.25, "momentum": 0.5}, steps=0)
+            by = assert_one_timeline(self._step(mod, 1), "mesh_fused")
+            assert by["Step::launch"].args["first_run"] is True
+            assert by["Step::launch"].args["mesh"] == "{'dp': 4}"
+            # 4 weights and their momenta: each placed onto the mesh once
+            g = by["Step::gather"].args
+            assert g["leaves"] == 8 and g["copies"] == 8
+            assert g["copy_bytes"] == 2 * 4 * (4 * 4 + 4 + 2 * 4 + 2)
+            by = assert_one_timeline(self._step(mod, 2), "mesh_fused")
+            assert by["Step::launch"].args["first_run"] is False
+            g = by["Step::gather"].args
+            assert g["leaves"] == 8 and g["copies"] == 0
+            assert telemetry.value("donation_copies_total",
+                                   path="mesh_fused") == n0 + 8
+            # set_params re-points the handles: the pool copies again
+            args, auxs = mod.get_params()
+            mod.set_params(args, auxs)
+            by = assert_one_timeline(self._step(mod, 3), "mesh_fused")
+            assert by["Step::gather"].args["copies"] > 0
+        finally:
+            telemetry.disable()

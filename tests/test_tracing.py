@@ -1,6 +1,7 @@
 """Causal tracing layer: engine flow events, cross-process KVStore trace
 propagation + merge_traces round-trip, jit-cache observability, and the
 flight recorder (see docs/observability.md "Tracing")."""
+import glob
 import io
 import json
 import os
@@ -592,3 +593,121 @@ class TestProfilerSatellites:
         assert os.path.basename(path) == "trace_worker3.json"
         assert merge_traces.validate_trace(
             merge_traces.load_trace(path)) == []
+
+
+# ---------------------------------------------------------------------------
+# the ring's reader, and the program's spans in jax's own trace
+# ---------------------------------------------------------------------------
+class TestFlightRecords:
+    def test_records_on_the_host_clock(self):
+        before = time.perf_counter()
+        with profiler.span("Step::a", "step", args={"n": 1}):
+            pass
+        with profiler.span("Step::b", "step"):
+            pass
+        after = time.perf_counter()
+        records, wrapped = tracing.flight.records()
+        assert not wrapped
+        assert [r.name for r in records] == ["Step::a", "Step::b"]
+        a, b = records
+        assert before <= a.begin_s <= a.end_s <= b.begin_s <= b.end_s <= after
+        assert a.cat == "step" and a.args == {"n": 1} and b.args is None
+        only_b, _ = tracing.flight.records(names=["Step::b"])
+        assert [r.name for r in only_b] == ["Step::b"]
+        late, _ = tracing.flight.records(since_s=b.begin_s)
+        assert [r.name for r in late] == ["Step::b"]
+
+    def test_a_wrapped_ring_is_reported(self, monkeypatch):
+        monkeypatch.setenv("MXNET_FLIGHT_RECORDER_SIZE", "16")
+        ring = tracing.FlightRecorder()
+        t0 = profiler._t0
+        for i in range(16):
+            ring.record("s%d" % i, "step", i * 1e6, i * 1e6 + 5e5)
+        records, wrapped = ring.records()
+        assert len(records) == 16 and not wrapped     # full, nothing lost
+        ring.record("s16", "step", 16e6, 16.5e6)
+        records, wrapped = ring.records()
+        assert [r.name for r in records][0] == "s1" and wrapped
+        # the oldest record kept ended at 1.5 s: a stretch that starts
+        # later is whole, one that starts earlier may have lost records
+        assert ring.records(since_s=t0 + 2.0)[1] is False
+        assert ring.records(since_s=t0 + 1.0)[1] is True
+        assert [r.name for r in ring.records(since_s=t0 + 15.0)[0]] == \
+            ["s15", "s16"]
+        ring.clear()
+        ring.record("again", "step", 0.0, 1.0)
+        records, wrapped = ring.records()
+        assert [r.name for r in records] == ["again"] and not wrapped
+
+    def test_dump_is_built_on_records(self, tmp_path, monkeypatch):
+        path = str(tmp_path / "ring.json")
+        monkeypatch.setenv("MXNET_FLIGHT_RECORDER_PATH", path)
+        profiler.record_span("kept", 10.0, 25.0, "step", args={"k": 1})
+        tracing.flight.dump("manual")
+        ev = json.load(open(path))["events"]
+        assert len(ev) == 1 and ev[0]["name"] == "kept"
+        assert ev[0]["cat"] == "step" and ev[0]["args"] == {"k": 1}
+        assert ev[0]["ts_us"] == pytest.approx(10.0, abs=1e-3)
+        assert ev[0]["dur_us"] == pytest.approx(15.0, abs=1e-3)
+
+    def test_loop_wait_span_around_each_tail(self):
+        from mxnet_tpu.train_loop import OverlappedLoop
+        loop = OverlappedLoop(2)
+        ran = []
+        for i in range(3):
+            loop.push(lambda i=i: ran.append(i))
+        assert ran == [0]
+        loop.drain()
+        assert ran == [0, 1, 2]
+        waits, _ = tracing.flight.records(names=["Loop::wait"])
+        assert len(waits) == 3
+        assert all(r.cat == "step" and r.args == {"depth": 2} for r in waits)
+
+
+def test_step_spans_in_a_jax_trace(tmp_path):
+    """While any jax trace is on, the program's step spans are events of
+    its host plane, named ``mx:<span>``."""
+    import jax
+    data = sym.var("data")
+    net = sym.SoftmaxOutput(sym.FullyConnected(data, num_hidden=4, name="fc"),
+                            sym.var("softmax_label"), name="softmax")
+    mod = _mx.mod.Module(net, context=[mx.cpu()])
+    mod.bind(data_shapes=[("data", (8, 10))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params()
+    mod.init_optimizer(optimizer="sgd")
+    batch = _mx.io.DataBatch(data=[nd.ones((8, 10))], label=[nd.zeros((8,))])
+
+    def step():
+        mod.forward_backward(batch)
+        mod.update()
+        mod.get_outputs()[0].asnumpy()
+
+    step()                                    # compile outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        step()
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert files
+    space = jax.profiler.ProfileData.from_file(files[-1])
+    names = {}
+    for plane in space.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("mx:"):
+                    names.setdefault(ev.name, []).append(
+                        (plane.name, ev.start_ns, ev.duration_ns))
+    for want in ("mx:Step::stage", "mx:Step::update", "mx:Step::launch",
+                 "mx:Step::writeback"):
+        assert len(names.get(want, ())) == 1, (want, sorted(names))
+    (plane, start, dur), = names["mx:Step::launch"]
+    assert plane == "/host:CPU"
+    (_, ustart, udur), = names["mx:Step::update"]
+    assert ustart <= start and start + dur <= ustart + udur
+    # the same span in the ring, to a tenth of a millisecond
+    ring, _ = tracing.flight.records(names=["Step::launch"])
+    assert abs((ring[-1].end_s - ring[-1].begin_s) * 1e9 - dur) < 1e5

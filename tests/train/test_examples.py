@@ -4,14 +4,11 @@ and example/module).
 
 Each test imports the example script and runs its main() at toy scale;
 convergence thresholds prove the demos actually train, not just execute.
+The families that run as subprocesses are in ``test_example_scripts_*.py``.
 """
 import os
-import re
-import subprocess
-import sys
 
 import numpy as np
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -215,148 +212,3 @@ def test_neural_style_example():
     # (measured ~0.48x at 120 steps; 0.6 leaves seed headroom)
     assert last < first * 0.6, (first, last)
     assert np.isfinite(img).all()
-
-
-# ---- round-5 families (VERDICT r4 item 5) --------------------------------
-#
-# These run their example script in a SUBPROCESS (fresh interpreter each):
-# twelve more in-process convergence runs pushed the single pytest
-# process's accumulated XLA compile state into a segfault at the tail of
-# the full suite.  Each script prints its metric and exits by its own
-# threshold; the tests parse the printed metric and apply their own
-# (sometimes looser, budget-matched) bar.
-
-
-def _run_example(relpath, args, pattern, extra_env=None, timeout=1500):
-    env = dict(os.environ)
-    # PYTHONPATH = repo ONLY and JAX_PLATFORMS=cpu: the example runs on
-    # CPU whatever the inherited environment names
-    env["PYTHONPATH"] = REPO
-    env["JAX_PLATFORMS"] = "cpu"
-    env.update(extra_env or {})
-    r = subprocess.run([sys.executable, os.path.join(REPO, relpath)]
-                       + list(args), env=env, capture_output=True,
-                       text=True, timeout=timeout)
-    m = re.search(pattern, r.stdout)
-    assert m, ("example produced no metric (rc=%d)\n%s\n%s"
-               % (r.returncode, r.stdout[-800:], r.stderr[-800:]))
-    return [float(g) for g in m.groups()]
-
-
-def test_fcn_xs_example_segments():
-    """FCN-16s-style dense prediction: deconv upsampling + crop-aligned
-    skip fusion recovers pixel-accurate masks."""
-    (acc,) = _run_example("example/fcn-xs/fcn_xs.py",
-                          ["--num-epochs", "6", "--samples", "128"],
-                          r"FCN pixel accuracy: ([0-9.]+)")
-    assert acc > 0.8, acc
-
-
-def test_module_gan_example():
-    """Module-API GAN: G trains purely from D's input gradients
-    (get_input_grads -> backward); best-trailing-eval selection."""
-    (err,) = _run_example("example/gan/gan_mnist.py", ["--iters", "250"],
-                          r"radius - 1\| of generated points: ([0-9.]+)")
-    assert err < 0.4, err
-
-
-def test_capsnet_example_routes():
-    """Dynamic routing-by-agreement trains (capsule lengths as class
-    scores, margin loss)."""
-    (acc,) = _run_example("example/capsnet/capsnet.py",
-                          ["--iters", "60"],
-                          r"capsnet routing accuracy: ([0-9.]+)")
-    assert acc > 0.8, acc
-
-
-def test_ner_example_tags():
-    """BiLSTM sequence labeling: the trigger->next-token rule needs
-    cross-timestep context, so beating the O-rate proves the recurrence
-    carries it."""
-    (acc,) = _run_example("example/named_entity_recognition/ner.py",
-                          ["--iters", "80"],
-                          r"NER entity-token accuracy: ([0-9.]+)")
-    assert acc > 0.9, acc
-
-
-def test_stochastic_depth_example():
-    """Per-layer Bernoulli block dropping at train time, p_l-scaled full
-    depth at eval (train/test asymmetry of stochastic depth)."""
-    (acc,) = _run_example("example/stochastic-depth/sd_cifar10.py",
-                          ["--iters", "120"],
-                          r"stochastic-depth eval accuracy: ([0-9.]+)")
-    assert acc > 0.85, acc
-
-
-def test_multivariate_ts_example_beats_naive():
-    """LSTNet-style conv+GRU forecasting: at horizon 6 the model must
-    exploit the planted cross-channel lags the naive forecast can't."""
-    got = _run_example("example/multivariate_time_series/lstnet.py",
-                       ["--iters", "150"],
-                       r"ratio ([0-9.]+)")
-    assert got[0] < 0.6, got
-
-
-def test_captcha_example_reads_all_slots():
-    """Multi-head captcha: summed per-slot CE; whole-sequence accuracy
-    requires every head right."""
-    (acc,) = _run_example("example/captcha/captcha_train.py",
-                          ["--iters", "200"],
-                          r"captcha whole-sequence accuracy: ([0-9.]+)")
-    assert acc > 0.7, acc
-
-
-def test_sgld_example_samples_posterior():
-    """SGLD: posterior-averaged accuracy high AND the samples actually
-    spread (a collapsed chain would have ~zero std)."""
-    acc, w_std = _run_example(
-        "example/bayesian-methods/sgld.py",
-        ["--iters", "500", "--burnin", "250"],
-        r"posterior-avg accuracy ([0-9.]+), posterior w-std ([0-9.]+)")
-    assert acc > 0.9, acc
-    assert w_std > 1e-4, w_std
-
-
-def test_rnn_time_major_example():
-    """NTC and TNC layouts learn the same Markov rule to near-identical
-    ppl (seeded init + same data: layout is semantics-free)."""
-    p_ntc, p_tnc = _run_example(
-        "example/rnn-time-major/rnn_time_major.py", ["--iters", "100"],
-        r"final ppl  NTC ([0-9.]+)   TNC ([0-9.]+)")
-    assert p_ntc < 6 and p_tnc < 6, (p_ntc, p_tnc)
-    assert abs(p_ntc - p_tnc) / p_ntc < 0.02, (p_ntc, p_tnc)
-
-
-def test_long_context_ring_lm_example():
-    """Transformer LM trained end-to-end with ring attention over the
-    sp mesh — the SP flagship (fwd + the round-5 ring backward) as a
-    user-facing recipe, not just a parallel-layer test."""
-    p0, p1 = _run_example(
-        "example/long-context-lm/train_ring_lm.py",
-        ["--iters", "150", "--sp", "4", "--seq-len", "128"],
-        r"ppl ([0-9.]+) -> ([0-9.]+)",
-        extra_env={"JAX_PLATFORMS": "cpu",
-                   "XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
-    assert p1 < 8.0 and p1 < 0.5 * p0, (p0, p1)
-
-
-def test_cnn_visualization_example():
-    """Saliency + Grad-CAM concentrate their mass on the evidence patch
-    (synthetic ground truth for 'the explanation points at the
-    evidence'); box covers only 6% of the image."""
-    sal, cam = _run_example(
-        "example/cnn_visualization/gradcam.py", ["--iters", "100"],
-        r"saliency mass in box: ([0-9.]+)   grad-cam mass in box: "
-        r"([0-9.]+)")
-    assert sal > 0.15, sal
-    assert cam > 0.3, cam
-
-
-def test_speech_recognition_example():
-    """BiLSTM+CTC acoustic model: learns phone identity AND alignment
-    from unaligned transcripts (blank=last convention)."""
-    (acc,) = _run_example(
-        "example/speech_recognition/speech_lstm_ctc.py",
-        ["--iters", "200", "--max-frames", "32"],
-        r"utterance exact-match rate: ([0-9.]+)")
-    assert acc > 0.6, acc
