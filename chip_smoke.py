@@ -404,17 +404,20 @@ def _op_hlo(name, attrs, arrays):
 
 
 def phase_kernels(device, seed, lstm_tbh=(35, 128, 650),
-                  attn_bhtd=(8, 12, 2048, 64), dtype="bfloat16"):
+                  attn_bhtd=((8, 12, 2048, 64), (1, 16, 1024, 64)),
+                  dtype="bfloat16"):
     """The two default-on Pallas kernels against the repo's XLA references,
     then the ops that dispatch to them.  On a TPU an op whose size gate
     says "kernel" must have the Mosaic custom call in its compiled HLO; off
-    the TPU (the CPU rehearsal) the same op must have lowered without it."""
+    the TPU (the CPU rehearsal) the same op must have lowered without it.
+    Attention runs at every shape of ``attn_bhtd``: a long sequence, and
+    one chip's rows of the benchmark's GPT-2 cells."""
     import jax
     import jax.numpy as jnp
     import mxnet_tpu as mx
     from mxnet_tpu import telemetry
     from mxnet_tpu.ops import pallas_attention, pallas_rnn
-    from mxnet_tpu.ops.nn import _mha_reference
+    from mxnet_tpu.ops.nn import _mha_reference, mha_uses_kernel
     from mxnet_tpu.ops.rnn import _lstm_scan_xla, rnn_param_size
 
     on_tpu = device.platform == "tpu"
@@ -435,13 +438,15 @@ def phase_kernels(device, seed, lstm_tbh=(35, 128, 650),
                                 lstm_args, low)
 
     # --- flash attention, causal ------------------------------------------
-    Bq, Hq, Tq, D = attn_bhtd
-    scale = D ** -0.5
-    qkv = tuple(normal(Bq, Hq, Tq, D) for _ in range(3))
-    attn = _kernel_vs_reference(
-        lambda q, k, v: pallas_attention.flash_attention(q, k, v, True,
-                                                         scale),
-        lambda q, k, v: _mha_reference(q, k, v, True, scale), qkv, low)
+    attn = []
+    for Bq, Hq, Tq, D in attn_bhtd:
+        scale = D ** -0.5
+        qkv = tuple(normal(Bq, Hq, Tq, D) for _ in range(3))
+        report = _kernel_vs_reference(
+            lambda q, k, v: pallas_attention.flash_attention(q, k, v, True,
+                                                             scale),
+            lambda q, k, v: _mha_reference(q, k, v, True, scale), qkv, low)
+        attn.append({"B_H_T_D": [Bq, Hq, Tq, D], "causal": True, **report})
 
     # --- the ops themselves ----------------------------------------------
     telemetry.enable()
@@ -460,53 +465,55 @@ def phase_kernels(device, seed, lstm_tbh=(35, 128, 650),
                       [a._data for a in (data, params, h0, h0)])
     rnn_kernel = "tpu_custom_call" in rnn_hlo
 
-    flash0 = telemetry.value("attention_dispatch_total", path="flash")
-    ref0 = telemetry.value("attention_dispatch_total", path="reference")
-    d_model = Hq * D
-    xa = mx.nd.array(rs.standard_normal((Bq, Tq, d_model)), ctx=ctx,
-                     dtype=dtype)
-    ws = [mx.nd.array(rs.standard_normal((d_model, d_model))
-                      * d_model ** -0.5, ctx=ctx, dtype=dtype)
-          for _ in range(4)]
-    mha_attrs = {"num_heads": Hq, "causal": True}
-    ya = mx.nd.MultiHeadAttention(xa, *ws, **mha_attrs)
-    assert ya.shape == (Bq, Tq, d_model) and np.all(np.isfinite(
-        ya.asnumpy().astype(np.float32)))
-    mha_gate = pallas_attention.flash_attention_available(Bq, Hq, Tq, Tq, D,
-                                                          low)
-    flash = telemetry.value("attention_dispatch_total", path="flash") - flash0
-    refd = telemetry.value("attention_dispatch_total",
-                           path="reference") - ref0
-    mha_hlo = _op_hlo("MultiHeadAttention", mha_attrs,
-                      [xa._data] + [w._data for w in ws])
-    mha_kernel = "tpu_custom_call" in mha_hlo
+    ops, mha = [("RNN", rnn_gate, rnn_kernel)], []
+    for Bq, Hq, Tq, D in attn_bhtd:
+        flash0 = telemetry.value("attention_dispatch_total", path="flash")
+        ref0 = telemetry.value("attention_dispatch_total", path="reference")
+        d_model = Hq * D
+        xa = mx.nd.array(rs.standard_normal((Bq, Tq, d_model)), ctx=ctx,
+                         dtype=dtype)
+        ws = [mx.nd.array(rs.standard_normal((d_model, d_model))
+                          * d_model ** -0.5, ctx=ctx, dtype=dtype)
+              for _ in range(4)]
+        mha_attrs = {"num_heads": Hq, "causal": True}
+        ya = mx.nd.MultiHeadAttention(xa, *ws, **mha_attrs)
+        assert ya.shape == (Bq, Tq, d_model) and np.all(np.isfinite(
+            ya.asnumpy().astype(np.float32)))
+        mha_gate = mha_uses_kernel(Bq, Hq, Tq, D, low)
+        flash = telemetry.value("attention_dispatch_total",
+                                path="flash") - flash0
+        refd = telemetry.value("attention_dispatch_total",
+                               path="reference") - ref0
+        mha_hlo = _op_hlo("MultiHeadAttention", mha_attrs,
+                          [xa._data] + [w._data for w in ws])
+        mha_kernel = "tpu_custom_call" in mha_hlo
+        if mha_gate and not pallas_attention.INTERPRET:
+            assert flash >= 1 and refd == 0, \
+                "attention_dispatch_total grew flash=%s reference=%s " \
+                "where the gate says kernel" % (flash, refd)
+        ops.append(("MultiHeadAttention", mha_gate, mha_kernel))
+        mha.append({"B_H_T_D": [Bq, Hq, Tq, D], "size_gate": bool(mha_gate),
+                    "tpu_custom_call": mha_kernel,
+                    "attention_dispatch": {"flash": int(flash),
+                                           "reference": int(refd)},
+                    "arm": "flash" if mha_kernel else "reference"})
 
-    for op, gate, kernel in (("RNN", rnn_gate, rnn_kernel),
-                             ("MultiHeadAttention", mha_gate, mha_kernel)):
+    for op, gate, kernel in ops:
         assert kernel == (gate and on_tpu), \
             "%s: size gate says %s on %s, compiled HLO %s the Mosaic " \
             "custom call" % (op, "kernel" if gate else "reference",
                              device.platform,
                              "has" if kernel else "does not have")
-    if mha_gate and not pallas_attention.INTERPRET:
-        assert flash >= 1 and refd == 0, \
-            "attention_dispatch_total grew flash=%s reference=%s where " \
-            "the gate says kernel" % (flash, refd)
 
     emit("kernels", dtype=dtype,
          tolerance="kernel error vs the f32 reference <= 3 x the error of "
                    "XLA's own low-precision run + 2^-8",
          lstm_scan={"T_B_H": list(lstm_tbh), **lstm},
-         flash_attention={"B_H_T_D": list(attn_bhtd), "causal": True,
-                          **attn},
+         flash_attention=attn,
          rnn_op={"size_gate": bool(rnn_gate),
                  "tpu_custom_call": rnn_kernel,
                  "arm": "pallas" if rnn_kernel else "lax.scan"},
-         mha_op={"size_gate": bool(mha_gate),
-                 "tpu_custom_call": mha_kernel,
-                 "attention_dispatch": {"flash": int(flash),
-                                        "reference": int(refd)},
-                 "arm": "flash" if mha_kernel else "reference"})
+         mha_op=mha)
 
 
 # ---------------------------------------------------------------- phase 5

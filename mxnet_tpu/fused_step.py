@@ -753,11 +753,15 @@ class ModuleFusedStep:
                                  mesh_sig=mesh_sig,
                                  param_shardings=pshardings)
             if first_run and _health.enabled:
-                _health.register_program(
-                    "mesh_step", fn, (pvals, svals, others, auxs, keys, ogs,
-                                      lrs, wds, ts, rescale), donated=True,
-                    env=ex._program_env(plan))
-        with _span("Step::launch", run):
+                with jax.set_mesh(mesh):
+                    _health.register_program(
+                        "mesh_step", fn, (pvals, svals, others, auxs, keys,
+                                          ogs, lrs, wds, ts, rescale),
+                        donated=True, env=ex._program_env(plan))
+        # traced under the mesh: an op that must keep a custom call on each
+        # device's own rows (MultiHeadAttention's flash kernel) finds it in
+        # ``jax.sharding.get_abstract_mesh()``
+        with _span("Step::launch", run), jax.set_mesh(mesh):
             new_p, new_s, outs, new_aux = fn(
                 pvals, svals, others, auxs, keys, ogs, lrs, wds, ts, rescale)
         if first_run and _health.enabled:

@@ -12,6 +12,8 @@ relayouts internally for the MXU.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -600,6 +602,52 @@ def _mha_reference(q, k, v, causal, scale):
     return o.astype(q.dtype)
 
 
+@partial(jax.jit, static_argnums=(3, 4, 5))
+def _kernel_or_reference(q, k, v, causal, scale, interpret):
+    """The kernel arm of ``MultiHeadAttention``, [B,H,T,d].  The platform
+    is resolved at LOWERING time (advisor r03): of the two branches the one
+    that does not match the target is pruned.  Jitted on its own because
+    the choice is linearized branch by branch: a model calls this once a
+    layer with the same shapes, and a jitted callee is traced, linearized
+    and lowered once a program (2 s of every program's trace at
+    GPT-2-medium's 24 layers otherwise)."""
+    from . import pallas_attention as pa
+    flash = partial(pa.flash_attention, causal=causal, scale=scale)
+    if interpret:
+        return flash(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, tpu=flash,
+        default=partial(_mha_reference, causal=causal, scale=scale))
+
+
+def mha_uses_kernel(B, H, T, d, dtype):
+    """MultiHeadAttention's own shape test: does the flash kernel beat
+    ``_mha_reference`` on a device that holds ``B`` rows of ``H`` heads?
+    (``pa.flash_attention_available`` answers for ring attention, whose
+    competitor is the scan.)
+
+    Forward + gradient, bf16, causal, device ms from a trace on the v5e
+    (``tools/bench_attention_arms.py``, PR 26), XLA arm / kernel:
+
+        B x H   d     T 256          T 512          T 1024         T 2048
+        16      64    0.012 / 0.018  0.040 / 0.057  0.352 / 0.146  3.40 / 0.50
+        64      64    0.041 / 0.073  0.362 / 0.227  3.57 / 0.584   13.5 / 2.01
+        16      128   0.014 / 0.027  0.044 / 0.068  0.368 / 0.166  3.43 / 0.52
+        64      128   0.048 / 0.091  0.385 / 0.242  3.53 / 0.609   13.6 / 2.03
+
+    The XLA arm is quick while its float32 scores (B x H x T x T) stay in
+    VMEM, 16 MB in every cell it wins, and pays HBM for them from 64 MB on,
+    every cell it loses, whatever ``d``: the kernel takes the shapes whose
+    scores reach 64 MB.  (Inside a training step the XLA arm also keeps the
+    probabilities of every layer for backward, which no VMEM holds: at the
+    GPT-2 cells' shape it cost 0.55 ms a layer there, not 0.35.)"""
+    from . import pallas_attention as pa
+    if d % 8 or T % 128 or not pa.kv_fits_vmem(T, d, dtype):
+        return False
+    # pa.INTERPRET is the tests' hook: any shape the kernel can run
+    return pa.INTERPRET or B * H * T * T * 4 >= 64 << 20
+
+
 @register("MultiHeadAttention", nin=5, aliases=("multiheadattention",),
           params={"num_heads": param(int, 0, required=True),
                   "causal": param(bool, True)},
@@ -612,8 +660,11 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
     ``sym.FullyConnected`` conventions (weights are (out, in), y=x·Wᵀ).
 
     Dispatch: ``MXNET_TPU_FLASH_ATTENTION`` (default on) selects the
-    Pallas flash kernel (ops/pallas_attention.py) whenever its shape/
-    VMEM gate admits the problem; otherwise the XLA reference runs.
+    Pallas flash kernel (ops/pallas_attention.py) wherever
+    ``mha_uses_kernel`` says the kernel beats the XLA arm at this shape;
+    otherwise the XLA reference runs.  The kernel's calls follow the
+    sharding of the batch and head dimensions (one sequence a chip under
+    the mesh fused step's ``P('dp')``).
     Both env gates are declared in ``env_keys`` so flipping either
     re-specializes every cached program containing this op (GL001).
 
@@ -623,7 +674,6 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
     rule (P(None, t)).
     """
     import os
-    from functools import partial
     from . import pallas_attention as pa
     if data.ndim != 3:
         raise MXNetError(
@@ -646,21 +696,14 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
     q, k, v = proj(query_weight), proj(key_weight), proj(value_weight)
 
     use_flash = os.environ.get("MXNET_TPU_FLASH_ATTENTION", "1") != "0" \
-        and pa.flash_attention_available(B, H, T, T, d, q.dtype)
-    ref = partial(_mha_reference, causal=causal, scale=scale)
+        and pa.enabled() \
+        and mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype)
     if use_flash:
-        flash = partial(pa.flash_attention, causal=causal, scale=scale)
-        if pa.INTERPRET:       # test hook: force the interpreter on CPU
-            out = flash(q, k, v)
-            path = "flash_interpret"
-        else:
-            # platform resolved at LOWERING time (advisor r03): the
-            # branch that does not match the target is pruned
-            out = jax.lax.platform_dependent(
-                q, k, v, tpu=flash, default=lambda q, k, v: ref(q, k, v))
-            path = "flash"
+        # test hook (pa.INTERPRET): force the interpreter on CPU
+        path = "flash_interpret" if pa.INTERPRET else "flash"
+        out = _kernel_or_reference(q, k, v, causal, scale, pa.INTERPRET)
     else:
-        out = ref(q, k, v)
+        out = _mha_reference(q, k, v, causal, scale)
         path = "reference"
     if _telemetry.enabled:
         # one inc per compiled attention variant, not per step — the

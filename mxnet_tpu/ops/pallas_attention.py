@@ -1,44 +1,71 @@
-"""Pallas flash-attention: the blockwise inner loop of ring attention.
+"""Pallas flash-attention: ``MultiHeadAttention``'s kernel arm and the
+blockwise inner loop of ring attention.
 
 SURVEY.md §7.1 maps ring attention's hot loop to a hand-written Pallas
 kernel.  ``parallel/ring_attention.py``'s building block is a
 ``lax.scan`` of (Q-block x K-block) updates; this module is the same
 math — online-softmax with running max/sum — as ONE Pallas kernel per
-(batch*head, Q-block): K/V live in VMEM, the K-block loop runs on-core,
-scores/accumulators never touch HBM.  Numerics match the scan
-formulation (f32 accumulation, running-max rescaling).
+(a few heads, Q-block): K/V live in VMEM, the K-block loop runs on-core,
+scores/accumulators never touch HBM.  Contract: the operands enter the
+MXU in their own dtype (bf16 in the benchmark's cells), scores, running
+max/sum and accumulators are float32, the probabilities are rounded to
+the operands' dtype as the MXU operand of the value product, and the
+logsumexp, T floats a head, is the only residual.
 
-Backward (round 5): hand-written Pallas dq and dk/dv kernels — the
-standard two-pass flash backward.  The forward saves the per-row
-logsumexp ``lse = m + log(l)``; the backward recomputes probabilities
-on-core as ``p = exp(s - lse)`` (no score materialization in HBM, same
-as forward), computes ``delta = rowsum(dO * O)`` once in XLA, then:
+Backward: hand-written Pallas dq and dk/dv kernels — the standard
+two-pass flash backward.  The forward saves the per-row logsumexp
+``lse = m + log(l)``; the backward recomputes probabilities on-core as
+``p = exp(s - lse)``, computes ``delta = rowsum(dO * O)`` once in XLA,
+then:
   dv_j = sum_i p_ij dO_i          (dk/dv kernel: grid over KV blocks,
-  dk_j = sum_i ds_ij q_i           loop over Q blocks)
+  dk_j = sum_i ds_ij q_i           loop over Q blocks, scores transposed)
   dq_i = sum_j ds_ij k_j          (dq kernel: grid over Q blocks,
                                    loop over KV blocks)
-with ``ds = p * (dp - delta) * scale``, ``dp = dO v^T``.  Both
-directions now run fused kernels — the reference's cuDNN precedent is
-fused-both-directions (/root/reference/src/operator/cudnn_rnn-inl.h:1).
+with ``ds = p * (dp - delta) * scale``, ``dp = dO v^T``.
 
-Used by ``parallel/ring_attention.blockwise_attention`` on TPU when
-``MXNET_TPU_PALLAS_ATTN`` != "0" and K/V fit VMEM; larger shapes fall
-back to the scan.  Reference analog: none (the 2018 reference predates
-flash attention); ref for the surrounding design: SURVEY.md §5.7.
+Causal: blocks past the diagonal are never visited, only blocks the
+diagonal crosses are masked, and in square blocks over a self-attention
+the diagonal block is computed in bands with static extents
+(``_bands``).  Without named blocks a sequence of up to 2,048 positions is
+ONE block (``default_blocks``): at T 1024 / d 64 that is 0.146 ms forward +
+backward for 16 heads on the v5e against 0.266 in 512-blocks and 0.352 for
+the XLA arm (PERF.md, PR 26).
+
+Who reaches it.  ``MultiHeadAttention`` (``ops/nn.py``), on a TPU, at the
+shapes its own test ``mha_uses_kernel`` admits: float32 scores of 64 MB or
+more a device, e.g. GPT-2-medium's (1, 16, 1024, 64); smaller shapes and
+every CPU run keep the XLA arm.  Under a mesh in context
+(``jax.set_mesh``; the mesh fused step) its three calls run under
+``shard_map`` over the mesh's ``dp`` (batch) and ``tp`` (heads) axes, each
+device on its own rows (``_on_own_rows``).  ``parallel/ring_attention``
+(``blockwise_attention``, the per-shard ``flash_attention_stats`` /
+``flash_attention_bwd``), when ``MXNET_TPU_PALLAS_ATTN`` != "0" and
+``flash_attention_available`` admits the shard: Tk >= 2048 against the
+scan, K/V within the VMEM envelope.  Reference analog: none (the 2018
+reference predates flash attention); ref for the surrounding design:
+SURVEY.md §5.7.
 """
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 __all__ = ["flash_attention", "flash_attention_available",
            "flash_attention_stats", "flash_attention_bwd"]
 
 INTERPRET = False
+
+
+def enabled() -> bool:
+    """``MXNET_TPU_PALLAS_ATTN`` != "0": the kernels may be dispatched."""
+    return os.environ.get("MXNET_TPU_PALLAS_ATTN", "1") != "0"
 
 
 def flash_attention_available(B, H, Tq, Tk, D, dtype=None) -> bool:
@@ -48,73 +75,194 @@ def flash_attention_available(B, H, Tq, Tk, D, dtype=None) -> bool:
     via ``jax.lax.platform_dependent`` (parallel/ring_attention.py), so
     CPU-committed arrays on a TPU host lower the scan formulation instead
     of Mosaic (advisor r03)."""
-    if os.environ.get("MXNET_TPU_PALLAS_ATTN", "1") == "0":
+    if not enabled():
         return False
     if D % 8 or Tq % 8 or Tk % 128:
         return False
     if not INTERPRET and Tk < 2048:
-        # measured crossover (tools/bench_ring_attention.py ring rows,
-        # B=1 H=8 D=128 bf16): XLA's fused scan hits ~89 TF at Tk=1024
-        # and beats the kernel 4x; the kernel wins ~2x from Tk=2048 up to
-        # the VMEM envelope below
+        # ring attention's crossover against the SCAN
+        # (tools/bench_ring_attention.py ring rows, B=1 H=8 D=128 bf16, the
+        # kernels as they were before PR 26): XLA's fused scan hits ~89 TF
+        # at Tk=1024 and beat the kernel 4x; the kernel wins ~2x from
+        # Tk=2048 up to the VMEM envelope below.  MultiHeadAttention, whose
+        # competitor is the dense XLA arm, decides by ops/nn.py
+        # ``mha_uses_kernel`` (tools/bench_attention_arms.py), not here.
         return False
-    # K+V resident in VMEM per (b,h) program, double-buffered by the
-    # pipeline.  Measured crossover (tools/bench_ring_attention.py):
-    # the kernel wins 1.9x while K/V stream from VMEM comfortably
-    # (T=4096/D=128), loses once the resident set crowds the 16 MB
-    # scoped-vmem limit (T=8192: 0.84x; T=16384: compile failure) —
-    # larger shapes use the HBM-blocked lax.scan formulation instead.
+    return kv_fits_vmem(Tk, D, dtype)
+
+
+def kv_fits_vmem(Tk, D, dtype=None) -> bool:
+    """K+V resident in VMEM per (b,h) program, double-buffered by the
+    pipeline.  Measured crossover (tools/bench_ring_attention.py):
+    the kernel wins 1.9x while K/V stream from VMEM comfortably
+    (T=4096/D=128), loses once the resident set crowds the 16 MB
+    scoped-vmem limit (T=8192: 0.84x; T=16384: compile failure) —
+    larger shapes use the HBM-blocked lax.scan formulation instead."""
     esize = jnp.dtype(dtype).itemsize if dtype is not None else 2
     kv_bytes = 2 * Tk * D * esize
     return 2 * kv_bytes <= 5 * 1024 * 1024
 
 
-def _online_softmax_loop(q_ref, k_ref, v_ref, *, TQ, BK, Tk, causal,
-                         scale):
-    """Shared kernel body: the online-softmax K-block loop, returning the
-    running (m, l, acc) — finalized differently by the normalized-output
-    kernel and the stats-emitting ring kernel."""
+_NT = (((1,), (1,)), ((), ()))       # A @ B^T: the MXU loads B transposed
+_NN = (((1,), (0,)), ((), ()))
+
+
+def _fold_scale(scale):
+    """True where ``scale`` is a power of two: ``x * scale`` is then exact
+    in any float dtype, so the kernels fold it into the operand a program
+    holds for its whole loop (one multiply a program, not one a score)."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _causal_bounds(row0, rows, cols, n_cols):
+    """For the ``rows`` query positions from ``row0`` against key blocks
+    of ``cols``: (blocks wholly at or under the diagonal, blocks the
+    diagonal reaches).  [0, full) needs no mask, [full, end) is masked,
+    [end, n_cols) is never computed."""
+    full = jnp.minimum((row0 + 1) // cols, n_cols)
+    end = jnp.minimum((row0 + rows + cols - 1) // cols, n_cols)
+    return full, end
+
+
+def _two_loops(lo, mid, hi, step, init, masked_first):
+    """``step(i, carry, masked)`` over [lo, hi): one stretch masked, the
+    other not, split at ``mid`` — the mask costs three VPU passes a score
+    and only the blocks the diagonal crosses need it."""
+    a = functools.partial(step, masked=masked_first)
+    b = functools.partial(step, masked=not masked_first)
+    return jax.lax.fori_loop(mid, hi, b, jax.lax.fori_loop(lo, mid, a, init))
+
+
+def _heads_per_program(BH, T, itemsize=2):
+    """Heads one program works through side by side: the K-block loop is
+    a chain (product, row maximum, exp, product) that waits on itself, and
+    a second head in the same body is independent work to fill the waits
+    with (measured, PR 26: 0.65 -> 0.43 ms in 128-blocks, 0.159 -> 0.150
+    in one 1024-block).  Each head keeps a band of scores alive, so fewer
+    of them share a program as T grows."""
+    g = max(1, min(4, 8192 // (T * itemsize)))
+    while BH % g:
+        g //= 2
+    return g
+
+
+def _each_head(G, step):
+    """``step(g, i, carry, masked)`` for one head -> the loop body over all
+    ``G`` heads of a program, carries in a tuple."""
+    def body(i, carries, masked):
+        return tuple(step(g, i, c, masked) for g, c in enumerate(carries))
+    return body
+
+
+def _bands(T, band):
+    """(number, size) of the bands a square diagonal block is cut into:
+    band r of a (T, T) block sees (r + 1) bands of keys, so of the block's
+    square (R + 1) / 2R is computed and not all of it."""
+    if band * T * 4 > 1 << 20:          # a band of f32 scores: 1 MB at most
+        band = 128
+    while band >= 128:                  # bands start on a lane tile
+        if T % band == 0:
+            return T // band, band
+        band -= 128
+    return 1, T
+
+
+# measured at (1, 16, 1024, 64), one block: the forward (row statistics a
+# band) is fastest in bands of 256, 0.049 against 0.058 ms; dq and dk/dv
+# (no reduction in the loop) in bands of 128, 0.040 / 0.053 against 0.042 /
+# 0.056
+_BAND_FWD, _BAND_BWD = 256, 128
+
+
+def _visible(shape, row_axis, shift):
+    """The causal mask of one tile: true where key <= query, the tile's
+    first key lying ``shift`` positions after its first query."""
+    rel = jax.lax.broadcasted_iota(jnp.int32, shape, row_axis) \
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - row_axis)
+    return rel >= shift
+
+
+def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
+                         scale, square=False):
+    """Shared kernel body: the online-softmax K-block loop over the ``G``
+    heads of a program, returning a running (m, l, acc) a head with m, l
+    of shape (TQ, 1) — finalized differently by the normalized-output
+    kernel and the stats-emitting ring kernel.
+
+    Causal: key blocks past the diagonal are never visited and only the
+    blocks the diagonal crosses are masked; ``square`` (self-attention in
+    square blocks) says block ``qi`` is the one diagonal block, which is
+    then computed in bands (``_bands``).  Every row sees key 0 in the first
+    block visited, so the running max is finite from the first step on and
+    ``exp(m_old - m_new)`` needs no guard (``exp(-inf) == 0``)."""
     qi = pl.program_id(1)
-    qb = q_ref[0]                                    # (TQ, D)
-    D = qb.shape[-1]
+    D = q_ref.shape[-1]
+    fold = _fold_scale(scale)
+    qs = [q_ref[g] * jnp.asarray(scale, q_ref.dtype) if fold else q_ref[g]
+          for g in range(G)]                         # (TQ, D) each
 
-    m0 = jnp.full((TQ,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((TQ,), jnp.float32)
-    a0 = jnp.zeros((TQ, D), jnp.float32)
+    def scores(qb, kblk):
+        s = jax.lax.dot_general(qb, kblk, _NT,
+                                preferred_element_type=jnp.float32)
+        return s if fold else s * scale
 
-    q_pos = qi * TQ + jax.lax.broadcasted_iota(jnp.int32, (TQ, BK), 0)
-
-    def body(i, carry):
+    def update(carry, s, vblk):
         m, l, acc = carry
-        kblk = k_ref[0, pl.ds(i * BK, BK), :]        # (BK, D)
-        vblk = v_ref[0, pl.ds(i * BK, BK), :]
-        s = jax.lax.dot_general(
-            qb, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (TQ, BK)
-        if causal:
-            k_pos = i * BK + jax.lax.broadcasted_iota(
-                jnp.int32, (TQ, BK), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m, m_cur)
-        # guard fully-masked rows: exp(-inf - (-inf)) -> use finite base
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[:, None])
-        alpha = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        l2 = l * alpha + jnp.sum(p, axis=-1)
-        acc2 = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l2 = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc2 = acc * alpha + jax.lax.dot_general(
+            p.astype(vblk.dtype), vblk, _NN,
             preferred_element_type=jnp.float32)
         return m_new, l2, acc2
 
-    return jax.lax.fori_loop(0, Tk // BK, body, (m0, l0, a0))
+    def step(g, i, carry, masked):
+        off = pl.multiple_of(i * BK, BK)
+        s = scores(qs[g], k_ref[g, pl.ds(off, BK), :])       # (TQ, BK)
+        if masked:
+            s = jnp.where(_visible((TQ, BK), 0, i * BK - qi * TQ), s,
+                          -jnp.inf)
+        return update(carry, s, v_ref[g, pl.ds(off, BK), :])
+
+    def diagonal(g, carry):
+        R, SB = _bands(TQ, _BAND_FWD)
+        base = pl.multiple_of(qi * BK, BK)
+        parts = []
+        for r in range(R):
+            rows, keys = slice(r * SB, (r + 1) * SB), (r + 1) * SB
+            s = scores(qs[g][rows], k_ref[g, pl.ds(base, keys), :])
+            s = jnp.where(_visible((SB, keys), 0, -r * SB), s, -jnp.inf)
+            parts.append(update(tuple(c[rows] for c in carry), s,
+                                v_ref[g, pl.ds(base, keys), :]))
+        return tuple(jnp.concatenate(c, axis=0) for c in zip(*parts))
+
+    body = _each_head(G, step)
+    init = ((jnp.full((TQ, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((TQ, 1), jnp.float32),
+             jnp.zeros((TQ, D), jnp.float32)),) * G
+    n = Tk // BK
+    if not causal:
+        return jax.lax.fori_loop(0, n, functools.partial(body, masked=False),
+                                 init)
+    if square:
+        under = jax.lax.fori_loop(0, qi, functools.partial(body, masked=False),
+                                  init)
+        return tuple(diagonal(g, c) for g, c in enumerate(under))
+    full, end = _causal_bounds(qi * TQ, TQ, BK, n)
+    return _two_loops(0, full, end, body, init, masked_first=False)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, TQ, BK, Tk, causal,
-                  scale, q_chunk_count):
-    m, l, acc = _online_softmax_loop(q_ref, k_ref, v_ref, TQ=TQ, BK=BK,
-                                     Tk=Tk, causal=causal, scale=scale)
-    o_ref[0] = (acc / jnp.maximum(l, 1e-37)[:, None]).astype(o_ref.dtype)
+def _row(col):
+    """(TQ, 1) per-row values -> one lane-dense (1, TQ) row: what leaves
+    the kernel is T floats a head, not T x 128."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], 128)))[0:1]
+
+
+def _col(ref, g):
+    """Head ``g`` of a (G, 1, 1, TQ) block of a lane-dense stats array ->
+    (TQ, 1)."""
+    return ref[g, 0, 0][:, None]
 
 
 def _out_sds(shape, dtype, like):
@@ -127,7 +275,24 @@ def _out_sds(shape, dtype, like):
         return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def default_blocks(Tq, Tk):
+    """(block_q, block_k) from the shape, where the caller names none:
+    one square block over the whole of a sequence up to 2,048 positions
+    (0.50 ms against 0.69 in 512-blocks at T 2048), 512-blocks beyond.
+    Measured on the v5e with ``tools/bench_attention_arms.py`` (PERF.md,
+    PR 26) at (1, 16, 1024, 64) bf16 causal: the K-block loop costs about
+    0.3 us a step whatever the block, so forward + backward take 0.65 ms
+    in 128-blocks, 0.34 in 256, 0.22 in 512 and 0.16 in one 1024-block,
+    whose diagonal bands (``_bands``) are straight-line code with static
+    extents and compute 56 % of the square."""
+    side = min(Tq, Tk)
+    return (side, side) if side <= 2048 else (512, 512)
+
+
 def _pick_blocks(Tq, Tk, block_q, block_k):
+    if block_q is None or block_k is None:
+        own_q, own_k = default_blocks(Tq, Tk)
+        block_q, block_k = block_q or own_q, block_k or own_k
     TQ = min(block_q, Tq)
     while Tq % TQ:
         TQ //= 2
@@ -137,48 +302,74 @@ def _pick_blocks(Tq, Tk, block_q, block_k):
     return TQ, BK
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+def _stats_spec(G, TQ):
+    return pl.BlockSpec((G, 1, 1, TQ), lambda b, t: (b, t, 0, 0))
+
+
+def _qkv_specs(G, TQ, Tk, D):
+    return [pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0)),
+            pl.BlockSpec((G, Tk, D), lambda b, t: (b, 0, 0)),
+            pl.BlockSpec((G, Tk, D), lambda b, t: (b, 0, 0))]
+
+
+# 32 MB of the v5e's 128 MB of VMEM: a 2048-block of f32 operands, double
+# buffered, needs more than the 16 MB a kernel gets unasked
+_PARALLEL = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"),
+    vmem_limit_bytes=32 << 20)
+
+
+def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k, stats):
+    """One forward ``pallas_call`` over [B,H,T,D]: ``stats`` None -> out;
+    "lse" -> (out, lse[B,H,Tq]); "ml" -> (acc f32, m, l) for the ring."""
+    return _fwd_program(q, k, v, causal, scale, block_q, block_k, stats,
+                        INTERPRET)
+
+
+# The three programs are jitted on their own: a model calls them once a
+# layer with the same shapes, and a jitted callee is traced and lowered to
+# Mosaic once a program, not once a layer (GPT-2-medium's 72 kernel
+# instances took 180 s of every start-up otherwise, compile cache or not).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     BH = B * H
-    q3 = q.reshape(BH, Tq, D)
-    k3 = k.reshape(BH, Tk, D)
-    v3 = v.reshape(BH, Tk, D)
     TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
+    nq = Tq // TQ
 
-    kern = functools.partial(
-        _flash_kernel, TQ=TQ, BK=BK, Tk=Tk, causal=causal, scale=scale,
-        q_chunk_count=Tq // TQ)
-    out = pl.pallas_call(
+    G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
+
+    def kern(q_ref, k_ref, v_ref, o_ref, *stat_refs):
+        heads = _online_softmax_loop(
+            q_ref, k_ref, v_ref, G=G, TQ=TQ, BK=BK, Tk=Tk, causal=causal,
+            scale=scale, square=TQ == BK and Tq == Tk)
+        for g, (m, l, acc) in enumerate(heads):
+            if stats == "ml":
+                o_ref[g] = acc
+                stat_refs[0][g, 0] = _row(m)
+                stat_refs[1][g, 0] = _row(l)
+                continue
+            o_ref[g] = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
+            if stats == "lse":
+                stat_refs[0][g, 0] = _row(lse_of(m, l))
+
+    n_stats = {None: 0, "lse": 1, "ml": 2}[stats]
+    outs = pl.pallas_call(
         kern,
-        grid=(BH, Tq // TQ),
-        in_specs=[
-            pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-        out_shape=_out_sds((BH, Tq, D), q.dtype, q),
-        interpret=INTERPRET,
-    )(q3, k3, v3)
-    return out.reshape(B, H, Tq, D)
-
-
-def _flash_stats_kernel(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
-                        TQ, BK, Tk, causal, scale):
-    """_flash_kernel's loop, but emitting the UNNORMALIZED accumulator
-    and the online-softmax stats (m, l) instead of the normalized output
-    — the building block for cross-shard merging in ring attention (each
-    ring step computes local stats on the resident K/V shard; the exact
-    combine happens outside in XLA)."""
-    m, l, acc = _online_softmax_loop(q_ref, k_ref, v_ref, TQ=TQ, BK=BK,
-                                     Tk=Tk, causal=causal, scale=scale)
-    acc_ref[0] = acc
-    # stats are lane-replicated to a trailing 128 dim: Mosaic requires the
-    # last two block dims to be (8k, 128k)-aligned, and a (1, TQ) block
-    # is not; callers read lane 0
-    m_ref[0] = jnp.broadcast_to(m[:, None], (TQ, 128))
-    l_ref[0] = jnp.broadcast_to(l[:, None], (TQ, 128))
+        grid=(BH // G, nq),
+        in_specs=_qkv_specs(G, TQ, Tk, D),
+        out_specs=[pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0))]
+        + [_stats_spec(G, TQ)] * n_stats,
+        out_shape=[_out_sds((BH, Tq, D),
+                            jnp.float32 if stats == "ml" else q.dtype, q)]
+        + [_out_sds((BH, nq, 1, TQ), jnp.float32, q)] * n_stats,
+        compiler_params=_PARALLEL,
+        name="flash_fwd",
+        interpret=interpret,
+    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D))
+    return (outs[0].reshape(B, H, Tq, D),
+            *(s.reshape(B, H, Tq) for s in outs[1:]))
 
 
 def flash_attention_stats(q, k, v, causal, scale, block_q=512,
@@ -190,34 +381,7 @@ def flash_attention_stats(q, k, v, causal, scale, block_q=512,
         m' = max(m_a, m_b);  l' = l_a*e^{m_a-m'} + l_b*e^{m_b-m'}
         acc' = acc_a*e^{m_a-m'} + acc_b*e^{m_b-m'};  out = acc'/l'
     """
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
-    kern = functools.partial(_flash_stats_kernel, TQ=TQ, BK=BK, Tk=Tk,
-                             causal=causal, scale=scale)
-    acc, m, l = pl.pallas_call(
-        kern,
-        grid=(BH, Tq // TQ),
-        in_specs=[
-            pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, TQ, 128), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, TQ, 128), lambda b, t: (b, t, 0)),
-        ],
-        out_shape=[
-            _out_sds((BH, Tq, D), jnp.float32, q),
-            _out_sds((BH, Tq, 128), jnp.float32, q),
-            _out_sds((BH, Tq, 128), jnp.float32, q),
-        ],
-        interpret=INTERPRET,
-    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D))
-    return (acc.reshape(B, H, Tq, D), m[..., 0].reshape(B, H, Tq),
-            l[..., 0].reshape(B, H, Tq))
+    return _flash_fwd_call(q, k, v, causal, scale, block_q, block_k, "ml")
 
 
 def lse_of(m, l):
@@ -226,218 +390,300 @@ def lse_of(m, l):
     return jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), jnp.inf)
 
 
-def pack_stats(lse, delta):
-    """(…,T) lse/delta -> one (…,T,128) f32 array for kernel input: lane 0
-    is lse, lane 1 is delta.  Mosaic wants the last two block dims
-    (8k, 128k)-aligned, so per-row scalars ride a 128-lane vector; packing
-    both into one array halves the HBM traffic vs two broadcasts."""
-    st = jnp.stack([lse, delta], axis=-1).astype(jnp.float32)
-    return jnp.pad(st, [(0, 0)] * (st.ndim - 1) + [(0, 126)])
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *,
+               G, TQ, BK, Tk, causal, scale, square):
+    """dq for one Q block of ``G`` heads: loop over KV blocks, recompute p
+    from lse, accumulate ds @ K in f32.  Scores lie (TQ, BK) as in the
+    forward; the loop holds no reduction.  Causal bounds, mask and the
+    banded diagonal block as in ``_online_softmax_loop``."""
+    qi = pl.program_id(1)
+    D = q_ref.shape[-1]
+    fold = _fold_scale(scale)
+    qs = [q_ref[g] * jnp.asarray(scale, q_ref.dtype) if fold else q_ref[g]
+          for g in range(G)]
+    dos = [do_ref[g] for g in range(G)]
+    lses = [_col(lse_ref, g) for g in range(G)]      # (TQ, 1)
+    deltas = [_col(dl_ref, g) for g in range(G)]
+
+    def grad(dq, qb, dob, lse, delta, kblk, vblk, mask):
+        s = jax.lax.dot_general(qb, kblk, _NT,
+                                preferred_element_type=jnp.float32)
+        p = jnp.exp((s if fold else s * scale) - lse)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(dob, vblk, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta)).astype(kblk.dtype)
+        return dq + jax.lax.dot_general(
+            ds, kblk, _NN, preferred_element_type=jnp.float32)
+
+    def step(g, i, dq, masked):
+        off = pl.multiple_of(i * BK, BK)
+        mask = _visible((TQ, BK), 0, i * BK - qi * TQ) if masked else None
+        return grad(dq, qs[g], dos[g], lses[g], deltas[g],
+                    k_ref[g, pl.ds(off, BK), :], v_ref[g, pl.ds(off, BK), :],
+                    mask)
+
+    def diagonal(g, dq):
+        R, SB = _bands(TQ, _BAND_BWD)
+        base = pl.multiple_of(qi * BK, BK)
+        parts = []
+        for r in range(R):
+            rows, keys = slice(r * SB, (r + 1) * SB), (r + 1) * SB
+            parts.append(grad(
+                dq[rows], qs[g][rows], dos[g][rows], lses[g][rows],
+                deltas[g][rows], k_ref[g, pl.ds(base, keys), :],
+                v_ref[g, pl.ds(base, keys), :],
+                _visible((SB, keys), 0, -r * SB)))
+        return jnp.concatenate(parts, axis=0)
+
+    body = _each_head(G, step)
+    init = (jnp.zeros((TQ, D), jnp.float32),) * G
+    n = Tk // BK
+    if not causal:
+        dqs = jax.lax.fori_loop(0, n, functools.partial(body, masked=False),
+                                init)
+    elif square:
+        under = jax.lax.fori_loop(0, qi, functools.partial(body, masked=False),
+                                  init)
+        dqs = tuple(diagonal(g, dq) for g, dq in enumerate(under))
+    else:
+        full, end = _causal_bounds(qi * TQ, TQ, BK, n)
+        dqs = _two_loops(0, full, end, body, init, masked_first=False)
+    for g, dq in enumerate(dqs):
+        dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k):
-    """Forward emitting (out, lse) — the residual-producing pass for the
-    custom VJP.  Same online-softmax loop; lse = m + log(l)."""
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
+                dv_ref, *, G, TQ, BK, Tq, causal, scale, square):
+    """dk/dv for one KV block of ``G`` heads: loop over Q blocks.  Scores
+    lie transposed, (BK, TQ) = K Q^T: lse and delta are lane-dense rows as
+    they arrive, and all five products are plain ``A @ B`` or ``A @ B^T``
+    with the score tile as the streamed operand.  Causal: start at the
+    first Q block that can see this KV block, mask up to the last one the
+    diagonal crosses; ``square``: that is block ``ki`` alone, computed in
+    bands of keys (band r is seen by the queries from band r on)."""
+    ki = pl.program_id(1)
+    D = k_ref.shape[-1]
+    fold = _fold_scale(scale)
+    ks = [k_ref[g] * jnp.asarray(scale, k_ref.dtype) if fold else k_ref[g]
+          for g in range(G)]                         # (BK, D) each
+    vs = [v_ref[g] for g in range(G)]
+
+    def grad(carry, kb, vb, qb, dob, lse, delta, mask):
+        dk, dv = carry
+        sT = jax.lax.dot_general(kb, qb, _NT,
+                                 preferred_element_type=jnp.float32)
+        pT = jnp.exp((sT if fold else sT * scale) - lse)
+        if mask is not None:
+            pT = jnp.where(mask, pT, 0.0)
+        dv = dv + jax.lax.dot_general(
+            pT.astype(dob.dtype), dob, _NN,
+            preferred_element_type=jnp.float32)
+        dpT = jax.lax.dot_general(vb, dob, _NT,
+                                  preferred_element_type=jnp.float32)
+        dsT = (pT * (dpT - delta)).astype(qb.dtype)
+        dk = dk + jax.lax.dot_general(
+            dsT, qb, _NN, preferred_element_type=jnp.float32)
+        return dk, dv
+
+    def step(g, i, carry, masked):
+        off = pl.multiple_of(i * TQ, TQ)
+        mask = _visible((BK, TQ), 1, ki * BK - i * TQ) if masked else None
+        return grad(carry, ks[g], vs[g], q_ref[g, pl.ds(off, TQ), :],
+                    do_ref[g, pl.ds(off, TQ), :], lse_ref[g, i],
+                    dl_ref[g, i], mask)
+
+    def diagonal(g):
+        R, SB = _bands(BK, _BAND_BWD)
+        zero = jnp.zeros((SB, D), jnp.float32)
+        parts = []
+        for r in range(R):
+            rows, n_q = slice(r * SB, (r + 1) * SB), TQ - r * SB
+            off = pl.multiple_of(ki * TQ + r * SB, SB)
+            parts.append(grad(
+                (zero, zero), ks[g][rows], vs[g][rows],
+                q_ref[g, pl.ds(off, n_q), :], do_ref[g, pl.ds(off, n_q), :],
+                lse_ref[g, ki, :, r * SB:], dl_ref[g, ki, :, r * SB:],
+                _visible((SB, n_q), 1, 0)))
+        return tuple(jnp.concatenate(c, axis=0) for c in zip(*parts))
+
+    body = _each_head(G, step)
+    init = ((jnp.zeros((BK, D), jnp.float32),
+             jnp.zeros((BK, D), jnp.float32)),) * G
+    n = Tq // TQ
+    if not causal:
+        grads = jax.lax.fori_loop(
+            0, n, functools.partial(body, masked=False), init)
+    elif square:
+        grads = jax.lax.fori_loop(
+            ki + 1, n, functools.partial(body, masked=False),
+            tuple(diagonal(g) for g in range(G)))
+    else:
+        lo = (ki * BK) // TQ
+        # Q blocks from here on lie wholly under the diagonal
+        clear = jnp.minimum((ki * BK + BK - 1 + TQ - 1) // TQ, n)
+        grads = _two_loops(lo, clear, n, body, init, masked_first=True)
+    for g, (dk, dv) in enumerate(grads):
+        dk_ref[g] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[g] = dv.astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _dq_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
+                out_dtype, interpret):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     BH = B * H
     TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
-
-    def kern(q_ref, k_ref, v_ref, o_ref, lse_ref):
-        m, l, acc = _online_softmax_loop(q_ref, k_ref, v_ref, TQ=TQ,
-                                         BK=BK, Tk=Tk, causal=causal,
-                                         scale=scale)
-        o_ref[0] = (acc / jnp.maximum(l, 1e-37)[:, None]).astype(
-            o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(lse_of(m, l)[:, None], (TQ, 128))
-
-    out, lse = pl.pallas_call(
-        kern,
-        grid=(BH, Tq // TQ),
-        in_specs=[
-            pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, TQ, 128), lambda b, t: (b, t, 0)),
-        ],
-        out_shape=[
-            _out_sds((BH, Tq, D), q.dtype, q),
-            _out_sds((BH, Tq, 128), jnp.float32, q),
-        ],
-        interpret=INTERPRET,
-    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D))
-    return (out.reshape(B, H, Tq, D),
-            lse[..., 0].reshape(B, H, Tq))
+    nq = Tq // TQ
+    G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, G=G, TQ=TQ, BK=BK, Tk=Tk,
+                          causal=causal, scale=scale,
+                          square=TQ == BK and Tq == Tk),
+        grid=(BH // G, nq),
+        in_specs=_qkv_specs(G, TQ, Tk, D) + [
+            pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0)),
+            _stats_spec(G, TQ), _stats_spec(G, TQ)],
+        out_specs=pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0)),
+        out_shape=_out_sds((BH, Tq, D), out_dtype, q),
+        compiler_params=_PARALLEL,
+        name="flash_dq",
+        interpret=interpret,
+    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D),
+      do.reshape(BH, Tq, D), lse.reshape(BH, nq, 1, TQ),
+      delta.reshape(BH, nq, 1, TQ))
+    return dq.reshape(B, H, Tq, D)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, dq_ref, *, TQ, BK,
-               Tk, causal, scale):
-    """dq for one Q block: loop over KV blocks, recompute p from lse,
-    accumulate ds @ K in f32.  Causal: the loop stops at the last block
-    that intersects the diagonal (traced upper bound)."""
-    qi = pl.program_id(1)
-    qb = q_ref[0]                                    # (TQ, D)
-    dob = do_ref[0]
-    D = qb.shape[-1]
-    lse = st_ref[0, :, 0:1]                          # (TQ, 1)
-    delta = st_ref[0, :, 1:2]
-    q_pos = qi * TQ + jax.lax.broadcasted_iota(jnp.int32, (TQ, BK), 0)
-
-    def body(i, dq):
-        kblk = k_ref[0, pl.ds(i * BK, BK), :]
-        vblk = v_ref[0, pl.ds(i * BK, BK), :]
-        s = jax.lax.dot_general(
-            qb, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (TQ, BK)
-        p = jnp.exp(s - lse)
-        if causal:
-            k_pos = i * BK + jax.lax.broadcasted_iota(
-                jnp.int32, (TQ, BK), 1)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-        dp = jax.lax.dot_general(
-            dob, vblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (TQ, BK)
-        ds = (p * (dp - delta) * scale).astype(kblk.dtype)
-        return dq + jax.lax.dot_general(
-            ds, kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    n_blocks = Tk // BK
-    if causal:
-        n_blocks = jnp.minimum(n_blocks,
-                               (qi * TQ + TQ + BK - 1) // BK)
-    dq_ref[0] = jax.lax.fori_loop(
-        0, n_blocks, body, jnp.zeros((TQ, D), jnp.float32))
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, dk_ref, dv_ref, *,
-                TQ, BK, Tq, causal, scale):
-    """dk/dv for one KV block: loop over Q blocks.  Causal: start at the
-    first Q block that can see this KV block (traced lower bound)."""
-    ki = pl.program_id(1)
-    kb = k_ref[0]                                    # (BK, D)
-    vb = v_ref[0]
-    D = kb.shape[-1]
-    k_pos = ki * BK + jax.lax.broadcasted_iota(jnp.int32, (TQ, BK), 1)
-
-    def body(i, carry):
-        dk, dv = carry
-        qb = q_ref[0, pl.ds(i * TQ, TQ), :]
-        dob = do_ref[0, pl.ds(i * TQ, TQ), :]
-        lse = st_ref[0, pl.ds(i * TQ, TQ), 0:1]
-        delta = st_ref[0, pl.ds(i * TQ, TQ), 1:2]
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (TQ, BK)
-        p = jnp.exp(s - lse)
-        if causal:
-            q_pos = i * TQ + jax.lax.broadcasted_iota(
-                jnp.int32, (TQ, BK), 0)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-        dv = dv + jax.lax.dot_general(
-            p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (BK, D)
-        dp = jax.lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (TQ, BK)
-        ds = (p * (dp - delta) * scale).astype(qb.dtype)
-        dk = dk + jax.lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (BK, D)
-        return dk, dv
-
-    lo = (ki * BK) // TQ if causal else 0
-    dk, dv = jax.lax.fori_loop(
-        lo, Tq // TQ, body,
-        (jnp.zeros((BK, D), jnp.float32), jnp.zeros((BK, D), jnp.float32)))
-    dk_ref[0] = dk
-    dv_ref[0] = dv
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _dkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
+                 out_dtype, interpret):
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    BH = B * H
+    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
+    nq = Tq // TQ
+    G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
+    whole_q = pl.BlockSpec((G, Tq, D), lambda b, t: (b, 0, 0))
+    kv_blk = pl.BlockSpec((G, BK, D), lambda b, t: (b, t, 0))
+    whole_stats = pl.BlockSpec((G, nq, 1, TQ), lambda b, t: (b, 0, 0, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, G=G, TQ=TQ, BK=BK, Tq=Tq,
+                          causal=causal, scale=scale,
+                          square=TQ == BK and Tq == Tk),
+        grid=(BH // G, Tk // BK),
+        in_specs=[whole_q, kv_blk, kv_blk, whole_q, whole_stats,
+                  whole_stats],
+        out_specs=[kv_blk, kv_blk],
+        out_shape=[_out_sds((BH, Tk, D), out_dtype, q)] * 2,
+        compiler_params=_PARALLEL,
+        name="flash_dkv",
+        interpret=interpret,
+    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D),
+      do.reshape(BH, Tq, D), lse.reshape(BH, nq, 1, TQ),
+      delta.reshape(BH, nq, 1, TQ))
+    return dk.reshape(B, H, Tk, D), dv.reshape(B, H, Tk, D)
 
 
 def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
-                        block_q=512, block_k=512):
-    """Pallas flash backward: (dq, dk, dv) in f32 (callers accumulating
-    across ring steps keep full precision; standalone callers cast).
+                        block_q=512, block_k=512, out_dtype=jnp.float32):
+    """Pallas flash backward: (dq, dk, dv), in f32 unless the caller names
+    another ``out_dtype`` (callers accumulating across ring steps keep
+    full precision; the standalone VJP has the kernels cast).
 
     q/k/v/do: [B,H,T,D]; lse/delta: [B,H,Tq] f32 (global logsumexp and
     rowsum(dO*O) — for ring attention these are the FULL-sequence stats,
-    making each per-shard call an exact partial contribution)."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
-    st = pack_stats(lse, delta).reshape(BH, Tq, 128)
-    q3 = q.reshape(BH, Tq, D)
-    k3 = k.reshape(BH, Tk, D)
-    v3 = v.reshape(BH, Tk, D)
-    do3 = do.reshape(BH, Tq, D)
+    making each per-shard call an exact partial contribution).  They
+    enter the kernels lane-dense, T floats a head."""
+    lse = lse.astype(jnp.float32)
+    delta = delta.astype(jnp.float32)
+    args = (q, k, v, do, lse, delta, causal, scale, block_q, block_k,
+            jnp.dtype(out_dtype), INTERPRET)
+    return (_dq_program(*args), *_dkv_program(*args))
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, TQ=TQ, BK=BK, Tk=Tk, causal=causal,
-                          scale=scale),
-        grid=(BH, Tq // TQ),
-        in_specs=[
-            pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, TQ, 128), lambda b, t: (b, t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TQ, D), lambda b, t: (b, t, 0)),
-        out_shape=_out_sds((BH, Tq, D), jnp.float32, q),
-        interpret=INTERPRET,
-    )(q3, k3, v3, do3, st)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, TQ=TQ, BK=BK, Tq=Tq, causal=causal,
-                          scale=scale),
-        grid=(BH, Tk // BK),
-        in_specs=[
-            pl.BlockSpec((1, Tq, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, BK, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, BK, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, Tq, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, Tq, 128), lambda b, t: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BK, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, BK, D), lambda b, t: (b, t, 0)),
-        ],
-        out_shape=[
-            _out_sds((BH, Tk, D), jnp.float32, q),
-            _out_sds((BH, Tk, D), jnp.float32, q),
-        ],
-        interpret=INTERPRET,
-    )(q3, k3, v3, do3, st)
-    shp = (B, H, Tq, D)
-    return (dq.reshape(shp), dk.reshape(B, H, Tk, D),
-            dv.reshape(B, H, Tk, D))
+# ------------------------------------------------------------ partitioning
+# A bare ``pallas_call`` is a custom call the SPMD partitioner knows nothing
+# about: inside a program whose batch is sharded (the mesh fused step, batch
+# ``P('dp')``) it would gather q, k, v and run every sequence on every chip.
+# (``jax.experimental.custom_partitioning`` would say it in one rule; libtpu
+# has no emitter for it: "Custom emitter for CustomSPMDPartitioning not
+# found".)  So where the program is traced under a mesh (``jax.set_mesh``, as
+# ``ModuleFusedStep._step_mesh`` does), the three calls of the op's arm run
+# under ``shard_map``: batch rows follow the mesh's ``dp`` axis and heads its
+# ``tp`` axis (``parallel.mesh``'s names), T and D stay whole, and each device
+# runs the kernel on its own rows.  Without a mesh, on one device, or inside
+# a ``shard_map`` that already holds those axes, the call is made as it is.
+BATCH_AXIS, HEAD_AXIS = "dp", "tp"
+
+
+def _split(B, H):
+    """(mesh axis or None) for the batch and the head dimension: the
+    ambient mesh's free ``dp`` / ``tp`` axis where it divides B / H."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return tuple(
+        name if name in mesh.auto_axes and mesh.shape[name] > 1
+        and size % mesh.shape[name] == 0 else None
+        for name, size in ((BATCH_AXIS, B), (HEAD_AXIS, H)))
+
+
+def rows_per_device(B, H):
+    """(B, H) as one device of the ambient mesh holds them."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return tuple(size // mesh.shape[name] if name else size
+                 for name, size in zip(_split(B, H), (B, H)))
+
+
+def _on_own_rows(fn, *arrays):
+    """``fn(*arrays)`` over [B, H, ...] arrays and results, each device on
+    the rows and heads ``_split`` gives it."""
+    over = _split(*arrays[0].shape[:2])
+    if over == (None, None):
+        return fn(*arrays)
+
+    def spec(x):
+        return P(*over, *(None,) * (x.ndim - 2))
+
+    return jax.shard_map(
+        fn, in_specs=tuple(spec(a) for a in arrays),
+        out_specs=jax.tree.map(spec, jax.eval_shape(fn, *arrays)),
+        axis_names={a for a in over if a}, check_vma=False)(*arrays)
+
+
+def _scale_of(q, scale):
+    return scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
-                    block_k=512):
-    """[B,H,T,D] attention; Pallas kernels both directions."""
-    sc = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    return _flash_fwd(q, k, v, causal, sc, block_q, block_k)
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None):
+    """[B,H,T,D] attention; Pallas kernels both directions, each device
+    of an ambient mesh on its own batch rows and heads (``_on_own_rows``).
+    Blocks default to ``default_blocks`` of the shape."""
+    sc = _scale_of(q, scale)
+    return _on_own_rows(
+        lambda *qkv: _flash_fwd_call(*qkv, causal, sc, block_q, block_k,
+                                     None)[0], q, k, v)
 
 
 def _fa_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
-    sc = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    out, lse = _flash_fwd_lse(q, k, v, causal, sc, block_q, block_k)
+    sc = _scale_of(q, scale)
+    out, lse = _on_own_rows(
+        lambda *qkv: _flash_fwd_call(*qkv, causal, sc, block_q, block_k,
+                                     "lse"), q, k, v)
     return out, (q, k, v, out, lse)
 
 
 def _fa_vjp_bwd(causal, scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
-    sc = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
+    sc = _scale_of(q, scale)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
-    dq, dk, dv = flash_attention_bwd(q, k, v, g, lse, delta, causal, sc,
-                                     block_q, block_k)
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
+    return _on_own_rows(
+        lambda *xs: flash_attention_bwd(*xs, causal, sc, block_q, block_k,
+                                        q.dtype), q, k, v, g, lse, delta)
 
 
 flash_attention.defvjp(_fa_vjp_fwd, _fa_vjp_bwd)

@@ -59,7 +59,8 @@ def test_phases_rehearsed_on_cpu(capsys, monkeypatch):
             data_shape=shape, classes=10, steps=3, warmup=1)
         # f32: XLA:CPU has no bf16 x bf16 -> f32 dot for the interpreter
         chip_smoke.phase_kernels(dev, 0, lstm_tbh=(3, 8, 16),
-                                 attn_bhtd=(1, 2, 128, 8), dtype="float32")
+                                 attn_bhtd=((1, 2, 128, 8), (1, 4, 256, 16)),
+                                 dtype="float32")
         chip_smoke.phase_serving(dev, watch, 0, net_fn=_tiny_net,
                                  example_shape=shape, buckets=(2, 4),
                                  request_rows=(1, 4, 3))
@@ -73,7 +74,8 @@ def test_phases_rehearsed_on_cpu(capsys, monkeypatch):
     assert lines["module_step"]["step_dispatch"] == {"fused": 3, "eager": 0}
     # off the TPU the ops lower without the Mosaic call
     assert lines["kernels"]["rnn_op"]["tpu_custom_call"] is False
-    assert lines["kernels"]["mha_op"]["tpu_custom_call"] is False
+    assert [m["tpu_custom_call"] for m in lines["kernels"]["mha_op"]] \
+        == [False, False]
     assert lines["serving"]["post_warmup_compile_requests"] == 0
 
 

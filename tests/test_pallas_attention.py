@@ -255,3 +255,110 @@ def test_ring_flash_bwd_8way_mesh():
     for a, b, nme in zip(gp, gs, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5, err_msg=nme)
+
+
+def _dense(q, k, v, causal):
+    """Dense softmax attention in float32 at the highest matmul precision:
+    the reference the re-blocked kernels are held to."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision="highest") / (q.shape[-1] ** 0.5)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("blocks", [(None, None), (256, 256), (128, 256)],
+                         ids=["one-block", "256x256", "128x256"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_reblocked_kernels_at_the_cells_shape(causal, dtype, blocks):
+    """ISSUE 26: T 1024 / d 64, the GPT-2 cells' sequence.  ``one-block`` is
+    the shape's own choice (one 1024-block whose diagonal is cut into
+    bands); ``256x256`` skips whole blocks past the diagonal and masks only
+    the diagonal block, in bands; ``128x256`` takes the general path (two
+    loops, the second one masked).  Forward and q/k/v gradients against
+    the dense float32 reference prove the bounds and the diagonal-only mask
+    exact."""
+    r = np.random.default_rng(26)
+    q, k, v, co = (jnp.asarray(r.standard_normal((1, 2, 1024, 64)) * 0.5,
+                               dtype) for _ in range(4))
+    got_o = pa.flash_attention(q, k, v, causal, None, *blocks)
+    got = jax.grad(lambda *a: jnp.vdot(
+        pa.flash_attention(*a, causal, None, *blocks).astype(jnp.float32),
+        co.astype(jnp.float32)), (0, 1, 2))(q, k, v)
+    want_o = _dense(q, k, v, causal)
+    want = jax.grad(lambda *a: jnp.vdot(_dense(*a, causal),
+                                        co.astype(jnp.float32)),
+                    (0, 1, 2))(q, k, v)
+    assert got_o.dtype == dtype and all(g.dtype == dtype for g in got)
+    # bf16: the kernel rounds p and ds to bf16 as MXU operands, results too
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for a, b, nme in zip((got_o,) + got, (want_o,) + want,
+                         ("out", "dq", "dk", "dv")):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a, np.float32) / scale,
+                                   np.asarray(b, np.float32) / scale,
+                                   rtol=0, atol=tol, err_msg=nme)
+
+
+@pytest.mark.parametrize("shape,kernel", [
+    ((1, 16, 1024, 64), True),      # one chip's rows of both GPT-2 cells
+    ((4, 16, 1024, 64), True),
+    ((1, 64, 512, 64), True),       # 64 MB of scores: the smallest that wins
+    ((1, 16, 2048, 128), True),
+    ((1, 16, 512, 64), False),      # 16 MB of scores stay in VMEM: XLA wins
+    ((1, 64, 256, 128), False),
+    ((2, 4, 32, 16), False),        # the CPU rehearsals' T
+    ((1, 16, 1024, 60), False),     # d off the sublane tiling
+    ((1, 16, 16384, 128), False),   # K/V past the VMEM envelope
+])
+def test_mha_shape_test_is_the_measured_table(shape, kernel):
+    """ISSUE 26: ``MultiHeadAttention`` chooses kernel or XLA arm from what
+    it observes in its input, after the crossover measured with
+    ``tools/bench_attention_arms.py`` — and ring attention's gate, which no
+    cell judges, still says what it said."""
+    from mxnet_tpu.ops.nn import mha_uses_kernel
+    pa.INTERPRET = False            # the autouse hook admits every shape
+    assert mha_uses_kernel(*shape, jnp.bfloat16) is kernel
+    assert not pa.flash_attention_available(1, 16, 1024, 1024, 64,
+                                            jnp.bfloat16)
+    assert pa.flash_attention_available(1, 16, 2048, 2048, 64, jnp.bfloat16)
+
+
+def test_mha_op_keeps_each_device_on_its_own_rows():
+    """ISSUE 26, tentpole step 4: under ``jax.jit`` with the batch sharded
+    ``P('dp')`` over four (virtual) devices and the mesh in context, as
+    ``ModuleFusedStep._step_mesh`` traces its program, the op's kernel
+    calls run under ``shard_map``: value and gradients equal the unsharded
+    run, and the compiled module gathers nothing."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops.registry import OPS
+    B, T, Dm, Hn = 4, 128, 64, 4
+    r = np.random.default_rng(5)
+    x = jnp.asarray(r.standard_normal((B, T, Dm)) * 0.5, jnp.float32)
+    ws = [jnp.asarray(r.standard_normal((Dm, Dm)) * 0.1, jnp.float32)
+          for _ in range(4)]
+    fn = OPS["MultiHeadAttention"].fn
+    step = jax.value_and_grad(
+        lambda *a: jnp.sum(fn({"num_heads": Hn, "causal": True}, *a) ** 2),
+        tuple(range(5)))
+    want = step(x, *ws)
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    xs = jax.device_put(x, NamedSharding(mesh, P("dp")))
+    wr = [jax.device_put(w, NamedSharding(mesh, P())) for w in ws]
+    with jax.set_mesh(mesh):
+        assert pa.rows_per_device(B, Hn) == (1, Hn)
+        jitted = jax.jit(step)
+        got = jitted(xs, *wr)
+        hlo = jitted.lower(xs, *wr).compile().as_text()
+    assert pa.rows_per_device(B, Hn) == (B, Hn)      # no mesh, no split
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
+    assert got[1][0].sharding.spec == P("dp")
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
+    for a, b, nme in zip(got[1], want[1], ("x", "wq", "wk", "wv", "wo")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-4, err_msg=nme)

@@ -68,17 +68,50 @@ def test_described_device_is_the_v5e(topo):
     assert health.peak_tflops("bfloat16", topo.devices[0].device_kind) == 197.0
 
 
+@pytest.mark.parametrize("shape", [(8, 12, 2048, 64), (1, 16, 1024, 64)],
+                         ids=["chip_smoke", "gpt2m_cells"])
 @pytest.mark.parametrize("direction", ["forward", "forward+backward"])
-def test_flash_attention_compiles(one_chip, direction):
+def test_flash_attention_compiles(one_chip, direction, shape):
+    """chip_smoke's attention width, and one chip's rows of the GPT-2
+    cells: (1, 16, 1024, 64) bf16 causal, one 1024-block."""
     from mxnet_tpu.ops import pallas_attention as pa
-    B, H, T, D = 8, 12, 2048, 64          # chip_smoke's attention width
-    assert pa.flash_attention_available(B, H, T, T, D, jnp.bfloat16)
+    from mxnet_tpu.ops.nn import mha_uses_kernel
+    B, H, T, D = shape
+    assert mha_uses_kernel(B, H, T, D, jnp.bfloat16)
     q = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
     fn = functools.partial(pa.flash_attention, causal=True)
     if direction == "forward":
         assert _custom_calls(fn, q, q, q) == 1
     else:       # forward-with-lse, dq, dk/dv
         assert _custom_calls(jax.grad(_sq(fn), (0, 1, 2)), q, q, q) == 3
+
+
+def test_mha_op_compiles_for_four_chips_each_on_its_own_sequence(topo):
+    """``gpt2m_train_dp4``'s attention, ahead of time: the op over
+    (4, 1024, 1024) with the batch sharded over the four chips of the
+    described host and the mesh in context, as the mesh fused step traces
+    it.  Forward + backward hold three Mosaic calls, each over ONE
+    sequence's 16 heads, and nothing is gathered: a bare ``pallas_call``
+    would have every chip run all four."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops.registry import OPS
+    B, T, Dm, Hn = 4, 1024, 1024, 16
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    x = jax.ShapeDtypeStruct((B, T, Dm), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    w = jax.ShapeDtypeStruct((Dm, Dm), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+    fn = functools.partial(OPS["MultiHeadAttention"].fn,
+                           {"num_heads": Hn, "causal": True})
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(jax.grad(_sq(fn), (0, 1, 2, 3, 4))).lower(
+            x, w, w, w, w).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    for ln in calls:            # results and operands: one sequence, 16 heads
+        assert "bf16[16,1024,64]" in ln and "bf16[64,1024,64]" not in ln
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
