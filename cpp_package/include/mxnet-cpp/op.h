@@ -696,16 +696,28 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
     Symbol key_weight,
     Symbol value_weight,
     Symbol out_proj_weight,
+    Symbol q_norm_gamma,
+    Symbol k_norm_gamma,
     int num_heads,
-    bool causal = true) {
+    bool causal = true,
+    int num_kv_heads = 0,
+    bool qk_norm = false,
+    double rope_theta = 0.0,
+    double eps = 1e-05) {
   SymbolBuilder builder_("MultiHeadAttention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
+  builder_.SetParam("num_kv_heads", num_kv_heads);
+  builder_.SetParam("qk_norm", qk_norm ? 1 : 0);
+  builder_.SetParam("rope_theta", rope_theta);
+  builder_.SetParam("eps", eps);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);
   builder_.SetInput("value_weight", value_weight);
   builder_.SetInput("out_proj_weight", out_proj_weight);
+  builder_.SetInput("q_norm_gamma", q_norm_gamma);
+  builder_.SetInput("k_norm_gamma", k_norm_gamma);
   return builder_.CreateSymbol(symbol_name);
 }
 
@@ -856,6 +868,17 @@ inline Symbol Proposal(const std::string &symbol_name,
   return builder_.CreateSymbol(symbol_name);
 }
 
+inline Symbol RMSNorm(const std::string &symbol_name,
+    Symbol data,
+    Symbol gamma,
+    double eps = 1e-05) {
+  SymbolBuilder builder_("RMSNorm");
+  builder_.SetParam("eps", eps);
+  builder_.SetInput("data", data);
+  builder_.SetInput("gamma", gamma);
+  return builder_.CreateSymbol(symbol_name);
+}
+
 inline Symbol RNN(const std::string &symbol_name,
     Symbol data,
     Symbol parameters,
@@ -1002,6 +1025,21 @@ inline Symbol SequenceReverse(const std::string &symbol_name,
   return builder_.CreateSymbol(symbol_name);
 }
 
+inline Symbol ShortConv(const std::string &symbol_name,
+    Symbol data,
+    Symbol in_proj_weight,
+    Symbol conv_weight,
+    Symbol out_proj_weight,
+    int kernel = 3) {
+  SymbolBuilder builder_("ShortConv");
+  builder_.SetParam("kernel", kernel);
+  builder_.SetInput("data", data);
+  builder_.SetInput("in_proj_weight", in_proj_weight);
+  builder_.SetInput("conv_weight", conv_weight);
+  builder_.SetInput("out_proj_weight", out_proj_weight);
+  return builder_.CreateSymbol(symbol_name);
+}
+
 inline Symbol SliceChannel(const std::string &symbol_name,
     Symbol data,
     int num_outputs,
@@ -1071,6 +1109,35 @@ inline Symbol SoftmaxOutput(const std::string &symbol_name,
   builder_.SetParam("smooth_alpha", smooth_alpha);
   builder_.SetInput("data", data);
   builder_.SetInput("label", label);
+  return builder_.CreateSymbol(symbol_name);
+}
+
+inline Symbol SparseMoE(const std::string &symbol_name,
+    Symbol data,
+    Symbol router_weight,
+    Symbol expert_bias,
+    Symbol expert_gate_weight,
+    Symbol expert_up_weight,
+    Symbol expert_down_weight,
+    Symbol expert_load,
+    int num_experts,
+    int num_experts_per_tok,
+    int num_hidden,
+    int num_held = 0,
+    int expert_offset = 0) {
+  SymbolBuilder builder_("SparseMoE");
+  builder_.SetParam("num_experts", num_experts);
+  builder_.SetParam("num_experts_per_tok", num_experts_per_tok);
+  builder_.SetParam("num_hidden", num_hidden);
+  builder_.SetParam("num_held", num_held);
+  builder_.SetParam("expert_offset", expert_offset);
+  builder_.SetInput("data", data);
+  builder_.SetInput("router_weight", router_weight);
+  builder_.SetInput("expert_bias", expert_bias);
+  builder_.SetInput("expert_gate_weight", expert_gate_weight);
+  builder_.SetInput("expert_up_weight", expert_up_weight);
+  builder_.SetInput("expert_down_weight", expert_down_weight);
+  builder_.SetInput("expert_load", expert_load);
   return builder_.CreateSymbol(symbol_name);
 }
 
@@ -4165,16 +4232,28 @@ inline Symbol multiheadattention(const std::string &symbol_name,
     Symbol key_weight,
     Symbol value_weight,
     Symbol out_proj_weight,
+    Symbol q_norm_gamma,
+    Symbol k_norm_gamma,
     int num_heads,
-    bool causal = true) {
+    bool causal = true,
+    int num_kv_heads = 0,
+    bool qk_norm = false,
+    double rope_theta = 0.0,
+    double eps = 1e-05) {
   SymbolBuilder builder_("multiheadattention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
+  builder_.SetParam("num_kv_heads", num_kv_heads);
+  builder_.SetParam("qk_norm", qk_norm ? 1 : 0);
+  builder_.SetParam("rope_theta", rope_theta);
+  builder_.SetParam("eps", eps);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);
   builder_.SetInput("value_weight", value_weight);
   builder_.SetInput("out_proj_weight", out_proj_weight);
+  builder_.SetInput("q_norm_gamma", q_norm_gamma);
+  builder_.SetInput("k_norm_gamma", k_norm_gamma);
   return builder_.CreateSymbol(symbol_name);
 }
 
@@ -4610,6 +4689,17 @@ inline Symbol rint(const std::string &symbol_name,
   return builder_.CreateSymbol(symbol_name);
 }
 
+inline Symbol rmsnorm(const std::string &symbol_name,
+    Symbol data,
+    Symbol gamma,
+    double eps = 1e-05) {
+  SymbolBuilder builder_("rmsnorm");
+  builder_.SetParam("eps", eps);
+  builder_.SetInput("data", data);
+  builder_.SetInput("gamma", gamma);
+  return builder_.CreateSymbol(symbol_name);
+}
+
 inline Symbol rmsprop_update(const std::string &symbol_name,
     Symbol data0,
     Symbol data1,
@@ -4903,6 +4993,21 @@ inline Symbol shape_array(const std::string &symbol_name,
   return builder_.CreateSymbol(symbol_name);
 }
 
+inline Symbol shortconv(const std::string &symbol_name,
+    Symbol data,
+    Symbol in_proj_weight,
+    Symbol conv_weight,
+    Symbol out_proj_weight,
+    int kernel = 3) {
+  SymbolBuilder builder_("shortconv");
+  builder_.SetParam("kernel", kernel);
+  builder_.SetInput("data", data);
+  builder_.SetInput("in_proj_weight", in_proj_weight);
+  builder_.SetInput("conv_weight", conv_weight);
+  builder_.SetInput("out_proj_weight", out_proj_weight);
+  return builder_.CreateSymbol(symbol_name);
+}
+
 inline Symbol shuffle(const std::string &symbol_name,
     Symbol data) {
   SymbolBuilder builder_("shuffle");
@@ -5112,6 +5217,35 @@ inline Symbol sparse_retain(const std::string &symbol_name,
   SymbolBuilder builder_("sparse_retain");
   builder_.SetInput("data0", data0);
   builder_.SetInput("data1", data1);
+  return builder_.CreateSymbol(symbol_name);
+}
+
+inline Symbol sparsemoe(const std::string &symbol_name,
+    Symbol data,
+    Symbol router_weight,
+    Symbol expert_bias,
+    Symbol expert_gate_weight,
+    Symbol expert_up_weight,
+    Symbol expert_down_weight,
+    Symbol expert_load,
+    int num_experts,
+    int num_experts_per_tok,
+    int num_hidden,
+    int num_held = 0,
+    int expert_offset = 0) {
+  SymbolBuilder builder_("sparsemoe");
+  builder_.SetParam("num_experts", num_experts);
+  builder_.SetParam("num_experts_per_tok", num_experts_per_tok);
+  builder_.SetParam("num_hidden", num_hidden);
+  builder_.SetParam("num_held", num_held);
+  builder_.SetParam("expert_offset", expert_offset);
+  builder_.SetInput("data", data);
+  builder_.SetInput("router_weight", router_weight);
+  builder_.SetInput("expert_bias", expert_bias);
+  builder_.SetInput("expert_gate_weight", expert_gate_weight);
+  builder_.SetInput("expert_up_weight", expert_up_weight);
+  builder_.SetInput("expert_down_weight", expert_down_weight);
+  builder_.SetInput("expert_load", expert_load);
   return builder_.CreateSymbol(symbol_name);
 }
 

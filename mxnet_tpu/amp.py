@@ -13,7 +13,9 @@ chips.  This module is the single source of the dtype policy, gated by
   from the new master (``Optimizer.fused_update_mp`` on the fused path,
   the generic ``update_multi_precision`` as the eager parity oracle);
 - loss reduction, softmax, batchnorm statistics and normalization
-  scale/shift (``*_gamma``/``*_beta``) stay fp32.
+  scale/shift (``*_gamma``/``*_beta``) stay fp32, and so does what decides
+  a sparse expert layer's routing (``*_router_weight``, ``*_expert_bias``):
+  a selection that flips with bf16 rounding sends a token to other experts.
 
 The flag is read at BIND time (it decides array dtypes) and joins every
 fused-program jit-cache key through ``Executor.STEP_ENV_KEYS`` (GL001),
@@ -35,6 +37,10 @@ ENV_FLAG = "MXNET_TPU_BF16"
 
 # dtypes that carry a master-fp32 copy through the optimizer
 _LOW_PRECISION = ("bfloat16", "float16")
+
+# arguments that stay fp32 under the policy, by the end of their name:
+# normalization gains/shifts, SparseMoE's router and selection bias
+_FP32_SUFFIXES = ("_gamma", "_beta", "_router_weight", "_expert_bias")
 
 
 def enabled():
@@ -65,14 +71,16 @@ def type_dict_for(symbol, data_names, label_names):
     backward runs bf16 too); labels stay fp32 (the loss head reduces in
     fp32) as do ``*_gamma``/``*_beta`` normalization params — their
     per-channel scale math is fp32-accumulated regardless of activation
-    dtype, and keeping them fp32 costs nothing (channel-sized).  Aux
-    states (moving stats) are fp32 by ``infer_type`` default.
+    dtype, and keeping them fp32 costs nothing (channel-sized) — and a
+    sparse expert layer's router and selection bias (``_FP32_SUFFIXES``).
+    Aux states (moving stats, expert load) are fp32 by ``infer_type``
+    default.
     """
     bf16 = compute_dtype()
     label_set = set(label_names or ())
     td = {}
     for n in symbol.list_arguments():
-        if n in label_set or n.endswith("_gamma") or n.endswith("_beta"):
+        if n in label_set or n.endswith(_FP32_SUFFIXES):
             td[n] = np.float32
         else:
             td[n] = bf16

@@ -14,6 +14,19 @@ from dataclasses import dataclass
 
 @dataclass(frozen=True)
 class TransformerConfig:
+    """The seven fields every consumer passes (positionally too) describe a
+    GPT-2-style decoder; the fields after them, all with defaults that
+    leave that graph as it is, select the block variants ``transformer_lm``
+    can build: the norm (``layer`` | ``rms``), positions (``learned`` |
+    ``rope``), the feed-forward (``gelu``: fc1/gelu/down with
+    biases; ``swiglu``: down(silu(gate) * up), no bias), grouped key/value
+    heads and per-head query/key normalisation, a pattern of sequence
+    mixers per layer (``full_attention`` | ``conv``, the gated short
+    convolution; empty: attention everywhere), and sparse experts in place
+    of the feed-forward from layer ``num_dense_layers`` on
+    (``num_experts`` > 0): ``experts_held`` of them from ``expert_offset``
+    live in this graph (0: all), each ``moe_d_ff`` wide, ``experts_per_tok``
+    a token."""
     name: str
     vocab_size: int
     n_layers: int
@@ -21,19 +34,49 @@ class TransformerConfig:
     n_heads: int
     d_ff: int
     seq_len: int
+    norm: str = "layer"
+    norm_eps: float = 1e-5
+    position: str = "learned"
+    rope_theta: float = 10000.0
+    ffn: str = "gelu"
+    n_kv_heads: int = 0
+    qk_norm: bool = False
+    layer_types: tuple = ()
+    conv_kernel: int = 3
+    num_dense_layers: int = 0
+    num_experts: int = 0
+    experts_per_tok: int = 0
+    experts_held: int = 0
+    expert_offset: int = 0
+    moe_d_ff: int = 0
+    tie_head: bool = False
 
     def __post_init__(self):
         if self.d_model % self.n_heads:
             raise ValueError("d_model %d not divisible by n_heads %d"
                              % (self.d_model, self.n_heads))
+        for field, known in (("norm", ("layer", "rms")),
+                             ("position", ("learned", "rope")),
+                             ("ffn", ("gelu", "swiglu"))):
+            if getattr(self, field) not in known:
+                raise ValueError("%s %r is not one of %s"
+                                 % (field, getattr(self, field), known))
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError("layer_types names %d layers of %d"
+                             % (len(self.layer_types), self.n_layers))
+        for kind in self.layer_types:
+            if kind not in ("full_attention", "conv"):
+                raise ValueError("layer type %r is not full_attention or "
+                                 "conv" % (kind,))
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
     def n_params(self) -> int:
-        """Weight count of the matmul-bearing parameters (embedding +
-        per-block QKVO/FFN + untied LM head; norms excluded — noise)."""
+        """Weight count of the matmul-bearing parameters of the GPT-2-style
+        graph the first seven fields describe (embedding + per-block
+        QKVO/FFN + untied LM head; norms excluded — noise)."""
         d, f, L, v = self.d_model, self.d_ff, self.n_layers, self.vocab_size
         return v * d + L * (4 * d * d + 2 * d * f) + d * v
 

@@ -8,7 +8,13 @@ names.  Two layers of API:
   training graph that binds through Module and runs the fused/mesh step
   end to end — Embedding, pre-norm blocks around the
   ``MultiHeadAttention`` op (Pallas flash kernel behind
-  ``MXNET_TPU_FLASH_ATTENTION``), gelu FFN, streaming-CE loss.
+  ``MXNET_TPU_FLASH_ATTENTION``), gelu FFN, streaming-CE loss.  The
+  fields of ``TransformerConfig`` after its first seven select the block
+  variants of the one definition (RMSNorm, rotary positions, grouped
+  key/value heads, the gated SiLU feed-forward, ``ShortConv`` layers by a
+  layer pattern, ``SparseMoE`` experts after leading dense layers, a head
+  tied to the embedding): an LFM2-class hybrid is a config, not a second
+  model.
   Parameter names are chosen so ``parallel.mesh.megatron_rules`` shards
   a DP×TP mesh with zero configuration: ``*_query/key/value_weight`` and
   ``*_fc1_weight`` column-parallel, ``*_out_proj_weight`` and
@@ -40,21 +46,65 @@ from ..ops.registry import OPS
 # ---------------------------------------------------------------------------
 # Symbol graph
 # ---------------------------------------------------------------------------
-def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str):
-    """One pre-norm decoder block: x + Attn(LN(x)); x + FFN(LN(x))."""
+def _norm(x, cfg: TransformerConfig, name: str):
     from .. import symbol as sym
-    n = "%sl%d_" % (prefix, idx)
-    h = sym.LayerNorm(x, name=n + "ln1")
-    a = sym.MultiHeadAttention(h, num_heads=cfg.n_heads, causal=True,
-                               name=n + "attn")
-    x = sym.elemwise_add(x, a, name=n + "attn_res")
-    h = sym.LayerNorm(x, name=n + "ln2")
+    if cfg.norm == "rms":
+        return sym.RMSNorm(x, eps=cfg.norm_eps, name=name)
+    return sym.LayerNorm(x, eps=cfg.norm_eps, name=name)
+
+
+def _feed_forward(h, cfg: TransformerConfig, idx: int, n: str):
+    """The block's second half on the normed stream: sparse experts from
+    layer ``num_dense_layers`` on where the config has experts, else the
+    dense feed-forward of the config's kind."""
+    from .. import symbol as sym
+    if cfg.num_experts and idx >= cfg.num_dense_layers:
+        return sym.SparseMoE(
+            h, num_experts=cfg.num_experts,
+            num_experts_per_tok=cfg.experts_per_tok,
+            num_hidden=cfg.moe_d_ff, num_held=cfg.experts_held,
+            expert_offset=cfg.expert_offset, name=n + "moe")
+    if cfg.ffn == "swiglu":
+        gate = sym.FullyConnected(h, num_hidden=cfg.d_ff, flatten=False,
+                                  no_bias=True, name=n + "ffn_gate")
+        up = sym.FullyConnected(h, num_hidden=cfg.d_ff, flatten=False,
+                                no_bias=True, name=n + "ffn_up")
+        f = sym.elemwise_mul(
+            sym.Activation(gate, act_type="silu", name=n + "ffn_silu"), up,
+            name=n + "ffn_gated")
+        return sym.FullyConnected(f, num_hidden=cfg.d_model, flatten=False,
+                                  no_bias=True, name=n + "ffn_down")
     f = sym.FullyConnected(h, num_hidden=cfg.d_ff, flatten=False,
                            no_bias=False, name=n + "ffn_fc1")
     f = sym.Activation(f, act_type="gelu", name=n + "ffn_gelu")
-    f = sym.FullyConnected(f, num_hidden=cfg.d_model, flatten=False,
-                           no_bias=False, name=n + "ffn_down")
-    return sym.elemwise_add(x, f, name=n + "ffn_res")
+    return sym.FullyConnected(f, num_hidden=cfg.d_model, flatten=False,
+                              no_bias=False, name=n + "ffn_down")
+
+
+def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str):
+    """One pre-norm decoder block: x + Mix(Norm(x)); x + FFN(Norm(x)).
+    ``Mix`` is attention, or the gated short convolution where
+    ``cfg.layer_types[idx]`` says ``conv``."""
+    from .. import symbol as sym
+    n = "%sl%d_" % (prefix, idx)
+    h = _norm(x, cfg, n + "ln1")
+    if cfg.layer_types and cfg.layer_types[idx] == "conv":
+        a = sym.ShortConv(h, kernel=cfg.conv_kernel, name=n + "conv")
+        x = sym.elemwise_add(x, a, name=n + "conv_res")
+    else:
+        variants = {}
+        if cfg.n_kv_heads and cfg.n_kv_heads != cfg.n_heads:
+            variants["num_kv_heads"] = cfg.n_kv_heads
+        if cfg.qk_norm:
+            variants.update(qk_norm=True, eps=cfg.norm_eps)
+        if cfg.position == "rope":
+            variants["rope_theta"] = cfg.rope_theta
+        a = sym.MultiHeadAttention(h, num_heads=cfg.n_heads, causal=True,
+                                   name=n + "attn", **variants)
+        x = sym.elemwise_add(x, a, name=n + "attn_res")
+    h = _norm(x, cfg, n + "ln2")
+    return sym.elemwise_add(x, _feed_forward(h, cfg, idx, n),
+                            name=n + "ffn_res")
 
 
 def transformer_lm(cfg: TransformerConfig, prefix: str = "tfm_",
@@ -67,32 +117,39 @@ def transformer_lm(cfg: TransformerConfig, prefix: str = "tfm_",
     ``get_outputs()[0]`` IS the batch loss.  ``loss=False``: returns the
     ``(B, T, vocab)`` logits (serving / eval).
 
-    Positions are encoded with a learned table added post-embedding
-    (gpt2 style); data is ``(B, T)`` token ids, label ``(B, T)`` next
-    tokens.
+    Data is ``(B, T)`` token ids, label ``(B, T)`` next tokens.  Positions
+    (``cfg.position``): ``learned`` adds a learned table post-embedding
+    (gpt2 style), ``rope`` turns queries and keys inside every attention
+    layer.  ``cfg.tie_head`` computes the logits on
+    the embedding's own rows: one argument, ``<prefix>tok_embedding_weight``,
+    whose gradient is the sum of its two uses.
     """
     from .. import symbol as sym
     data = sym.Variable("data")                       # (B, T) token ids
-    tok = sym.Embedding(data, input_dim=cfg.vocab_size,
-                        output_dim=cfg.d_model,
-                        name=prefix + "tok_embedding")
-    # learned positions: arange(T) broadcast over the batch rides the
-    # same Embedding op — slice_axis of a (1, T) iota variable would need
-    # a T-sized input; instead embed positions of `data*0 + iota` shape
-    pos_ids = sym.broadcast_like(
-        sym.expand_dims(sym.arange(0, cfg.seq_len, name=prefix + "iota"),
-                        axis=0),
-        data, name=prefix + "pos_ids")
-    pos = sym.Embedding(pos_ids, input_dim=cfg.seq_len,
-                        output_dim=cfg.d_model,
-                        name=prefix + "pos_embedding")
-    x = sym.broadcast_add(tok, pos, name=prefix + "embed_sum")
+    table = {"weight": sym.Variable(prefix + "tok_embedding_weight")} \
+        if cfg.tie_head else {}
+    x = sym.Embedding(data, input_dim=cfg.vocab_size,
+                      output_dim=cfg.d_model,
+                      name=prefix + "tok_embedding", **table)
+    if cfg.position == "learned":
+        # learned positions: arange(T) broadcast over the batch rides the
+        # same Embedding op — slice_axis of a (1, T) iota variable would
+        # need a T-sized input; instead embed positions of `data*0 + iota`
+        # shape
+        pos_ids = sym.broadcast_like(
+            sym.expand_dims(sym.arange(0, cfg.seq_len, name=prefix + "iota"),
+                            axis=0),
+            data, name=prefix + "pos_ids")
+        pos = sym.Embedding(pos_ids, input_dim=cfg.seq_len,
+                            output_dim=cfg.d_model,
+                            name=prefix + "pos_embedding")
+        x = sym.broadcast_add(x, pos, name=prefix + "embed_sum")
     for i in range(cfg.n_layers):
         x = transformer_block(x, cfg, i, prefix)
-    x = sym.LayerNorm(x, name=prefix + "final_ln")
+    x = _norm(x, cfg, prefix + "final_ln")
     logits = sym.FullyConnected(x, num_hidden=cfg.vocab_size,
                                 flatten=False, no_bias=True,
-                                name=prefix + "lm_head")
+                                name=prefix + "lm_head", **table)
     if not loss:
         return logits
     label = sym.Variable("softmax_label")             # (B, T) next ids

@@ -27,6 +27,16 @@ from .executor_group import DataParallelExecutorGroup
 
 __all__ = ["Module"]
 
+# SparseMoE's auxiliary state (selections each expert got in the last
+# step), published where the Module copies auxiliary states to the host
+# anyway (get_params): reading it there costs no sync of its own.
+_EXPERT_LOAD_SUFFIX = "_expert_load"
+_MOE_EXPERT_LOAD = _telemetry.gauge(
+    "moe_expert_load",
+    "Selections each expert of a SparseMoE layer received in the last "
+    "training step (from the op's expert_load auxiliary state, as of the "
+    "last Module.get_params)", ("layer", "expert"))
+
 
 class Module(BaseModule):
     def __init__(self, symbol, data_names=("data",),
@@ -413,6 +423,13 @@ class Module(BaseModule):
             fs.demesh()
         arg, aux = {}, {}
         self._exec_group.get_params(arg, aux)
+        if _telemetry.enabled:
+            for name, load in aux.items():
+                if name.endswith(_EXPERT_LOAD_SUFFIX):
+                    layer = name[:-len(_EXPERT_LOAD_SUFFIX)]
+                    for e, n in enumerate(load.asnumpy()):
+                        _MOE_EXPERT_LOAD.labels(
+                            layer=layer, expert=str(e)).set(float(n))
         return arg, aux
 
     def install_monitor(self, mon):

@@ -442,7 +442,7 @@ def _pooling(attrs, data):
 
 @register("Activation", nin=1, aliases=("activation",),
           params={"act_type": param(["relu", "sigmoid", "tanh", "softrelu",
-                                     "softsign", "gelu"], "relu",
+                                     "softsign", "gelu", "silu"], "relu",
                                     required=True)})
 def _activation(attrs, x):
     act = attrs["act_type"]
@@ -458,6 +458,8 @@ def _activation(attrs, x):
         # exact (erf) formulation: the tanh approximation would put the
         # fused and eager transformer steps on different curves
         return jax.nn.gelu(x, approximate=False)
+    if act == "silu":
+        return jax.nn.silu(x)
     return jax.nn.soft_sign(x)
 
 
@@ -579,6 +581,42 @@ def _layer_norm(attrs, data, gamma, beta):
     return (out.astype(data.dtype), jnp.squeeze(mean, ax), jnp.squeeze(var, ax))
 
 
+def _rms_norm_last(x, gamma, eps):
+    """``gamma * x / sqrt(mean(x^2) + eps)`` over the last axis: the mean
+    and the scaling in float32 whatever ``x`` is stored in, the result in
+    ``x``'s own type."""
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+                        + eps)
+    return (x32 * inv * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+@register("RMSNorm", nin=2, aliases=("rmsnorm",),
+          params={"eps": param(float, 1e-5)})
+def _rms_norm(attrs, data, gamma):
+    """Root-mean-square normalisation over the last axis (Zhang & Sennrich
+    2019): ``gamma * x / sqrt(mean(x^2) + eps)``, no mean subtracted and no
+    shift.  Statistics in float32 (``*_gamma`` stays float32 under the bf16
+    policy, ``amp.type_dict_for``).  No reference analog: the 2018 reference
+    has LayerNorm only."""
+    return _rms_norm_last(data, gamma, attrs["eps"])
+
+
+def _rotary(x, theta):
+    """Rotary positions on [B,H,T,d] in the rotate-half pairing (dimension
+    i turns with dimension i + d/2 by ``t * theta^(-2i/d)``), computed in
+    float32 and stored in ``x``'s type."""
+    T, d = x.shape[-2:]
+    half = d // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)               # [T, d/2]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 _ATTN_DISPATCH = _telemetry.counter(
     "attention_dispatch_total",
     "MultiHeadAttention dispatch decisions by formulation path (trace-time)",
@@ -648,16 +686,33 @@ def mha_uses_kernel(B, H, T, d, dtype):
     return pa.INTERPRET or B * H * T * T * 4 >= 64 << 20
 
 
-@register("MultiHeadAttention", nin=5, aliases=("multiheadattention",),
+@register("MultiHeadAttention", nin=-1, aliases=("multiheadattention",),
           params={"num_heads": param(int, 0, required=True),
-                  "causal": param(bool, True)},
+                  "causal": param(bool, True),
+                  "num_kv_heads": param(int, 0),
+                  "qk_norm": param(bool, False),
+                  "rope_theta": param(float, 0.0),
+                  "eps": param(float, 1e-5)},
           env_keys=("MXNET_TPU_FLASH_ATTENTION", "MXNET_TPU_PALLAS_ATTN"))
 def _multi_head_attention(attrs, data, query_weight, key_weight,
-                          value_weight, out_proj_weight):
+                          value_weight, out_proj_weight, *qk_gammas):
     """Decoder attention: QKV projections, scaled-dot-product over
     ``num_heads``, output projection.  No reference analog — the
     reference predates transformer first-class ops; the contract follows
     ``sym.FullyConnected`` conventions (weights are (out, in), y=x·Wᵀ).
+
+    Variants, all off by default (GPT-2's graph keeps its five inputs and
+    its program): ``num_kv_heads`` < ``num_heads`` gives grouped-query
+    attention — ``key_weight`` / ``value_weight`` are
+    ``(num_kv_heads * head, model_dim)`` and query head ``i`` attends to
+    key/value head ``i // (num_heads / num_kv_heads)``; the heads are
+    repeated in front of the score product, so a key/value head's gradient
+    is the sum over its query heads by autodiff.  ``qk_norm`` adds two
+    inputs, ``q_norm_gamma`` and ``k_norm_gamma`` of ``(head,)``: RMSNorm
+    (``eps``) over each head of the queries and keys.  ``rope_theta`` > 0
+    turns queries and keys by rotary positions (rotate-half pairing) after
+    the normalisation.  All three happen in front of the arm's choice: the
+    flash kernels and the XLA arm see normalised, rotated, repeated heads.
 
     Dispatch: ``MXNET_TPU_FLASH_ATTENTION`` (default on) selects the
     Pallas flash kernel (ops/pallas_attention.py) wherever
@@ -685,15 +740,35 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
         raise MXNetError(
             "MultiHeadAttention: num_heads=%d must divide model_dim=%d"
             % (H, D))
+    # the variants' attrs by .get: callers of the bare fn pass GPT-2's two
+    Hkv = attrs.get("num_kv_heads") or H
+    if H % Hkv:
+        raise MXNetError(
+            "MultiHeadAttention: num_kv_heads=%d must divide num_heads=%d"
+            % (Hkv, H))
+    qk_norm, theta = bool(attrs.get("qk_norm")), attrs.get("rope_theta") or 0
+    if qk_norm != (len(qk_gammas) == 2):
+        raise MXNetError(
+            "MultiHeadAttention: qk_norm takes q_norm_gamma and "
+            "k_norm_gamma, and only qk_norm does (got %d extra inputs)"
+            % len(qk_gammas))
     d = D // H
     causal = attrs["causal"]
     scale = 1.0 / (d ** 0.5)
 
-    def proj(w):
-        y = jnp.matmul(data, w.T)                     # [B,T,D]
-        return y.reshape(B, T, H, d).transpose(0, 2, 1, 3)   # [B,H,T,d]
+    def proj(w, heads):
+        y = jnp.matmul(data, w.T)                     # [B,T,heads*d]
+        return y.reshape(B, T, heads, d).transpose(0, 2, 1, 3)
 
-    q, k, v = proj(query_weight), proj(key_weight), proj(value_weight)
+    q, k, v = proj(query_weight, H), proj(key_weight, Hkv), \
+        proj(value_weight, Hkv)                       # [B,heads,T,d]
+    if qk_norm:
+        q = _rms_norm_last(q, qk_gammas[0], attrs.get("eps", 1e-5))
+        k = _rms_norm_last(k, qk_gammas[1], attrs.get("eps", 1e-5))
+    if theta > 0:
+        q, k = _rotary(q, theta), _rotary(k, theta)
+    if Hkv != H:
+        k, v = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
 
     use_flash = os.environ.get("MXNET_TPU_FLASH_ATTENTION", "1") != "0" \
         and pa.enabled() \
@@ -712,6 +787,144 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
         _ATTN_DISPATCH.labels(path=path).inc()
     out = out.transpose(0, 2, 1, 3).reshape(B, T, D)  # [B,T,D]
     return jnp.matmul(out, out_proj_weight.T)
+
+
+_SHORTCONV_DISPATCH = _telemetry.counter(
+    "shortconv_dispatch_total",
+    "ShortConv dispatch decisions by formulation path (trace-time)",
+    ("path",))
+
+
+@register("ShortConv", nin=4, aliases=("shortconv",),
+          params={"kernel": param(int, 3)})
+def _short_conv(attrs, data, in_proj_weight, conv_weight, out_proj_weight):
+    """Gated short causal convolution (the LFM2 ``conv`` layer; Hasani et
+    al. 2024): ``[b, c, x] = split3(data · W_inᵀ)``, ``u = b * x``,
+    ``conv_t = sum_j k_j * u_{t-(L-1)+j}`` with ``u`` zero before position
+    0 (depthwise, causal, ``L = kernel`` taps, no bias), and
+    ``out = (c * conv) · W_outᵀ``.
+
+    ``data`` is (batch, time, dim); ``in_proj_weight`` (3 dim, dim) and
+    ``out_proj_weight`` (dim, dim) lie (out, in) as FullyConnected's do (the
+    first column-parallel, the second row-parallel under
+    ``megatron_rules``); ``conv_weight`` is (dim, kernel), one row of taps a
+    channel, the last tap on the current position.  The ``L`` taps are
+    ``L`` shifted multiply-adds that XLA fuses with the two gates (path
+    ``shift`` of ``shortconv_dispatch_total``), accumulated in float32.  No
+    reference analog."""
+    if data.ndim != 3:
+        raise MXNetError(
+            "ShortConv: data must be (batch, time, dim), got %s"
+            % (data.shape,))
+    T = data.shape[1]
+    L = attrs["kernel"]
+    if conv_weight.shape[-1] != L:
+        raise MXNetError("ShortConv: conv_weight %s has not kernel=%d taps"
+                         % (conv_weight.shape, L))
+    b, c, x = jnp.split(jnp.matmul(data, in_proj_weight.T), 3, axis=-1)
+    u = jnp.pad((b * x).astype(jnp.float32), ((0, 0), (L - 1, 0), (0, 0)))
+    taps = conv_weight.astype(jnp.float32)
+    conv = sum(u[:, j:j + T, :] * taps[:, j] for j in range(L))
+    if _telemetry.enabled:
+        # graftlint: disable=GL002 -- counts compiled variants, not calls
+        _SHORTCONV_DISPATCH.labels(path="shift").inc()
+    gated = (c.astype(jnp.float32) * conv).astype(data.dtype)
+    return jnp.matmul(gated, out_proj_weight.T)
+
+
+_MOE_DISPATCH = _telemetry.counter(
+    "moe_dispatch_total",
+    "SparseMoE dispatch decisions by formulation of the held experts' "
+    "products (trace-time)", ("path",))
+
+
+@register("SparseMoE", nin=7, aliases=("sparsemoe",), nout=2, visible=1,
+          aux_writeback={1: 6},
+          params={"num_experts": param(int, 0, required=True),
+                  "num_experts_per_tok": param(int, 0, required=True),
+                  "num_hidden": param(int, 0, required=True),
+                  "num_held": param(int, 0),
+                  "expert_offset": param(int, 0)})
+def _sparse_moe(attrs, data, router_weight, expert_bias, expert_gate_weight,
+                expert_up_weight, expert_down_weight, expert_load):
+    """Sigmoid-routed sparse mixture of gated (SiLU) experts that is told
+    which experts it holds (the LFM2 / DeepSeek-V3 style of routing).
+
+    Routing runs over all ``num_experts``: ``s = sigmoid(x · W_gᵀ)`` in
+    float32, ``sel = top_k(s + expert_bias)`` (the bias, a buffer, takes no
+    gradient and only steers the selection), weights ``w_e = s_e`` for
+    ``e`` in ``sel``, divided by ``sum_sel s + 1e-6`` (the source's
+    ``norm_topk_prob`` with a ``routed_scaling_factor`` of 1: the one
+    weighting the op has).  The op HOLDS the
+    experts ``expert_offset .. expert_offset + held`` (``held`` is the
+    leading axis of the three expert weights; ``num_held`` only tells shape
+    inference how many to allocate, 0 meaning all) and returns
+    ``sum_{e in sel, e held} w_e * Expert_e(x)`` with
+    ``Expert_e(x) = (silu(x · G_e) * (x · U_e)) · D_e``: its own experts'
+    part of the layer's result.  What other holders' experts add is theirs
+    to compute; summed over the holders the parts are the whole layer
+    (``tests/test_lfm2_ops.py``).  On one chip there is no exchange.
+
+    Shapes are static, no token is dropped and the cost does not follow the
+    routing: every held expert runs over EVERY token, as one gated
+    feed-forward of ``held x num_hidden`` columns (three dense products
+    forward, six backward), and a token's column block of expert ``e`` is
+    scaled by ``w_e`` where the token selected ``e`` and by nought where it
+    did not (path ``dense`` of ``moe_dispatch_total``, the one formulation).
+    That pays for ``tokens x held`` rows where ``tokens x k x held /
+    num_experts`` are routed on average, ``num_experts / k`` times the
+    required operations (16 times at LFM2's 64 / 4); in exchange there is no
+    sort, no gather and no slot buffer, any imbalance costs the same, and a
+    step's time is the same whatever the router has learnt.  A grouped
+    product over the rows really routed is the next step once a balancing
+    rule bounds the loads (ROADMAP R2).  The expert weights lie (expert, out,
+    in), one ``FullyConnected`` matrix an expert as the source keeps them:
+    ``expert_gate_weight`` and ``expert_up_weight`` (held, num_hidden, dim),
+    ``expert_down_weight`` (held, dim, num_hidden); laid (expert, in, out)
+    the optimizer's float32 state is copied into the gradient's layout and
+    back every step.  ``router_weight`` (num_experts, dim) and
+    ``expert_bias`` (num_experts,) stay float32 under the bf16 policy.
+
+    ``expert_load`` (num_experts,) is an auxiliary state as BatchNorm's
+    moving statistics are: in training it is overwritten with the number of
+    selections each of the ``num_experts`` experts got in this step.  No
+    reference analog; ``parallel/moe.py`` is the older functional layer
+    (softmax gate, fixed capacity, drops tokens)."""
+    E, k = attrs["num_experts"], attrs["num_experts_per_tok"]
+    held, off = expert_gate_weight.shape[0], attrs["expert_offset"]
+    if not 0 < k <= E or off < 0 or off + held > E:
+        raise MXNetError(
+            "SparseMoE: experts %d..%d of %d, %d a token"
+            % (off, off + held, E, k))
+    D = data.shape[-1]
+    x = data.reshape(-1, D)
+
+    # ---- router: float32, all E experts
+    logits = jnp.matmul(x.astype(jnp.float32),
+                        router_weight.astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)                                   # [N, E]
+    biased = s + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+    _, sel = jax.lax.top_k(biased, k)                            # [N, k]
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+
+    # ---- the held experts over every token; a token's weight for a held
+    # expert it did not select is nought (one_hot of an index >= held)
+    here = jax.nn.one_hot((sel - off) % E, held, dtype=w.dtype)  # [N, k, held]
+    w_held = jnp.sum(w[..., None] * here, axis=1)                # [N, held]
+    if _telemetry.enabled:
+        # graftlint: disable=GL002 -- counts compiled variants, not calls
+        _MOE_DISPATCH.labels(path="dense").inc()
+    # (the benchmark's roofline metric finds the products by these subscripts,
+    # which jnp.einsum leaves in the events' scope)
+    gate = jnp.einsum("nd,efd->nef", x, expert_gate_weight)
+    up = jnp.einsum("nd,efd->nef", x, expert_up_weight)
+    h = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) \
+        * w_held[..., None]
+    y = jnp.einsum("nef,edf->nd", h.astype(x.dtype), expert_down_weight)
+    counts = jnp.sum(jax.nn.one_hot(sel, E, dtype=jnp.int32), axis=(0, 1))
+    return y.reshape(data.shape), counts.astype(expert_load.dtype)
 
 
 @register("InstanceNorm", nin=3, aliases=("instancenorm",),

@@ -78,18 +78,51 @@ def _embedding_hint(attrs, shapes):
     return out
 
 
+def _fill(shapes, want):
+    """``shapes`` with its unknown parameter shapes (inputs 1..) taken from
+    ``want``, the op's parameter shapes in input order."""
+    out = list(shapes)
+    for i in range(1, len(out)):
+        if out[i] is None:
+            out[i] = want[i - 1]
+    return out
+
+
 def _mha_hint(attrs, shapes):
-    """MultiHeadAttention: all four projection weights are square
-    (model_dim, model_dim) in the FullyConnected (out, in) orientation."""
+    """MultiHeadAttention: the projection weights in the FullyConnected
+    (out, in) orientation — query and output (model_dim, model_dim), key and
+    value (num_kv_heads * head, model_dim), square too unless the heads are
+    grouped — and, under ``qk_norm``, the two per-head gains of (head,)."""
     data = shapes[0]
     if data is None:
         return shapes
     D = data[-1]
-    out = list(shapes)
-    for i in range(1, len(out)):
-        if out[i] is None:
-            out[i] = (D, D)
-    return out
+    H = attrs["num_heads"]
+    kv = D // H * (attrs.get("num_kv_heads") or H)
+    return _fill(shapes, [(D, D), (kv, D), (kv, D), (D, D), (D // H,),
+                          (D // H,)])
+
+
+def _short_conv_hint(attrs, shapes):
+    """ShortConv: in_proj (3 dim, dim), the taps (dim, kernel), out_proj
+    (dim, dim)."""
+    data = shapes[0]
+    if data is None:
+        return shapes
+    D = data[-1]
+    return _fill(shapes, [(3 * D, D), (D, attrs["kernel"]), (D, D)])
+
+
+def _sparse_moe_hint(attrs, shapes):
+    """SparseMoE: the router over all experts, the bias, the weights of the
+    ``num_held`` experts held (0: all) as (expert, out, in), the load."""
+    data = shapes[0]
+    if data is None:
+        return shapes
+    D, E, F = data[-1], attrs["num_experts"], attrs["num_hidden"]
+    held = attrs.get("num_held") or E
+    return _fill(shapes, [(E, D), (E,), (held, F, D), (held, F, D),
+                          (held, D, F), (E,)])
 
 
 def _rnn_hint(attrs, shapes):
@@ -148,8 +181,16 @@ def install():
         "InstanceNorm": (("data", "gamma", "beta"), (), _channel_hint()),
         "Embedding": (("data", "weight"), (), _embedding_hint),
         "MultiHeadAttention": (("data", "query_weight", "key_weight",
-                                "value_weight", "out_proj_weight"), (),
+                                "value_weight", "out_proj_weight",
+                                "q_norm_gamma", "k_norm_gamma"), (),
                                _mha_hint),
+        "RMSNorm": (("data", "gamma"), (), _channel_hint(None, -1)),
+        "ShortConv": (("data", "in_proj_weight", "conv_weight",
+                       "out_proj_weight"), (), _short_conv_hint),
+        "SparseMoE": (("data", "router_weight", "expert_bias",
+                       "expert_gate_weight", "expert_up_weight",
+                       "expert_down_weight", "expert_load"), (6,),
+                      _sparse_moe_hint),
         "LeakyReLU": (("data", "gamma"), (), _channel_hint()),
         "RNN": (("data", "parameters", "state", "state_cell"), (),
                 _rnn_hint),

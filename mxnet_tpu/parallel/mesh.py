@@ -98,14 +98,24 @@ class ShardingRules:
 
 
 def megatron_rules(mesh: Mesh, tp_axis: str = "tp") -> ShardingRules:
-    """Default TP rules for our model zoo's parameter naming."""
+    """Default TP rules for our model zoo's parameter naming, and the
+    expert axis: a ``SparseMoE``'s expert weights (expert, in, out) are
+    split over the mesh's ``ep`` axis where it has one and replicated where
+    it has none; its router and bias are replicated."""
     t = tp_axis
+    experts = P("ep") if "ep" in mesh.axis_names else P()
     return ShardingRules(mesh, rules=[
-        # row-parallel (input-split) rule FIRST: out_proj/fc2/down names
+        # the expert axis FIRST: expert_down_weight would otherwise match
+        # the row-parallel rule on its (expert, in) axes
+        (r"expert_(gate|up|down)_weight$", experts),
+        (r"(router_weight|expert_bias)$", P()),
+        # row-parallel (input-split) rule next: out_proj/fc2/down names
         # also end in proj_weight/fc2_weight, which the column rule below
         # would otherwise claim — first match wins in spec_for
         (r"(out_proj|fc2|down)\w*_weight$", P(None, t)),
-        (r"(fc|dense|proj|query|key|value)\d*_weight$", P(t, None)),
+        # column-parallel: the gated feed-forward's gate and up, and
+        # ShortConv's in_proj, beside the projections that were there
+        (r"(fc|dense|proj|query|key|value|gate|up)\d*_weight$", P(t, None)),
         (r"conv\w*_weight$", P(t, None, None, None)),
         (r"embedding\w*_weight$", P(None, t)),
     ])

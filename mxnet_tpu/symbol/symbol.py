@@ -506,6 +506,9 @@ def _create(op_name: str, sym_inputs: Sequence[Symbol],
                 op.parse_attrs(dict(kwargs)).get("act_type",
                                                  "leaky") != "prelu":
             needed -= 1    # gamma exists only for the prelu variant
+        if op.name == "MultiHeadAttention" and \
+                not op.parse_attrs(dict(kwargs)).get("qk_norm"):
+            needed -= 2    # the per-head gains exist only under qk_norm
         while len(entries) < needed:
             argname = op.arg_names[len(entries)]
             v = _Node(None, "%s_%s" % (name, argname), {}, [])

@@ -295,6 +295,15 @@ FD_SPECS = {
                                                  sym.var("y")),
         lambda r: {"x": _u((2, 3), r=r), "y": _u((2, 3), r=r)},
         {"grad_nodes": ["x"]}),
+    "RMSNorm": (
+        lambda: _op("RMSNorm")(sym.var("x"), sym.var("gamma"), eps=1e-5),
+        lambda r: {"x": _u((2, 6), r=r), "gamma": _u((6,), 0.5, 1.5, r)}),
+    "ShortConv": (
+        lambda: _op("ShortConv")(sym.var("x"), sym.var("w_in"),
+                                 sym.var("taps"), sym.var("w_out"),
+                                 kernel=3),
+        lambda r: {"x": _u((1, 5, 4), r=r), "w_in": _u((12, 4), r=r),
+                   "taps": _u((4, 3), r=r), "w_out": _u((4, 4), r=r)}),
 }
 
 # Piecewise-constant / integer-output ops: gradients are zero or
@@ -363,6 +372,9 @@ EXEMPT = {
     "RNN": "tests/test_gluon_rnn.py + tests/test_pallas_rnn.py",
     "MultiHeadAttention": "flash-vs-reference parity + op-level grads in "
                           "tests/test_pallas_attention.py",
+    "SparseMoE": "top-k routing is piecewise constant in the router; "
+                 "forward + every gradient against the plain reference in "
+                 "tests/test_lfm2_ops.py",
     "Custom": "tests/test_custom_op.py",
     "_foreach": "tests/test_benchmarks.py + control-flow tests",
     "CTCLoss": "tests/test_contrib_ops.py",

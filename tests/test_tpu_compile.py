@@ -12,6 +12,7 @@ module-scoped, non-autouse fixture, never at import, in a ``skipif`` or in
 library, and it compiles in-process.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +113,70 @@ def test_mha_op_compiles_for_four_chips_each_on_its_own_sequence(topo):
     for ln in calls:            # results and operands: one sequence, 16 heads
         assert "bf16[16,1024,64]" in ln and "bf16[64,1024,64]" not in ln
     assert "all-gather" not in hlo and "all-to-all" not in hlo
+
+
+def test_grouped_rotary_attention_reaches_the_kernels(one_chip):
+    """``lfm2moe_train_2k``'s attention layer, ahead of time: 32 query heads
+    over 8 key/value heads of 64 at T 2048, per-head norms and rotary
+    positions.  Forward + backward hold the three Mosaic calls over 32
+    heads in one 2048-block, and no T x T tensor."""
+    from mxnet_tpu.ops.registry import OPS
+    op = OPS["MultiHeadAttention"]
+    attrs = op.parse_attrs(dict(num_heads=32, num_kv_heads=8, qk_norm=True,
+                                rope_theta=1e6))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, 2048, 2048), sds(2048, 2048), sds(512, 2048),
+            sds(512, 2048), sds(2048, 2048), sds(64, dtype=jnp.float32),
+            sds(64, dtype=jnp.float32))
+    fn = functools.partial(op.fn, attrs)
+    hlo = jax.jit(jax.grad(_sq(fn), tuple(range(7)))).lower(
+        *args).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert all("bf16[32,2048,64]" in ln for ln in calls)
+    assert "[1,32,2048,2048]" not in hlo and "[32,2048,2048]" not in hlo
+
+
+def test_sparse_moe_compiles_at_the_cells_widths(one_chip):
+    """``lfm2moe_train_2k``'s expert layer, ahead of time: 2048 tokens, 64
+    experts routed, 8 held of 1536, forward + backward.  The held experts'
+    products are the compiler's own dense products over every token (nine,
+    0.93 TFLOP a layer: nothing whose extent follows the routing, no kernel
+    call, no grouped product), each with the einsum's subscripts in its scope,
+    by which ``moe_experts_roofline_pct.train`` finds them, in well under a
+    GB of temporaries."""
+    from mxnet_tpu.ops.registry import OPS
+    op = OPS["SparseMoE"]
+    attrs = op.parse_attrs(dict(num_experts=64, num_experts_per_tok=4,
+                                num_hidden=1536, num_held=8))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, 2048, 2048), sds(64, 2048, dtype=jnp.float32),
+            sds(64, dtype=jnp.float32), sds(8, 1536, 2048),
+            sds(8, 1536, 2048), sds(8, 2048, 1536),
+            sds(64, dtype=jnp.float32))
+
+    def loss(*a):
+        with jax.named_scope("SparseMoE:tfm_l1_moe"):   # as the registry does
+            return jnp.sum(op.fn(attrs, *a)[0].astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 3, 4, 5))).lower(
+        *args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo and "ragged-dot" not in hlo
+    assert " while(" not in hlo and " conditional(" not in hlo
+    reads = re.compile(
+        r"SparseMoE:[^ ]*/(nd,efd->nef|nef,edf->nd)/dot_general")
+    products = [ln for ln in hlo.splitlines()
+                if " convolution(" in ln and reads.search(ln)]
+    assert len(products) == 9
+    assert 0.9e12 < compiled.cost_analysis()["flops"] < 1.0e12
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
