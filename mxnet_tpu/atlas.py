@@ -19,7 +19,8 @@ contract:
 - ``Optimizer::<Name>`` — one optimizer's fused update stage
   (:func:`optimizer_scope`; ``Optimizer.atlas_scope_name`` overrides).
 - ``GradSync`` — the in-program gradient reduce (replica sum / mesh
-  all-reduce).
+  all-reduce) and, where the mesh step keeps the optimizer's state split
+  over ``dp``, the all-gather of the updated weights.
 
 jax carries these names into the lowered StableHLO as MLIR location
 debug info, through ``jax.vjp`` as ``jvp(...)`` / ``transpose(jvp(...))``

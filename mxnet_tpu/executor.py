@@ -517,7 +517,7 @@ class Executor:
         return ("update",) + self._step_env()
 
     def step_program(self, pnames, update_fns, mesh_sig=None,
-                     param_shardings=None):
+                     param_shardings=None, state_shardings=None):
         """Whole-step program: forward + vjp-backward + optimizer update in
         ONE ``jax.jit`` with params and opt-state donated — weights update
         in place on device, zero per-param python dispatch.
@@ -538,7 +538,14 @@ class Executor:
         updated param and its opt-state to the INPUT's sharding: without
         the constraint GSPMD may pick a different output layout (e.g.
         shard a small bias), which would silently break the take/give
-        donation chain on the next step.
+        donation chain on the next step.  ``state_shardings`` (aligned
+        too; ``parallel.mesh.state_sharding``) is given where the opt-state
+        has a layout of its own, split over ``dp``: the gradient is
+        constrained to it BEFORE the update, so the partitioner
+        reduce-scatters the partial sums where they are made (and does not
+        all-reduce and slice), each replica updates its part of the leaf,
+        the state stays where it was taken and the new weight, pinned to
+        the param's sharding, is all-gathered.
         """
         key = self._step_key(mesh_sig)
         fn = self._jitted.get(key)
@@ -568,15 +575,27 @@ class Executor:
             (outs, new_aux), vjp = jax.vjp(lambda *g: pure(list(g)), *pvals)
             grads = vjp((list(ograds), [jnp.zeros_like(a) for a in new_aux]))
             new_p, new_s = [], []
+            pin = jax.lax.with_sharding_constraint
             for i, upd in enumerate(update_fns):
+                g = grads[i]
+                if state_shardings is not None:
+                    g = pin(g, state_shardings[i])
                 with jax.named_scope(_atlas.optimizer_scope(upd)):
-                    w, s = upd(pvals[i], grads[i], svals[i],
+                    w, s = upd(pvals[i], g, svals[i],
                                lrs[i], wds[i], rescale, ts[i])
+                    if state_shardings is not None:
+                        # the update ends, cast to the weight's dtype
+                        # included, on the replica's own part: its fusion
+                        # keeps this scope, the gather below has its own
+                        w = pin(w, state_shardings[i])
+                if state_shardings is not None:
+                    with jax.named_scope(_atlas.GRAD_SYNC):
+                        w = pin(w, param_shardings[i])
+                elif param_shardings is not None:
+                    w = pin(w, param_shardings[i])
                 if param_shardings is not None:
-                    sh = param_shardings[i]
-                    w = jax.lax.with_sharding_constraint(w, sh)
-                    s = jax.tree_util.tree_map(
-                        lambda a: jax.lax.with_sharding_constraint(a, sh), s)
+                    ssh = (state_shardings or param_shardings)[i]
+                    s = jax.tree_util.tree_map(lambda a: pin(a, ssh), s)
                 new_p.append(w)
                 new_s.append(s)
             return new_p, new_s, outs, new_aux
@@ -607,15 +626,15 @@ class Executor:
         """Ones head-gradients for a {arg_name: shape} dict (cached
         shape+dtype inference).  The mesh step passes full-batch shapes
         here; the bound per-device shapes come from ``_default_ograds``.
-        Output dtypes come from abstract evaluation of the plan under the
-        bound argument dtypes — ``jax.vjp`` requires cotangent dtype ==
-        output dtype, and bf16 bindings produce bf16 heads (fp32 for heads
-        that reduce in fp32, e.g. SoftmaxOutput on low-precision input)."""
+        Output shapes and dtypes come from ONE abstract evaluation of the
+        plan under the bound argument dtypes — ``jax.vjp`` requires
+        cotangent dtype == output dtype, and bf16 bindings produce bf16
+        heads (fp32 for heads that reduce in fp32, e.g. SoftmaxOutput on
+        low-precision input)."""
         shape_key = tuple(tuple(shapes[n]) for n in self.arg_names)
         key = ("oshapes", shape_key, self._dtype_sig())
         cached = self._jitted.get(key)
         if cached is None:
-            _, oshapes, _ = self._symbol.infer_shape(**shapes)
             plan = self._plan(True)
             avals = {n: jax.ShapeDtypeStruct(tuple(shapes[n]),
                                              np.dtype(self.arg_dict[n].dtype))
@@ -627,7 +646,7 @@ class Executor:
             outs = jax.eval_shape(
                 lambda a, x, k: plan.execute(a, x, k)[0],
                 avals, aux_avals, kstruct)
-            cached = [(s, o.dtype) for s, o in zip(oshapes, outs)]
+            cached = [(tuple(o.shape), o.dtype) for o in outs]
             self._jitted[key] = cached
         return [jnp.ones(s, dt) for s, dt in cached]
 

@@ -66,6 +66,12 @@ DONATION_COPY_BYTES = _telemetry.counter(
     "donation_copy_bytes_total",
     "Bytes of the buffers counted by donation_copies_total",
     ("path",))
+OPT_STATE_SHARDED_BYTES = _telemetry.gauge(
+    "opt_state_sharded_bytes",
+    "Global bytes of the optimizer-state leaves the mesh step holds in a "
+    "layout split over the mesh's dp axis (each replica holds and updates "
+    "1/dp of them; 0: every replica updates every leaf)",
+    ("path",))
 
 
 def _span(name, args=None):
@@ -253,6 +259,8 @@ class ModuleFusedStep:
         self._unsupported = False
         self._structural_ok = {}     # env tuple -> bool
         self._mesh_cache = None      # (key, (mesh, rules, dp_axis)|None)
+        self._layout = None          # _mesh_layout()'s, of that mesh
+        self._split = None           # _count_split()'s, of that layout
         self._meshed = False         # handles currently hold mesh globals
         self._mesh_outputs = None    # full-batch outputs of the last step
         self.steps = 0               # fused steps since bind (Step::update)
@@ -605,7 +613,53 @@ class ModuleFusedStep:
                 except (ValueError, TypeError):
                     setup = None
         self._mesh_cache = (key, setup)
+        self._layout = self._split = None
         return setup
+
+    def _mesh_layout(self):
+        """(param shardings, state shardings, mesh signature) of the
+        trainable params in ``_slots_for_mesh``'s order, derived once per
+        mesh from what can be seen: the mesh, the rules' spec of the
+        param and its shape.  The opt-state's layout is the param's,
+        split further over ``dp`` (``parallel.mesh.state_sharding``).
+        Where no leaf is split (a mesh without a ``dp`` extent, small
+        leaves only) the state shardings are None, the signature does not
+        name them and the program is the one it was."""
+        mesh, rules, dp = self._mesh_setup()
+        if self._layout is None:
+            from .parallel.mesh import replicated_sharding, state_sharding
+            repl = replicated_sharding(mesh)
+            ex = self._eg.execs[0]
+            psh, ssh = [], []
+            for name in self._mod._param_names:
+                if name not in self._pset:
+                    continue
+                shape = ex.arg_dict[name].shape
+                sh = repl if rules is None else \
+                    rules.sharding_for(name, shape)
+                psh.append(sh)
+                ssh.append(state_sharding(sh, shape, dp))
+            sig = (tuple(sorted(mesh.shape.items())),
+                   tuple(str(sh.spec) for sh in psh))
+            if ssh == psh:
+                ssh = None
+            else:
+                sig += (tuple(str(sh.spec) for sh in ssh),)
+            self._layout = (psh, ssh, sig)
+        return self._layout
+
+    def _count_split(self, svals):
+        """(count, global bytes) of the opt-state leaves in ``svals``
+        (``_step_mesh``'s, aligned with the layout) that are split over
+        ``dp``: ``Step::gather``'s ``sharded`` / ``sharded_bytes`` and the
+        operators' gauge."""
+        psh, ssh, _ = self._mesh_layout()
+        split = [leaf for sv, p, s in zip(svals, psh, ssh or psh)
+                 if s is not p for leaf in sv]
+        nbytes = sum(a.nbytes for a in split)
+        if _telemetry.enabled:
+            OPT_STATE_SHARDED_BYTES.labels(path="mesh_fused").set(nbytes)
+        return len(split), nbytes
 
     def _mesh_ok(self):
         """Mesh-path eligibility on top of ``eligible()``: local synced-DP
@@ -680,15 +734,10 @@ class ModuleFusedStep:
         execs = eg.execs
         ex = execs[0]
         ndev = len(execs)
-        mesh, rules, dp = self._mesh_setup()
+        mesh, _, dp = self._mesh_setup()
+        pshardings, sshardings, mesh_sig = self._mesh_layout()
         repl = NamedSharding(mesh, P())
         bsh = NamedSharding(mesh, P(dp))
-
-        def psh(name, shape):
-            if rules is not None:
-                return rules.sharding_for(name, shape)
-            return repl
-
         staged, self._pending = self._pending, None
         states = m._updater.states
         pool = self._pools[0]
@@ -724,18 +773,24 @@ class ModuleFusedStep:
             slots = self._slots_for_mesh(ex, ndev)
         pvals, svals = [], []
         with _gather_span(pool) as args:
-            for name, slot, _, _, _ in slots:
-                sh = psh(name, ex.arg_dict[name].shape)
+            placed = pool.copies
+            for (name, slot, _, _, _), sh, ssh in zip(
+                    slots, pshardings, sshardings or pshardings):
                 pvals.append(self._take_mesh(
                     ("w", name), [e.arg_dict[name] for e in execs], sh))
                 # mp slots: leaf 0 is the master-fp32 copy — same shape as
-                # the param, so it inherits the param's sharding like every
+                # the param, so it takes the state's layout like every
                 # moment
                 leaves = self._slot_leaves(ex, name, states[slot])
                 svals.append(tuple(
-                    pool.take_sharded(("s", slot, j), leaf, sh)
+                    pool.take_sharded(("s", slot, j), leaf, ssh)
                     for j, leaf in enumerate(leaves)))
+            if self._split is None or pool.copies != placed:
+                # counted when a leaf was placed (the first step, a state
+                # set from outside), not on every step
+                self._split = self._count_split(svals)
             args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
+            args["sharded"], args["sharded_bytes"] = self._split
             lrs, wds, ts, rescale = self._slot_scalars(slots)
         run = {"mesh": str(dict(mesh.shape))}
         with _span("Step::program", run):
@@ -743,15 +798,12 @@ class ModuleFusedStep:
             keys = ex._keys(plan)
             ex._last_keys = keys
             ogs = ex._ograds_for(full_shapes)
-            pshardings = [psh(s[0], ex.arg_dict[s[0]].shape) for s in slots]
-            mesh_sig = (tuple(sorted(mesh.shape.items())),
-                        tuple(str(sh.spec) for sh in pshardings))
             update_fns = self._update_fns(ex, slots)
             first_run = run["first_run"] = \
                 ex._step_key(mesh_sig) not in ex._jitted
-            fn = ex.step_program([s[0] for s in slots], update_fns,
-                                 mesh_sig=mesh_sig,
-                                 param_shardings=pshardings)
+            fn = ex.step_program(
+                [s[0] for s in slots], update_fns, mesh_sig=mesh_sig,
+                param_shardings=pshardings, state_shardings=sshardings)
             if first_run and _health.enabled:
                 with jax.set_mesh(mesh):
                     _health.register_program(

@@ -72,14 +72,18 @@ class DataParallelExecutorGroup:
             group2ctxs = [group2ctxs] * len(contexts)
         assert len(group2ctxs) == len(contexts), \
             "group2ctxs must match the number of contexts"
+        inferred = {}   # replicas of one slice size share one graph walk
         for ctx, slc, g2c in zip(contexts, self.slices, group2ctxs):
             n_i = slc.stop - slc.start
             shapes = {d.name: (n_i,) + d.shape[1:] for d in data_shapes}
             for l in (label_shapes or []):
                 shapes[l.name] = (n_i,) + l.shape[1:]
+            if n_i not in inferred:
+                inferred[n_i] = symbol.infer_shape(**shapes)
             self.execs.append(symbol.simple_bind(ctx=ctx, grad_req=req,
                                                  group2ctx=g2c,
                                                  type_dict=type_dict,
+                                                 _inferred=inferred[n_i],
                                                  **shapes))
         self.data_shapes = data_shapes
         self.label_shapes = label_shapes

@@ -13,7 +13,12 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["make_mesh", "data_parallel_sharding", "replicated_sharding",
-           "ShardingRules", "megatron_rules", "host_shard_hint", "P"]
+           "state_sharding", "ShardingRules", "megatron_rules",
+           "host_shard_hint", "P"]
+
+#: a leaf under this many elements keeps its parameter's sharding: a
+#: collective of its own would cost more than updating it on every chip
+STATE_SHARD_MIN_ELEMENTS = 1 << 16
 
 
 def host_shard_hint(mesh: Optional[Mesh] = None,
@@ -57,6 +62,40 @@ def data_parallel_sharding(mesh: Mesh, axis: str = "dp") -> NamedSharding:
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+def state_sharding(param_sharding: NamedSharding, shape,
+                   dp_axis: str = "dp") -> NamedSharding:
+    """Where the optimizer's state of one parameter lives (its float32
+    master, moments, momentum): the parameter's own sharding, split
+    further over the mesh's data-parallel axis, so that each replica
+    updates ``1/dp`` of the leaf from a reduce-scattered gradient and the
+    new weight is all-gathered (Xu et al. 2020, cross-replica sharding of
+    the weight update).
+
+    The split goes along the largest axis that the parameter's spec leaves
+    unsharded and whose extent divides by the ``dp`` size.  A leaf with no
+    such axis, one under ``STATE_SHARD_MIN_ELEMENTS``, a spec that already
+    names ``dp``, or a ``dp`` axis of size 1 keeps the parameter's
+    sharding and is updated on every replica.
+    """
+    mesh = param_sharding.mesh
+    n = mesh.shape.get(dp_axis, 1)
+    shape = tuple(shape)
+    if n == 1 or int(np.prod(shape)) < STATE_SHARD_MIN_ELEMENTS:
+        return param_sharding
+    spec = list(param_sharding.spec) + \
+        [None] * (len(shape) - len(param_sharding.spec))
+    used = {a for ax in spec if ax is not None
+            for a in ((ax,) if isinstance(ax, str) else ax)}
+    free = [i for i, (dim, ax) in enumerate(zip(shape, spec))
+            if ax is None and dim % n == 0]
+    if dp_axis in used or not free:
+        return param_sharding
+    spec[max(free, key=lambda i: shape[i])] = dp_axis
+    while spec[-1] is None:     # as a program's result names it, so that
+        spec.pop()              # the layout taken equals the layout given
+    return NamedSharding(mesh, P(*spec))
 
 
 class ShardingRules:

@@ -389,13 +389,17 @@ class Symbol:
     def simple_bind(self, ctx: Optional[Context] = None, grad_req="write",
                     type_dict=None, stype_dict=None, group2ctx=None,
                     shared_arg_names=None, shared_exec=None,
-                    shared_buffer=None, **kwargs):
+                    shared_buffer=None, _inferred=None, **kwargs):
         """Infer shapes from the given input shapes, allocate all arrays,
-        return a bound Executor (ref: symbol.py:1552 → GraphExecutor::Init)."""
+        return a bound Executor (ref: symbol.py:1552 → GraphExecutor::Init).
+
+        ``_inferred`` is ``infer_shape(**kwargs)``'s result from an earlier
+        bind of this symbol at these input shapes: a group that binds one
+        graph on several devices walks it once, not once a device."""
         from ..executor import Executor
         from .. import ndarray as nd
         ctx = ctx or current_context()
-        arg_shapes, _, aux_shapes = self.infer_shape(**kwargs)
+        arg_shapes, _, aux_shapes = _inferred or self.infer_shape(**kwargs)
         arg_types, _, aux_types = self.infer_type(**(type_dict or {}))
         arg_names = self.list_arguments()
         aux_names = self.list_auxiliary_states()

@@ -428,3 +428,448 @@ class TestMeshStepTimeline:
             assert by["Step::gather"].args["copies"] > 0
         finally:
             telemetry.disable()
+
+
+# ---------------------------------------------------------------------------
+# the optimizer state's own layout: split over ``dp`` (4 virtual devices)
+# ---------------------------------------------------------------------------
+CTX4 = CTX8[:4]
+SGD_MOM = ("sgd", {"learning_rate": 0.5, "momentum": 0.5})
+
+
+@pytest.fixture
+def split_small(monkeypatch):
+    """The test net's two matrices (16 and 8 elements) count as large
+    leaves; its biases (4 and 2) stay small."""
+    from mxnet_tpu.parallel import mesh as pmesh
+    monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+
+
+def _leaves_by_name(mod):
+    """{param: its state's leaves, as the fused step lays them (the master
+    first for a low-precision weight)} of the base slots."""
+    ndev = len(mod._context)
+    ex = mod._exec_group.execs[0]
+    out = {}
+    for i, name in enumerate(mod._param_names):
+        st = mod._updater.states[mod._optimizer.slot_index(i, ndev, 0)]
+        out[name] = fused_state_leaves(
+            st, mod._optimizer.fused_mp(ex.arg_dict[name]))
+    return out
+
+
+class TestStateShardingRule:
+    @pytest.mark.parametrize("axes,spec,shape,want", [
+        # replicated parameter: the largest axis that divides by dp
+        ({"dp": 4}, (), (4096, 1024), ("dp",)),
+        ({"dp": 4}, (), (1024, 4096), (None, "dp")),
+        ({"dp": 4}, (), (1024, 1024), ("dp",)),
+        ({"dp": 4}, (), (512, 512, 3, 3), ("dp",)),
+        # GPT-2's tables: 50,257 rows do not divide by 4
+        ({"dp": 4}, (), (50257, 1024), (None, "dp")),
+        # no axis divides, too small, a bias: the parameter's own
+        ({"dp": 4}, (), (50257, 1023), ()),
+        ({"dp": 4}, (), (128, 128), ()),
+        ({"dp": 4}, (), (4096,), ()),
+        ({"dp": 4}, (), (1 << 16,), ("dp",)),
+        # under tp the state's axis is one the rule left free
+        ({"dp": 4, "tp": 2}, ("tp", None), (4096, 1024), ("tp", "dp")),
+        ({"dp": 4, "tp": 2}, (None, "tp"), (1024, 4096), ("dp", "tp")),
+        ({"dp": 4, "tp": 2}, (None, "tp"), (50257, 1024), (None, "tp")),
+        ({"dp": 2, "tp": 2}, ("tp", None, None, None), (512, 256, 3, 3),
+         ("tp", "dp")),
+        # a spec that already names dp, and a dp axis of one
+        ({"dp": 4, "tp": 2}, ("dp", None), (4096, 1024), ("dp", None)),
+        ({"dp": 1, "tp": 8}, ("tp", None), (4096, 1024), ("tp", None)),
+    ])
+    def test_spec(self, axes, spec, shape, want):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from mxnet_tpu.parallel.mesh import make_mesh, state_sharding
+        n = int(np.prod(list(axes.values())))
+        mesh = make_mesh(axes, devices=[c.jax_device for c in CTX8[:n]])
+        own = NamedSharding(mesh, P(*spec))
+        got = state_sharding(own, shape)
+        assert got.spec == P(*want) and got.mesh is mesh
+        if tuple(want) == tuple(spec):
+            assert got is own       # the step tells a split leaf by this
+
+
+class TestStateSplitOverDp:
+    def _assert_layout(self, mod, large, small, ndev=4):
+        from jax.sharding import PartitionSpec as P
+        ex = mod._exec_group.execs[0]
+        leaves = _leaves_by_name(mod)
+        for name in large + small:
+            w = ex.arg_dict[name]._data
+            assert w.sharding.spec == P(), name      # the parameter's own
+            assert len(w.addressable_shards) == ndev
+        for name in large:
+            for leaf in leaves[name]:
+                a = leaf._data
+                assert "dp" in a.sharding.spec, (name, a.sharding.spec)
+                for s in a.addressable_shards:
+                    assert s.data.size * ndev == a.size
+        for name in small:
+            for leaf in leaves[name]:
+                assert leaf._data.sharding.spec == P(), name
+                assert leaf._data.addressable_shards[0].data.size == \
+                    leaf._data.size
+
+    @pytest.mark.parametrize("name,kwargs,steps",
+                             [SGD_MOM + (3,),
+                              ("sgd", {"learning_rate": 0.25}, 2)],
+                             ids=["sgd_mom", "sgd"])
+    def test_bitexact_vs_single_device(self, monkeypatch, split_small,
+                                       name, kwargs, steps):
+        mod4 = _run(monkeypatch, CTX4, name, kwargs, steps)
+        if "momentum" in kwargs:
+            self._assert_layout(mod4, ["fc1_weight", "fc2_weight"],
+                                ["fc1_bias", "fc2_bias"])
+        mod1 = _run(monkeypatch, [mx.cpu(0)], name, kwargs, steps)
+        _assert_bitexact(mod4, mod1)
+
+    def test_sgd_momentum_layout_and_replicated_layouts_numbers(
+            self, monkeypatch):
+        """A float32 weight without a master: the momentum is split, the
+        weight gathered in float32; the numbers are the replicated
+        layout's (the same mesh with every leaf counted small)."""
+        repl = _run(monkeypatch, CTX4, *SGD_MOM, 3)
+        self._assert_layout(repl, [], list(repl._param_names))
+        from mxnet_tpu.parallel import mesh as pmesh
+        monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+        split = _run(monkeypatch, CTX4, *SGD_MOM, 3)
+        self._assert_layout(split, ["fc1_weight", "fc2_weight"],
+                            ["fc1_bias", "fc2_bias"])
+        _assert_bitexact(split, repl)
+
+    def test_adam_with_masters_layout_and_replicated_layouts_numbers(
+            self, monkeypatch):
+        """bf16 weights under Adam: master, mean and variance split, the
+        bf16 weight in the parameter's sharding.  Adam divides, so a last
+        bit may follow the order of the exchange's sum: allclose."""
+        from mxnet_tpu import amp
+        import jax.numpy as jnp
+        monkeypatch.setenv(amp.ENV_FLAG, "1")
+        okw = {"learning_rate": 0.01, "multi_precision": True}
+        repl = _run(monkeypatch, CTX4, "adam", okw, 3)
+        from mxnet_tpu.parallel import mesh as pmesh
+        monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+        split = _run(monkeypatch, CTX4, "adam", okw, 3)
+        self._assert_layout(split, ["fc1_weight", "fc2_weight"],
+                            ["fc1_bias", "fc2_bias"])
+        ex = split._exec_group.execs[0]
+        assert ex.arg_dict["fc1_weight"]._data.dtype == jnp.bfloat16
+        leaves = _leaves_by_name(split)["fc1_weight"]
+        assert [l._data.dtype for l in leaves] == [jnp.float32] * 3
+        _assert_close(split, repl, rtol=1e-2)       # the bf16 weights
+        for name, got in _leaves_by_name(split).items():
+            want = _leaves_by_name(repl)[name]
+            for j, (a, b) in enumerate(zip(got, want)):
+                np.testing.assert_allclose(
+                    a.asnumpy(), b.asnumpy(), rtol=2e-5, atol=1e-6,
+                    err_msg="%s[%d]" % (name, j))
+
+    def test_demesh_then_eager_step_bitexact(self, monkeypatch,
+                                             split_small):
+        """Two steps on split state, then (flag off) one per-device step:
+        ``_demesh`` makes whole per-device copies again."""
+        mod4 = _run(monkeypatch, CTX4, *SGD_MOM, 2)
+        monkeypatch.setenv(fused.MESH_ENV_FLAG, "0")
+        rs = np.random.RandomState(7)
+        for _ in range(2):          # the draws of steps 1 and 2
+            rs.randint(0, 2, (8, 4)), rs.randint(-1, 2, (8, 2))
+        x = rs.randint(0, 2, (8, 4)).astype(np.float32)
+        y = rs.randint(-1, 2, (8, 2)).astype(np.float32)
+        mod4.forward_backward(_Batch(x, y))
+        mod4.update()
+        ex = mod4._exec_group.execs
+        for k in range(4):          # per-device again, whole
+            w = ex[k].arg_dict["fc1_weight"]._data
+            assert w.devices() == {CTX4[k].jax_device}
+            st = mod4._updater.states[
+                mod4._optimizer.slot_index(0, 4, k)]
+            assert st._data.devices() == {CTX4[k].jax_device}
+            assert st.shape == (4, 4)
+        mod1 = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 3)
+        _assert_bitexact(mod4, mod1)
+
+    def test_get_params_and_states_read_whole_arrays(self, monkeypatch,
+                                                     split_small):
+        mod4 = _run(monkeypatch, CTX4, *SGD_MOM, 2)
+        mod1 = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 2)
+        # the pickled states without a de-mesh first: np.asarray of a
+        # global array with four shards
+        import pickle
+        states = pickle.loads(mod4._updater.get_states())
+        assert states[0].shape == (4, 4)
+        np.testing.assert_array_equal(
+            states[0], mod1._updater.states[0].asnumpy())
+        args4, _ = mod4.get_params()
+        args1, _ = mod1.get_params()
+        for k in args1:
+            np.testing.assert_array_equal(args4[k].asnumpy(),
+                                          args1[k].asnumpy())
+
+    def test_caller_held_state_survives_the_split_placement(
+            self, monkeypatch, split_small):
+        """A state the pool does not own (``set_states``) reaches the split
+        layout as a copy (``take_sharded``'s ``jnp.array`` before the put),
+        so what the caller holds is neither deleted by the step's donation
+        nor changed."""
+        import pickle
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, 1)
+        mod._updater.set_states(pickle.loads(mod._updater.get_states()))
+        held = {k: st._data for k, st in mod._updater.states.items()}
+        before = {k: np.asarray(a) for k, a in held.items()}
+        rs = np.random.RandomState(3)
+        mod.forward_backward(_Batch(
+            rs.randint(0, 2, (8, 4)).astype(np.float32),
+            rs.randint(-1, 2, (8, 2)).astype(np.float32)))
+        mod.update()
+        self._assert_layout(mod, ["fc1_weight", "fc2_weight"],
+                            ["fc1_bias", "fc2_bias"])
+        for k, a in held.items():
+            assert not a.is_deleted(), k
+            np.testing.assert_array_equal(np.asarray(a), before[k])
+        new = _leaves_by_name(mod)["fc1_weight"][0]._data
+        assert not np.array_equal(np.asarray(new), before[0])
+
+    def test_checkpoint_from_split_run_into_one_device_module(
+            self, monkeypatch, split_small, tmp_path):
+        """``save_checkpoint(save_optimizer_states=True)`` after split
+        steps, loaded into a Module on one device, which then takes the
+        third step to the numbers of three steps on one device.  (The
+        updater's states are keyed ``param * ndev + device``, as the
+        reference's: the one-device Module takes the base slots.)"""
+        import pickle
+        mod4 = _run(monkeypatch, CTX4, *SGD_MOM, 2)
+        prefix = str(tmp_path / "split")
+        mod4.save_checkpoint(prefix, 2, save_optimizer_states=True)
+        with open(prefix + "-0002.states", "rb") as f:
+            saved = pickle.loads(f.read())
+        assert sorted(saved) == list(range(4 * 4))
+        for i in range(4):
+            for k in range(1, 4):       # whole copies on every device
+                np.testing.assert_array_equal(saved[4 * i],
+                                              saved[4 * i + k])
+        mod1 = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 0)
+        _, args, auxs = mx.model.load_checkpoint(prefix, 2)
+        mod1.set_params(args, auxs)
+        mod1._updater.set_states({i: saved[4 * i] for i in range(4)})
+        for i in range(4):
+            mod1._optimizer._index_update_count[i] = 2
+        mod1._optimizer.num_update = 2
+        rs = np.random.RandomState(7)
+        for _ in range(2):
+            rs.randint(0, 2, (8, 4)), rs.randint(-1, 2, (8, 2))
+        batch = _Batch(rs.randint(0, 2, (8, 4)).astype(np.float32),
+                       rs.randint(-1, 2, (8, 2)).astype(np.float32))
+        mod1.forward_backward(batch)
+        mod1.update()
+        want = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 3)
+        _assert_bitexact(mod1, want)
+
+    def test_train_checkpointer_snapshot_is_whole(self, monkeypatch,
+                                                  split_small, tmp_path):
+        """``_ft_snapshot`` (what ``TrainCheckpointer`` writes) of a split
+        run restores into a fresh Module on the same devices."""
+        from mxnet_tpu.checkpoint import TrainCheckpointer
+        mod4 = _run(monkeypatch, CTX4, *SGD_MOM, 2)
+        ckpt = TrainCheckpointer(str(tmp_path), every_n_steps=1)
+        ckpt.save_sync(2, *mod4._ft_snapshot(0, 2, 2))
+        tree, meta, blobs = ckpt.load(ckpt.latest())
+        ckpt.close()
+        fresh = _run(monkeypatch, CTX4, *SGD_MOM, 0)
+        fresh._ft_restore(tree, meta, blobs)
+        _assert_bitexact(fresh, mod4)
+        rs = np.random.RandomState(11)
+        batch = _Batch(rs.randint(0, 2, (8, 4)).astype(np.float32),
+                       rs.randint(-1, 2, (8, 2)).astype(np.float32))
+        for m in (fresh, mod4):
+            m.forward_backward(batch)
+            m.update()
+        self._assert_layout(fresh, ["fc1_weight", "fc2_weight"],
+                            ["fc1_bias", "fc2_bias"])
+        _assert_bitexact(fresh, mod4)       # (get_params de-meshes)
+
+    def test_megatron_dp2_tp2_state_axis_is_not_the_rules(
+            self, monkeypatch, split_small):
+        from mxnet_tpu.parallel.mesh import make_mesh, megatron_rules
+        from jax.sharding import PartitionSpec as P
+
+        def rules(mod):
+            return megatron_rules(make_mesh(
+                {"dp": 2, "tp": 2}, devices=[c.jax_device for c in CTX4]))
+
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, 2,
+                   mesh_axes={"dp": 2, "tp": 2}, rules_fn=rules)
+        ex = mod._exec_group.execs[0]
+        leaves = _leaves_by_name(mod)
+        # column-parallel fc1 (tp on the rows): the state takes the columns
+        assert ex.arg_dict["fc1_weight"]._data.sharding.spec == \
+            P("tp", None)
+        assert leaves["fc1_weight"][0]._data.sharding.spec == P("tp", "dp")
+        # row-parallel fc2 (tp on the columns): the state takes the rows
+        assert ex.arg_dict["fc2_weight"]._data.sharding.spec == \
+            P(None, "tp")
+        assert leaves["fc2_weight"][0]._data.sharding.spec == P("dp", "tp")
+        for s in leaves["fc1_weight"][0]._data.addressable_shards:
+            assert s.data.shape == (2, 2)       # a quarter each
+        assert leaves["fc1_bias"][0]._data.sharding.spec == P()
+        mod1 = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 2)
+        _assert_bitexact(mod, mod1)
+
+    def test_dp_of_one_keeps_the_parents_signature_and_program(
+            self, monkeypatch, split_small):
+        from mxnet_tpu.parallel.mesh import make_mesh, megatron_rules
+        ctx2 = CTX8[:2]
+
+        def rules(mod):
+            return megatron_rules(make_mesh(
+                {"dp": 1, "tp": 2}, devices=[c.jax_device for c in ctx2]))
+
+        mod = _run(monkeypatch, ctx2, *SGD_MOM, 2,
+                   mesh_axes={"dp": 1, "tp": 2}, rules_fn=rules)
+        psh, ssh, sig = mod._fused_step._mesh_layout()
+        assert ssh is None
+        assert sig == ((("dp", 1), ("tp", 2)),
+                       tuple(str(sh.spec) for sh in psh))
+        ex = mod._exec_group.execs[0]
+        assert [k for k in ex._jitted if k[0] == "step"][0][1] == sig
+        for name, got in _leaves_by_name(mod).items():
+            assert got[0]._data.sharding == \
+                ex.arg_dict[name]._data.sharding
+        mod1 = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 2)
+        _assert_bitexact(mod, mod1)
+
+    def test_layout_change_is_a_new_program(self, monkeypatch):
+        """The signature carries the state's layout: the same mesh and
+        rules with another split is another step program."""
+        from mxnet_tpu.parallel import mesh as pmesh
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, 1)
+        ex = mod._exec_group.execs[0]
+        keys1 = {k for k in ex._jitted if k[0] == "step"}
+        assert len(keys1) == 1 and len(next(iter(keys1))[1]) == 2
+        monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+        mod._fused_step.on_mesh_change()
+        rs = np.random.RandomState(9)
+        mod.forward_backward(_Batch(
+            rs.randint(0, 2, (8, 4)).astype(np.float32),
+            rs.randint(-1, 2, (8, 2)).astype(np.float32)))
+        mod.update()
+        keys2 = {k for k in ex._jitted if k[0] == "step"}
+        assert len(keys2) == 2 and keys1 < keys2
+        (new,) = keys2 - keys1
+        assert new[1][2] == ("PartitionSpec('dp',)", "PartitionSpec()",
+                             "PartitionSpec(None, 'dp')", "PartitionSpec()")
+
+
+def test_group_bind_walks_the_graph_once(monkeypatch):
+    """Binding one graph on several devices infers its shapes once for the
+    replicas of a slice size (it was once a device: 0.5 s each at
+    GPT-2-medium's depth, in ``gpt2m_train_dp4``'s set-up), and every
+    replica is bound as a lone ``simple_bind`` binds it."""
+    walks = []
+    real = mx.sym.Symbol.infer_shape
+    monkeypatch.setattr(
+        mx.sym.Symbol, "infer_shape",
+        lambda self, *a, **k: walks.append(k) or real(self, *a, **k))
+    mod = _build_module(CTX4)
+    bound = [k for k in walks if k.get("data") == (2, 4)]
+    assert len(bound) == 1, walks
+    execs = mod._exec_group.execs
+    lone = execs[0]._symbol.simple_bind(
+        ctx=mx.cpu(0), data=(2, 4), softmax_label=(2, 2))
+    for e in execs:
+        assert {n: (a.shape, a.dtype) for n, a in e.arg_dict.items()} == \
+            {n: (a.shape, a.dtype) for n, a in lone.arg_dict.items()}
+        assert sorted(e.grad_dict) == sorted(execs[0].grad_dict)
+
+
+class TestStateSplitTimeline:
+    _step = TestMeshStepTimeline._step
+
+    def test_gather_counts_sharded_and_copies_nothing(self, monkeypatch,
+                                                      split_small):
+        from test_fused_step import assert_one_timeline
+        telemetry.enable()
+        try:
+            mod = _run(monkeypatch, CTX4, *SGD_MOM, steps=0)
+            by = assert_one_timeline(self._step(mod, 1), "mesh_fused")
+            g = by["Step::gather"].args
+            # two matrices' momenta of 16 and 8 float32 elements
+            assert g["leaves"] == 8 and g["copies"] == 8
+            assert g["sharded"] == 2 and g["sharded_bytes"] == 4 * (16 + 8)
+            for seed in (2, 3):     # the donation chain finds them there
+                by = assert_one_timeline(self._step(mod, seed),
+                                         "mesh_fused")
+                g = by["Step::gather"].args
+                assert g["copies"] == 0 and g["copy_bytes"] == 0
+                assert g["sharded"] == 2
+                assert by["Step::launch"].args["first_run"] is False
+            assert telemetry.value("opt_state_sharded_bytes",
+                                   path="mesh_fused") == 96
+        finally:
+            telemetry.disable()
+
+    def test_count_is_retaken_when_a_state_is_placed_again(
+            self, monkeypatch, split_small):
+        """``sharded`` / ``sharded_bytes`` are counted in a step that
+        places a leaf, not in every step: a state set from outside is
+        copied (``copies`` > 0) and counted again."""
+        import pickle
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, steps=0)
+        fs = mod._fused_step
+        counted = []
+        real = fs._count_split
+        monkeypatch.setattr(fs, "_count_split",
+                            lambda sv: counted.append(1) or real(sv))
+        for seed in (1, 2, 3):
+            self._step(mod, seed)
+        assert len(counted) == 1            # the first step's placement
+        mod._updater.set_states(pickle.loads(mod._updater.get_states()))
+        g = {r.name: r for r in self._step(mod, 4)}["Step::gather"].args
+        assert len(counted) == 2 and g["copies"] > 0
+        assert g["sharded"] == 2 and g["sharded_bytes"] == 4 * (16 + 8)
+
+    def test_replicated_layout_counts_none(self, monkeypatch):
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, steps=0)
+        g = {r.name: r for r in self._step(mod, 1)}["Step::gather"].args
+        assert g["sharded"] == 0 and g["sharded_bytes"] == 0
+
+    def test_the_benchmarks_metric_reads_it(self, monkeypatch,
+                                            split_small):
+        """``state_sharded_leaves.train`` as ``perf/`` reads it: the file's
+        reader and params over the program's own ring, two traced steps
+        of three."""
+        import json
+        import os
+        from mxnet_tpu import tracing
+        from perf import harness
+        from perf.reducers import program_span_ms
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "perf", "metrics",
+                               "state_sharded_leaves.train.json")) as f:
+            spec = json.load(f)
+        assert spec["reducer"] == "program_span_ms"
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            entry = [m for m in json.load(f)["per_layer"]
+                     if m["name"] == spec["name"]]
+        assert entry == [{"name": "state_sharded_leaves.train",
+                          "unit": "count", "better": "higher",
+                          "source": "program_counter",
+                          "layer": "collectives",
+                          "moves": "train_items_per_s",
+                          "workloads": ["gpt2m_train_dp4"]}]
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, steps=0)
+        tracing.flight.clear()
+        spans = harness.Spans()
+        rs = np.random.RandomState(5)
+        for _ in range(3):
+            batch = _Batch(rs.randint(0, 2, (8, 4)).astype(np.float32),
+                           rs.randint(-1, 2, (8, 2)).astype(np.float32))
+            with spans("dispatch"):
+                mod.forward_backward(batch)
+                mod.update()
+        ctx = {"spans": spans, "traced_steps": 2}
+        assert program_span_ms.read(ctx, spec["params"]) == 2 * 2

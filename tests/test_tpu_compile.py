@@ -225,3 +225,128 @@ def test_conv3x3_compiles(one_chip):
     w = jax.ShapeDtypeStruct((C, C, 3, 3), jnp.bfloat16, sharding=one_chip)
     grad = jax.grad(_sq(pallas_conv.conv3x3_same), (0, 1))
     assert _custom_calls(grad, x, w) == 3
+
+
+def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
+    """``gpt2m_train_dp4``'s step in small, ahead of time: a two-layer
+    transformer's mesh fused step (Adam, bf16 weights with float32
+    masters, the batch ``P('dp')``) compiled for the four described chips
+    with the optimizer's state in the layout ``state_sharding`` gives it.
+    Every large leaf's new bf16 weight is all-gathered (under ``GradSync``)
+    from the quarter a chip updated (under ``Optimizer::Adam``), its gradient reaches the update through a reduce-scatter
+    (the TPU compiler's ``all-reduce-scatter`` fusion; the token table's
+    through an all-to-all of the rows' gradients), no all-reduce left in
+    the program carries a large gradient, and nothing else is gathered:
+    the constraint on the gradient does not pull an activation or a
+    weight-gradient product into another partitioning."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp
+    from mxnet_tpu.models import transformer_lm
+    from mxnet_tpu.models.configs import TransformerConfig
+    from mxnet_tpu.parallel.mesh import (STATE_SHARD_MIN_ELEMENTS,
+                                         state_sharding)
+    monkeypatch.setenv(amp.ENV_FLAG, "1")
+    B, T, V, D = 4, 128, 1001, 256
+    mod = mx.mod.Module(
+        transformer_lm(TransformerConfig("small", V, 2, D, 2, 4 * D, T),
+                       prefix="tfm_"),
+        data_names=("data",), label_names=("softmax_label",),
+        context=[mx.cpu(i) for i in range(4)])
+    mod.bind(data_shapes=[("data", (B, T))],
+             label_shapes=[("softmax_label", (B, T))])
+    mod.init_params(mx.init.Uniform(0.01))
+    mod.init_optimizer(kvstore="local", optimizer="adam",
+                       optimizer_params={"learning_rate": 1e-4,
+                                         "multi_precision": True})
+    fs, ex, opt = mod._fused_step, mod._exec_group.execs[0], mod._optimizer
+    pnames = fs._pnames
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    repl = NamedSharding(mesh, P())
+
+    def sds(shape, dtype, sharding=repl):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    shapes = [ex.arg_dict[n].shape for n in pnames]
+    ssh = [state_sharding(repl, s) for s in shapes]
+    large = [s for s, sh in zip(shapes, ssh) if sh is not repl]
+    assert len(large) == 14 and (V, D) in large     # table, head, 2 x 6
+    assert all(int(np.prod(s)) >= STATE_SHARD_MIN_ELEMENTS for s in large)
+    # matrices bf16 under a float32 master, LayerNorm's leaves float32
+    mp = [opt.fused_mp(ex.arg_dict[n]) for n in pnames]
+    assert all(m for m, sh in zip(mp, ssh) if sh is not repl)
+    fn = ex.step_program(
+        pnames, [opt.fused_update_mp if m else opt.fused_update for m in mp],
+        mesh_sig=("described",), param_shardings=[repl] * len(pnames),
+        state_shardings=ssh)
+    pvals = [sds(s, ex.arg_dict[n].dtype) for n, s in zip(pnames, shapes)]
+    svals = [tuple(sds(s, jnp.float32, sh) for _ in range(3 if m else 2))
+             for s, sh, m in zip(shapes, ssh, mp)]
+    ids = sds((B, T), ex.arg_dict["data"].dtype, NamedSharding(mesh, P("dp")))
+    plan = ex._plan(True)
+    keys = ex._keys(plan)
+    ogs = ex._ograds_for({**{n: ex.arg_dict[n].shape for n in ex.arg_names},
+                          "data": (B, T), "softmax_label": (B, T)})
+    vec = sds((len(pnames),), jnp.float32)
+    with jax.set_mesh(mesh):
+        hlo = fn.lower(
+            pvals, svals, [ids, ids], [], sds(keys.shape, keys.dtype),
+            [sds(o.shape, o.dtype) for o in ogs], vec, vec, vec,
+            sds((), jnp.float32)).compile().as_text()
+    entry = hlo[hlo.index("\nENTRY "):].splitlines()
+    result = re.compile(r"= \(?((?:\w+\[[\d,]*\][^ ]* ?)+)\)? ")
+
+    def results(ln):
+        """[(dtype, shape)] of the instruction's result (a tuple's parts)."""
+        return [(d, tuple(int(x) for x in dims.split(",") if x))
+                for d, dims in re.findall(
+                    r"(\w+)\[([\d,]*)\]",
+                    result.search(re.sub(r"/\*.*?\*/", "", ln)).group(1))]
+
+    # all-gathers by channel (the compiler clones one into the variants of
+    # a fusion): the new bf16 weight of every large leaf, and beside them
+    # only the token ids travel (for the table's gradient)
+    gathered = {re.search(r"channel_id=(\d+)", ln).group(1): results(ln)[0]
+                for ln in hlo.splitlines() if " all-gather(" in ln}
+    weights = [shape for dt, shape in gathered.values() if dt == "bf16"]
+    assert sorted(weights) == sorted(large), weights
+    assert {dt for dt, _ in gathered.values()} <= {"bf16", "s32"}, gathered
+    # reduce-scatters: each chip's quarter of every large gradient (the
+    # token table's may come through the all-to-all instead)
+    scattered = sorted(
+        int(np.prod(shape)) for ln in entry
+        if "calls=%all-reduce-scatter" in ln or " reduce-scatter(" in ln
+        for _, shape in results(ln))
+    quarter = sorted(int(np.prod(s)) // 4 for s in large)
+    if len(scattered) == len(large) - 1:
+        assert " all-to-all(" in hlo
+        quarter.remove(V * D // 4)
+    assert len(scattered) == len(quarter), scattered
+    assert all(q <= g <= 1.1 * q for g, q in zip(scattered, quarter))
+    # the yardstick's scopes (``optimizer_ms.train`` reads the device time
+    # under ``Optimizer::``): every update fusion, which writes a chip's
+    # quarter of the float32 master, mean and variance of a large leaf,
+    # carries the optimizer's scope, and no gather of a new weight does
+    # (they run under ``GradSync``)
+    def scope(ln):
+        found = re.search(r'op_name="([^"]*)"', ln)
+        return found.group(1) if found else ""
+
+    quarters = {sh.shard_shape(s) for s, sh in zip(shapes, ssh)
+                if sh is not repl}
+    updates = [ln for ln in entry if " fusion(" in ln
+               and sum(1 for dt, shape in results(ln)
+                       if dt == "f32" and shape in quarters) >= 3]
+    assert len(updates) == len(large), len(updates)
+    assert all("Optimizer::Adam" in scope(ln) for ln in updates), \
+        [scope(ln) for ln in updates]
+    gathers = [ln for ln in hlo.splitlines() if " all-gather(" in ln
+               and results(ln)[0][0] == "bf16"]
+    assert all("Optimizer::" not in scope(ln) and "GradSync" in scope(ln)
+               for ln in gathers), [scope(ln) for ln in gathers]
+    # what is still all-reduced whole is small: biases, norms, the loss
+    reduced = [r for ln in entry if " all-reduce(" in ln
+               for r in results(ln)]
+    assert reduced and all(int(np.prod(shape)) < STATE_SHARD_MIN_ELEMENTS
+                           for _, shape in reduced), reduced
