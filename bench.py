@@ -666,9 +666,9 @@ def bench_transformer():
     ladder entry, fed by ``io.SyntheticLMIter`` (deterministic
     next-token stream), trained with SGD — the whole step in one
     donated-buffer executable, attention dispatching to the Pallas
-    flash kernel when ``MXNET_TPU_FLASH_ATTENTION`` + the shape gates
-    allow (``attention_dispatch_total{path=...}`` says which path this
-    run actually compiled).  Reported alongside the throughput row:
+    flash kernel when the shape gates allow
+    (``attention_dispatch_total{path=...}`` says which path this run
+    actually compiled).  Reported alongside the throughput row:
 
       - **MFU** against the chip peak from ``health.peak_tflops`` using
         ``TransformerConfig.flops_per_token()`` (PaLM 6N+12LTd
@@ -797,8 +797,6 @@ def bench_transformer():
         "flops_per_token": flops_per_tok,
         "dtype": dtype,
         **stamp,
-        "flash_attention_env": os.environ.get(
-            "MXNET_TPU_FLASH_ATTENTION", "1"),
         "attention_dispatch": paths,
         "step_ms_median_blocked": round(m["step_ms_median_blocked"], 2),
         "step_spread_pct": round(m["step_spread_pct"], 1),

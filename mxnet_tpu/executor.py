@@ -300,15 +300,8 @@ class Executor:
     # contract as ops/registry.py env_keys).  MXNET_TPU_BF16 decides array
     # dtypes at BIND time, but it also selects per-slot mp update_fns
     # closure-captured by the step program — a mid-process flip must
-    # recompile, not reuse.  The attention gates are consulted at trace
-    # time wherever a step contains attention — the MultiHeadAttention op
-    # (whose own env_keys join the plan union) or the functional
-    # parallel/ring_attention forms composed into a custom stage, which
-    # the plan's op-level union cannot see — so they are declared here
-    # too: a flip re-specializes every cached step program.
-    STEP_ENV_KEYS = ("MXNET_TPU_FUSED_STEP", "MXNET_TPU_MESH_STEP",
-                     "MXNET_TPU_BF16", "MXNET_TPU_FLASH_ATTENTION",
-                     "MXNET_TPU_PALLAS_ATTN")
+    # recompile, not reuse.
+    STEP_ENV_KEYS = ("MXNET_TPU_FUSED_STEP", "MXNET_TPU_BF16")
 
     def __init__(self, symbol, ctx: Context, args: Dict[str, Any],
                  args_grad: Dict[str, Any], grad_req: Dict[str, str],
@@ -511,11 +504,6 @@ class Executor:
         return ("step",) + ((mesh_sig,) if mesh_sig is not None else ()) \
             + self._step_env() + self._plan_env(True)
 
-    def _update_key(self):
-        """Cache key of the update-only program (optimizer update_fns only —
-        no graph ops, so no plan env component)."""
-        return ("update",) + self._step_env()
-
     def step_program(self, pnames, update_fns, mesh_sig=None,
                      param_shardings=None, state_shardings=None):
         """Whole-step program: forward + vjp-backward + optimizer update in
@@ -602,19 +590,6 @@ class Executor:
 
         fn = jax.jit(fn, donate_argnums=(0, 1))
         self._jitted[key] = fn
-        return fn
-
-    def update_program(self, update_fns):
-        """Cached donated update-only program (multi-device local path:
-        fwdbwd stays per-device, the update fuses into one launch)."""
-        key = self._update_key()
-        fn = self._jitted.get(key)
-        if fn is None:
-            _program_cache.ensure_enabled()
-            fn = build_update_program(update_fns)
-            self._jitted[key] = fn
-        elif _telemetry.enabled:
-            _program_cache.note_memory_hit()
         return fn
 
     def _gather(self):

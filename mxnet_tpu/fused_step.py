@@ -5,16 +5,18 @@ eager path: one fwdbwd XLA program plus a python loop of per-param
 optimizer kernels plus per-param KVStore round-trips.  This module drives
 the fused alternative: ``Executor.step_program`` compiles forward + vjp +
 every optimizer update into ONE ``jax.jit`` with params and opt-state
-donated (``donate_argnums``), so a local single-device step is exactly one
-device launch and weights update in place.  Multi-device local training
-keeps per-device fwdbwd programs and fuses the reduce+update phase into
-one donated ``Executor.update_program`` per device.
+donated (``donate_argnums``), so a step is exactly one device launch and
+weights update in place.  ``ModuleFusedStep.step`` is the one driver of
+that program: on one device as it stands, on N devices as ONE GSPMD program
+over a device ``Mesh`` (XLA inserts the gradient exchange from the
+``P('dp')`` batch sharding).  The input placements make it a mesh program;
+the driver's body is the same.
 
 Gated by ``MXNET_TPU_FUSED_STEP`` (default ON for the local path); the
 eager per-param loop remains both the OFF fallback and the parity oracle —
 any structural surprise (monitor installed, sparse grads, exotic optimizer
-state, kvstore-side update) falls back per step, counted by
-``step_dispatch_total{path=...}``.
+state, kvstore-side update, N contexts that cannot host a mesh) falls back
+per step, counted by ``step_dispatch_total{path=...}``.
 
 Donation safety: XLA donation genuinely deletes the input buffer (also on
 the CPU backend), while NDArray handles are freely re-pointed by python
@@ -26,7 +28,10 @@ donated buffer is ever double-used.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import functools
+import itertools
 import os
 
 import jax
@@ -38,12 +43,11 @@ from . import health as _health
 from . import memwatch as _memwatch
 from . import profiler as _profiler
 
-__all__ = ["enabled", "mesh_enabled", "ModuleFusedStep",
+__all__ = ["enabled", "ModuleFusedStep",
            "TrainerFusedUpdate", "TrainerMeshUpdate", "DonationPool",
-           "STEP_DISPATCH", "STEP_TIME", "ENV_FLAG", "MESH_ENV_FLAG"]
+           "STEP_DISPATCH", "STEP_TIME", "ENV_FLAG"]
 
 ENV_FLAG = "MXNET_TPU_FUSED_STEP"
-MESH_ENV_FLAG = "MXNET_TPU_MESH_STEP"
 
 STEP_DISPATCH = _telemetry.counter(
     "step_dispatch_total",
@@ -76,7 +80,7 @@ OPT_STATE_SHARDED_BYTES = _telemetry.gauge(
 
 def _span(name, args=None):
     """One phase of the step timeline (docs/observability.md "Step
-    timeline"): the same names on every Module fused path."""
+    timeline"), each opened once, in ``ModuleFusedStep.step``."""
     return _profiler.span(name, "step", args=args)
 
 
@@ -98,16 +102,6 @@ def enabled():
         ("0", "false", "off", "")
 
 
-def mesh_enabled():
-    """MXNET_TPU_MESH_STEP gate; default ON.  Selects the GSPMD mesh
-    variant of the fused step for local multi-device training: ONE global
-    program over a device ``Mesh`` (XLA inserts the gradient all-reduce
-    from the ``P('dp')`` batch sharding) instead of per-device programs
-    plus a host-side KVStore reduce."""
-    return os.environ.get(MESH_ENV_FLAG, "1").lower() not in \
-        ("0", "false", "off", "")
-
-
 def _env_tuple():
     from .executor import Executor
     return tuple(os.environ.get(k) for k in Executor.STEP_ENV_KEYS)
@@ -126,7 +120,10 @@ class DonationPool:
     current array if this pool produced it (nobody else can hold it — the
     program output went straight into the handle), else a fresh copy
     (externally written handles may share their buffer with caller-held
-    arrays via no-op device_put/astype/broadcast_to).  ``give`` writes a
+    arrays via no-op device_put/astype/broadcast_to).  It has
+    ``take_sharded``'s signature (its ``sharding`` is None: the array stays
+    on its device), so a step binds one of the two once, outside its loops.
+    ``give`` writes a
     program output back into the handle and records it as pool-owned.
     Every copy is counted (``copies``, ``copy_bytes``, and the telemetry
     pair ``donation_copies_total`` / ``donation_copy_bytes_total``): a copy
@@ -147,7 +144,7 @@ class DonationPool:
             DONATION_COPIES.labels(path=path).inc()
             DONATION_COPY_BYTES.labels(path=path).inc(nbytes)
 
-    def take(self, slot, handle):
+    def take(self, slot, handle, sharding):
         cur = handle._data
         if self._own.get(slot) is not cur:
             self.count_copy("fused", cur)
@@ -214,9 +211,9 @@ def _as_jax(arr):
 class _StagedBatch:
     """A staged (deferred) train batch, materialised lazily in whichever
     layout the consumer needs: ``feeds()`` gives the per-device sliced
-    feeds for the eager replay / per-device programs, ``full()`` the
-    full-batch device arrays the mesh program shards on the ``dp`` axis —
-    the mesh path never pays the per-device slice + placement work."""
+    feeds for the eager replay, ``full()`` the whole batch's device arrays
+    for the fused step — a mesh step never pays the per-device slice +
+    placement work."""
 
     def __init__(self, eg, data_batch):
         self._eg = eg
@@ -228,37 +225,53 @@ class _StagedBatch:
             self._feeds = self._eg._load_batch(self._batch)
         return self._feeds
 
-    def full(self):
-        out = {}
+    def full(self, read):
+        """{input name: ``read(array)``} (``_Placement.read``)."""
         eg = self._eg
+        out = {}
         for name, arr in zip(eg.data_names, self._batch.data):
-            out[name] = _as_jax(arr)
+            out[name] = read(arr)
         for name, arr in zip(eg.label_names, self._batch.label or []):
-            out[name] = _as_jax(arr)
+            out[name] = read(arr)
         return out
+
+
+# What differs between the fused step on one device and on a mesh, decided
+# once per bind / mesh change (``ModuleFusedStep._placement``): the
+# counters' label and the program's name with health; the mesh (None: one
+# device); ``read(array)``, a staged input's device array; ``put_batch(v,
+# handle)`` and ``put_other(v)``, how a batch input and a non-parameter
+# input are placed; ``take``, the pool's method for a donated leaf;
+# ``scope()``, the context the program is lowered and launched in;
+# ``span_args``, what ``Step::program`` / ``Step::launch`` say of it;
+# ``land(outs)``, which puts the outputs where ``get_outputs`` finds them
+# and returns the step's ``mesh_outputs()``.
+_Placement = collections.namedtuple(
+    "_Placement", "path program mesh read put_batch put_other take scope "
+    "span_args land")
 
 
 class ModuleFusedStep:
     """Drives Module's fused train step.
 
-    ``forward_backward`` stages the per-device feeds; ``update`` then
-    dispatches, for a single device, ONE whole-step program (fwd + vjp +
-    update, params/opt-state donated) or, for multiple devices, the
-    per-device fwdbwd programs followed by one donated update program per
-    device.  Gradients are not written back to ``grad_dict`` on the
-    single-device fused path (they only exist inside the program); the
-    flush hooks replay a staged batch through the eager oracle whenever
-    outputs or input grads must be observable before ``update``.
+    ``forward_backward`` stages the batch; ``update`` then dispatches ONE
+    whole-step program (fwd + vjp + update, params/opt-state donated)
+    through ``step``: on one device, or over the mesh of N devices.
+    Gradients are not written back to ``grad_dict`` (they only exist
+    inside the program); the flush hooks replay a staged batch through
+    the eager oracle whenever outputs or input grads must be observable
+    before ``update``.
     """
 
     def __init__(self, module):
         self._mod = module
         self._eg = module._exec_group
-        self._pools = [DonationPool() for _ in self._eg.execs]
+        self._pool = DonationPool()
         self._pending = None
         self._unsupported = False
         self._structural_ok = {}     # env tuple -> bool
         self._mesh_cache = None      # (key, (mesh, rules, dp_axis)|None)
+        self._place = None           # _placement()'s (False: none), and
         self._layout = None          # _mesh_layout()'s, of that mesh
         self._split = None           # _count_split()'s, of that layout
         self._meshed = False         # handles currently hold mesh globals
@@ -268,8 +281,7 @@ class ModuleFusedStep:
         # (new init_optimizer / rebind) must not reuse a predecessor's
         for ex in self._eg.execs:
             for k in [k for k in ex._jitted
-                      if isinstance(k, tuple) and k
-                      and k[0] in ("step", "update")]:
+                      if isinstance(k, tuple) and k and k[0] == "step"]:
                 del ex._jitted[k]
         req = self._eg.grad_req
         self._pnames = [n for n in module._param_names
@@ -319,6 +331,8 @@ class ModuleFusedStep:
 
     # -- eligibility ------------------------------------------------------
     def eligible(self):
+        """One answer for ``Module``: a fused step (one device, or a mesh)
+        or the eager loop."""
         if not enabled() or self._unsupported:
             return False
         m = self._mod
@@ -332,6 +346,8 @@ class ModuleFusedStep:
         for ex in self._eg.execs:
             if ex._monitor is not None or ex._group2ctx:
                 return False
+        if self._placement() is None:   # N contexts, no mesh over them
+            return False
         # keyed by the step env values (bound dtypes are fixed, but the
         # dtype gate in supports_fused depends on optimizer mp config and
         # a stale cached verdict must not survive an env flip)
@@ -356,35 +372,6 @@ class ModuleFusedStep:
                     return False
         return True
 
-    # -- dispatch ---------------------------------------------------------
-    def step(self):
-        """Consume the staged batch with fused programs.  Returns the
-        dispatch path taken ("fused" / "mesh_fused", both truthy) or False
-        (after replaying the batch eagerly) when the updater state turns
-        out not to be fusable, so Module.update can run the eager loop."""
-        ndev = len(self._eg.execs)
-        with _span("Step::validate"):
-            ok = self._states_fusable(ndev)
-        if not ok:
-            self._unsupported = True
-            self.flush_eager()
-            return False
-        self.steps += 1
-        if ndev == 1:
-            self._step_single()
-            return "fused"
-        if self._mesh_ok():
-            return self._step_mesh()
-        self._demesh()
-        staged, self._pending = self._pending, None
-        if staged is not None:
-            with _span("Step::feed"):
-                feeds = staged.feeds()
-            for ex, feed in zip(self._eg.execs, feeds):
-                ex.forward_backward(**feed)
-        self._update_multi()
-        return "fused"
-
     def _states_fusable(self, ndev):
         """Validate any pre-existing (e.g. preloaded) updater states before
         touching counts or consuming the pending feed.  Expected layout is
@@ -405,186 +392,183 @@ class ModuleFusedStep:
                 return False
         return True
 
-    def _slots_for_device(self, ex, k, ndev):
-        """Create-missing-state + count + capture per-slot scalars, in the
-        exact order of the eager loop (param-major, device-minor ordering
-        is handled by the caller for ndev > 1)."""
-        out = []
-        for i, name in enumerate(self._mod._param_names):
-            if name in self._pset:
-                out.extend(self._slots_for_device_one(ex, i, k, ndev))
-        return out
+    # -- dispatch ---------------------------------------------------------
+    def step(self):
+        """Consume the staged batch with the fused program.  Returns the
+        dispatch path taken ("fused" on one device, "mesh_fused" on a
+        mesh, both truthy) or False (after replaying the batch eagerly)
+        when the updater state turns out not to be fusable, so
+        Module.update can run the eager loop.
 
-    def _slot_mp(self, ex, name):
-        """Whether this param's slot is multi-precision (bf16/f16 weight
-        with a master-fp32 leaf prepended to its flat state)."""
-        return self._mod._optimizer.fused_mp(ex.arg_dict[name])
-
-    def _slot_leaves(self, ex, name, state):
+        One device is the mesh step's degenerate case: no sibling execs,
+        no shardings (``_step_key()`` and the program are the plain ones)
+        and ``_placement()``'s callables, bound here outside the loops."""
         from . import optimizer as _opt
-        return _opt.fused_state_leaves(state, self._slot_mp(ex, name))
-
-    def _update_fns(self, ex, slots):
-        """Per-slot traced update: the mp wrapper for low-precision
-        weights, the plain fused core for fp32 ones — mixed layouts
-        (bf16 conv weights + fp32 batchnorm scales) fuse into one
-        program."""
-        opt_ = self._mod._optimizer
-        return [opt_.fused_update_mp if self._slot_mp(ex, s[0])
-                else opt_.fused_update for s in slots]
-
-    def _gather_update_inputs(self, ex, k, slots):
-        """Pool-guarded param/state buffers + the scalar arrays."""
         m = self._mod
-        pool = self._pools[k]
+        opt_ = m._optimizer
+        execs = self._eg.execs
+        ex, rest = execs[0], execs[1:]
+        with _span("Step::validate"):
+            ok = self._states_fusable(len(execs))
+        if not ok:
+            self._unsupported = True
+            self.flush_eager()
+            return False
+        self.steps += 1
+        place = self._placement()
+        pshardings, sshardings, mesh_sig = self._mesh_layout()
+        leaf_psh = pshardings or itertools.repeat(None)
+        leaf_ssh = sshardings or leaf_psh
+        pool, take, pset = self._pool, place.take, self._pset
         states = m._updater.states
-        pvals, svals = [], []
-        with _gather_span(pool) as args:
-            for name, slot, _, _, _ in slots:
-                pvals.append(pool.take(("w", name), ex.arg_dict[name]))
-                leaves = self._slot_leaves(ex, name, states[slot])
-                svals.append(tuple(pool.take(("s", slot, j), leaf)
-                                   for j, leaf in enumerate(leaves)))
-            args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
-            scalars = self._slot_scalars(slots)
-        return (pvals, svals) + scalars
-
-    def _slot_scalars(self, slots):
-        """(lrs, wds, ts, rescale): four small host-to-device copies."""
-        return (jnp.asarray([s[2] for s in slots], jnp.float32),
-                jnp.asarray([s[3] for s in slots], jnp.float32),
-                jnp.asarray([s[4] for s in slots], jnp.float32),
-                jnp.asarray(self._mod._optimizer.rescale_grad, jnp.float32))
-
-    def _writeback(self, ex, k, slots, new_p, new_s):
-        pool = self._pools[k]
-        states = self._mod._updater.states
-        for (name, slot, _, _, _), w, st in zip(slots, new_p, new_s):
-            pool.give(("w", name), ex.arg_dict[name], w)
-            leaves = self._slot_leaves(ex, name, states[slot])
-            for j, (leaf, arr) in enumerate(zip(leaves, st)):
-                pool.give(("s", slot, j), leaf, arr)
-
-    def _step_single(self):
-        from .ndarray.ndarray import NDArray
-        ex = self._eg.execs[0]
+        mp_of, leaves_of = opt_.fused_mp, _opt.fused_state_leaves
         staged, self._pending = self._pending, None
         with _span("Step::feed"):
-            feeds = staged.feeds() if staged is not None else None
-            for kname, v in (feeds[0] if feeds else {}).items():
-                dst = ex.arg_dict[kname]
-                if isinstance(v, NDArray):
-                    # adopt pre-placed producer batches as-is
-                    # (PrefetchingIter device double buffering): no re-put,
-                    # no same-dtype astype
-                    src = v._data
-                    dst._data = src if src.dtype == dst.dtype \
-                        else src.astype(dst.dtype)
+            full = staged.full(place.read) if staged is not None else {}
+            put_batch, put_other = place.put_batch, place.put_other
+            batch_names = set(self._eg.data_names) | \
+                set(self._eg.label_names)
+            others, shapes = [], {}
+            for n in ex.arg_names:
+                handle = ex.arg_dict[n]
+                if n in pset:
+                    shapes[n] = handle.shape
+                    continue
+                if n in batch_names:
+                    v = full.get(n)
+                    if v is None:       # replayed without a staged batch
+                        v = handle._data
+                    if v.dtype != handle.dtype:
+                        v = v.astype(handle.dtype)
+                    v = put_batch(v, handle)
                 else:
-                    dst._data = jnp.asarray(v, dst.dtype)
-            others = [ex.arg_dict[n]._data for n in ex.arg_names
-                      if n not in self._pset]
-            auxs = [ex.aux_dict[n]._data for n in ex.aux_names]
+                    v = put_other(handle._data)
+                others.append(v)
+                shapes[n] = tuple(v.shape)
+            auxs = [put_other(ex.aux_dict[n]._data) for n in ex.aux_names]
         with _span("Step::slots", {"params": len(self._pnames)}):
-            slots = self._slots_for_device(ex, 0, 1)
-        pvals, svals, lrs, wds, ts, rescale = \
-            self._gather_update_inputs(ex, 0, slots)
-        run = {}
+            slots = self._slots(ex, len(execs))
+        pvals, svals, taken = [], [], []    # taken: (handle, leaves, mp)
+        with _gather_span(pool) as args:
+            placed = pool.copies
+            for (name, slot, _, _, _), psh, ssh in zip(
+                    slots, leaf_psh, leaf_ssh):
+                handle = ex.arg_dict[name]
+                for e in rest:
+                    # all execs' views of one param must agree: where one
+                    # was written from outside, the slot is copied
+                    if e.arg_dict[name]._data is not handle._data:
+                        pool.disown(("w", name))
+                        break
+                pvals.append(take(("w", name), handle, psh))
+                # mp slots: leaf 0 is the master-fp32 copy — same shape as
+                # the param, so it takes the state's layout like every
+                # moment
+                mp = mp_of(handle)
+                leaves = leaves_of(states[slot], mp)
+                svals.append(tuple(take(("s", slot, j), leaf, ssh)
+                                   for j, leaf in enumerate(leaves)))
+                taken.append((handle, leaves, mp))
+            if self._split is None or pool.copies != placed:
+                # counted when a leaf was placed (the first step, a state
+                # set from outside), not on every step
+                self._split = self._count_split(svals)
+            args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
+            args["sharded"], args["sharded_bytes"] = self._split
+            # (lrs, wds, ts, rescale): four small host-to-device copies
+            lrs = jnp.asarray([s[2] for s in slots], jnp.float32)
+            wds = jnp.asarray([s[3] for s in slots], jnp.float32)
+            ts = jnp.asarray([s[4] for s in slots], jnp.float32)
+            rescale = jnp.asarray(opt_.rescale_grad, jnp.float32)
+        run = dict(place.span_args)
         with _span("Step::program", run):
             plan = ex._plan(True)
             keys = ex._keys(plan)
             ex._last_keys = keys
-            ogs = ex._default_ograds()
-            update_fns = self._update_fns(ex, slots)
-            first_run = run["first_run"] = ex._step_key() not in ex._jitted
-            fn = ex.step_program([s[0] for s in slots], update_fns)
+            ogs = ex._ograds_for(shapes)
+            # per-slot traced update: the mp wrapper for low-precision
+            # weights, the plain fused core for fp32 ones — mixed layouts
+            # (bf16 conv weights + fp32 batchnorm scales) fuse into one
+            # program
+            update_fns = [opt_.fused_update_mp if mp else opt_.fused_update
+                          for _, _, mp in taken]
+            first_run = run["first_run"] = \
+                ex._step_key(mesh_sig) not in ex._jitted
+            fn = ex.step_program(
+                [s[0] for s in slots], update_fns, mesh_sig=mesh_sig,
+                param_shardings=pshardings, state_shardings=sshardings)
             if first_run and _health.enabled:
                 # lowering-only analysis — the dispatch below still owns
                 # the one and only compilation of this program
-                _health.register_program(
-                    "step", fn, (pvals, svals, others, auxs, keys, ogs, lrs,
-                                 wds, ts, rescale), donated=True,
-                    env=ex._program_env(plan))
-        with _span("Step::launch", run):
+                with place.scope():
+                    _health.register_program(
+                        place.program, fn, (pvals, svals, others, auxs,
+                                            keys, ogs, lrs, wds, ts,
+                                            rescale),
+                        donated=True, env=ex._program_env(plan))
+        # on a mesh, traced under it: an op that must keep a custom call on
+        # each device's own rows (MultiHeadAttention's flash kernel) finds
+        # it in ``jax.sharding.get_abstract_mesh()``
+        with _span("Step::launch", run), place.scope():
             new_p, new_s, outs, new_aux = fn(
                 pvals, svals, others, auxs, keys, ogs, lrs, wds, ts, rescale)
         if first_run and _health.enabled:
-            _health.audit_donation("step", (pvals, svals))
+            _health.audit_donation(place.program, (pvals, svals))
         with _span("Step::writeback"):
-            self._writeback(ex, 0, slots, new_p, new_s)
-            ex._writeback_aux(new_aux)
-            ex._wrap_outputs(outs)
+            give = pool.give
+            for (name, slot, _, _, _), (handle, leaves, _), w, st in zip(
+                    slots, taken, new_p, new_s):
+                give(("w", name), handle, w)
+                for e in rest:
+                    e.arg_dict[name]._data = w
+                for j, (leaf, arr) in enumerate(zip(leaves, st)):
+                    give(("s", slot, j), leaf, arr)
+            for n, v in zip(ex.aux_names, new_aux):
+                for e in execs:
+                    e.aux_dict[n]._data = v
+            self._mesh_outputs = place.land(outs)
             # the donated inputs' (now dead) array objects go here and not
             # at the function's end, outside every phase: a thousand leaves
             # take a millisecond to drop
             del pvals, svals, new_p, new_s
+        self._meshed = place.mesh is not None
+        return place.path
 
-    def _update_multi(self):
-        """Per-device update programs (multi-device without a mesh): the
-        timeline's spans from ``Step::gather`` on come once per device."""
-        m = self._mod
-        execs = self._eg.execs
-        ndev = len(execs)
-        reduce_grads = m._kvstore is not None
-        # eager count order is param-major, device-minor: interleave the
-        # per-device slot capture accordingly
-        per_dev = [[] for _ in range(ndev)]
-        with _span("Step::slots", {"params": len(self._pnames)}):
-            for i, name in enumerate(m._param_names):
-                if name not in self._pset:
-                    continue
-                for k, ex in enumerate(execs):
-                    per_dev[k].extend(
-                        self._slots_for_device_one(ex, i, k, ndev))
-        for k, ex in enumerate(execs):
-            slots = per_dev[k]
-            pvals, svals, lrs, wds, ts, rescale = \
-                self._gather_update_inputs(ex, k, slots)
-            run = {}
-            with _span("Step::program", run):
-                dev = ex._ctx.jax_device
-                gvals = []
-                for name, _, _, _, _ in slots:
-                    if reduce_grads:
-                        gvals.append(
-                            [jax.device_put(e.grad_dict[name]._data, dev)
-                             for e in execs])
-                    else:
-                        gvals.append([ex.grad_dict[name]._data])
-                first_run = run["first_run"] = \
-                    ex._update_key() not in ex._jitted
-                fn = ex.update_program(self._update_fns(ex, slots))
-                if first_run and k == 0 and _health.enabled:
-                    _health.register_program(
-                        "update", fn, (pvals, svals, gvals, lrs, wds, ts,
-                                       rescale), donated=True,
-                        env=ex._program_env())
-            with _span("Step::launch", run):
-                new_p, new_s = fn(pvals, svals, gvals, lrs, wds, ts, rescale)
-            if first_run and k == 0 and _health.enabled:
-                _health.audit_donation("update", (pvals, svals))
-            with _span("Step::writeback"):
-                self._writeback(ex, k, slots, new_p, new_s)
-
-    def _slots_for_device_one(self, ex, i, k, ndev):
-        """Single-param slot capture (multi-device interleaving order)."""
+    def _slots(self, ex, ndev):
+        """Create-missing-state + count + capture per-slot scalars, in the
+        eager loop's parameter order: ONE logical state per param, held in
+        the device-0 slot of the eager layout; on a mesh the sibling slots
+        alias it so checkpoints (`get_states`) and the eager resume path
+        keep seeing the layout they expect.  The count advances once per
+        step — the program IS the single update."""
         m = self._mod
         opt_ = m._optimizer
         states = m._updater.states
-        name = m._param_names[i]
-        slot = opt_.slot_index(i, ndev, k)
-        w = ex.arg_dict[name]
-        if slot not in states:
-            states[slot] = opt_.create_state_multi_precision(slot, w)
-            m._updater.states_synced[slot] = True
-        opt_._update_count(slot)
-        t = opt_._index_update_count[slot]
-        # host-side lr corrections (Adam's f64 bias fold) ride in the
-        # captured lr so the traced program matches the eager oracle
-        return [(name, slot, opt_.fused_slot_lr(opt_._get_lr(slot), t),
-                 opt_._get_wd(slot), t)]
+        pset = self._pset
+        out = []
+        for i, name in enumerate(m._param_names):
+            if name not in pset:
+                continue
+            base = opt_.slot_index(i, ndev, 0)
+            if base not in states:
+                states[base] = opt_.create_state_multi_precision(
+                    base, ex.arg_dict[name])
+                m._updater.states_synced[base] = True
+            opt_._update_count(base)
+            cnt = opt_._index_update_count[base]
+            for k in range(1, ndev):
+                sib = opt_.slot_index(i, ndev, k)
+                states[sib] = states[base]
+                m._updater.states_synced[sib] = True
+                opt_._index_update_count[sib] = cnt
+            # host-side lr corrections (Adam's f64 bias fold) ride in the
+            # captured lr so the traced program matches the eager oracle
+            out.append((name, base,
+                        opt_.fused_slot_lr(opt_._get_lr(base), cnt),
+                        opt_._get_wd(base), cnt))
+        return out
 
-    # -- mesh (GSPMD) path ------------------------------------------------
+    # -- placement: one device, or a mesh ---------------------------------
     def on_mesh_change(self):
         """Module.set_mesh hook: drop the cached mesh so the next step
         re-derives shardings (and a new step-program cache key)."""
@@ -613,20 +597,103 @@ class ModuleFusedStep:
                 except (ValueError, TypeError):
                     setup = None
         self._mesh_cache = (key, setup)
-        self._layout = self._split = None
+        self._place = self._layout = self._split = None
         return setup
+
+    def _placement(self):
+        """The ``_Placement`` of this bind: one device as it stands, or N
+        contexts as one mesh.  None where N contexts cannot host a mesh —
+        facts of the bind (no kvstore selected: intentionally unsynced
+        replicas; ragged batch slices; an input whose batch axis is not 0)
+        and of the mesh (duplicate devices, a batch that does not divide
+        over ``dp``): ``eligible()`` says no and the step is the eager
+        loop's."""
+        eg = self._eg
+        setup = self._mesh_setup() if len(eg.execs) > 1 else None
+        if self._place is None:
+            if len(eg.execs) == 1:
+                self._place = self._place_on_device()
+            elif setup is None or not self._batch_shards(setup):
+                self._place = False
+            else:
+                self._place = self._place_on_mesh(setup)
+        return self._place or None
+
+    def _place_on_device(self):
+        ex, ctx = self._eg.execs[0], self._eg.contexts[0]
+
+        def adopt(v, handle):
+            # pre-placed producer batches as they are (PrefetchingIter
+            # device double buffering): no re-put
+            handle._data = v
+            return v
+
+        def land(outs):
+            ex._wrap_outputs(outs)      # no mesh outputs: the exec's own
+
+        return _Placement(
+            "fused", "step", None,
+            lambda arr: arr.as_in_context(ctx)._data, adopt, lambda v: v,
+            self._pool.take, contextlib.nullcontext, {}, land)
+
+    def _place_on_mesh(self, setup):
+        from .ndarray.ndarray import NDArray
+        from .parallel.mesh import data_parallel_sharding, \
+            replicated_sharding
+        mesh, _, dp = setup
+        ctx = self._eg.contexts[0]
+        repl = replicated_sharding(mesh)
+        bsh = data_parallel_sharding(mesh, dp)
+
+        def shard(v, _handle):
+            # producer-prefetched batches (PrefetchingIter with
+            # sharding=batch_sharding()) arrive pre-sharded: the H2D +
+            # shard already happened during the PREVIOUS step
+            if getattr(v, "sharding", None) != bsh:
+                v = jax.device_put(v, bsh)
+            return v
+
+        return _Placement(
+            "mesh_fused", "mesh_step", mesh, _as_jax, shard,
+            lambda v: jax.device_put(v, repl), self._pool.take_sharded,
+            functools.partial(jax.set_mesh, mesh),
+            {"mesh": str(dict(mesh.shape))},
+            lambda outs: [NDArray(o, ctx) for o in outs])
+
+    def _batch_shards(self, setup):
+        """Local synced-DP semantics (a local kvstore selected) and a batch
+        that shards evenly on axis 0 of every input."""
+        eg = self._eg
+        if self._mod._kvstore is None:
+            return False
+        if len({s.stop - s.start for s in eg.slices}) != 1:
+            return False
+        mesh, _, dp = setup
+        bs = eg.batch_size
+        if bs % mesh.shape[dp] != 0:
+            return False
+        from .io import DataDesc
+        for d in list(eg.data_shapes) + list(eg.label_shapes or []):
+            if d.shape[0] != bs or \
+                    DataDesc.get_batch_axis(getattr(d, "layout", "NCHW")) != 0:
+                return False
+        return True
 
     def _mesh_layout(self):
         """(param shardings, state shardings, mesh signature) of the
-        trainable params in ``_slots_for_mesh``'s order, derived once per
-        mesh from what can be seen: the mesh, the rules' spec of the
-        param and its shape.  The opt-state's layout is the param's,
-        split further over ``dp`` (``parallel.mesh.state_sharding``).
-        Where no leaf is split (a mesh without a ``dp`` extent, small
-        leaves only) the state shardings are None, the signature does not
-        name them and the program is the one it was."""
-        mesh, rules, dp = self._mesh_setup()
+        trainable params in ``_slots``' order, derived once per mesh from
+        what can be seen: the mesh, the rules' spec of the param and its
+        shape; three Nones on one device.  The opt-state's layout is the
+        param's, split further over ``dp``
+        (``parallel.mesh.state_sharding``).  Where no leaf is split (a
+        mesh without a ``dp`` extent, small leaves only) the state
+        shardings are None, the signature does not name them and the
+        program is the one it was."""
         if self._layout is None:
+            if self._placement().mesh is None:
+                self._layout = (None, None, None)
+                return self._layout
+            mesh, rules, dp = self._mesh_setup()
             from .parallel.mesh import replicated_sharding, state_sharding
             repl = replicated_sharding(mesh)
             ex = self._eg.execs[0]
@@ -650,189 +717,17 @@ class ModuleFusedStep:
 
     def _count_split(self, svals):
         """(count, global bytes) of the opt-state leaves in ``svals``
-        (``_step_mesh``'s, aligned with the layout) that are split over
-        ``dp``: ``Step::gather``'s ``sharded`` / ``sharded_bytes`` and the
+        (``step``'s, aligned with the layout) that are split over ``dp``:
+        ``Step::gather``'s ``sharded`` / ``sharded_bytes`` and the
         operators' gauge."""
         psh, ssh, _ = self._mesh_layout()
-        split = [leaf for sv, p, s in zip(svals, psh, ssh or psh)
+        split = [leaf for sv, p, s in zip(svals, psh or (), ssh or psh or ())
                  if s is not p for leaf in sv]
         nbytes = sum(a.nbytes for a in split)
         if _telemetry.enabled:
-            OPT_STATE_SHARDED_BYTES.labels(path="mesh_fused").set(nbytes)
+            OPT_STATE_SHARDED_BYTES.labels(
+                path=self._placement().path).set(nbytes)
         return len(split), nbytes
-
-    def _mesh_ok(self):
-        """Mesh-path eligibility on top of ``eligible()``: local synced-DP
-        semantics (a local kvstore selected), a buildable mesh, and a
-        batch that shards evenly on axis 0 of every input."""
-        if not mesh_enabled():
-            return False
-        eg = self._eg
-        if len(eg.execs) <= 1 or self._mod._kvstore is None:
-            return False
-        if len({s.stop - s.start for s in eg.slices}) != 1:
-            return False
-        setup = self._mesh_setup()
-        if setup is None:
-            return False
-        mesh, _, dp = setup
-        bs = eg.batch_size
-        if bs % mesh.shape[dp] != 0:
-            return False
-        from .io import DataDesc
-        for d in list(eg.data_shapes) + list(eg.label_shapes or []):
-            if d.shape[0] != bs or \
-                    DataDesc.get_batch_axis(getattr(d, "layout", "NCHW")) != 0:
-                return False
-        return True
-
-    def _slots_for_mesh(self, ex, ndev):
-        """Per-param slot capture for the mesh step: ONE logical state per
-        param, held in the device-0 slot of the eager layout; the sibling
-        slots alias it so checkpoints (`get_states`) and the eager resume
-        path keep seeing the layout they expect.  The count advances once
-        per step — the global program IS the single update."""
-        m = self._mod
-        opt_ = m._optimizer
-        states = m._updater.states
-        out = []
-        for i, name in enumerate(m._param_names):
-            if name not in self._pset:
-                continue
-            base = opt_.slot_index(i, ndev, 0)
-            w = ex.arg_dict[name]
-            if base not in states:
-                states[base] = opt_.create_state_multi_precision(base, w)
-                m._updater.states_synced[base] = True
-            opt_._update_count(base)
-            cnt = opt_._index_update_count[base]
-            for k in range(1, ndev):
-                sib = opt_.slot_index(i, ndev, k)
-                states[sib] = states[base]
-                m._updater.states_synced[sib] = True
-                opt_._index_update_count[sib] = cnt
-            out.append((name, base,
-                        opt_.fused_slot_lr(opt_._get_lr(base), cnt),
-                        opt_._get_wd(base), cnt))
-        return out
-
-    def _take_mesh(self, slot, handles, sharding):
-        """Pool-guarded donate-safe mesh placement of a set of handles that
-        must agree (all execs' views of one param).  Divergent handles —
-        some exec was written externally — disown the slot and copy."""
-        pool = self._pools[0]
-        cur = handles[0]._data
-        if any(h._data is not cur for h in handles[1:]):
-            pool.disown(slot)
-        return pool.take_sharded(slot, handles[0], sharding)
-
-    def _step_mesh(self):
-        from .ndarray.ndarray import NDArray
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        m = self._mod
-        eg = self._eg
-        execs = eg.execs
-        ex = execs[0]
-        ndev = len(execs)
-        mesh, _, dp = self._mesh_setup()
-        pshardings, sshardings, mesh_sig = self._mesh_layout()
-        repl = NamedSharding(mesh, P())
-        bsh = NamedSharding(mesh, P(dp))
-        staged, self._pending = self._pending, None
-        states = m._updater.states
-        pool = self._pools[0]
-        with _span("Step::feed"):
-            full = staged.full() if staged is not None else {}
-            batch_names = set(eg.data_names) | set(eg.label_names)
-            others, full_shapes = [], {}
-            for n in ex.arg_names:
-                if n in self._pset:
-                    full_shapes[n] = ex.arg_dict[n].shape
-                    continue
-                if n in batch_names:
-                    v = full.get(n)
-                    if v is None:       # replayed without a staged batch
-                        v = ex.arg_dict[n]._data
-                    dt = ex.arg_dict[n].dtype
-                    if v.dtype != dt:
-                        v = v.astype(dt)
-                    if getattr(v, "sharding", None) != bsh:
-                        # producer-prefetched batches (PrefetchingIter with
-                        # sharding=batch_sharding()) arrive pre-sharded: the
-                        # H2D + shard already happened during the PREVIOUS
-                        # step
-                        v = jax.device_put(v, bsh)
-                    others.append(v)
-                    full_shapes[n] = tuple(v.shape)
-                else:
-                    others.append(jax.device_put(ex.arg_dict[n]._data, repl))
-                    full_shapes[n] = ex.arg_dict[n].shape
-            auxs = [jax.device_put(ex.aux_dict[n]._data, repl)
-                    for n in ex.aux_names]
-        with _span("Step::slots", {"params": len(self._pnames)}):
-            slots = self._slots_for_mesh(ex, ndev)
-        pvals, svals = [], []
-        with _gather_span(pool) as args:
-            placed = pool.copies
-            for (name, slot, _, _, _), sh, ssh in zip(
-                    slots, pshardings, sshardings or pshardings):
-                pvals.append(self._take_mesh(
-                    ("w", name), [e.arg_dict[name] for e in execs], sh))
-                # mp slots: leaf 0 is the master-fp32 copy — same shape as
-                # the param, so it takes the state's layout like every
-                # moment
-                leaves = self._slot_leaves(ex, name, states[slot])
-                svals.append(tuple(
-                    pool.take_sharded(("s", slot, j), leaf, ssh)
-                    for j, leaf in enumerate(leaves)))
-            if self._split is None or pool.copies != placed:
-                # counted when a leaf was placed (the first step, a state
-                # set from outside), not on every step
-                self._split = self._count_split(svals)
-            args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
-            args["sharded"], args["sharded_bytes"] = self._split
-            lrs, wds, ts, rescale = self._slot_scalars(slots)
-        run = {"mesh": str(dict(mesh.shape))}
-        with _span("Step::program", run):
-            plan = ex._plan(True)
-            keys = ex._keys(plan)
-            ex._last_keys = keys
-            ogs = ex._ograds_for(full_shapes)
-            update_fns = self._update_fns(ex, slots)
-            first_run = run["first_run"] = \
-                ex._step_key(mesh_sig) not in ex._jitted
-            fn = ex.step_program(
-                [s[0] for s in slots], update_fns, mesh_sig=mesh_sig,
-                param_shardings=pshardings, state_shardings=sshardings)
-            if first_run and _health.enabled:
-                with jax.set_mesh(mesh):
-                    _health.register_program(
-                        "mesh_step", fn, (pvals, svals, others, auxs, keys,
-                                          ogs, lrs, wds, ts, rescale),
-                        donated=True, env=ex._program_env(plan))
-        # traced under the mesh: an op that must keep a custom call on each
-        # device's own rows (MultiHeadAttention's flash kernel) finds it in
-        # ``jax.sharding.get_abstract_mesh()``
-        with _span("Step::launch", run), jax.set_mesh(mesh):
-            new_p, new_s, outs, new_aux = fn(
-                pvals, svals, others, auxs, keys, ogs, lrs, wds, ts, rescale)
-        if first_run and _health.enabled:
-            _health.audit_donation("mesh_step", (pvals, svals))
-        with _span("Step::writeback"):
-            for (name, slot, _, _, _), w, st in zip(slots, new_p, new_s):
-                pool.give(("w", name), ex.arg_dict[name], w)
-                for e in execs[1:]:
-                    e.arg_dict[name]._data = w
-                leaves = self._slot_leaves(ex, name, states[slot])
-                for j, (leaf, arr) in enumerate(zip(leaves, st)):
-                    pool.give(("s", slot, j), leaf, arr)
-            for n, v in zip(ex.aux_names, new_aux):
-                for e in execs:
-                    e.aux_dict[n]._data = v
-            self._mesh_outputs = [NDArray(o, ex._ctx) for o in outs]
-            del pvals, svals, new_p, new_s      # as in _step_single
-        self._meshed = True
-        return "mesh_fused"
 
     def _demesh(self):
         """Point every exec's handles back at per-device arrays (the mesh
@@ -846,7 +741,7 @@ class ModuleFusedStep:
         m = self._mod
         execs = self._eg.execs
         ndev = len(execs)
-        pool = self._pools[0]
+        pool = self._pool
         opt_ = m._optimizer
         states = m._updater.states if m._updater is not None else {}
         for i, name in enumerate(m._param_names):
@@ -947,7 +842,7 @@ class TrainerFusedUpdate:
                 opt_._update_count(i)
                 d = per_dev[k]
                 d["p"].append(w._data)
-                d["s"].append(tuple(self._pools[k].take((i, j), leaf)
+                d["s"].append(tuple(self._pools[k].take((i, j), leaf, None)
                                     for j, leaf in enumerate(leaves)))
                 d["g"].append([grads[k]._data])
                 d["lr"].append(opt_.fused_slot_lr(
@@ -1069,7 +964,7 @@ class TrainerMeshUpdate:
         return self._mesh or None
 
     def eligible(self):
-        if not enabled() or not mesh_enabled() or self._unsupported:
+        if not enabled() or self._unsupported:
             return False
         tr = self._tr
         if len(tr._contexts) <= 1 or tr._update_on_kvstore:

@@ -138,7 +138,7 @@ class Trainer:
     def step(self, batch_size, ignore_stale_grad=False):
         """Make one optimization step: allreduce grads then update.
 
-        On local multi-device with MXNET_TPU_MESH_STEP (default ON) the
+        On local multi-device (a local kvstore, distinct devices) the
         two phases fuse into ONE GSPMD program over a ``dp`` mesh — raw
         per-device gradients are adopted zero-copy as batch shards and XLA
         inserts the all-reduce — so the host-side kvstore push/pull never

@@ -7,8 +7,9 @@ names.  Two layers of API:
 * **Symbol graph** (``transformer_lm`` / ``transformer_block``): the
   training graph that binds through Module and runs the fused/mesh step
   end to end — Embedding, pre-norm blocks around the
-  ``MultiHeadAttention`` op (Pallas flash kernel behind
-  ``MXNET_TPU_FLASH_ATTENTION``), gelu FFN, streaming-CE loss.  The
+  ``MultiHeadAttention`` op (Pallas flash kernel where
+  ``ops.nn.mha_uses_kernel`` admits the shape), gelu FFN, streaming-CE
+  loss.  The
   fields of ``TransformerConfig`` after its first seven select the block
   variants of the one definition (RMSNorm, rotary positions, grouped
   key/value heads, the gated SiLU feed-forward, ``ShortConv`` layers by a

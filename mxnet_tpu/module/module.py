@@ -312,9 +312,7 @@ class Module(BaseModule):
         if _health.enabled:
             _health.monitor.on_step(
                 "mesh_step" if path == "mesh_fused" else
-                ("fwdbwd",) if path == "eager" else
-                ("step" if len(self._context) == 1
-                 else ("fwdbwd", "update")))
+                ("fwdbwd",) if path == "eager" else "step")
 
     def _update(self):
         """The body of ``update``; returns the path taken (``fused``,

@@ -126,116 +126,13 @@ def _stem_s2d_conv(attrs, data, weight):
 
 def _is_3x3_same_unit(attrs, data, nd):
     """Shared shape predicate: 2-D / 3x3 kernel / stride 1 / dilate 1 /
-    SAME pad / ungrouped — the class both GEMM formulations cover."""
+    SAME pad / ungrouped — the class the Pallas "s1" kernel covers."""
     k = attrs["kernel"]
     return (nd == 2 and tuple(k) == (3, 3)
             and tuple(attrs["stride"] or (1, 1)) == (1, 1)
             and tuple(attrs["dilate"] or (1, 1)) == (1, 1)
             and tuple(attrs["pad"] or (0, 0)) == (1, 1)
             and attrs["num_group"] == 1 and data.ndim == 4)
-
-
-def _nhwc_taps(data):
-    """Yield the nine SAME-padded NHWC tap views flattened to
-    (N*H*W, C) — the shared building block of both 9-GEMM forms."""
-    N, C, H, W = data.shape
-    xh = jnp.transpose(data, (0, 2, 3, 1))               # NHWC
-    xp = jnp.pad(xh, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    for dy in range(3):
-        for dx in range(3):
-            yield dy, dx, xp[:, dy:dy + H, dx:dx + W, :].reshape(
-                N * H * W, C)
-
-
-def _shifted_gemm_eligible(attrs, data, nd):
-    """3x3 / stride 1 / dilate 1 / SAME / ungrouped 2-D convs can run as
-    9 shifted GEMMs — measured STABLE at 175-191 TF on v5e in chained
-    blocks where the lax.conv lowering is bimodal across processes
-    (136 TF fast mode, ~21 TF slow mode; tools/probe_fused_block.py).
-    E2E-MEASURED AND REJECTED as a default: inside the full ResNet-50
-    training graph the same formulation collapses to 125 img/s (~18x
-    slower than lax.conv) — the chain win does not survive whole-graph
-    scheduling (docs/perf_analysis.md round-4 probe).  Kept behind
-    MXNET_TPU_CONV_SHIFTED_GEMM=1 as a probing tool.  The flag is read
-    at TRACE time and is part of Convolution's jit-cache key
-    (``env_keys`` in ops/registry.py), so toggling it takes effect on
-    the next call — no cache clearing or process restart needed."""
-    import os
-    if os.environ.get("MXNET_TPU_CONV_SHIFTED_GEMM", "0") != "1":
-        return False
-    return _is_3x3_same_unit(attrs, data, nd)
-
-
-def _shifted_gemm_conv(data, weight):
-    """NCHW 3x3 SAME conv as 9 shifted (NHW, C)x(C, O) GEMMs."""
-    N, C, H, W = data.shape
-    O = weight.shape[0]
-    acc = None
-    for dy, dx, tap in _nhwc_taps(data):
-        wk = weight[:, :, dy, dx].T                      # (C, O)
-        # f32 accumulation across the 9 taps (matches lax.conv's
-        # single f32 accumulate and the probe formulation — bf16
-        # partial rounding would change the numerics being compared)
-        part = jax.lax.dot_general(
-            tap, wk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc = part if acc is None else acc + part
-    return jnp.transpose(acc.reshape(N, H, W, O),
-                         (0, 3, 1, 2)).astype(data.dtype)
-
-
-def _gemm_wgrad_eligible(attrs, data, nd):
-    """3x3 / stride 1 / SAME / ungrouped convs at SMALL spatial dims get
-    a hand 9-GEMM weight-gradient formulation: tools/probe_wgrad.py
-    (round 5, v5e) measured XLA's chosen wgrad lowering at 90 TF (14px)
-    and 61 TF (7px) while the per-tap GEMM form hits 178/128 TF — ~2x —
-    with XLA winning at 56/28px (259/307 TF), hence the H<=16 gate.
-    Forward and dgrad stay on lax.conv; only the VJP's dw changes.
-    E2e-measured OFF-worthy (2,445 vs 2,497 img/s — see
-    docs/perf_analysis.md round 5); enable with MXNET_TPU_GEMM_WGRAD=1.
-    Like MXNET_TPU_CONV_SHIFTED_GEMM, the flag is read at TRACE time and
-    is part of Convolution's jit-cache key (``env_keys`` in
-    ops/registry.py), so toggling it takes effect on the next call."""
-    import os
-    if os.environ.get("MXNET_TPU_GEMM_WGRAD", "0") != "1":
-        return False
-    return (_is_3x3_same_unit(attrs, data, nd)
-            and data.shape[2] <= 16 and data.shape[3] <= 16)
-
-
-@jax.custom_vjp
-def _conv3x3_same_gemm_wgrad(data, weight):
-    """3x3 SAME conv whose VJP computes dw as 9 per-tap GEMMs (dgrad
-    stays the standard transposed conv)."""
-    return jax.lax.conv_general_dilated(
-        data, weight, (1, 1), [(1, 1), (1, 1)],
-        dimension_numbers=_conv_dnums(2))
-
-
-def _c3g_fwd(data, weight):
-    return _conv3x3_same_gemm_wgrad(data, weight), (data, weight)
-
-
-def _c3g_bwd(res, g):
-    data, weight = res
-    N, C, H, W = data.shape
-    O = weight.shape[0]
-    # dgrad: conv of g with the spatially-flipped, io-swapped kernel
-    wT = jnp.flip(weight.transpose(1, 0, 2, 3), axis=(2, 3))
-    dx = jax.lax.conv_general_dilated(
-        g, wT.astype(g.dtype), (1, 1), [(1, 1), (1, 1)],
-        dimension_numbers=_conv_dnums(2)).astype(data.dtype)
-    # wgrad: dw[o,c,dy,dx] = sum_nhw x_pad[n,c,h+dy,w+dx] g[n,o,h,w] —
-    # one (NHW,C)x(NHW,O) GEMM per tap, f32 accumulation
-    g2 = jnp.transpose(g, (0, 2, 3, 1)).reshape(N * H * W, O)
-    taps = [jax.lax.dot_general(tap, g2, (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-            for _, _, tap in _nhwc_taps(data)]           # each (C, O)
-    dw = jnp.stack(taps).reshape(3, 3, C, O).transpose(3, 2, 0, 1)
-    return dx, dw.astype(weight.dtype)
-
-
-_conv3x3_same_gemm_wgrad.defvjp(_c3g_fwd, _c3g_bwd)
 
 
 def _pallas_conv_mode(attrs, data, nd):
@@ -272,8 +169,7 @@ def _pallas_conv_mode(attrs, data, nd):
 
 @register("Convolution", nin=-1, aliases=("convolution", "Convolution_v1"),
           params=dict(_CONV_PARAMS),
-          env_keys=("MXNET_TPU_PALLAS_CONV", "MXNET_TPU_CONV_SHIFTED_GEMM",
-                    "MXNET_TPU_GEMM_WGRAD", "MXNET_TPU_STEM_S2D"))
+          env_keys=("MXNET_TPU_PALLAS_CONV", "MXNET_TPU_STEM_S2D"))
 def _convolution(attrs, data, weight, *maybe_bias):
     """N-D convolution on the MXU (ref: src/operator/nn/convolution.cc)."""
     k = attrs["kernel"]
@@ -293,12 +189,6 @@ def _convolution(attrs, data, weight, *maybe_bias):
         else:
             path = "pallas_s2"
             out = pallas_conv.conv3x3_s2(data, weight)
-    elif _shifted_gemm_eligible(attrs, data, nd):
-        path = "shifted_gemm"
-        out = _shifted_gemm_conv(data, weight)
-    elif _gemm_wgrad_eligible(attrs, data, nd):
-        path = "gemm_wgrad"
-        out = _conv3x3_same_gemm_wgrad(data, weight)
     else:
         path = "lax"
         out = jax.lax.conv_general_dilated(
@@ -692,8 +582,7 @@ def mha_uses_kernel(B, H, T, d, dtype):
                   "num_kv_heads": param(int, 0),
                   "qk_norm": param(bool, False),
                   "rope_theta": param(float, 0.0),
-                  "eps": param(float, 1e-5)},
-          env_keys=("MXNET_TPU_FLASH_ATTENTION", "MXNET_TPU_PALLAS_ATTN"))
+                  "eps": param(float, 1e-5)})
 def _multi_head_attention(attrs, data, query_weight, key_weight,
                           value_weight, out_proj_weight, *qk_gammas):
     """Decoder attention: QKV projections, scaled-dot-product over
@@ -714,21 +603,17 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
     the normalisation.  All three happen in front of the arm's choice: the
     flash kernels and the XLA arm see normalised, rotated, repeated heads.
 
-    Dispatch: ``MXNET_TPU_FLASH_ATTENTION`` (default on) selects the
-    Pallas flash kernel (ops/pallas_attention.py) wherever
+    Dispatch: the Pallas flash kernel (ops/pallas_attention.py) wherever
     ``mha_uses_kernel`` says the kernel beats the XLA arm at this shape;
     otherwise the XLA reference runs.  The kernel's calls follow the
     sharding of the batch and head dimensions (one sequence a chip under
     the mesh fused step's ``P('dp')``).
-    Both env gates are declared in ``env_keys`` so flipping either
-    re-specializes every cached program containing this op (GL001).
 
     Weight names are chosen so ``parallel.mesh.megatron_rules`` shards
     them with zero extra configuration: query/key/value_weight match the
     column-parallel rule (P(t, None)), out_proj_weight the row-parallel
     rule (P(None, t)).
     """
-    import os
     from . import pallas_attention as pa
     if data.ndim != 3:
         raise MXNetError(
@@ -770,10 +655,7 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
     if Hkv != H:
         k, v = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
 
-    use_flash = os.environ.get("MXNET_TPU_FLASH_ATTENTION", "1") != "0" \
-        and pa.enabled() \
-        and mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype)
-    if use_flash:
+    if mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype):
         # test hook (pa.INTERPRET): force the interpreter on CPU
         path = "flash_interpret" if pa.INTERPRET else "flash"
         out = _kernel_or_reference(q, k, v, causal, scale, pa.INTERPRET)

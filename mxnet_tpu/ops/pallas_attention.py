@@ -39,8 +39,8 @@ every CPU run keep the XLA arm.  Under a mesh in context
 ``shard_map`` over the mesh's ``dp`` (batch) and ``tp`` (heads) axes, each
 device on its own rows (``_on_own_rows``).  ``parallel/ring_attention``
 (``blockwise_attention``, the per-shard ``flash_attention_stats`` /
-``flash_attention_bwd``), when ``MXNET_TPU_PALLAS_ATTN`` != "0" and
-``flash_attention_available`` admits the shard: Tk >= 2048 against the
+``flash_attention_bwd``), when ``flash_attention_available`` admits the
+shard: Tk >= 2048 against the
 scan, K/V within the VMEM envelope.  Reference analog: none (the 2018
 reference predates flash attention); ref for the surrounding design:
 SURVEY.md §5.7.
@@ -49,7 +49,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -63,20 +62,13 @@ __all__ = ["flash_attention", "flash_attention_available",
 INTERPRET = False
 
 
-def enabled() -> bool:
-    """``MXNET_TPU_PALLAS_ATTN`` != "0": the kernels may be dispatched."""
-    return os.environ.get("MXNET_TPU_PALLAS_ATTN", "1") != "0"
-
-
 def flash_attention_available(B, H, Tq, Tk, D, dtype=None) -> bool:
-    """SIZE/ENV eligibility only — would the kernel compile on a TPU.
+    """SIZE eligibility only — would the kernel compile on a TPU.
 
     No platform check here: callers resolve TPU-vs-other at LOWERING time
     via ``jax.lax.platform_dependent`` (parallel/ring_attention.py), so
     CPU-committed arrays on a TPU host lower the scan formulation instead
     of Mosaic (advisor r03)."""
-    if not enabled():
-        return False
     if D % 8 or Tq % 8 or Tk % 128:
         return False
     if not INTERPRET and Tk < 2048:
@@ -611,7 +603,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
 # (``jax.experimental.custom_partitioning`` would say it in one rule; libtpu
 # has no emitter for it: "Custom emitter for CustomSPMDPartitioning not
 # found".)  So where the program is traced under a mesh (``jax.set_mesh``, as
-# ``ModuleFusedStep._step_mesh`` does), the three calls of the op's arm run
+# ``ModuleFusedStep.step`` does on a mesh), the three calls of the op's arm run
 # under ``shard_map``: batch rows follow the mesh's ``dp`` axis and heads its
 # ``tp`` axis (``parallel.mesh``'s names), T and D stay whole, and each device
 # runs the kernel on its own rows.  Without a mesh, on one device, or inside

@@ -16,15 +16,10 @@ This module provides:
   models with many heads: seq-gather/head-scatter costs one all_to_all each
   way instead of (n-1) ring hops).
 
-Trace-time env gate: these entry points consult
-``ops.pallas_attention.flash_attention_available`` (the
-``MXNET_TPU_PALLAS_ATTN`` kernel gate) when deciding the per-shard
-formulation, so the decision is baked into whatever program the caller
-traces them into.  The declared cache-key contract covering that read:
-``Executor.STEP_ENV_KEYS`` re-specializes every cached step program when
-the gate flips, and the ``MultiHeadAttention`` op declares the same keys
-in its ``env_keys`` for plan-level programs.  Callers jitting these
-functions directly own their own cache and must key it likewise.
+The per-shard formulation is chosen at trace time by
+``ops.pallas_attention.flash_attention_available``, a test of the shard's
+shape: the choice is baked into whatever program the caller traces these
+entry points into.
 """
 from __future__ import annotations
 

@@ -74,7 +74,7 @@ def _env_snapshot() -> Dict[str, str]:
     except Exception:
         # executor may not be importable yet (ledger enabled during
         # package init); fall back to the known cache-key flags.
-        keys = ("MXNET_TPU_FUSED_STEP", "MXNET_TPU_MESH_STEP")
+        keys = ("MXNET_TPU_FUSED_STEP",)
     # program-cache location/size join for the same reason: a warm deploy
     # and a cold one differ ONLY in these (plus the artifacts on disk)
     keys = keys + ("MXNET_PROGRAM_CACHE_DIR", "MXNET_PROGRAM_CACHE_MAX_BYTES")

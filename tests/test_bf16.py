@@ -231,11 +231,9 @@ def _batch(i, batch=8):
 
 
 def _run_bf16(monkeypatch, fused_flag, opt_name, opt_kwargs, steps=4,
-              ctxs=None, mesh=None):
+              ctxs=None):
     monkeypatch.setenv(amp.ENV_FLAG, "1")
     monkeypatch.setenv(fused.ENV_FLAG, fused_flag)
-    if mesh is not None:
-        monkeypatch.setenv(fused.MESH_ENV_FLAG, mesh)
     mod = _build_module(ctxs=ctxs)
     ex0 = mod._exec_group.execs[0]
     assert ex0.arg_dict["fc1_weight"].dtype == BF16
@@ -278,8 +276,8 @@ class TestModuleParity:
     def test_mesh_step_bf16(self, monkeypatch):
         ctxs = [mx.cpu(0), mx.cpu(1)]
         kwargs = {"learning_rate": 0.05, "momentum": 0.9}
-        f = _run_bf16(monkeypatch, "1", "sgd", kwargs, ctxs=ctxs, mesh="1")
-        e = _run_bf16(monkeypatch, "1", "sgd", kwargs, ctxs=ctxs, mesh="0")
+        f = _run_bf16(monkeypatch, "1", "sgd", kwargs, ctxs=ctxs)
+        e = _run_bf16(monkeypatch, "0", "sgd", kwargs, ctxs=ctxs)
         for k in e[0]:
             np.testing.assert_allclose(
                 f[0][k].asnumpy().astype(np.float32),

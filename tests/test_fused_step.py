@@ -335,23 +335,31 @@ def assert_one_timeline(recs, path):
 
 
 class TestStepTimeline:
-    def _module(self, monkeypatch):
+    def _module(self, monkeypatch, ctxs=None):
         monkeypatch.setenv(fused.ENV_FLAG, "1")
-        mod = _build_module()
+        mod = _build_module(ctxs=ctxs)
         mod.init_optimizer(
             optimizer="sgd",
             optimizer_params={"learning_rate": 0.05, "momentum": 0.9})
         return mod
 
-    def test_each_span_once_in_order(self, monkeypatch):
-        mod = self._module(monkeypatch)
+    @pytest.mark.parametrize(
+        "ctxs,path", [(None, "fused"),
+                      ([mx.cpu(i) for i in range(4)], "mesh_fused")],
+        ids=["one_device", "mesh"])
+    def test_each_span_once_in_order(self, monkeypatch, ctxs, path):
+        """The one step body leaves the same spans, once each and in the
+        same order, on one device and on a mesh."""
+        mod = self._module(monkeypatch, ctxs)
         first = assert_one_timeline(
-            timeline_of_one_step(mod, _batch(0)), "fused")
+            timeline_of_one_step(mod, _batch(0)), path)
         assert first["Step::launch"].args["first_run"] is True
+        assert ("mesh" in first["Step::launch"].args) == (ctxs is not None)
         second = assert_one_timeline(
-            timeline_of_one_step(mod, _batch(1)), "fused")
+            timeline_of_one_step(mod, _batch(1)), path)
         assert second["Step::launch"].args["first_run"] is False
         assert second["Step::update"].args["step"] == 2
+        assert second["Step::gather"].args["copies"] == 0
 
     def test_donation_copies_first_step_and_after_set_params(
             self, monkeypatch):

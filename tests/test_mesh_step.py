@@ -1,4 +1,4 @@
-"""Mesh-native GSPMD fused training step (MXNET_TPU_MESH_STEP).
+"""Mesh-native GSPMD fused training step (N contexts under Module).
 
 Parity contract: the mesh-fused global program — batch sharded ``P('dp')``,
 params/opt-state placed per NamedSharding, all donated — must produce the
@@ -14,8 +14,8 @@ get allclose instead.
 Plus the mechanics: donation genuinely frees the previous mesh buffers,
 the mesh signature participates in the step-program jit-cache key, DP×TP
 ``ShardingRules`` actually shard the parameter handles, the telemetry
-counter says ``mesh_fused``, and the flag-off / mesh→eager interop paths
-fall back seamlessly.
+counter says ``mesh_fused``, N contexts that cannot host a mesh take the
+eager oracle, and the mesh→eager interop paths fall back seamlessly.
 """
 import numpy as np
 import pytest
@@ -72,10 +72,9 @@ def _collect(mod):
     return args, states
 
 
-def _run(monkeypatch, ctxs, opt_name, okw, steps, mesh_flag="1",
+def _run(monkeypatch, ctxs, opt_name, okw, steps, fused_flag="1",
          batch=8, feat=4, out=2, mesh_axes=None, rules_fn=None):
-    monkeypatch.setenv(fused.ENV_FLAG, "1")
-    monkeypatch.setenv(fused.MESH_ENV_FLAG, mesh_flag)
+    monkeypatch.setenv(fused.ENV_FLAG, fused_flag)
     mod = _build_module(ctxs, batch=batch, feat=feat, out=out)
     if mesh_axes is not None:
         rules = rules_fn(mod) if rules_fn is not None else None
@@ -190,27 +189,41 @@ class TestMeshMechanics:
         assert old_s.is_deleted()
         assert np.isfinite(ex.arg_dict["fc1_weight"].asnumpy()).all()
 
-    def test_flag_off_falls_back_to_fused(self, monkeypatch):
+    @pytest.mark.parametrize(
+        "ctxs,sizes", [(CTX8[:3], [3, 3, 2]),
+                       ([mx.cpu(0), mx.cpu(0)], [4, 4])],
+        ids=["ragged_slices", "duplicate_devices"])
+    def test_no_mesh_over_the_contexts_takes_the_eager_oracle(
+            self, monkeypatch, ctxs, sizes):
+        """N contexts under a local kvstore that cannot host a mesh (8
+        rows in ragged slices; one device bound twice): every step is
+        dispatched ``eager``, and the Module ends where it ends under
+        ``MXNET_TPU_FUSED_STEP=0``, bit for bit."""
+        okw = {"learning_rate": 0.25, "momentum": 0.5}
         telemetry.enable()
         try:
-            mesh0 = telemetry.value("step_dispatch_total", path="mesh_fused")
-            fused0 = telemetry.value("step_dispatch_total", path="fused")
-            _run(monkeypatch, CTX8, "sgd", {"learning_rate": 0.25},
-                 steps=2, mesh_flag="0")
-            assert telemetry.value("step_dispatch_total",
-                                   path="mesh_fused") == mesh0
-            assert telemetry.value("step_dispatch_total",
-                                   path="fused") == fused0 + 2
+            before = {p: telemetry.value("step_dispatch_total", path=p)
+                      for p in ("mesh_fused", "fused", "eager")}
+            mod = _run(monkeypatch, ctxs, "sgd", okw, steps=2)
+            after = {p: telemetry.value("step_dispatch_total", path=p)
+                     for p in before}
         finally:
             telemetry.disable()
+        assert [s.stop - s.start for s in mod._exec_group.slices] == sizes
+        assert not mod._fused_step.eligible()
+        assert after == dict(before, eager=before["eager"] + 2)
+        oracle = _run(monkeypatch, ctxs, "sgd", okw, steps=2,
+                      fused_flag="0")
+        _assert_bitexact(mod, oracle)
 
     def test_mesh_then_eager_interop_bitexact(self, monkeypatch):
-        """One mesh step, then (flag flipped off) one per-device step: the
-        de-mesh restores per-device layout exactly — the combined
-        trajectory matches two single-device fused steps bit-for-bit."""
+        """One mesh step, then (the fused step switched off) one eager
+        per-device step: the de-mesh restores per-device layout exactly —
+        the combined trajectory matches two single-device fused steps
+        bit-for-bit."""
         mod8 = _run(monkeypatch, CTX8, "sgd",
                     {"learning_rate": 0.25, "momentum": 0.5}, steps=1)
-        monkeypatch.setenv(fused.MESH_ENV_FLAG, "0")
+        monkeypatch.setenv(fused.ENV_FLAG, "0")
         rs = np.random.RandomState(7)
         rs.randint(0, 2, (8, 4)), rs.randint(-1, 2, (8, 2))  # step-1 draws
         x = rs.randint(0, 2, (8, 4)).astype(np.float32)
@@ -284,7 +297,6 @@ class TestTrainerMesh:
         from mxnet_tpu import autograd, gluon
         from mxnet_tpu.gluon import nn
         monkeypatch.setenv(fused.ENV_FLAG, "1")
-        monkeypatch.setenv(fused.MESH_ENV_FLAG, "1")
         mx.random.seed(3)
         np.random.seed(3)
         net = nn.Sequential()
@@ -571,10 +583,11 @@ class TestStateSplitOverDp:
 
     def test_demesh_then_eager_step_bitexact(self, monkeypatch,
                                              split_small):
-        """Two steps on split state, then (flag off) one per-device step:
-        ``_demesh`` makes whole per-device copies again."""
+        """Two steps on split state, then (the fused step switched off)
+        one eager per-device step: ``_demesh`` makes whole per-device
+        copies again."""
         mod4 = _run(monkeypatch, CTX4, *SGD_MOM, 2)
-        monkeypatch.setenv(fused.MESH_ENV_FLAG, "0")
+        monkeypatch.setenv(fused.ENV_FLAG, "0")
         rs = np.random.RandomState(7)
         for _ in range(2):          # the draws of steps 1 and 2
             rs.randint(0, 2, (8, 4)), rs.randint(-1, 2, (8, 2))

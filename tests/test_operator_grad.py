@@ -150,41 +150,3 @@ def test_backward_golden_values():
     tu.check_symbolic_backward(
         sym.clip(sym.var("data"), a_min=-1.0, a_max=1.0), {"data": x},
         [og], {"data": (np.abs(x) <= 1.0).astype(np.float32)})
-
-
-def test_shifted_gemm_conv_matches_lax_conv(monkeypatch):
-    """MXNET_TPU_CONV_SHIFTED_GEMM=1 probing path (round-4 bottleneck
-    probe; default OFF — e2e-rejected, see ops/nn.py docstring): the 9
-    shifted-GEMM formulation must match lax.conv exactly, fwd + grad."""
-    import os
-    import numpy as np
-    from mxnet_tpu import nd, symbol as sym, test_utils as tu
-    from mxnet_tpu.ops.registry import OPS
-
-    r = np.random.RandomState(3)
-    x = r.randn(2, 5, 8, 8).astype(np.float32)
-    w = (r.randn(6, 5, 3, 3) * 0.2).astype(np.float32)
-
-    def run():
-        OPS["Convolution"]._jit_cache.clear()
-        return nd.Convolution(nd.array(x), nd.array(w), kernel=(3, 3),
-                              num_filter=6, pad=(1, 1),
-                              no_bias=True).asnumpy()
-
-    monkeypatch.setenv("MXNET_TPU_CONV_SHIFTED_GEMM", "0")
-    ref = run()
-    try:
-        monkeypatch.setenv("MXNET_TPU_CONV_SHIFTED_GEMM", "1")
-        got = run()
-        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
-
-        s = sym.Convolution(sym.var("x"), sym.var("w"), kernel=(3, 3),
-                            num_filter=4, pad=(1, 1), no_bias=True)
-        tu.check_numeric_gradient(
-            sym.sum(s), {"x": r.randn(2, 3, 5, 5) * 0.5,
-                         "w": r.randn(4, 3, 3, 3) * 0.3},
-            rtol=2e-2, atol=2e-2)
-    finally:
-        # executables traced with flag=1 must never leak into later tests
-        monkeypatch.setenv("MXNET_TPU_CONV_SHIFTED_GEMM", "0")
-        OPS["Convolution"]._jit_cache.clear()

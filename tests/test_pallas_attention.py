@@ -331,7 +331,7 @@ def test_mha_shape_test_is_the_measured_table(shape, kernel):
 def test_mha_op_keeps_each_device_on_its_own_rows():
     """ISSUE 26, tentpole step 4: under ``jax.jit`` with the batch sharded
     ``P('dp')`` over four (virtual) devices and the mesh in context, as
-    ``ModuleFusedStep._step_mesh`` traces its program, the op's kernel
+    ``ModuleFusedStep.step`` traces its mesh program, the op's kernel
     calls run under ``shard_map``: value and gradients equal the unsharded
     run, and the compiled module gathers nothing."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -200,38 +200,6 @@ def test_pipeline_transformer_matches_sequential():
 
 
 # ---------------------------------------------------------------------------
-# env-gated dispatch is part of the jit cache key
-# ---------------------------------------------------------------------------
-def test_flash_env_flip_retraces_not_stale(monkeypatch):
-    """MXNET_TPU_FLASH_ATTENTION is in the MultiHeadAttention op's
-    env_keys: flipping it between forwards on a LIVE executor must
-    re-trace (jit-cache miss) instead of replaying the stale variant —
-    the GL001/GL002 contract, pinned behaviorally."""
-    from mxnet_tpu import telemetry
-    from mxnet_tpu import health as _health
-    telemetry.enable()
-    monkeypatch.delenv("MXNET_TPU_FLASH_ATTENTION", raising=False)
-    B = 2
-    exe = _bind_block(B)
-    rng = np.random.RandomState(1)
-    for name in _SYM2FN:
-        exe.arg_dict[name][:] = (rng.standard_normal(
-            exe.arg_dict[name].shape).astype(np.float32) * 0.05)
-    exe.arg_dict["data"][:] = rng.standard_normal(
-        (B, CFG.seq_len, CFG.d_model)).astype(np.float32)
-
-    exe.forward(is_train=False)[0].asnumpy()
-    warm, _ = _health._compile_totals()
-    exe.forward(is_train=False)[0].asnumpy()   # same env: pure cache hit
-    hit, _ = _health._compile_totals()
-    assert hit == warm
-    monkeypatch.setenv("MXNET_TPU_FLASH_ATTENTION", "0")
-    exe.forward(is_train=False)[0].asnumpy()   # flipped env: must miss
-    flipped, _ = _health._compile_totals()
-    assert flipped > hit
-
-
-# ---------------------------------------------------------------------------
 # megatron sharding rules cover the model's parameter names
 # ---------------------------------------------------------------------------
 def test_megatron_rules_shard_transformer_names():

@@ -9,7 +9,7 @@ Round 3 prototyped the implicit-GEMM framing in this file and measured
 drives the LIBRARY kernels — the exact code the ``MXNET_TPU_PALLAS_CONV``
 dispatch runs — so probe numbers and production numbers cannot drift.
 
-Protocol (same as tools/probe_wgrad.py): windowed timing with a
+Protocol: windowed timing with a
 data-feedback chain — each jitted call folds a loss-dependent epsilon
 back into its input so neither XLA nor the runtime can overlap, reorder
 or dead-code the kernels; per-call time is the median of paired

@@ -349,8 +349,7 @@ def _norm_ledger(path: str) -> dict:
                 env = rec.get("env")
                 if isinstance(env, dict):
                     ctx.setdefault("step_env", {
-                        k: env[k] for k in
-                        ("MXNET_TPU_FUSED_STEP", "MXNET_TPU_MESH_STEP")
+                        k: env[k] for k in ("MXNET_TPU_FUSED_STEP",)
                         if k in env})
     if run_id:
         ctx["run_id"] = run_id
