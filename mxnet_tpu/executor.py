@@ -528,12 +528,20 @@ class Executor:
         shard a small bias), which would silently break the take/give
         donation chain on the next step.  ``state_shardings`` (aligned
         too; ``parallel.mesh.state_sharding``) is given where the opt-state
-        has a layout of its own, split over ``dp``: the gradient is
-        constrained to it BEFORE the update, so the partitioner
-        reduce-scatters the partial sums where they are made (and does not
-        all-reduce and slice), each replica updates its part of the leaf,
-        the state stays where it was taken and the new weight, pinned to
-        the param's sharding, is all-gathered.
+        has a layout of its own, split over ``dp``.  A param whose state
+        sharding is not its own is then HELD in the state's layout between
+        steps: it arrives, donated, as each replica's part, is all-gathered
+        to the param's sharding at the TOP of the program (under
+        ``GradSync``; a temporary of the step, never an output and never
+        an alias of an input, so forward runs beside the gathers and no
+        donated weight is copied), and the vjp is taken w.r.t. the gathered
+        value.  The gradient is constrained to the state's layout BEFORE
+        the update, so the partitioner reduce-scatters the partial sums
+        where they are made (and does not all-reduce and slice), each
+        replica updates its part of the leaf from the part of the weight it
+        holds, and the new weight and the state leave in the layout they
+        were taken in.  A leaf whose two shardings are the same object
+        (small, ``dp`` of 1, a spec that names ``dp``) is untouched.
         """
         key = self._step_key(mesh_sig)
         fn = self._jitted.get(key)
@@ -560,10 +568,21 @@ class Executor:
                     av, dict(zip(aux_names, auxs)), keys)
                 return outs, [new_aux[n] for n in aux_names]
 
-            (outs, new_aux), vjp = jax.vjp(lambda *g: pure(list(g)), *pvals)
+            pin = jax.lax.with_sharding_constraint
+            gathered = pvals
+            if state_shardings is not None:
+                # a weight held as the replica's part of it: gathered here,
+                # ahead of forward, where the products hide the exchange
+                with jax.named_scope(_atlas.GRAD_SYNC):
+                    gathered = [p if ssh is psh else pin(p, psh)
+                                for p, psh, ssh in zip(
+                                    pvals, param_shardings, state_shardings)]
+            (outs, new_aux), vjp = jax.vjp(
+                lambda *g: pure(list(g)), *gathered)
             grads = vjp((list(ograds), [jnp.zeros_like(a) for a in new_aux]))
             new_p, new_s = [], []
-            pin = jax.lax.with_sharding_constraint
+            # weights and state leave in the layout they were taken in
+            out_shardings = state_shardings or param_shardings
             for i, upd in enumerate(update_fns):
                 g = grads[i]
                 if state_shardings is not None:
@@ -573,17 +592,14 @@ class Executor:
                                lrs[i], wds[i], rescale, ts[i])
                     if state_shardings is not None:
                         # the update ends, cast to the weight's dtype
-                        # included, on the replica's own part: its fusion
-                        # keeps this scope, the gather below has its own
-                        w = pin(w, state_shardings[i])
-                if state_shardings is not None:
-                    with jax.named_scope(_atlas.GRAD_SYNC):
-                        w = pin(w, param_shardings[i])
-                elif param_shardings is not None:
-                    w = pin(w, param_shardings[i])
-                if param_shardings is not None:
-                    ssh = (state_shardings or param_shardings)[i]
-                    s = jax.tree_util.tree_map(lambda a: pin(a, ssh), s)
+                        # included, on the replica's own part, and the
+                        # weight stays there: its fusion keeps this scope
+                        w = pin(w, out_shardings[i])
+                if state_shardings is None and param_shardings is not None:
+                    w = pin(w, out_shardings[i])
+                if out_shardings is not None:
+                    osh = out_shardings[i]
+                    s = jax.tree_util.tree_map(lambda a: pin(a, osh), s)
                 new_p.append(w)
                 new_s.append(s)
             return new_p, new_s, outs, new_aux

@@ -417,8 +417,8 @@ class ModuleFusedStep:
         self.steps += 1
         place = self._placement()
         pshardings, sshardings, mesh_sig = self._mesh_layout()
-        leaf_psh = pshardings or itertools.repeat(None)
-        leaf_ssh = sshardings or leaf_psh
+        # a param and its state are taken in ONE layout: the state's
+        leaf_ssh = sshardings or pshardings or itertools.repeat(None)
         pool, take, pset = self._pool, place.take, self._pset
         states = m._updater.states
         mp_of, leaves_of = opt_.fused_mp, _opt.fused_state_leaves
@@ -451,8 +451,7 @@ class ModuleFusedStep:
         pvals, svals, taken = [], [], []    # taken: (handle, leaves, mp)
         with _gather_span(pool) as args:
             placed = pool.copies
-            for (name, slot, _, _, _), psh, ssh in zip(
-                    slots, leaf_psh, leaf_ssh):
+            for (name, slot, _, _, _), ssh in zip(slots, leaf_ssh):
                 handle = ex.arg_dict[name]
                 for e in rest:
                     # all execs' views of one param must agree: where one
@@ -460,7 +459,9 @@ class ModuleFusedStep:
                     if e.arg_dict[name]._data is not handle._data:
                         pool.disown(("w", name))
                         break
-                pvals.append(take(("w", name), handle, psh))
+                # the weight is held between steps in its state's layout
+                # (the program gathers it at its top): taken as given
+                pvals.append(take(("w", name), handle, ssh))
                 # mp slots: leaf 0 is the master-fp32 copy — same shape as
                 # the param, so it takes the state's layout like every
                 # moment
@@ -474,7 +475,8 @@ class ModuleFusedStep:
                 # set from outside), not on every step
                 self._split = self._count_split(svals)
             args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
-            args["sharded"], args["sharded_bytes"] = self._split
+            args["held_split"], args["sharded"], args["sharded_bytes"] = \
+                self._split
             # (lrs, wds, ts, rescale): four small host-to-device copies
             lrs = jnp.asarray([s[2] for s in slots], jnp.float32)
             wds = jnp.asarray([s[3] for s in slots], jnp.float32)
@@ -685,7 +687,10 @@ class ModuleFusedStep:
         what can be seen: the mesh, the rules' spec of the param and its
         shape; three Nones on one device.  The opt-state's layout is the
         param's, split further over ``dp``
-        (``parallel.mesh.state_sharding``).  Where no leaf is split (a
+        (``parallel.mesh.state_sharding``), and between steps the weight
+        is held in it too: ``step`` takes and gives both in the state's
+        sharding and the program gathers the weight to the param's at its
+        top.  Where no leaf is split (a
         mesh without a ``dp`` extent, small leaves only) the state
         shardings are None, the signature does not name them and the
         program is the one it was."""
@@ -716,22 +721,25 @@ class ModuleFusedStep:
         return self._layout
 
     def _count_split(self, svals):
-        """(count, global bytes) of the opt-state leaves in ``svals``
-        (``step``'s, aligned with the layout) that are split over ``dp``:
-        ``Step::gather``'s ``sharded`` / ``sharded_bytes`` and the
-        operators' gauge."""
+        """(params held split, their opt-state leaves, those leaves' global
+        bytes): the params of the layout whose state sharding is not their
+        own, so that between steps the weight and every state leaf in
+        ``svals`` (``step``'s, aligned with the layout) are split over
+        ``dp``.  ``Step::gather``'s ``held_split`` / ``sharded`` /
+        ``sharded_bytes`` and the operators' gauge."""
         psh, ssh, _ = self._mesh_layout()
-        split = [leaf for sv, p, s in zip(svals, psh or (), ssh or psh or ())
-                 if s is not p for leaf in sv]
-        nbytes = sum(a.nbytes for a in split)
+        split = [sv for sv, p, s in zip(svals, psh or (), ssh or psh or ())
+                 if s is not p]
+        nbytes = sum(leaf.nbytes for sv in split for leaf in sv)
         if _telemetry.enabled:
             OPT_STATE_SHARDED_BYTES.labels(
                 path=self._placement().path).set(nbytes)
-        return len(split), nbytes
+        return len(split), sum(len(sv) for sv in split), nbytes
 
     def _demesh(self):
         """Point every exec's handles back at per-device arrays (the mesh
-        globals are sliced/re-placed onto each context's device) and split
+        globals, a weight held split over ``dp`` among them, are assembled
+        whole onto each context's device) and split
         the aliased mesh opt-state into genuine per-device copies, so the
         eager per-device programs and the local-kvstore reduce can resume
         seamlessly after any number of mesh steps."""

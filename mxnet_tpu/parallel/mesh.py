@@ -67,17 +67,21 @@ def replicated_sharding(mesh: Mesh) -> NamedSharding:
 def state_sharding(param_sharding: NamedSharding, shape,
                    dp_axis: str = "dp") -> NamedSharding:
     """Where the optimizer's state of one parameter lives (its float32
-    master, moments, momentum): the parameter's own sharding, split
-    further over the mesh's data-parallel axis, so that each replica
-    updates ``1/dp`` of the leaf from a reduce-scattered gradient and the
-    new weight is all-gathered (Xu et al. 2020, cross-replica sharding of
-    the weight update).
+    master, moments, momentum) and, between steps, the parameter itself:
+    the parameter's own sharding, split further over the mesh's
+    data-parallel axis, so that each replica updates ``1/dp`` of the leaf
+    from a reduce-scattered gradient (Xu et al. 2020, cross-replica
+    sharding of the weight update).  The mesh step holds, donates and
+    gives back the weight in this layout and all-gathers it to the
+    parameter's sharding at the top of its program, beside forward
+    (``Executor.step_program``).
 
     The split goes along the largest axis that the parameter's spec leaves
     unsharded and whose extent divides by the ``dp`` size.  A leaf with no
     such axis, one under ``STATE_SHARD_MIN_ELEMENTS``, a spec that already
     names ``dp``, or a ``dp`` axis of size 1 keeps the parameter's
-    sharding and is updated on every replica.
+    sharding (the SAME object is returned: the step tells a split leaf by
+    that) and is updated on every replica.
     """
     mesh = param_sharding.mesh
     n = mesh.shape.get(dp_axis, 1)

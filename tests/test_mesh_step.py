@@ -508,20 +508,23 @@ class TestStateShardingRule:
 
 class TestStateSplitOverDp:
     def _assert_layout(self, mod, large, small, ndev=4):
+        """Between steps a split leaf's weight is held in its state's
+        layout (a part a device, as every state leaf), every other leaf's
+        in the parameter's own."""
         from jax.sharding import PartitionSpec as P
         ex = mod._exec_group.execs[0]
         leaves = _leaves_by_name(mod)
-        for name in large + small:
-            w = ex.arg_dict[name]._data
-            assert w.sharding.spec == P(), name      # the parameter's own
-            assert len(w.addressable_shards) == ndev
         for name in large:
-            for leaf in leaves[name]:
-                a = leaf._data
+            w = ex.arg_dict[name]._data
+            for a in [w] + [leaf._data for leaf in leaves[name]]:
                 assert "dp" in a.sharding.spec, (name, a.sharding.spec)
+                assert a.sharding == w.sharding, name
                 for s in a.addressable_shards:
                     assert s.data.size * ndev == a.size
         for name in small:
+            w = ex.arg_dict[name]._data
+            assert w.sharding.spec == P(), name      # the parameter's own
+            assert len(w.addressable_shards) == ndev
             for leaf in leaves[name]:
                 assert leaf._data.sharding.spec == P(), name
                 assert leaf._data.addressable_shards[0].data.size == \
@@ -705,6 +708,88 @@ class TestStateSplitOverDp:
                             ["fc1_bias", "fc2_bias"])
         _assert_bitexact(fresh, mod4)       # (get_params de-meshes)
 
+    @pytest.mark.parametrize("name,kwargs,bf16", [
+        SGD_MOM + (False,),
+        ("adam", {"learning_rate": 0.01, "multi_precision": True}, True),
+    ], ids=["sgd_mom", "adam_masters"])
+    def test_weight_is_held_in_its_states_layout(self, monkeypatch,
+                                                 split_small, name, kwargs,
+                                                 bf16):
+        """After two steps every split leaf's weight carries its state's
+        sharding, every other leaf its parameter's, and what the step took
+        is what the step before gave: the same array objects, nothing
+        copied."""
+        from mxnet_tpu import amp
+        if bf16:
+            monkeypatch.setenv(amp.ENV_FLAG, "1")
+        mod = _run(monkeypatch, CTX4, name, kwargs, 2)
+        self._assert_layout(mod, ["fc1_weight", "fc2_weight"],
+                            ["fc1_bias", "fc2_bias"])
+        fs, ex = mod._fused_step, mod._exec_group.execs[0]
+        psh, ssh, _ = fs._mesh_layout()
+        for n, p, s in zip(fs._pnames, psh, ssh):
+            w = ex.arg_dict[n]._data
+            assert w.sharding == s, n
+            assert (s is p) == (n.endswith("bias")), n
+            assert fs._pool._own[("w", n)] is w
+            for e in mod._exec_group.execs[1:]:     # the siblings' views
+                assert e.arg_dict[n]._data is w
+        copies = fs._pool.copies
+        rs = np.random.RandomState(3)
+        mod.forward_backward(_Batch(
+            rs.randint(0, 2, (8, 4)).astype(np.float32),
+            rs.randint(-1, 2, (8, 2)).astype(np.float32)))
+        mod.update()
+        assert fs._pool.copies == copies
+
+    @pytest.mark.parametrize("between", [
+        "get_params", "set_params", "save_checkpoint", "snapshot",
+        "demesh"])
+    def test_reads_and_writes_between_steps_keep_the_layout(
+            self, monkeypatch, split_small, tmp_path, between):
+        """What reads or writes the weights between two mesh steps sees
+        whole arrays, and the step after it takes every leaf in the layout
+        it had and lands on one device's numbers."""
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, 2)
+        want = {k: v.asnumpy() for k, v in
+                _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 2)
+                .get_params()[0].items()}
+        if between == "get_params":
+            got = mod.get_params()[0]
+        elif between == "set_params":
+            # a weight set from outside while the handles hold split
+            # globals: no de-mesh in between
+            ex = mod._exec_group.execs[0]
+            got = {k: mx.nd.array(np.asarray(ex.arg_dict[k]._data))
+                   for k in want}
+            assert mod._fused_step._meshed
+            mod.set_params(got, {})
+        elif between == "save_checkpoint":
+            prefix = str(tmp_path / "held")
+            mod.save_checkpoint(prefix, 2, save_optimizer_states=True)
+            got = mx.model.load_checkpoint(prefix, 2)[1]
+        elif between == "snapshot":
+            tree = mod._ft_snapshot(0, 2, 2)[0]
+            got = {k: mx.nd.array(tree["param/" + k]) for k in want}
+        else:
+            mod._fused_step.demesh()
+            got = {k: mod._exec_group.execs[3].arg_dict[k] for k in want}
+            for k, v in got.items():
+                assert v._data.devices() == {CTX4[3].jax_device}, k
+        for k in want:
+            assert got[k].shape == want[k].shape
+            np.testing.assert_array_equal(got[k].asnumpy(), want[k])
+        rs = np.random.RandomState(7)
+        for _ in range(2):          # the draws of steps 1 and 2
+            rs.randint(0, 2, (8, 4)), rs.randint(-1, 2, (8, 2))
+        mod.forward_backward(_Batch(
+            rs.randint(0, 2, (8, 4)).astype(np.float32),
+            rs.randint(-1, 2, (8, 2)).astype(np.float32)))
+        mod.update()
+        self._assert_layout(mod, ["fc1_weight", "fc2_weight"],
+                            ["fc1_bias", "fc2_bias"])
+        _assert_bitexact(mod, _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 3))
+
     def test_megatron_dp2_tp2_state_axis_is_not_the_rules(
             self, monkeypatch, split_small):
         from mxnet_tpu.parallel.mesh import make_mesh, megatron_rules
@@ -718,14 +803,19 @@ class TestStateSplitOverDp:
                    mesh_axes={"dp": 2, "tp": 2}, rules_fn=rules)
         ex = mod._exec_group.execs[0]
         leaves = _leaves_by_name(mod)
-        # column-parallel fc1 (tp on the rows): the state takes the columns
+        psh, ssh, _ = mod._fused_step._mesh_layout()
+        # column-parallel fc1 (tp on the rows): the state takes the columns,
+        # and the weight is held with it
+        assert psh[0].spec == P("tp", None)
         assert ex.arg_dict["fc1_weight"]._data.sharding.spec == \
-            P("tp", None)
+            P("tp", "dp")
         assert leaves["fc1_weight"][0]._data.sharding.spec == P("tp", "dp")
         # row-parallel fc2 (tp on the columns): the state takes the rows
+        assert psh[2].spec == P(None, "tp")
         assert ex.arg_dict["fc2_weight"]._data.sharding.spec == \
-            P(None, "tp")
+            P("dp", "tp")
         assert leaves["fc2_weight"][0]._data.sharding.spec == P("dp", "tp")
+        assert ex.arg_dict["fc1_bias"]._data.sharding == psh[1]
         for s in leaves["fc1_weight"][0]._data.addressable_shards:
             assert s.data.shape == (2, 2)       # a quarter each
         assert leaves["fc1_bias"][0]._data.sharding.spec == P()
@@ -754,6 +844,45 @@ class TestStateSplitOverDp:
                 ex.arg_dict[name]._data.sharding
         mod1 = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 2)
         _assert_bitexact(mod, mod1)
+
+    @pytest.mark.parametrize("ctxs,split,pins", [
+        ([mx.cpu(0)], False, 0),    # one device: no sharding is named
+        (CTX4, False, 4 + 4),       # replicated: new weights and momenta
+        # split: the two held matrices gathered at the top, every gradient
+        # to its state's layout, new weights and momenta as they came
+        (CTX4, True, 2 + 4 + 4 + 4),
+    ], ids=["one_device", "replicated", "split"])
+    def test_what_the_step_program_pins(self, monkeypatch, ctxs, split,
+                                        pins):
+        """Only a leaf whose state is split is gathered in the program (at
+        its top, under ``GradSync``); without one the traced body names the
+        shardings it named before the weights were held split, and on one
+        device none."""
+        import jax
+        from mxnet_tpu.executor import Executor
+        from mxnet_tpu.parallel import mesh as pmesh
+        if split:
+            monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+        real, seen = Executor.step_program, []
+
+        def step_program(self, *a, **k):
+            fn = real(self, *a, **k)
+
+            def call(*args):
+                seen.append(str(jax.make_jaxpr(fn)(*args)))
+                return fn(*args)
+            return call
+
+        monkeypatch.setattr(Executor, "step_program", step_program)
+        _run(monkeypatch, ctxs, *SGD_MOM, 1)
+        (text,) = seen
+        assert text.count("sharding_constraint[") == pins
+        if split:       # the two gathers: ahead of forward's first product
+            eqns = text.splitlines()
+            pinned = [i for i, ln in enumerate(eqns)
+                      if "sharding_constraint[" in ln]
+            assert pinned[1] < min(i for i, ln in enumerate(eqns)
+                                   if "dot_general" in ln)
 
     def test_layout_change_is_a_new_program(self, monkeypatch):
         """The signature carries the state's layout: the same mesh and
@@ -813,12 +942,13 @@ class TestStateSplitTimeline:
             # two matrices' momenta of 16 and 8 float32 elements
             assert g["leaves"] == 8 and g["copies"] == 8
             assert g["sharded"] == 2 and g["sharded_bytes"] == 4 * (16 + 8)
+            assert g["held_split"] == 2     # the two matrices themselves
             for seed in (2, 3):     # the donation chain finds them there
                 by = assert_one_timeline(self._step(mod, seed),
                                          "mesh_fused")
                 g = by["Step::gather"].args
                 assert g["copies"] == 0 and g["copy_bytes"] == 0
-                assert g["sharded"] == 2
+                assert g["sharded"] == 2 and g["held_split"] == 2
                 assert by["Step::launch"].args["first_run"] is False
             assert telemetry.value("opt_state_sharded_bytes",
                                    path="mesh_fused") == 96
@@ -844,17 +974,50 @@ class TestStateSplitTimeline:
         g = {r.name: r for r in self._step(mod, 4)}["Step::gather"].args
         assert len(counted) == 2 and g["copies"] > 0
         assert g["sharded"] == 2 and g["sharded_bytes"] == 4 * (16 + 8)
+        assert g["held_split"] == 2
 
     def test_replicated_layout_counts_none(self, monkeypatch):
         mod = _run(monkeypatch, CTX4, *SGD_MOM, steps=0)
         g = {r.name: r for r in self._step(mod, 1)}["Step::gather"].args
         assert g["sharded"] == 0 and g["sharded_bytes"] == 0
+        assert g["held_split"] == 0
 
+    @pytest.mark.parametrize("ctxs,axes,want", [
+        ([mx.cpu(0)], None, 0),
+        (CTX8[:2], {"dp": 1, "tp": 2}, 0),
+        (CTX4, {"dp": 2, "tp": 2}, 2),
+        (CTX4, None, 2),
+    ], ids=["one_device", "dp1_tp2", "dp2_tp2", "dp4"])
+    def test_held_split_counts_the_params_taken_in_their_states_layout(
+            self, monkeypatch, split_small, ctxs, axes, want):
+        """``Step::gather``'s ``held_split``: 0 on one device and on a mesh
+        whose ``dp`` is 1, the number of split params otherwise (a plain
+        SGD weight has no state leaf and is still held split), with
+        nothing copied from the second step on."""
+        from mxnet_tpu.parallel.mesh import make_mesh, megatron_rules
+
+        def rules(mod):
+            return megatron_rules(make_mesh(
+                axes, devices=[c.jax_device for c in ctxs]))
+
+        mod = _run(monkeypatch, ctxs, "sgd", {"learning_rate": 0.25},
+                   steps=0, mesh_axes=axes,
+                   rules_fn=rules if axes else None)
+        for seed in (1, 2, 3):
+            g = {r.name: r for r in self._step(mod, seed)}[
+                "Step::gather"].args
+            assert g["held_split"] == want and g["sharded"] == 0
+            assert (g["copies"] == 0) == (seed > 1)
+
+    @pytest.mark.parametrize("metric,count", [
+        ("state_sharded_leaves.train", 2),
+        ("weights_held_split.train", 2),
+    ])
     def test_the_benchmarks_metric_reads_it(self, monkeypatch,
-                                            split_small):
-        """``state_sharded_leaves.train`` as ``perf/`` reads it: the file's
-        reader and params over the program's own ring, two traced steps
-        of three."""
+                                            split_small, metric, count):
+        """``state_sharded_leaves.train`` and ``weights_held_split.train``
+        as ``perf/`` reads them: the file's reader and params over the
+        program's own ring, two traced steps of three."""
         import json
         import os
         from mxnet_tpu import tracing
@@ -862,13 +1025,13 @@ class TestStateSplitTimeline:
         from perf.reducers import program_span_ms
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         with open(os.path.join(root, "perf", "metrics",
-                               "state_sharded_leaves.train.json")) as f:
+                               metric + ".json")) as f:
             spec = json.load(f)
         assert spec["reducer"] == "program_span_ms"
         with open(os.path.join(root, "BENCHMARK.json")) as f:
             entry = [m for m in json.load(f)["per_layer"]
                      if m["name"] == spec["name"]]
-        assert entry == [{"name": "state_sharded_leaves.train",
+        assert entry == [{"name": metric,
                           "unit": "count", "better": "higher",
                           "source": "program_counter",
                           "layer": "collectives",
@@ -885,4 +1048,4 @@ class TestStateSplitTimeline:
                 mod.forward_backward(batch)
                 mod.update()
         ctx = {"spans": spans, "traced_steps": 2}
-        assert program_span_ms.read(ctx, spec["params"]) == 2 * 2
+        assert program_span_ms.read(ctx, spec["params"]) == 2 * count

@@ -232,8 +232,14 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
     transformer's mesh fused step (Adam, bf16 weights with float32
     masters, the batch ``P('dp')``) compiled for the four described chips
     with the optimizer's state in the layout ``state_sharding`` gives it.
-    Every large leaf's new bf16 weight is all-gathered (under ``GradSync``)
-    from the quarter a chip updated (under ``Optimizer::Adam``), its gradient reaches the update through a reduce-scatter
+    Every large leaf's bf16 weight arrives as the quarter a chip holds and
+    is all-gathered (under ``GradSync``) in the forward half of the
+    program, ahead of the product that reads it: none after the first
+    backward instruction, none after the last update fusion, and no
+    ``copy`` of a gathered weight anywhere (the gathered weight is a
+    temporary, never an alias of a donated input).  A chip updates its
+    quarter (under ``Optimizer::Adam``) and the new weight leaves as that
+    quarter; its gradient reaches the update through a reduce-scatter
     (the TPU compiler's ``all-reduce-scatter`` fusion; the token table's
     through an all-to-all of the rows' gradients), no all-reduce left in
     the program carries a large gradient, and nothing else is gathered:
@@ -280,7 +286,9 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
         pnames, [opt.fused_update_mp if m else opt.fused_update for m in mp],
         mesh_sig=("described",), param_shardings=[repl] * len(pnames),
         state_shardings=ssh)
-    pvals = [sds(s, ex.arg_dict[n].dtype) for n, s in zip(pnames, shapes)]
+    # a weight is taken as it is held: in its state's layout
+    pvals = [sds(s, ex.arg_dict[n].dtype, sh)
+             for n, s, sh in zip(pnames, shapes, ssh)]
     svals = [tuple(sds(s, jnp.float32, sh) for _ in range(3 if m else 2))
              for s, sh, m in zip(shapes, ssh, mp)]
     ids = sds((B, T), ex.arg_dict["data"].dtype, NamedSharding(mesh, P("dp")))
@@ -290,10 +298,14 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
                           "data": (B, T), "softmax_label": (B, T)})
     vec = sds((len(pnames),), jnp.float32)
     with jax.set_mesh(mesh):
-        hlo = fn.lower(
+        compiled = fn.lower(
             pvals, svals, [ids, ids], [], sds(keys.shape, keys.dtype),
             [sds(o.shape, o.dtype) for o in ogs], vec, vec, vec,
-            sds((), jnp.float32)).compile().as_text()
+            sds((), jnp.float32)).compile()
+    hlo = compiled.as_text()
+    # the new weights leave as they came: the held quarter of a large leaf
+    new_p = compiled.output_shardings[0]
+    assert [sh.spec for sh in new_p] == [sh.spec for sh in ssh]
     entry = hlo[hlo.index("\nENTRY "):].splitlines()
     result = re.compile(r"= \(?((?:\w+\[[\d,]*\][^ ]* ?)+)\)? ")
 
@@ -345,6 +357,39 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
                and results(ln)[0][0] == "bf16"]
     assert all("Optimizer::" not in scope(ln) and "GradSync" in scope(ln)
                for ln in gathers), [scope(ln) for ln in gathers]
+    # where the gathers sit in the schedule (the entry is printed in its
+    # order): a gathered weight is whole, in the entry, where a plain
+    # all-gather or the done-half of an asynchronous one (which runs
+    # beside the forward fusions it is threaded through) yields it
+    whole = {("bf16", tuple(s)) for s in large}
+    at = {"gather": [], "forward": [], "backward": [], "update": []}
+    for i, ln in enumerate(entry):
+        if "GradSync" in scope(ln) and " parameter(" not in ln and (
+                " all-gather(" in ln or "async-collective-done" in ln) \
+                and results(ln)[0] in whole:
+            at["gather"].append(i)
+        elif ln in updates:
+            at["update"].append(i)
+        elif re.search(r"transpose\(jvp\((FullyConnected|MultiHead)", ln):
+            at["backward"].append(i)
+        elif re.search(r"jvp\((FullyConnected|MultiHeadAttention)", ln):
+            at["forward"].append(i)
+    assert len(at["gather"]) == len(large), at["gather"]
+    assert max(at["gather"]) < min(at["backward"]) < min(at["update"])
+    assert max(at["gather"]) < max(at["forward"])
+    # every weight the forward products read is a gathered one
+    names = {re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = ", entry[i]).group(1)
+             for i in at["gather"]}
+    read = {n for i in at["forward"] for n in names
+            if re.search(re.escape(n) + r"[,)]", entry[i])}
+    assert len(read) == len(large) - 1, (len(read), len(large))  # - the table
+    # the parent copied every donated weight at entry and every gathered
+    # one at the end (at this size the compiler may still move a gathered
+    # table into fast memory ahead of forward: not that)
+    copies = [ln for i, ln in enumerate(entry)
+              if re.search(r" copy\(", ln) and results(ln)[0] in whole
+              and (" copy(%param" in ln or i > min(at["update"]))]
+    assert not copies, copies
     # what is still all-reduced whole is small: biases, norms, the loss
     reduced = [r for ln in entry if " all-reduce(" in ln
                for r in results(ln)]
