@@ -703,7 +703,10 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
     int num_kv_heads = 0,
     bool qk_norm = false,
     double rope_theta = 0.0,
-    double eps = 1e-05) {
+    double eps = 1e-05,
+    int head_dim = 0,
+    int window = 0,
+    const std::string &rope_yarn = "()") {
   SymbolBuilder builder_("MultiHeadAttention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
@@ -711,6 +714,9 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
   builder_.SetParam("qk_norm", qk_norm ? 1 : 0);
   builder_.SetParam("rope_theta", rope_theta);
   builder_.SetParam("eps", eps);
+  builder_.SetParam("head_dim", head_dim);
+  builder_.SetParam("window", window);
+  builder_.SetParam("rope_yarn", rope_yarn);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);
@@ -1124,13 +1130,15 @@ inline Symbol SparseMoE(const std::string &symbol_name,
     int num_experts_per_tok,
     int num_hidden,
     int num_held = 0,
-    int expert_offset = 0) {
+    int expert_offset = 0,
+    const std::string &score = "sigmoid") {
   SymbolBuilder builder_("SparseMoE");
   builder_.SetParam("num_experts", num_experts);
   builder_.SetParam("num_experts_per_tok", num_experts_per_tok);
   builder_.SetParam("num_hidden", num_hidden);
   builder_.SetParam("num_held", num_held);
   builder_.SetParam("expert_offset", expert_offset);
+  builder_.SetParam("score", score);
   builder_.SetInput("data", data);
   builder_.SetInput("router_weight", router_weight);
   builder_.SetInput("expert_bias", expert_bias);
@@ -4239,7 +4247,10 @@ inline Symbol multiheadattention(const std::string &symbol_name,
     int num_kv_heads = 0,
     bool qk_norm = false,
     double rope_theta = 0.0,
-    double eps = 1e-05) {
+    double eps = 1e-05,
+    int head_dim = 0,
+    int window = 0,
+    const std::string &rope_yarn = "()") {
   SymbolBuilder builder_("multiheadattention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
@@ -4247,6 +4258,9 @@ inline Symbol multiheadattention(const std::string &symbol_name,
   builder_.SetParam("qk_norm", qk_norm ? 1 : 0);
   builder_.SetParam("rope_theta", rope_theta);
   builder_.SetParam("eps", eps);
+  builder_.SetParam("head_dim", head_dim);
+  builder_.SetParam("window", window);
+  builder_.SetParam("rope_yarn", rope_yarn);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);
@@ -5232,13 +5246,15 @@ inline Symbol sparsemoe(const std::string &symbol_name,
     int num_experts_per_tok,
     int num_hidden,
     int num_held = 0,
-    int expert_offset = 0) {
+    int expert_offset = 0,
+    const std::string &score = "sigmoid") {
   SymbolBuilder builder_("sparsemoe");
   builder_.SetParam("num_experts", num_experts);
   builder_.SetParam("num_experts_per_tok", num_experts_per_tok);
   builder_.SetParam("num_hidden", num_hidden);
   builder_.SetParam("num_held", num_held);
   builder_.SetParam("expert_offset", expert_offset);
+  builder_.SetParam("score", score);
   builder_.SetInput("data", data);
   builder_.SetInput("router_weight", router_weight);
   builder_.SetInput("expert_bias", expert_bias);

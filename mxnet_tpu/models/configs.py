@@ -21,12 +21,19 @@ class TransformerConfig:
     ``rope``), the feed-forward (``gelu``: fc1/gelu/down with
     biases; ``swiglu``: down(silu(gate) * up), no bias), grouped key/value
     heads and per-head query/key normalisation, a pattern of sequence
-    mixers per layer (``full_attention`` | ``conv``, the gated short
-    convolution; empty: attention everywhere), and sparse experts in place
+    mixers per layer (``full_attention`` | ``sliding_attention``, which sees
+    the last ``window`` positions | ``conv``, the gated short convolution;
+    empty: full attention everywhere), heads of a size of their own
+    (``head_dim``; 0: ``d_model / n_heads``), rotary parameters of a layer
+    kind's own (``rope``: triples ``(kind, theta, yarn)`` with ``yarn``
+    empty or ``(factor, original positions, beta_fast, beta_slow,
+    attention_factor)``; a kind it does not name turns by ``rope_theta``),
+    and sparse experts in place
     of the feed-forward from layer ``num_dense_layers`` on
     (``num_experts`` > 0): ``experts_held`` of them from ``expert_offset``
     live in this graph (0: all), each ``moe_d_ff`` wide, ``experts_per_tok``
-    a token."""
+    a token, scored by ``moe_score`` (``sigmoid`` under a selection bias |
+    ``softmax``)."""
     name: str
     vocab_size: int
     n_layers: int
@@ -50,14 +57,19 @@ class TransformerConfig:
     expert_offset: int = 0
     moe_d_ff: int = 0
     tie_head: bool = False
+    head_dim: int = 0
+    window: int = 0
+    rope: tuple = ()
+    moe_score: str = "sigmoid"
 
     def __post_init__(self):
-        if self.d_model % self.n_heads:
+        if not self.head_dim and self.d_model % self.n_heads:
             raise ValueError("d_model %d not divisible by n_heads %d"
                              % (self.d_model, self.n_heads))
         for field, known in (("norm", ("layer", "rms")),
                              ("position", ("learned", "rope")),
-                             ("ffn", ("gelu", "swiglu"))):
+                             ("ffn", ("gelu", "swiglu")),
+                             ("moe_score", ("sigmoid", "softmax"))):
             if getattr(self, field) not in known:
                 raise ValueError("%s %r is not one of %s"
                                  % (field, getattr(self, field), known))
@@ -65,13 +77,22 @@ class TransformerConfig:
             raise ValueError("layer_types names %d layers of %d"
                              % (len(self.layer_types), self.n_layers))
         for kind in self.layer_types:
-            if kind not in ("full_attention", "conv"):
-                raise ValueError("layer type %r is not full_attention or "
-                                 "conv" % (kind,))
+            if kind not in ("full_attention", "sliding_attention", "conv"):
+                raise ValueError("layer type %r is not full_attention, "
+                                 "sliding_attention or conv" % (kind,))
+        if "sliding_attention" in self.layer_types and self.window < 1:
+            raise ValueError("sliding_attention layers need a window")
+        for kind, theta, yarn in self.rope:
+            if not theta > 0 or len(yarn) not in (0, 5):
+                raise ValueError("rope of %r is not a theta and a yarn of "
+                                 "none or five: %r, %r" % (kind, theta, yarn))
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def rope_of(self, kind: str):
+        """(theta, yarn) the layers of ``kind`` turn their heads by."""
+        for name, theta, yarn in self.rope:
+            if name == kind:
+                return float(theta), tuple(yarn)
+        return self.rope_theta, ()
 
     def n_params(self) -> int:
         """Weight count of the matmul-bearing parameters of the GPT-2-style

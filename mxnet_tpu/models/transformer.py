@@ -14,8 +14,10 @@ names.  Two layers of API:
   variants of the one definition (RMSNorm, rotary positions, grouped
   key/value heads, the gated SiLU feed-forward, ``ShortConv`` layers by a
   layer pattern, ``SparseMoE`` experts after leading dense layers, a head
-  tied to the embedding): an LFM2-class hybrid is a config, not a second
-  model.
+  tied to the embedding; sliding-window layers among full ones with a rope
+  of each kind's own, heads of a size of their own, softmax-scored
+  experts): an LFM2-class hybrid or a Mellum-class mixture is a config,
+  not a second model.
   Parameter names are chosen so ``parallel.mesh.megatron_rules`` shards
   a DP×TP mesh with zero configuration: ``*_query/key/value_weight`` and
   ``*_fc1_weight`` column-parallel, ``*_out_proj_weight`` and
@@ -64,7 +66,9 @@ def _feed_forward(h, cfg: TransformerConfig, idx: int, n: str):
             h, num_experts=cfg.num_experts,
             num_experts_per_tok=cfg.experts_per_tok,
             num_hidden=cfg.moe_d_ff, num_held=cfg.experts_held,
-            expert_offset=cfg.expert_offset, name=n + "moe")
+            expert_offset=cfg.expert_offset, name=n + "moe",
+            **({} if cfg.moe_score == "sigmoid"
+               else {"score": cfg.moe_score}))
     if cfg.ffn == "swiglu":
         gate = sym.FullyConnected(h, num_hidden=cfg.d_ff, flatten=False,
                                   no_bias=True, name=n + "ffn_gate")
@@ -85,11 +89,15 @@ def _feed_forward(h, cfg: TransformerConfig, idx: int, n: str):
 def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str):
     """One pre-norm decoder block: x + Mix(Norm(x)); x + FFN(Norm(x)).
     ``Mix`` is attention, or the gated short convolution where
-    ``cfg.layer_types[idx]`` says ``conv``."""
+    ``cfg.layer_types[idx]`` says ``conv``.  A ``sliding_attention``
+    layer's node is ``<prefix>l<idx>_swa`` (a device trace's scopes then
+    tell the two kinds of attention apart) and its weights keep the
+    ``<prefix>l<idx>_attn_`` names of every attention layer."""
     from .. import symbol as sym
     n = "%sl%d_" % (prefix, idx)
     h = _norm(x, cfg, n + "ln1")
-    if cfg.layer_types and cfg.layer_types[idx] == "conv":
+    kind = cfg.layer_types[idx] if cfg.layer_types else "full_attention"
+    if kind == "conv":
         a = sym.ShortConv(h, kernel=cfg.conv_kernel, name=n + "conv")
         x = sym.elemwise_add(x, a, name=n + "conv_res")
     else:
@@ -99,9 +107,23 @@ def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str):
         if cfg.qk_norm:
             variants.update(qk_norm=True, eps=cfg.norm_eps)
         if cfg.position == "rope":
-            variants["rope_theta"] = cfg.rope_theta
+            variants["rope_theta"], yarn = cfg.rope_of(kind)
+            if yarn:
+                variants["rope_yarn"] = yarn
+        if cfg.head_dim:
+            variants["head_dim"] = cfg.head_dim
+        name = n + "attn"
+        if kind == "sliding_attention":
+            # the weights under the names every attention layer gives them
+            # (megatron_rules, checkpoints), the node under its own kind's
+            variants.update(window=cfg.window, **{
+                w: sym.Variable("%s_%s" % (name, w))
+                for w in ("query_weight", "key_weight", "value_weight",
+                          "out_proj_weight")
+                + (("q_norm_gamma", "k_norm_gamma") if cfg.qk_norm else ())})
+            name = n + "swa"
         a = sym.MultiHeadAttention(h, num_heads=cfg.n_heads, causal=True,
-                                   name=n + "attn", **variants)
+                                   name=name, **variants)
         x = sym.elemwise_add(x, a, name=n + "attn_res")
     h = _norm(x, cfg, n + "ln2")
     return sym.elemwise_add(x, _feed_forward(h, cfg, idx, n),

@@ -14,6 +14,8 @@ from __future__ import annotations
 
 from functools import partial
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -492,15 +494,46 @@ def _rms_norm(attrs, data, gamma):
     return _rms_norm_last(data, gamma, attrs["eps"])
 
 
-def _rotary(x, theta):
+def yarn_frequencies(d, theta, yarn):
+    """YaRN's ``d / 2`` rotary frequencies (Peng et al. 2023, as the public
+    configs state them) from ``yarn`` = (factor, original positions,
+    beta_fast, beta_slow): dimension ``i`` keeps ``theta^(-2i/d)`` below the
+    band ``[low, high]``, takes it divided by ``factor`` above, and a linear
+    blend between; the band's ends are the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over the original positions.
+    Static: the same at every length.  float64 numpy, worked at trace
+    time."""
+    factor, original, beta_fast, beta_slow = yarn[:4]
+    i = np.arange(d // 2, dtype=np.float64)
+    plain = theta ** (-2.0 * i / d)
+
+    def turns(b):
+        return d * math.log(original / (2 * math.pi * b)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), d - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def _rotary(x, theta, yarn=()):
     """Rotary positions on [B,H,T,d] in the rotate-half pairing (dimension
     i turns with dimension i + d/2 by ``t * theta^(-2i/d)``), computed in
-    float32 and stored in ``x``'s type."""
+    float32 and stored in ``x``'s type.  ``yarn`` (factor, original
+    positions, beta_fast, beta_slow, attention_factor): the frequencies are
+    ``yarn_frequencies`` and cos and sin are both multiplied by the
+    attention factor."""
     T, d = x.shape[-2:]
     half = d // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    if yarn:
+        inv = jnp.asarray(yarn_frequencies(d, theta, yarn), jnp.float32)
+    else:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)               # [T, d/2]
+    if yarn:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x32 = x.astype(jnp.float32)
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
@@ -511,18 +544,27 @@ _ATTN_DISPATCH = _telemetry.counter(
     "attention_dispatch_total",
     "MultiHeadAttention dispatch decisions by formulation path (trace-time)",
     ("path",))
+_ATTN_KV_BLOCKS = _telemetry.counter(
+    "attention_kv_blocks_total",
+    "Key blocks at or under the diagonal that the flash forward kernel's "
+    "loop bounds take in (visited) and a window lets them leave out "
+    "(skipped), a head, a compiled attention variant (trace-time)",
+    ("kind", "fate"))
 
 
-def _mha_reference(q, k, v, causal, scale):
+def _mha_reference(q, k, v, causal, scale, window=None):
     """XLA reference attention, [B,H,T,d].  Same math contract as the
     Pallas flash kernel: f32 score/softmax/accumulate regardless of the
-    input dtype, and the causal mask admits position j<=i exactly."""
+    input dtype, the causal mask admits position j<=i exactly, and a
+    ``window`` on top of it the ``window`` positions i - window < j <= i."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         keep = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            keep &= jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :] < window
         s = jnp.where(keep, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32),
@@ -530,8 +572,8 @@ def _mha_reference(q, k, v, causal, scale):
     return o.astype(q.dtype)
 
 
-@partial(jax.jit, static_argnums=(3, 4, 5))
-def _kernel_or_reference(q, k, v, causal, scale, interpret):
+@partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _kernel_or_reference(q, k, v, causal, scale, interpret, window=None):
     """The kernel arm of ``MultiHeadAttention``, [B,H,T,d].  The platform
     is resolved at LOWERING time (advisor r03): of the two branches the one
     that does not match the target is pruned.  Jitted on its own because
@@ -541,11 +583,13 @@ def _kernel_or_reference(q, k, v, causal, scale, interpret):
     GPT-2-medium's 24 layers otherwise)."""
     from . import pallas_attention as pa
     flash = partial(pa.flash_attention, causal=causal, scale=scale)
+    reference = partial(_mha_reference, causal=causal, scale=scale)
+    if window is not None:
+        flash, reference = (partial(f, window=window)
+                            for f in (flash, reference))
     if interpret:
         return flash(q, k, v)
-    return jax.lax.platform_dependent(
-        q, k, v, tpu=flash,
-        default=partial(_mha_reference, causal=causal, scale=scale))
+    return jax.lax.platform_dependent(q, k, v, tpu=flash, default=reference)
 
 
 def mha_uses_kernel(B, H, T, d, dtype):
@@ -582,7 +626,10 @@ def mha_uses_kernel(B, H, T, d, dtype):
                   "num_kv_heads": param(int, 0),
                   "qk_norm": param(bool, False),
                   "rope_theta": param(float, 0.0),
-                  "eps": param(float, 1e-5)})
+                  "eps": param(float, 1e-5),
+                  "head_dim": param(int, 0),
+                  "window": param(int, 0),
+                  "rope_yarn": param("floats", ())})
 def _multi_head_attention(attrs, data, query_weight, key_weight,
                           value_weight, out_proj_weight, *qk_gammas):
     """Decoder attention: QKV projections, scaled-dot-product over
@@ -602,6 +649,16 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
     turns queries and keys by rotary positions (rotate-half pairing) after
     the normalisation.  All three happen in front of the arm's choice: the
     flash kernels and the XLA arm see normalised, rotated, repeated heads.
+    ``head_dim`` > 0 gives the heads a size of their own, not
+    ``model_dim / num_heads``: ``query_weight`` is then
+    ``(num_heads * head_dim, model_dim)``, ``out_proj_weight``
+    ``(model_dim, num_heads * head_dim)``.  ``window`` > 0 is sliding-window
+    attention: position ``t`` sees the ``window`` positions
+    ``t - window < s <= t``, its own among them; the kernels skip the key
+    blocks no query of a block sees (``attention_kv_blocks_total``), the
+    XLA arm masks them.  ``rope_yarn`` = (factor, original positions,
+    beta_fast, beta_slow, attention_factor) turns the rotary frequencies
+    into YaRN's (``yarn_frequencies``; needs ``rope_theta``).
 
     Dispatch: the Pallas flash kernel (ops/pallas_attention.py) wherever
     ``mha_uses_kernel`` says the kernel beats the XLA arm at this shape;
@@ -621,7 +678,8 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
             "got %s" % (data.shape,))
     B, T, D = data.shape
     H = attrs["num_heads"]
-    if H <= 0 or D % H:
+    d = attrs.get("head_dim") or 0
+    if H <= 0 or (not d and D % H):
         raise MXNetError(
             "MultiHeadAttention: num_heads=%d must divide model_dim=%d"
             % (H, D))
@@ -637,9 +695,19 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
             "MultiHeadAttention: qk_norm takes q_norm_gamma and "
             "k_norm_gamma, and only qk_norm does (got %d extra inputs)"
             % len(qk_gammas))
-    d = D // H
+    d = d or D // H
     causal = attrs["causal"]
     scale = 1.0 / (d ** 0.5)
+    window, yarn = attrs.get("window") or None, attrs.get("rope_yarn") or ()
+    if window and not causal:
+        raise MXNetError("MultiHeadAttention: a window needs causal=True")
+    if yarn and (len(yarn) != 5 or not theta > 0):
+        raise MXNetError(
+            "MultiHeadAttention: rope_yarn is (factor, original positions, "
+            "beta_fast, beta_slow, attention_factor) on top of rope_theta, "
+            "got %r with rope_theta=%r" % (yarn, theta))
+    if window is not None and window >= T:
+        window = None           # every earlier position: plain causal
 
     def proj(w, heads):
         y = jnp.matmul(data, w.T)                     # [B,T,heads*d]
@@ -651,23 +719,32 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
         q = _rms_norm_last(q, qk_gammas[0], attrs.get("eps", 1e-5))
         k = _rms_norm_last(k, qk_gammas[1], attrs.get("eps", 1e-5))
     if theta > 0:
-        q, k = _rotary(q, theta), _rotary(k, theta)
+        q, k = _rotary(q, theta, yarn), _rotary(k, theta, yarn)
     if Hkv != H:
         k, v = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
 
     if mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype):
         # test hook (pa.INTERPRET): force the interpreter on CPU
-        path = "flash_interpret" if pa.INTERPRET else "flash"
-        out = _kernel_or_reference(q, k, v, causal, scale, pa.INTERPRET)
+        path = ("flash" if window is None else "flash_window") \
+            + ("_interpret" if pa.INTERPRET else "")
+        out = _kernel_or_reference(q, k, v, causal, scale, pa.INTERPRET,
+                                   window)
+        blocks = pa.kv_block_plan(T, T, causal, window)
     else:
-        out = _mha_reference(q, k, v, causal, scale)
-        path = "reference"
+        out = _mha_reference(q, k, v, causal, scale, window)
+        path, blocks = "reference", None
     if _telemetry.enabled:
         # one inc per compiled attention variant, not per step — the
         # dispatch is a trace-time choice, same contract as conv_dispatch
         # graftlint: disable=GL002 -- counts compiled variants, not calls
         _ATTN_DISPATCH.labels(path=path).inc()
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, D)  # [B,T,D]
+        if blocks is not None:
+            kind = "sliding_attention" if attrs.get("window") \
+                else "full_attention"
+            for fate, count in zip(("visited", "skipped"), blocks):
+                # graftlint: disable=GL002 -- counts compiled variants
+                _ATTN_KV_BLOCKS.labels(kind=kind, fate=fate).inc(count)
+    out = out.transpose(0, 2, 1, 3).reshape(B, T, H * d)  # [B,T,H*d]
     return jnp.matmul(out, out_proj_weight.T)
 
 
@@ -718,26 +795,39 @@ _MOE_DISPATCH = _telemetry.counter(
     "moe_dispatch_total",
     "SparseMoE dispatch decisions by formulation of the held experts' "
     "products (trace-time)", ("path",))
+_MOE_SCORE = _telemetry.counter(
+    "moe_score_total",
+    "SparseMoE routers by the scoring of their experts (trace-time)",
+    ("score",))
 
 
-@register("SparseMoE", nin=7, aliases=("sparsemoe",), nout=2, visible=1,
-          aux_writeback={1: 6},
+@register("SparseMoE", nin=-1, aliases=("sparsemoe",), nout=2, visible=1,
+          aux_writeback={1: -1},
           params={"num_experts": param(int, 0, required=True),
                   "num_experts_per_tok": param(int, 0, required=True),
                   "num_hidden": param(int, 0, required=True),
                   "num_held": param(int, 0),
-                  "expert_offset": param(int, 0)})
-def _sparse_moe(attrs, data, router_weight, expert_bias, expert_gate_weight,
-                expert_up_weight, expert_down_weight, expert_load):
-    """Sigmoid-routed sparse mixture of gated (SiLU) experts that is told
-    which experts it holds (the LFM2 / DeepSeek-V3 style of routing).
+                  "expert_offset": param(int, 0),
+                  "score": param(("sigmoid", "softmax"), "sigmoid")})
+def _sparse_moe(attrs, data, router_weight, *rest):
+    """Sparse mixture of gated (SiLU) experts that is told which experts it
+    holds, routed by sigmoid scores under a selection bias (the LFM2 /
+    DeepSeek-V3 style) or by softmax scores (``score``).
+
+    Inputs: ``data, router_weight, expert_bias, expert_gate_weight,
+    expert_up_weight, expert_down_weight, expert_load``; under
+    ``score="softmax"`` there is no ``expert_bias`` (six inputs: no leaf
+    for an optimizer or a checkpoint to carry).
 
     Routing runs over all ``num_experts``: ``s = sigmoid(x · W_gᵀ)`` in
     float32, ``sel = top_k(s + expert_bias)`` (the bias, a buffer, takes no
     gradient and only steers the selection), weights ``w_e = s_e`` for
     ``e`` in ``sel``, divided by ``sum_sel s + 1e-6`` (the source's
     ``norm_topk_prob`` with a ``routed_scaling_factor`` of 1: the one
-    weighting the op has).  The op HOLDS the
+    weighting the op has).  Under ``softmax``: ``s = softmax(x · W_gᵀ)``
+    over all experts in float32, ``sel = top_k(s)``, ``w_e = s_e / sum_sel
+    s`` (softmax, then top-k, then normalised over the selected).  The op
+    HOLDS the
     experts ``expert_offset .. expert_offset + held`` (``held`` is the
     leading axis of the three expert weights; ``num_held`` only tells shape
     inference how many to allocate, 0 meaning all) and returns
@@ -772,6 +862,15 @@ def _sparse_moe(attrs, data, router_weight, expert_bias, expert_gate_weight,
     selections each of the ``num_experts`` experts got in this step.  No
     reference analog; ``parallel/moe.py`` is the older functional layer
     (softmax gate, fixed capacity, drops tokens)."""
+    score = attrs.get("score", "sigmoid")
+    if len(rest) != 4 + (score == "sigmoid"):
+        raise MXNetError(
+            "SparseMoE: score=%r takes %d inputs (expert_bias only under "
+            "sigmoid), got %d" % (score, 6 + (score == "sigmoid"),
+                                  2 + len(rest)))
+    expert_bias = rest[0] if score == "sigmoid" else None
+    expert_gate_weight, expert_up_weight, expert_down_weight, expert_load = \
+        rest[-4:]
     E, k = attrs["num_experts"], attrs["num_experts_per_tok"]
     held, off = expert_gate_weight.shape[0], attrs["expert_offset"]
     if not 0 < k <= E or off < 0 or off + held > E:
@@ -785,11 +884,17 @@ def _sparse_moe(attrs, data, router_weight, expert_bias, expert_gate_weight,
     logits = jnp.matmul(x.astype(jnp.float32),
                         router_weight.astype(jnp.float32).T,
                         precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)                                   # [N, E]
-    biased = s + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
-    _, sel = jax.lax.top_k(biased, k)                            # [N, k]
-    w = jnp.take_along_axis(s, sel, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    if score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)                      # [N, E]
+        _, sel = jax.lax.top_k(s, k)                             # [N, k]
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    else:
+        s = jax.nn.sigmoid(logits)
+        biased = s + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+        _, sel = jax.lax.top_k(biased, k)
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
 
     # ---- the held experts over every token; a token's weight for a held
     # expert it did not select is nought (one_hot of an index >= held)
@@ -798,6 +903,8 @@ def _sparse_moe(attrs, data, router_weight, expert_bias, expert_gate_weight,
     if _telemetry.enabled:
         # graftlint: disable=GL002 -- counts compiled variants, not calls
         _MOE_DISPATCH.labels(path="dense").inc()
+        # graftlint: disable=GL002 -- counts compiled variants, not calls
+        _MOE_SCORE.labels(score=score).inc()
     # (the benchmark's roofline metric finds the products by these subscripts,
     # which jnp.einsum leaves in the events' scope)
     gate = jnp.einsum("nd,efd->nef", x, expert_gate_weight)

@@ -26,7 +26,14 @@ with ``ds = p * (dp - delta) * scale``, ``dp = dO v^T``.
 Causal: blocks past the diagonal are never visited, only blocks the
 diagonal crosses are masked, and in square blocks over a self-attention
 the diagonal block is computed in bands with static extents
-(``_bands``).  Without named blocks a sequence of up to 2,048 positions is
+(``_bands``).  A ``window`` W on top of it (position t sees the W positions
+t - W < s <= t: sliding-window attention) bounds every loop from the other
+side too: key blocks wholly below the window's lower edge are never visited
+(``_window_bounds``; ``_window_q_bounds`` for the query loop of dk/dv), only
+the blocks that edge crosses carry its mask, and a window that reaches
+position 0 from every query (W >= T) is plain causal, the same kernels
+(``kv_block_plan`` counts what the forward loop takes in and leaves out).
+Without named blocks a sequence of up to 2,048 positions is
 ONE block (``default_blocks``): at T 1024 / d 64 that is 0.146 ms forward +
 backward for 16 heads on the v5e against 0.266 in 512-blocks and 0.352 for
 the XLA arm (PERF.md, PR 26).
@@ -57,7 +64,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["flash_attention", "flash_attention_available",
-           "flash_attention_stats", "flash_attention_bwd"]
+           "flash_attention_stats", "flash_attention_bwd", "kv_block_plan"]
 
 INTERPRET = False
 
@@ -106,14 +113,44 @@ def _fold_scale(scale):
     return math.frexp(scale)[0] == 0.5
 
 
+def _min_max(x):
+    """(min, max) for bounds worked on python ints (``kv_block_plan``) or on
+    a program's traced block index."""
+    return (min, max) if isinstance(x, int) else (jnp.minimum, jnp.maximum)
+
+
 def _causal_bounds(row0, rows, cols, n_cols):
     """For the ``rows`` query positions from ``row0`` against key blocks
     of ``cols``: (blocks wholly at or under the diagonal, blocks the
     diagonal reaches).  [0, full) needs no mask, [full, end) is masked,
     [end, n_cols) is never computed."""
-    full = jnp.minimum((row0 + 1) // cols, n_cols)
-    end = jnp.minimum((row0 + rows + cols - 1) // cols, n_cols)
+    lesser, _ = _min_max(row0)
+    full = lesser((row0 + 1) // cols, n_cols)
+    end = lesser((row0 + rows + cols - 1) // cols, n_cols)
     return full, end
+
+
+def _window_bounds(row0, rows, cols, window):
+    """The lower edge of a ``window`` for the ``rows`` query positions from
+    ``row0`` against key blocks of ``cols``: (first block any of them sees,
+    first block all of them see whole).  [0, lo) is never computed, [lo,
+    clear) carries the window's mask; ``clear`` may lie past the diagonal
+    (a window narrower than a block)."""
+    _, greater = _min_max(row0)
+    lo = greater(row0 - window + 1, 0) // cols
+    clear = greater(row0 + rows - window + cols - 1, 0) // cols
+    return lo, clear
+
+
+def _window_q_bounds(col0, cols, rows, window, n_rows):
+    """The same edge seen from the ``cols`` keys from ``col0`` against
+    query blocks of ``rows``: (first query block with a position past the
+    window of key ``col0``, query blocks that reach the block at all).
+    [edge, hi) carries the window's mask, [hi, n_rows) is never computed."""
+    lesser, _ = _min_max(col0)
+    edge = (col0 + window) // rows
+    hi = lesser((col0 + cols + window - 2) // rows + 1, n_rows)
+    return edge, hi
 
 
 def _two_loops(lo, mid, hi, step, init, masked_first):
@@ -123,6 +160,36 @@ def _two_loops(lo, mid, hi, step, init, masked_first):
     a = functools.partial(step, masked=masked_first)
     b = functools.partial(step, masked=not masked_first)
     return jax.lax.fori_loop(mid, hi, b, jax.lax.fori_loop(lo, mid, a, init))
+
+
+def _three_loops(lo, a, b, hi, step, init):
+    """``step`` over [lo, hi) with a mask on both ends: [lo, a) and [b, hi)
+    masked, [a, b) not."""
+    return jax.lax.fori_loop(
+        b, hi, functools.partial(step, masked=True),
+        _two_loops(lo, a, b, step, init, masked_first=True))
+
+
+def _key_block_loop(body, init, qi, TQ, BK, n, square, window):
+    """The causal K-block loop of one Q block, forward and dq alike:
+    ``body(i, carry, masked)`` over the blocks the diagonal and, under a
+    ``window``, its lower edge leave to visit, masked only where one of them
+    crosses a block.  ``square``: up to the diagonal block, which the caller
+    computes in bands."""
+    if window is not None:
+        lo, clear = _window_bounds(qi * TQ, TQ, BK, window)
+    if square:
+        if window is None:
+            return jax.lax.fori_loop(
+                0, qi, functools.partial(body, masked=False), init)
+        # at least a block wide: the diagonal block lies all inside
+        return _two_loops(lo, jnp.minimum(clear, qi), qi, body, init,
+                          masked_first=True)
+    full, end = _causal_bounds(qi * TQ, TQ, BK, n)
+    if window is None:
+        return _two_loops(0, full, end, body, init, masked_first=False)
+    edge = jnp.minimum(clear, end)
+    return _three_loops(lo, edge, jnp.maximum(edge, full), end, body, init)
 
 
 def _heads_per_program(BH, T, itemsize=2):
@@ -166,16 +233,19 @@ def _bands(T, band):
 _BAND_FWD, _BAND_BWD = 256, 128
 
 
-def _visible(shape, row_axis, shift):
+def _visible(shape, row_axis, shift, window=None):
     """The causal mask of one tile: true where key <= query, the tile's
-    first key lying ``shift`` positions after its first query."""
+    first key lying ``shift`` positions after its first query; under a
+    ``window`` also query - key < window."""
     rel = jax.lax.broadcasted_iota(jnp.int32, shape, row_axis) \
         - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - row_axis)
-    return rel >= shift
+    if window is None:
+        return rel >= shift
+    return (rel >= shift) & (rel < shift + window)
 
 
 def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
-                         scale, square=False):
+                         scale, square=False, window=None):
     """Shared kernel body: the online-softmax K-block loop over the ``G``
     heads of a program, returning a running (m, l, acc) a head with m, l
     of shape (TQ, 1) — finalized differently by the normalized-output
@@ -186,7 +256,13 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
     square blocks) says block ``qi`` is the one diagonal block, which is
     then computed in bands (``_bands``).  Every row sees key 0 in the first
     block visited, so the running max is finite from the first step on and
-    ``exp(m_old - m_new)`` needs no guard (``exp(-inf) == 0``)."""
+    ``exp(m_old - m_new)`` needs no guard (``exp(-inf) == 0``).
+
+    ``window`` (causal, narrower than the keys): the loop starts at the
+    first block the window's lower edge reaches and masks up to the first
+    block every row sees whole (``_window_bounds``).  A row may see nothing
+    of the first blocks visited, so a masked step under a window keeps
+    ``exp`` off ``-inf - -inf``."""
     qi = pl.program_id(1)
     D = q_ref.shape[-1]
     fold = _fold_scale(scale)
@@ -198,11 +274,13 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
                                 preferred_element_type=jnp.float32)
         return s if fold else s * scale
 
-    def update(carry, s, vblk):
+    def update(carry, s, vblk, guard=False):
         m, l, acc = carry
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
+        # a row that has seen no key yet: exp(-inf - 0) == 0, not nan
+        at = jnp.where(m_new == -jnp.inf, 0.0, m_new) if guard else m_new
+        p = jnp.exp(s - at)
+        alpha = jnp.exp(m - at)
         l2 = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc2 = acc * alpha + jax.lax.dot_general(
             p.astype(vblk.dtype), vblk, _NN,
@@ -213,9 +291,10 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
         off = pl.multiple_of(i * BK, BK)
         s = scores(qs[g], k_ref[g, pl.ds(off, BK), :])       # (TQ, BK)
         if masked:
-            s = jnp.where(_visible((TQ, BK), 0, i * BK - qi * TQ), s,
+            s = jnp.where(_visible((TQ, BK), 0, i * BK - qi * TQ, window), s,
                           -jnp.inf)
-        return update(carry, s, v_ref[g, pl.ds(off, BK), :])
+        return update(carry, s, v_ref[g, pl.ds(off, BK), :],
+                      guard=masked and window is not None)
 
     def diagonal(g, carry):
         R, SB = _bands(TQ, _BAND_FWD)
@@ -237,12 +316,10 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
     if not causal:
         return jax.lax.fori_loop(0, n, functools.partial(body, masked=False),
                                  init)
+    heads = _key_block_loop(body, init, qi, TQ, BK, n, square, window)
     if square:
-        under = jax.lax.fori_loop(0, qi, functools.partial(body, masked=False),
-                                  init)
-        return tuple(diagonal(g, c) for g, c in enumerate(under))
-    full, end = _causal_bounds(qi * TQ, TQ, BK, n)
-    return _two_loops(0, full, end, body, init, masked_first=False)
+        return tuple(diagonal(g, c) for g, c in enumerate(heads))
+    return heads
 
 
 def _row(col):
@@ -267,10 +344,13 @@ def _out_sds(shape, dtype, like):
         return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def default_blocks(Tq, Tk):
+def default_blocks(Tq, Tk, window=None):
     """(block_q, block_k) from the shape, where the caller names none:
     one square block over the whole of a sequence up to 2,048 positions
-    (0.50 ms against 0.69 in 512-blocks at T 2048), 512-blocks beyond.
+    (0.50 ms against 0.69 in 512-blocks at T 2048), 512-blocks beyond, and
+    under a ``window`` narrower than the sequence at any length: one block
+    leaves nothing to skip, and its diagonal bands know the causal mask
+    alone (not measured; the cell that has a window runs T 4096).
     Measured on the v5e with ``tools/bench_attention_arms.py`` (PERF.md,
     PR 26) at (1, 16, 1024, 64) bf16 causal: the K-block loop costs about
     0.3 us a step whatever the block, so forward + backward take 0.65 ms
@@ -278,12 +358,14 @@ def default_blocks(Tq, Tk):
     whose diagonal bands (``_bands``) are straight-line code with static
     extents and compute 56 % of the square."""
     side = min(Tq, Tk)
+    if window is not None and window < Tk:
+        side = min(side, 512)
     return (side, side) if side <= 2048 else (512, 512)
 
 
-def _pick_blocks(Tq, Tk, block_q, block_k):
+def _pick_blocks(Tq, Tk, block_q, block_k, window=None):
     if block_q is None or block_k is None:
-        own_q, own_k = default_blocks(Tq, Tk)
+        own_q, own_k = default_blocks(Tq, Tk, window)
         block_q, block_k = block_q or own_q, block_k or own_k
     TQ = min(block_q, Tq)
     while Tq % TQ:
@@ -292,6 +374,12 @@ def _pick_blocks(Tq, Tk, block_q, block_k):
     while Tk % BK:
         BK //= 2
     return TQ, BK
+
+
+def _square(TQ, BK, Tq, Tk, window):
+    """Self-attention in square blocks, the diagonal block computed in bands
+    under the causal mask alone: a window at least a block wide."""
+    return TQ == BK and Tq == Tk and (window is None or window >= TQ)
 
 
 def _stats_spec(G, TQ):
@@ -311,23 +399,25 @@ _PARALLEL = pltpu.CompilerParams(
     vmem_limit_bytes=32 << 20)
 
 
-def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k, stats):
+def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k, stats,
+                    window=None):
     """One forward ``pallas_call`` over [B,H,T,D]: ``stats`` None -> out;
     "lse" -> (out, lse[B,H,Tq]); "ml" -> (acc f32, m, l) for the ring."""
     return _fwd_program(q, k, v, causal, scale, block_q, block_k, stats,
-                        INTERPRET)
+                        INTERPRET, window)
 
 
 # The three programs are jitted on their own: a model calls them once a
 # layer with the same shapes, and a jitted callee is traced and lowered to
 # Mosaic once a program, not once a layer (GPT-2-medium's 72 kernel
 # instances took 180 s of every start-up otherwise, compile cache or not).
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
-def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret,
+                 window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
+    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
     nq = Tq // TQ
 
     G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
@@ -335,7 +425,8 @@ def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret):
     def kern(q_ref, k_ref, v_ref, o_ref, *stat_refs):
         heads = _online_softmax_loop(
             q_ref, k_ref, v_ref, G=G, TQ=TQ, BK=BK, Tk=Tk, causal=causal,
-            scale=scale, square=TQ == BK and Tq == Tk)
+            scale=scale, square=_square(TQ, BK, Tq, Tk, window),
+            window=window)
         for g, (m, l, acc) in enumerate(heads):
             if stats == "ml":
                 o_ref[g] = acc
@@ -383,11 +474,11 @@ def lse_of(m, l):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *,
-               G, TQ, BK, Tk, causal, scale, square):
+               G, TQ, BK, Tk, causal, scale, square, window=None):
     """dq for one Q block of ``G`` heads: loop over KV blocks, recompute p
     from lse, accumulate ds @ K in f32.  Scores lie (TQ, BK) as in the
-    forward; the loop holds no reduction.  Causal bounds, mask and the
-    banded diagonal block as in ``_online_softmax_loop``."""
+    forward; the loop holds no reduction.  Causal bounds, the window's,
+    mask and the banded diagonal block as in ``_online_softmax_loop``."""
     qi = pl.program_id(1)
     D = q_ref.shape[-1]
     fold = _fold_scale(scale)
@@ -411,7 +502,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *,
 
     def step(g, i, dq, masked):
         off = pl.multiple_of(i * BK, BK)
-        mask = _visible((TQ, BK), 0, i * BK - qi * TQ) if masked else None
+        mask = _visible((TQ, BK), 0, i * BK - qi * TQ, window) \
+            if masked else None
         return grad(dq, qs[g], dos[g], lses[g], deltas[g],
                     k_ref[g, pl.ds(off, BK), :], v_ref[g, pl.ds(off, BK), :],
                     mask)
@@ -435,26 +527,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *,
     if not causal:
         dqs = jax.lax.fori_loop(0, n, functools.partial(body, masked=False),
                                 init)
-    elif square:
-        under = jax.lax.fori_loop(0, qi, functools.partial(body, masked=False),
-                                  init)
-        dqs = tuple(diagonal(g, dq) for g, dq in enumerate(under))
     else:
-        full, end = _causal_bounds(qi * TQ, TQ, BK, n)
-        dqs = _two_loops(0, full, end, body, init, masked_first=False)
+        dqs = _key_block_loop(body, init, qi, TQ, BK, n, square, window)
+        if square:
+            dqs = tuple(diagonal(g, dq) for g, dq in enumerate(dqs))
     for g, dq in enumerate(dqs):
         dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
-                dv_ref, *, G, TQ, BK, Tq, causal, scale, square):
+                dv_ref, *, G, TQ, BK, Tq, causal, scale, square, window=None):
     """dk/dv for one KV block of ``G`` heads: loop over Q blocks.  Scores
     lie transposed, (BK, TQ) = K Q^T: lse and delta are lane-dense rows as
     they arrive, and all five products are plain ``A @ B`` or ``A @ B^T``
     with the score tile as the streamed operand.  Causal: start at the
     first Q block that can see this KV block, mask up to the last one the
     diagonal crosses; ``square``: that is block ``ki`` alone, computed in
-    bands of keys (band r is seen by the queries from band r on)."""
+    bands of keys (band r is seen by the queries from band r on).  Under a
+    ``window`` the loop ends at the last Q block that reaches this KV block
+    and masks from the first one with a position past it
+    (``_window_q_bounds``)."""
     ki = pl.program_id(1)
     D = k_ref.shape[-1]
     fold = _fold_scale(scale)
@@ -481,7 +573,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
 
     def step(g, i, carry, masked):
         off = pl.multiple_of(i * TQ, TQ)
-        mask = _visible((BK, TQ), 1, ki * BK - i * TQ) if masked else None
+        mask = _visible((BK, TQ), 1, ki * BK - i * TQ, window) \
+            if masked else None
         return grad(carry, ks[g], vs[g], q_ref[g, pl.ds(off, TQ), :],
                     do_ref[g, pl.ds(off, TQ), :], lse_ref[g, i],
                     dl_ref[g, i], mask)
@@ -508,32 +601,46 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
         grads = jax.lax.fori_loop(
             0, n, functools.partial(body, masked=False), init)
     elif square:
-        grads = jax.lax.fori_loop(
-            ki + 1, n, functools.partial(body, masked=False),
-            tuple(diagonal(g) for g in range(G)))
+        if window is None:
+            grads = jax.lax.fori_loop(
+                ki + 1, n, functools.partial(body, masked=False),
+                tuple(diagonal(g) for g in range(G)))
+        else:
+            edge, hi = _window_q_bounds(ki * BK, BK, TQ, window, n)
+            grads = _two_loops(ki + 1, jnp.minimum(edge, hi), hi, body,
+                               tuple(diagonal(g) for g in range(G)),
+                               masked_first=False)
     else:
         lo = (ki * BK) // TQ
         # Q blocks from here on lie wholly under the diagonal
         clear = jnp.minimum((ki * BK + BK - 1 + TQ - 1) // TQ, n)
-        grads = _two_loops(lo, clear, n, body, init, masked_first=True)
+        if window is None:
+            grads = _two_loops(lo, clear, n, body, init, masked_first=True)
+        else:
+            edge, hi = _window_q_bounds(ki * BK, BK, TQ, window, n)
+            clear = jnp.minimum(clear, hi)
+            grads = _three_loops(
+                lo, clear, jnp.maximum(clear, jnp.minimum(edge, hi)), hi,
+                body, init)
     for g, (dk, dv) in enumerate(grads):
         dk_ref[g] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[g] = dv.astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _dq_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
-                out_dtype, interpret):
+                out_dtype, interpret, window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
+    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
     nq = Tq // TQ
     G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, G=G, TQ=TQ, BK=BK, Tk=Tk,
                           causal=causal, scale=scale,
-                          square=TQ == BK and Tq == Tk),
+                          square=_square(TQ, BK, Tq, Tk, window),
+                          window=window),
         grid=(BH // G, nq),
         in_specs=_qkv_specs(G, TQ, Tk, D) + [
             pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0)),
@@ -549,13 +656,13 @@ def _dq_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
     return dq.reshape(B, H, Tq, D)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _dkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
-                 out_dtype, interpret):
+                 out_dtype, interpret, window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k)
+    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
     nq = Tq // TQ
     G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
     whole_q = pl.BlockSpec((G, Tq, D), lambda b, t: (b, 0, 0))
@@ -564,7 +671,8 @@ def _dkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, G=G, TQ=TQ, BK=BK, Tq=Tq,
                           causal=causal, scale=scale,
-                          square=TQ == BK and Tq == Tk),
+                          square=_square(TQ, BK, Tq, Tk, window),
+                          window=window),
         grid=(BH // G, Tk // BK),
         in_specs=[whole_q, kv_blk, kv_blk, whole_q, whole_stats,
                   whole_stats],
@@ -580,7 +688,8 @@ def _dkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
 
 
 def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
-                        block_q=512, block_k=512, out_dtype=jnp.float32):
+                        block_q=512, block_k=512, out_dtype=jnp.float32,
+                        window=None):
     """Pallas flash backward: (dq, dk, dv), in f32 unless the caller names
     another ``out_dtype`` (callers accumulating across ring steps keep
     full precision; the standalone VJP has the kernels cast).
@@ -592,7 +701,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
     lse = lse.astype(jnp.float32)
     delta = delta.astype(jnp.float32)
     args = (q, k, v, do, lse, delta, causal, scale, block_q, block_k,
-            jnp.dtype(out_dtype), INTERPRET)
+            jnp.dtype(out_dtype), INTERPRET, window)
     return (_dq_program(*args), *_dkv_program(*args))
 
 
@@ -648,34 +757,67 @@ def _scale_of(q, scale):
     return scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _window_of(window, causal, Tk):
+    """The window the kernels are built for: None where it reaches position
+    0 from every query (plain causal, the same programs)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError("a window of %r needs causal attention and at "
+                         "least one position" % (window,))
+    return None if window >= Tk else int(window)
+
+
+def kv_block_plan(Tq, Tk, causal=True, window=None, block_q=None,
+                  block_k=None):
+    """(visited, skipped): of the key blocks at or under the diagonal, a
+    head, how many the forward kernel's loop bounds take in and how many a
+    ``window`` lets them leave out.  The bounds are the kernel's own, on
+    python ints; nothing runs."""
+    window = _window_of(window, causal, Tk)
+    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
+    visited = skipped = 0
+    for row0 in range(0, Tq, TQ):
+        end = _causal_bounds(row0, TQ, BK, Tk // BK)[1] if causal \
+            else Tk // BK
+        lo = 0 if window is None else _window_bounds(row0, TQ, BK, window)[0]
+        visited, skipped = visited + end - lo, skipped + lo
+    return visited, skipped
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None):
+                    block_k=None, window=None):
     """[B,H,T,D] attention; Pallas kernels both directions, each device
     of an ambient mesh on its own batch rows and heads (``_on_own_rows``).
-    Blocks default to ``default_blocks`` of the shape."""
+    Blocks default to ``default_blocks`` of the shape.  ``window`` (causal
+    only): position t sees the ``window`` positions up to and with t."""
     sc = _scale_of(q, scale)
+    window = _window_of(window, causal, k.shape[2])
     return _on_own_rows(
         lambda *qkv: _flash_fwd_call(*qkv, causal, sc, block_q, block_k,
-                                     None)[0], q, k, v)
+                                     None, window)[0], q, k, v)
 
 
-def _fa_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
+def _fa_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window):
     sc = _scale_of(q, scale)
+    window = _window_of(window, causal, k.shape[2])
     out, lse = _on_own_rows(
         lambda *qkv: _flash_fwd_call(*qkv, causal, sc, block_q, block_k,
-                                     "lse"), q, k, v)
+                                     "lse", window), q, k, v)
     return out, (q, k, v, out, lse)
 
 
-def _fa_vjp_bwd(causal, scale, block_q, block_k, res, g):
+def _fa_vjp_bwd(causal, scale, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     sc = _scale_of(q, scale)
+    window = _window_of(window, causal, k.shape[2])
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     return _on_own_rows(
         lambda *xs: flash_attention_bwd(*xs, causal, sc, block_q, block_k,
-                                        q.dtype), q, k, v, g, lse, delta)
+                                        q.dtype, window),
+        q, k, v, g, lse, delta)
 
 
 flash_attention.defvjp(_fa_vjp_fwd, _fa_vjp_bwd)

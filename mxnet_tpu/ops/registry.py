@@ -147,6 +147,8 @@ class Operator:
         self.visible = None
         # indices of auxiliary inputs (reference FListAuxiliaryStates —
         # BatchNorm's moving stats): not gradient targets, not arguments.
+        # A negative index counts from the last input, as in aux_writeback
+        # (SparseMoE's load, behind a list of inputs its scoring decides).
         self.aux_inputs: Tuple[int, ...] = ()
         # partial shape inference hook: fn(attrs, in_shapes) -> in_shapes
         # with None entries filled (the FInferShape analog for inferring

@@ -90,17 +90,20 @@ def _fill(shapes, want):
 
 def _mha_hint(attrs, shapes):
     """MultiHeadAttention: the projection weights in the FullyConnected
-    (out, in) orientation — query and output (model_dim, model_dim), key and
-    value (num_kv_heads * head, model_dim), square too unless the heads are
-    grouped — and, under ``qk_norm``, the two per-head gains of (head,)."""
+    (out, in) orientation — query (num_heads * head, model_dim) and output
+    its transpose, key and value (num_kv_heads * head, model_dim), all
+    square unless the heads are grouped or ``head_dim`` names a head that is
+    not model_dim / num_heads — and, under ``qk_norm``, the two per-head
+    gains of (head,)."""
     data = shapes[0]
     if data is None:
         return shapes
     D = data[-1]
     H = attrs["num_heads"]
-    kv = D // H * (attrs.get("num_kv_heads") or H)
-    return _fill(shapes, [(D, D), (kv, D), (kv, D), (D, D), (D // H,),
-                          (D // H,)])
+    hd = attrs.get("head_dim") or D // H
+    kv = hd * (attrs.get("num_kv_heads") or H)
+    return _fill(shapes, [(H * hd, D), (kv, D), (kv, D), (D, H * hd), (hd,),
+                          (hd,)])
 
 
 def _short_conv_hint(attrs, shapes):
@@ -114,15 +117,17 @@ def _short_conv_hint(attrs, shapes):
 
 
 def _sparse_moe_hint(attrs, shapes):
-    """SparseMoE: the router over all experts, the bias, the weights of the
-    ``num_held`` experts held (0: all) as (expert, out, in), the load."""
+    """SparseMoE: the router over all experts, the bias (sigmoid scoring
+    only), the weights of the ``num_held`` experts held (0: all) as
+    (expert, out, in), the load."""
     data = shapes[0]
     if data is None:
         return shapes
     D, E, F = data[-1], attrs["num_experts"], attrs["num_hidden"]
     held = attrs.get("num_held") or E
-    return _fill(shapes, [(E, D), (E,), (held, F, D), (held, F, D),
-                          (held, D, F), (E,)])
+    bias = [(E,)] if attrs.get("score", "sigmoid") == "sigmoid" else []
+    return _fill(shapes, [(E, D)] + bias + [(held, F, D), (held, F, D),
+                                            (held, D, F), (E,)])
 
 
 def _rnn_hint(attrs, shapes):
@@ -189,7 +194,7 @@ def install():
                        "out_proj_weight"), (), _short_conv_hint),
         "SparseMoE": (("data", "router_weight", "expert_bias",
                        "expert_gate_weight", "expert_up_weight",
-                       "expert_down_weight", "expert_load"), (6,),
+                       "expert_down_weight", "expert_load"), (-1,),
                       _sparse_moe_hint),
         "LeakyReLU": (("data", "gamma"), (), _channel_hint()),
         "RNN": (("data", "parameters", "state", "state_cell"), (),

@@ -7,7 +7,7 @@ inputs; everything else becomes node attrs.
 from __future__ import annotations
 
 from ..ops.registry import OPS
-from .symbol import Symbol, _create
+from .symbol import Symbol, _arg_names, _create
 
 
 def _make_fn(op_name):
@@ -35,7 +35,10 @@ def _make_fn(op_name):
             kwargs.pop(k)
         if kw_syms:
             if op.arg_names:
-                slots = {n: i for i, n in enumerate(op.arg_names)}
+                names = _arg_names(op, kwargs)
+                if any(k in op.arg_names and k not in names for k in kw_syms):
+                    names = op.arg_names    # an input its attrs switch off,
+                slots = {n: i for i, n in enumerate(names)}  # given anyway
                 total = max((slots.get(k, -1) for k in kw_syms), default=-1)
                 ins = list(sym_inputs) + [None] * (
                     max(0, total + 1 - len(sym_inputs)))

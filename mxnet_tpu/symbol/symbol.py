@@ -473,6 +473,38 @@ def _norm_grad_req(grad_req, arg_names):
 # --------------------------------------------------------------------------
 # symbol creation
 # --------------------------------------------------------------------------
+def _without_bias(attrs, names):
+    return names[:-1] if attrs.get("no_bias") else names
+
+
+#: ops whose attrs switch declared inputs off: op -> (attrs, names) -> names
+_SWITCHED_ARGS = {
+    "Convolution": _without_bias, "Deconvolution": _without_bias,
+    "FullyConnected": _without_bias, "AttentionConvolution": _without_bias,
+    # gamma exists only for the prelu variant
+    "LeakyReLU": lambda attrs, names: names if attrs.get(
+        "act_type", "leaky") == "prelu" else names[:-1],
+    # the per-head gains exist only under qk_norm
+    "MultiHeadAttention": lambda attrs, names: names if attrs.get(
+        "qk_norm") else names[:-2],
+    # softmax scores are selected as they are: no selection bias to carry
+    "SparseMoE": lambda attrs, names: names if attrs.get(
+        "score") != "softmax" else [n for n in names if n != "expert_bias"],
+}
+
+
+def _arg_names(op, kwargs) -> List[str]:
+    """The inputs a node of ``op`` with these attrs takes, by name and in
+    order: the op's declared arguments without those its attrs switch off
+    (reference behavior: ``no_bias`` drops ``bias``)."""
+    names = list(op.arg_names or ())
+    switch = _SWITCHED_ARGS.get(op.name)
+    if switch is None or not names:
+        return names
+    return switch(op.parse_attrs({k: v for k, v in kwargs.items()
+                                  if v is not None}), names)
+
+
 def _create(op_name: str, sym_inputs: Sequence[Symbol],
             kwargs: Dict[str, Any], name: Optional[str] = None,
             attr: Optional[Dict[str, str]] = None) -> Symbol:
@@ -485,13 +517,14 @@ def _create(op_name: str, sym_inputs: Sequence[Symbol],
     from ..name import current_scope as _cs
     name = _cs().get(name, op.name.lower())
 
+    arg_names = _arg_names(op, kwargs)
     entries: List[Tuple[Optional[_Node], int]] = []
     for s in sym_inputs:
         if s is None:
             # interior gap from keyword placement: auto-create a variable
             # named after the (scope-resolved) node name + arg name
-            argname = op.arg_names[len(entries)] if op.arg_names and \
-                len(entries) < len(op.arg_names) else "arg%d" % len(entries)
+            argname = arg_names[len(entries)] \
+                if len(entries) < len(arg_names) else "arg%d" % len(entries)
             entries.append((_Node(None, "%s_%s" % (name, argname), {}, []), 0))
             continue
         if len(s._outputs) != 1:
@@ -500,23 +533,9 @@ def _create(op_name: str, sym_inputs: Sequence[Symbol],
 
     # auto-create missing parameter variables (reference behavior: calling
     # sym.Convolution(data=x, name='c1') creates c1_weight / c1_bias)
-    if op.arg_names:
-        needed = len(op.arg_names)
-        if op.name in ("Convolution", "Deconvolution", "FullyConnected",
-                       "AttentionConvolution") and \
-                op.parse_attrs(dict(kwargs)).get("no_bias"):
-            needed -= 1
-        if op.name == "LeakyReLU" and \
-                op.parse_attrs(dict(kwargs)).get("act_type",
-                                                 "leaky") != "prelu":
-            needed -= 1    # gamma exists only for the prelu variant
-        if op.name == "MultiHeadAttention" and \
-                not op.parse_attrs(dict(kwargs)).get("qk_norm"):
-            needed -= 2    # the per-head gains exist only under qk_norm
-        while len(entries) < needed:
-            argname = op.arg_names[len(entries)]
-            v = _Node(None, "%s_%s" % (name, argname), {}, [])
-            entries.append((v, 0))
+    while len(entries) < len(arg_names):
+        v = _Node(None, "%s_%s" % (name, arg_names[len(entries)]), {}, [])
+        entries.append((v, 0))
 
     # AttrScope defaults (ctx_group, __lr_mult__, ...) apply to EVERY node
     # created in scope — including operator-overload nodes (a * b) that

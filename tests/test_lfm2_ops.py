@@ -542,3 +542,49 @@ def test_config_refuses_what_it_cannot_build():
         TransformerConfig("x", 64, 2, 32, 2, 64, 8, layer_types=("conv",))
     with pytest.raises(ValueError):
         TransformerConfig("x", 64, 1, 32, 2, 64, 8, layer_types=("scan",))
+
+
+# ------------------------------------- the graphs and kernels of PR 30's cells
+@pytest.mark.parametrize("cell,json_sha1,names_sha1", [
+    ("gpt2m_train_1k", "a386f0c2266cbe6ba5a4bffb26f9a2cee432fe9f",
+     "01b4f1e0a01dc1a8204ed18ee464f1ac37208fb2"),
+    ("lfm2moe_train_2k", "06a79f66a7a78f26af3bf35bd388b5c2c29fbcba",
+     "5bffd99842a238faef09ac67046265aff19d1da5")])
+def test_the_accepted_cells_graphs_are_what_they_were(cell, json_sha1,
+                                                      names_sha1):
+    """``gpt2_medium`` and ``lfm2_24b_a2b`` at their rehearsal sizes: the
+    Symbol's JSON (every node, input and attr: an attr that is off is
+    absent) and its arguments and auxiliary states, in order, are letter for
+    letter what they were before ``MultiHeadAttention`` had a head size, a
+    window and YaRN and ``SparseMoE`` a scoring (sha1s taken on commit
+    c48259c)."""
+    import os
+    from perf import harness
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    got = harness.load_cell(root, cell, rehearse=True)
+    with mx.name.NameManager():     # unnamed nodes count from nought
+        net = got.builder.symbol(got.config, got.workload)
+    assert hashlib.sha1(net.tojson().encode()).hexdigest() == json_sha1
+    names = net.list_arguments() + ["|"] + net.list_auxiliary_states()
+    assert hashlib.sha1(",".join(names).encode()).hexdigest() == names_sha1
+
+
+@pytest.mark.parametrize("shape,jaxpr_sha1", [
+    ((1, 4, 1024, 64), "d836ede8a2abc10d35f0f424b4c6fa9ff63cc8f5"),
+    ((1, 2, 4096, 128), "fa92ff978ffa7e539920ae35d2ec110e71f14358")],
+    ids=["one_block", "512_blocks"])
+def test_flash_attention_without_a_window_is_the_kernels_it_was(shape,
+                                                                jaxpr_sha1):
+    """``flash_attention(window=None)``, forward and the three gradients,
+    traces to the jaxpr (kernel bodies included) it traced to before the
+    kernels knew a window: in one block as the GPT-2 and LFM2 cells run it,
+    and in 512-blocks (sha1s taken on commit c48259c; the lowered text
+    itself carries the checkout's path and cannot be pinned)."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(q, k, v, True)
+                       .astype(jnp.float32) ** 2)
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, q, q))
+    assert hashlib.sha1(text.encode()).hexdigest() == jaxpr_sha1

@@ -179,6 +179,48 @@ def test_sparse_moe_compiles_at_the_cells_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_mellum_attention_reaches_its_kernels(one_chip, kind):
+    """``mellum2moe_train_4k``'s two kinds of attention layer, ahead of time:
+    32 query heads of 128 over 4 key/value heads under a 2304-wide stream at
+    T 4096, in 512-blocks; a window of 1024 with the default rope, or full
+    attention under YaRN.  Forward + backward hold the three Mosaic calls
+    over 32 heads, no T x T tensor, and the sliding layer's calls carry the
+    scope ``window_flash_roofline_pct.train`` finds them by."""
+    import json
+    import os
+    from mxnet_tpu.ops.registry import OPS
+    op = OPS["MultiHeadAttention"]
+    variant = dict(window=1024) if kind == "sliding_attention" else dict(
+        rope_yarn=(16.0, 8192.0, 32.0, 1.0, 1.2772588722239782))
+    attrs = op.parse_attrs(dict(num_heads=32, num_kv_heads=4, head_dim=128,
+                                rope_theta=5e5, **variant))
+    node = "tfm_l0_swa" if kind == "sliding_attention" else "tfm_l3_attn"
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    args = (sds(1, 4096, 2304), sds(4096, 2304), sds(512, 2304),
+            sds(512, 2304), sds(2304, 4096))
+
+    def loss(*a):
+        with jax.named_scope("MultiHeadAttention:" + node):
+            return jnp.sum(op.fn(attrs, *a).astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, tuple(range(5)))).lower(
+        *args).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert all("bf16[32,4096,128]" in ln for ln in calls)
+    assert "[1,32,4096,4096]" not in hlo and "[32,4096,4096]" not in hlo
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "metrics",
+                           "window_flash_roofline_pct.train.json")) as f:
+        reads = re.compile(json.load(f)["params"]["kernel"])
+    found = [bool(reads.search(ln)) for ln in calls]
+    assert found == [kind == "sliding_attention"] * 3
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_flash_attention_ring_variant_compiles(one_chip, direction):
     """The stats-emitting kernel ring attention runs per shard, and the
