@@ -30,6 +30,8 @@ import os
 
 import numpy as np
 
+from .base import dtype_name
+
 __all__ = ["ENV_FLAG", "enabled", "is_low_precision", "compute_dtype",
            "type_dict_for"]
 
@@ -52,7 +54,7 @@ def enabled():
 def is_low_precision(dtype):
     """Whether ``dtype`` is a storage dtype that needs an fp32 master."""
     try:
-        return np.dtype(dtype).name in _LOW_PRECISION
+        return dtype_name(dtype) in _LOW_PRECISION
     except TypeError:
         return False
 

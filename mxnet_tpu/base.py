@@ -154,3 +154,11 @@ def c_array(ctype, values):  # pragma: no cover - legacy-compat shim
 @functools.lru_cache(maxsize=None)
 def _np_dtype(name_or_dtype) -> np.dtype:
     return np.dtype(name_or_dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def dtype_name(dtype) -> str:
+    """``np.dtype(dtype).name``, kept by the dtype asked of: numpy builds the
+    name in Python on every read, and a train step asks it of every leaf
+    (``amp.is_low_precision``, ``Executor._dtype_sig``)."""
+    return np.dtype(dtype).name

@@ -15,13 +15,14 @@ run through an un-jitted eager replay of the same plan.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .base import MXNetError, AttrDict
+from .base import MXNetError, AttrDict, dtype_name
 from .context import Context
 from . import atlas as _atlas
 from . import random as _random
@@ -59,6 +60,13 @@ _PROG_HITS = _telemetry.counter(
 _PROG_MISSES = _telemetry.counter(
     "op_jit_cache_misses_total",
     "Operator jit-cache lookups that built a new entry", ("op",))
+# Which way a mesh step program with its state split over ``dp`` exchanges
+# the gradients (``parallel.mesh.exchange_path``); one inc per program
+# built, nothing on one device.
+_GRAD_EXCHANGE = _telemetry.counter(
+    "grad_exchange_total",
+    "Mesh step programs built, by the way their split leaves' gradients "
+    "are exchanged (trace-time)", ("path",))
 
 
 class _Plan:
@@ -386,8 +394,8 @@ class Executor:
         adoption path casts to the bound dtype), but serving hot-swap
         re-points ``_arg_params`` and a bf16-weights binding must never
         share a program slot with an fp32 one."""
-        return tuple(np.dtype(self.arg_dict[n].dtype).name
-                     for n in self.arg_names)
+        return tuple([dtype_name(self.arg_dict[n].dtype)
+                      for n in self.arg_names])
 
     def _fwd_key(self, train: bool):
         return ("fwd", bool(train)) + self._plan_env(train) \
@@ -556,6 +564,10 @@ class Executor:
         update_fns = tuple(update_fns)
         pset = set(pnames)
         other_names = [n for n in arg_names if n not in pset]
+        from .parallel import mesh as _mesh
+        exchange = _mesh.exchange_path(param_shardings, state_shardings)
+        if exchange is not None and _telemetry.enabled:
+            _GRAD_EXCHANGE.labels(path=exchange).inc()
 
         def fn(pvals, svals, others, auxs, keys, ograds, lrs, wds, ts,
                rescale):
@@ -577,9 +589,17 @@ class Executor:
                     gathered = [p if ssh is psh else pin(p, psh)
                                 for p, psh, ssh in zip(
                                     pvals, param_shardings, state_shardings)]
-            (outs, new_aux), vjp = jax.vjp(
-                lambda *g: pure(list(g)), *gathered)
-            grads = vjp((list(ograds), [jnp.zeros_like(a) for a in new_aux]))
+            # on TPUs a split weight's gradient leaves the product that
+            # makes it around a ring of asynchronous permutes, not through
+            # a blocking reduce-scatter behind backward
+            with (_mesh.grad_exchange(state_shardings[0].mesh, "dp")
+                  if exchange == "async" else contextlib.nullcontext()):
+                (outs, new_aux), vjp = jax.vjp(
+                    lambda *g: pure(list(g)), *gathered)
+                grads = vjp((list(ograds),
+                             [jnp.zeros_like(a) for a in new_aux]))
+                if exchange == "async":
+                    grads = _mesh.ended(grads)
             new_p, new_s = [], []
             # weights and state leave in the layout they were taken in
             out_shardings = state_shardings or param_shardings

@@ -36,6 +36,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
 
 from . import atlas as _atlas
 from . import telemetry as _telemetry
@@ -204,7 +205,6 @@ def _as_jax(arr):
     data = getattr(arr, "_data", None)
     if data is not None:
         return data
-    import numpy as _np
     return jnp.asarray(_np.asarray(arr))
 
 
@@ -273,6 +273,7 @@ class ModuleFusedStep:
         self._mesh_cache = None      # (key, (mesh, rules, dp_axis)|None)
         self._place = None           # _placement()'s (False: none), and
         self._layout = None          # _mesh_layout()'s, of that mesh
+        self._exchange = None        # its exchange_path(): a mesh, split
         self._split = None           # _count_split()'s, of that layout
         self._meshed = False         # handles currently hold mesh globals
         self._mesh_outputs = None    # full-batch outputs of the last step
@@ -381,10 +382,15 @@ class ModuleFusedStep:
         m = self._mod
         opt_ = m._optimizer
         arity = opt_.fused_state_arity()
-        for slot, st in m._updater.states.items():
+        states = m._updater.states
+        for slot, st in states.items():
             i, k = divmod(slot, ndev)
             if not (0 <= i < len(m._param_names) and k < ndev):
                 return False
+            if k and states.get(slot - k, states) is st:
+                # a mesh step's sibling slot (``_slots``): the device-0
+                # slot's own object, looked at there
+                continue
             w = self._eg.execs[k].arg_dict.get(m._param_names[i])
             mp = w is not None and opt_.fused_mp(w)
             leaves = _opt.fused_state_leaves(st, mp)
@@ -451,12 +457,14 @@ class ModuleFusedStep:
         pvals, svals, taken = [], [], []    # taken: (handle, leaves, mp)
         with _gather_span(pool) as args:
             placed = pool.copies
+            arg_dict, views = ex.arg_dict, [e.arg_dict for e in rest]
             for (name, slot, _, _, _), ssh in zip(slots, leaf_ssh):
-                handle = ex.arg_dict[name]
-                for e in rest:
+                handle = arg_dict[name]
+                data = handle._data
+                for view in views:
                     # all execs' views of one param must agree: where one
                     # was written from outside, the slot is copied
-                    if e.arg_dict[name]._data is not handle._data:
+                    if view[name]._data is not data:
                         pool.disown(("w", name))
                         break
                 # the weight is held between steps in its state's layout
@@ -467,8 +475,8 @@ class ModuleFusedStep:
                 # moment
                 mp = mp_of(handle)
                 leaves = leaves_of(states[slot], mp)
-                svals.append(tuple(take(("s", slot, j), leaf, ssh)
-                                   for j, leaf in enumerate(leaves)))
+                svals.append(tuple([take(("s", slot, j), leaf, ssh)
+                                    for j, leaf in enumerate(leaves)]))
                 taken.append((handle, leaves, mp))
             if self._split is None or pool.copies != placed:
                 # counted when a leaf was placed (the first step, a state
@@ -477,12 +485,16 @@ class ModuleFusedStep:
             args["leaves"] = len(pvals) + sum(len(sv) for sv in svals)
             args["held_split"], args["sharded"], args["sharded_bytes"] = \
                 self._split
-            # (lrs, wds, ts, rescale): four small host-to-device copies
-            lrs = jnp.asarray([s[2] for s in slots], jnp.float32)
-            wds = jnp.asarray([s[3] for s in slots], jnp.float32)
-            ts = jnp.asarray([s[4] for s in slots], jnp.float32)
-            rescale = jnp.asarray(opt_.rescale_grad, jnp.float32)
+            # (lrs, wds, ts, rescale): four small host arrays, copied to
+            # the device(s) by the launch itself
+            lrs = _np.asarray([s[2] for s in slots], _np.float32)
+            wds = _np.asarray([s[3] for s in slots], _np.float32)
+            ts = _np.asarray([s[4] for s in slots], _np.float32)
+            rescale = _np.asarray(opt_.rescale_grad, _np.float32)
         run = dict(place.span_args)
+        if self._exchange is not None:
+            # how the program exchanges its split leaves' gradients
+            run["exchange"] = self._exchange
         with _span("Step::program", run):
             plan = ex._plan(True)
             keys = ex._keys(plan)
@@ -518,11 +530,12 @@ class ModuleFusedStep:
             _health.audit_donation(place.program, (pvals, svals))
         with _span("Step::writeback"):
             give = pool.give
+            views = [e.arg_dict for e in rest]
             for (name, slot, _, _, _), (handle, leaves, _), w, st in zip(
                     slots, taken, new_p, new_s):
                 give(("w", name), handle, w)
-                for e in rest:
-                    e.arg_dict[name]._data = w
+                for view in views:
+                    view[name]._data = w
                 for j, (leaf, arr) in enumerate(zip(leaves, st)):
                     give(("s", slot, j), leaf, arr)
             for n, v in zip(ex.aux_names, new_aux):
@@ -545,24 +558,27 @@ class ModuleFusedStep:
         step — the program IS the single update."""
         m = self._mod
         opt_ = m._optimizer
-        states = m._updater.states
+        states, synced = m._updater.states, m._updater.states_synced
+        counts, slot_index = opt_._index_update_count, opt_.slot_index
         pset = self._pset
+        siblings = range(1, ndev)
         out = []
         for i, name in enumerate(m._param_names):
             if name not in pset:
                 continue
-            base = opt_.slot_index(i, ndev, 0)
-            if base not in states:
-                states[base] = opt_.create_state_multi_precision(
+            base = slot_index(i, ndev, 0)
+            st = states.get(base, states)
+            if st is states:
+                st = states[base] = opt_.create_state_multi_precision(
                     base, ex.arg_dict[name])
-                m._updater.states_synced[base] = True
+                synced[base] = True
             opt_._update_count(base)
-            cnt = opt_._index_update_count[base]
-            for k in range(1, ndev):
-                sib = opt_.slot_index(i, ndev, k)
-                states[sib] = states[base]
-                m._updater.states_synced[sib] = True
-                opt_._index_update_count[sib] = cnt
+            cnt = counts[base]
+            for k in siblings:
+                sib = slot_index(i, ndev, k)
+                states[sib] = st
+                synced[sib] = True
+                counts[sib] = cnt
             # host-side lr corrections (Adam's f64 bias fold) ride in the
             # captured lr so the traced program matches the eager oracle
             out.append((name, base,
@@ -599,7 +615,7 @@ class ModuleFusedStep:
                 except (ValueError, TypeError):
                     setup = None
         self._mesh_cache = (key, setup)
-        self._place = self._layout = self._split = None
+        self._place = self._layout = self._split = self._exchange = None
         return setup
 
     def _placement(self):
@@ -699,7 +715,8 @@ class ModuleFusedStep:
                 self._layout = (None, None, None)
                 return self._layout
             mesh, rules, dp = self._mesh_setup()
-            from .parallel.mesh import replicated_sharding, state_sharding
+            from .parallel.mesh import exchange_path, \
+                replicated_sharding, state_sharding
             repl = replicated_sharding(mesh)
             ex = self._eg.execs[0]
             psh, ssh = [], []
@@ -718,6 +735,7 @@ class ModuleFusedStep:
             else:
                 sig += (tuple(str(sh.spec) for sh in ssh),)
             self._layout = (psh, ssh, sig)
+            self._exchange = exchange_path(psh, ssh)
         return self._layout
 
     def _count_split(self, svals):

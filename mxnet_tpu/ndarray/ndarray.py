@@ -52,8 +52,10 @@ class NDArray:
 
     @property
     def dtype(self):
-        return np.dtype(self._data.dtype.name if hasattr(self._data.dtype, "name")
-                        else self._data.dtype)
+        d = self._data.dtype
+        if isinstance(d, np.dtype):     # a jax array's: what np.dtype(name)
+            return d                    # would give, without building a name
+        return np.dtype(d.name if hasattr(d, "name") else d)
 
     @property
     def size(self) -> int:

@@ -23,6 +23,9 @@ import numpy as np
 from .registry import register, param
 from ..base import MXNetError
 from .. import telemetry as _telemetry
+# ``x @ w.T``; inside the mesh step on TPUs the weight's gradient comes
+# through its ring, everywhere else it is the plain product
+from ..parallel.mesh import matmul_wt as _matmul_wt
 
 # Trace-time dispatch mix of the Convolution formulations (one inc per
 # compiled specialization, not per step — executables are cached).  Lets
@@ -258,7 +261,7 @@ def _fully_connected(attrs, data, weight, *maybe_bias):
         x = data.reshape(data.shape[0], -1)
     else:
         x = data
-    out = jnp.matmul(x, weight.T)
+    out = _matmul_wt(x, weight)
     if not attrs["no_bias"] and maybe_bias:
         out = out + maybe_bias[0]
     return out
@@ -710,7 +713,7 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
         window = None           # every earlier position: plain causal
 
     def proj(w, heads):
-        y = jnp.matmul(data, w.T)                     # [B,T,heads*d]
+        y = _matmul_wt(data, w)                       # [B,T,heads*d]
         return y.reshape(B, T, heads, d).transpose(0, 2, 1, 3)
 
     q, k, v = proj(query_weight, H), proj(key_weight, Hkv), \
@@ -745,7 +748,7 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
                 # graftlint: disable=GL002 -- counts compiled variants
                 _ATTN_KV_BLOCKS.labels(kind=kind, fate=fate).inc(count)
     out = out.transpose(0, 2, 1, 3).reshape(B, T, H * d)  # [B,T,H*d]
-    return jnp.matmul(out, out_proj_weight.T)
+    return _matmul_wt(out, out_proj_weight)
 
 
 _SHORTCONV_DISPATCH = _telemetry.counter(

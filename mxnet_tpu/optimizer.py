@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from .base import Registry, MXNetError
+from . import amp as _amp
 from . import ndarray as nd
 from .ndarray.ndarray import NDArray
 
@@ -64,7 +65,6 @@ class Optimizer:
         return None
 
     def create_state_multi_precision(self, index, weight):
-        from . import amp as _amp
         if self.multi_precision and _amp.is_low_precision(weight.dtype):
             w32 = weight.astype(np.float32)
             state = (self.create_state(index, w32), w32)
@@ -95,7 +95,6 @@ class Optimizer:
     def _mp_state(self, weight, state):
         """Whether ``state`` is the eager multi-precision layout
         ``(inner_state, master_fp32)`` for this low-precision weight."""
-        from . import amp as _amp
         return (self.multi_precision and _amp.is_low_precision(weight.dtype)
                 and isinstance(state, tuple) and len(state) == 2
                 and isinstance(state[1], NDArray)
@@ -201,7 +200,6 @@ class Optimizer:
         """Whether this weight rides the fused path in multi-precision
         form: low-precision storage with a master-fp32 leaf PREPENDED to
         its flat state tuple, updated via ``fused_update_mp``."""
-        from . import amp as _amp
         return self.multi_precision and _amp.is_low_precision(weight.dtype)
 
     def fused_update_mp(self, weight, grad, state, lr, wd, rescale, t):

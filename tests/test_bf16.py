@@ -422,3 +422,27 @@ class TestServing:
         after = {k for k in ex._jitted if k[0] == "fwd"}
         assert before == after, "bf16 hot-swap must not recompile"
         assert ex.arg_dict["fc_weight"].dtype == BF16
+
+
+# ---------------------------------------------------------------------------
+# what the step asks of every weight on every step: its dtype, and whether
+# that dtype carries a master (answers kept by dtype since PR 32)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype,low", [
+    ("float32", False), ("bfloat16", True), ("float16", True),
+    ("int32", False), ("uint8", False),
+])
+def test_dtype_and_low_precision_by_every_spelling(dtype, low):
+    want = np.dtype(dtype)
+    arr = mx.nd.zeros((2, 3), dtype=dtype)
+    assert arr.dtype == want and isinstance(arr.dtype, np.dtype)
+    assert arr.dtype.name == dtype
+    spellings = [dtype, want, want.type]
+    if dtype == "bfloat16":
+        spellings.append(jnp.bfloat16)
+    for spelling in spellings:
+        # asked twice: the first answer is kept, the second is the kept one
+        assert amp.is_low_precision(spelling) is low
+        assert amp.is_low_precision(spelling) is low
+    assert amp.is_low_precision([dtype]) is False      # unhashable
+    assert amp.is_low_precision("no_such_dtype") is False

@@ -1049,3 +1049,300 @@ class TestStateSplitTimeline:
                 mod.update()
         ctx = {"spans": spans, "traced_steps": 2}
         assert program_span_ms.read(ctx, spec["params"]) == 2 * count
+
+
+# ---------------------------------------------------------------------------
+# how the split leaves' gradients are exchanged (PR 32): in a row behind
+# backward on a CPU mesh, as the parent's program; around the ring of
+# ``parallel.mesh.matmul_wt`` on TPUs (forced here, where a test wants it)
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def ring_on_cpu(monkeypatch):
+    """``exchange_path`` answers ``async`` wherever it would answer ``row``
+    (the steering is the test's: the program has no switch); yields what it
+    was asked and the rings it traced."""
+    from mxnet_tpu.parallel import mesh as pmesh
+    real_path, real_ring = pmesh.exchange_path, pmesh._ring_reduce_scatter
+    asked, rings = [], []
+
+    def path(psh, ssh):
+        asked.append(real_path(psh, ssh))
+        return "async" if asked[-1] == "row" else asked[-1]
+
+    def ring(part, axis, name, order, *beside):
+        rings.append((tuple(part.shape), axis, order))
+        return real_ring(part, axis, name, order, *beside)
+
+    monkeypatch.setattr(pmesh, "exchange_path", path)
+    monkeypatch.setattr(pmesh, "_ring_reduce_scatter", ring)
+    return asked, rings
+
+
+class TestGradExchange:
+    _step = TestMeshStepTimeline._step
+
+    @pytest.mark.parametrize("ctxs,split,want", [
+        ([mx.cpu(0)], True, None),      # one device: nothing to exchange
+        (CTX4, False, None),            # no leaf is split over dp
+        (CTX4, True, "row"),            # split, on CPU devices
+    ], ids=["one_device", "replicated", "split"])
+    def test_counter_and_span_name_the_path(self, monkeypatch, ctxs, split,
+                                            want):
+        from mxnet_tpu.parallel import mesh as pmesh
+        if split:
+            monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+        telemetry.enable()
+        try:
+            was = {p: telemetry.value("grad_exchange_total", path=p)
+                   for p in ("row", "async")}
+            mod = _run(monkeypatch, ctxs, *SGD_MOM, steps=0)
+            by = {r.name: r for r in self._step(mod, 1)}
+            self._step(mod, 2)          # the same program: counted once
+            grew = {p: telemetry.value("grad_exchange_total", path=p)
+                    - was[p] for p in was}
+            assert grew == {"row": 1 if want else 0, "async": 0}
+            for span in ("Step::program", "Step::launch"):
+                assert by[span].args.get("exchange") == want, span
+            assert mod._fused_step._exchange == want
+        finally:
+            telemetry.disable()
+
+    def test_exchange_path_reads_the_layout(self):
+        """``async`` needs TPUs under weights that are whole on every
+        device; None where no state is split."""
+        from types import SimpleNamespace as NS
+        from jax.sharding import PartitionSpec as P
+        from mxnet_tpu.parallel.mesh import exchange_path
+
+        def sh(platform, whole, dp=4):
+            mesh = NS(devices=np.array([NS(platform=platform)] * 4),
+                      shape={"dp": dp, "tp": 4 // dp})
+            return NS(mesh=mesh, spec=P() if whole else P("tp", None))
+
+        assert exchange_path([sh("tpu", True)], None) is None
+        assert exchange_path([sh("tpu", True)] * 2,
+                             [sh("tpu", False)] * 2) == "async"
+        assert exchange_path([sh("tpu", True), sh("tpu", False)],
+                             [sh("tpu", False)] * 2) == "row"
+        assert exchange_path([sh("tpu", True, 2)],
+                             [sh("tpu", False, 2)]) == "row"
+        assert exchange_path([sh("cpu", True)], [sh("cpu", False)]) == "row"
+
+    @pytest.mark.parametrize("ctxs", [[mx.cpu(0)], CTX4],
+                             ids=["one_device", "cpu_mesh"])
+    def test_off_the_ring_the_product_is_the_plain_one(self, monkeypatch,
+                                                       split_small, ctxs):
+        """One device and a CPU mesh lower the program they lowered before
+        ``matmul_wt`` stood in the ops: the text with the op's product put
+        back to ``jnp.matmul(x, w.T)`` is the same text, and it holds no
+        permute."""
+        import hashlib
+        import jax
+        import jax.numpy as jnp
+        from mxnet_tpu.executor import Executor
+        from mxnet_tpu.ops import nn as ops_nn
+        real, texts = Executor.step_program, []
+
+        def step_program(self, *a, **k):
+            fn = real(self, *a, **k)
+
+            def call(*args):
+                texts.append(fn.lower(*args).as_text())
+                return fn(*args)
+            return call
+
+        monkeypatch.setattr(Executor, "step_program", step_program)
+        _run(monkeypatch, ctxs, *SGD_MOM, 1)
+        monkeypatch.setattr(ops_nn, "_matmul_wt",
+                            lambda x, w: jnp.matmul(x, w.T))
+        _run(monkeypatch, ctxs, *SGD_MOM, 1)
+        ours, plain = (hashlib.sha1(t.encode()).hexdigest() for t in texts)
+        assert ours == plain
+        assert "collective_permute" not in texts[0]
+
+    @pytest.mark.parametrize("shape", [(8, 4, 2), (16, 8, 8)],
+                             ids=["one_way", "both_ways"])
+    def test_ring_ends_where_one_device_ends(self, monkeypatch, split_small,
+                                             ring_on_cpu, shape):
+        """The ring forced onto the CPU mesh: two steps of SGD with
+        momentum end bit for bit where one device ends (sums of small
+        integers, whatever their order), both matrices' gradients came
+        round it, and the leaves lie as the row's program leaves them."""
+        asked, rings = ring_on_cpu
+        batch, feat, out = shape
+        mod4 = _run(monkeypatch, CTX4, *SGD_MOM, 2, batch=batch, feat=feat,
+                    out=out)
+        assert set(asked) == {"row"}
+        assert mod4._fused_step._exchange == "async"
+        # fc2 [out, 4] first (backward's order), then fc1 [4, feat]: split
+        # along the larger axis, slices of one row (one way) or two (both)
+        assert [r[:2] for r in rings] == [
+            ((out, 4), 0 if out >= 4 else 1), ((4, feat), 1 if feat > 4
+                                               else 0)]
+        large = ["fc1_weight", "fc2_weight"] + ["fc2_bias"] * (out >= 8)
+        TestStateSplitOverDp()._assert_layout(
+            mod4, large, sorted(set(mod4._param_names) - set(large)))
+        mod1 = _run(monkeypatch, [mx.cpu(0)], *SGD_MOM, 2, batch=batch,
+                    feat=feat, out=out)
+        _assert_bitexact(mod4, mod1)
+
+    def test_ring_under_adam_with_masters(self, monkeypatch, ring_on_cpu):
+        """bf16 weights under Adam, the gradients round the ring in bf16:
+        the state ends where the row's program ends, to the last bits of
+        a bf16 sum taken in another order."""
+        from mxnet_tpu import amp
+        from mxnet_tpu.parallel import mesh as pmesh
+        monkeypatch.setenv(amp.ENV_FLAG, "1")
+        monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+        okw = {"learning_rate": 0.01, "multi_precision": True}
+        ring = _run(monkeypatch, CTX4, "adam", okw, 3)
+        assert len(ring_on_cpu[1]) == 2
+        monkeypatch.undo()
+        monkeypatch.setenv(amp.ENV_FLAG, "1")
+        monkeypatch.setattr(pmesh, "STATE_SHARD_MIN_ELEMENTS", 8)
+        row = _run(monkeypatch, CTX4, "adam", okw, 3)
+        assert row._fused_step._exchange == "row"
+        _assert_close(ring, row, rtol=1e-2)
+        for name, got in _leaves_by_name(ring).items():
+            for j, (a, b) in enumerate(zip(got, _leaves_by_name(row)[name])):
+                assert a._data.sharding == b._data.sharding, (name, j)
+                np.testing.assert_allclose(     # a hop rounds to bf16
+                    a.asnumpy(), b.asnumpy(), rtol=1e-2, atol=1e-6,
+                    err_msg="%s[%d]" % (name, j))
+
+    def test_ring_program_permutes_and_holds_each_ring(self, monkeypatch,
+                                                       split_small,
+                                                       ring_on_cpu):
+        """The traced ring: a slice's halves meet at its owner over two
+        hops one way and one the other (six ``ppermute`` a leaf on four
+        devices), and two barriers a ring hold the order: the weight's
+        product (and the ring before) ahead of the input's, and the first
+        hop's end with the input's product."""
+        import jax
+        from mxnet_tpu.executor import Executor
+        real, seen = Executor.step_program, []
+
+        def step_program(self, *a, **k):
+            fn = real(self, *a, **k)
+
+            def call(*args):
+                seen.append(str(jax.make_jaxpr(fn)(*args)))
+                return fn(*args)
+            return call
+
+        monkeypatch.setattr(Executor, "step_program", step_program)
+        _run(monkeypatch, CTX4, *SGD_MOM, 1, batch=16, feat=8, out=8)
+        (text,) = seen
+        assert text.count("ppermute[") == 2 * 2 * (2 + 1)
+        assert text.count("optimization_barrier") == 2 * 2
+        # every gradient reaches its update already in the state's layout:
+        # no row of the parent's reduce-scatters is left to make
+        assert text.count("shard_map[") == 2
+
+    @pytest.mark.parametrize("coords,want", [
+        ([(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0)], (0, 1, 3, 2)),
+        ([(0, 0, 0), (1, 0, 0), (1, 1, 0), (0, 1, 0)], (0, 1, 2, 3)),
+        (None, (0, 1, 2, 3)),
+    ], ids=["2x2", "2x2_in_ring_order", "no_coords"])
+    def test_ring_order_follows_the_chips_links(self, coords, want):
+        from types import SimpleNamespace as NS
+        from mxnet_tpu.parallel.mesh import _ring_order
+        devs = [NS(coords=c) if coords else NS() for c in coords or [0] * 4]
+        mesh = NS(shape={"dp": 4}, axis_names=("dp",),
+                  devices=np.array(devs, dtype=object))
+        assert _ring_order(mesh, "dp") == want
+
+    @pytest.mark.parametrize("shape,axis,order", [
+        ((8, 6), 0, (0, 1, 2, 3)),      # slices of two rows: both ways
+        ((4, 6), 0, (0, 1, 3, 2)),      # of one row: one way
+        ((3, 16), 1, (0, 1, 3, 2)),
+        ((5, 8), 1, (0, 3, 1, 2)),
+        ((4, 3), 0, (1, 0)),            # two chips: one hop, no shorter way
+        ((3, 6), 1, (2, 0, 1)),
+        ((16, 3), 0, (0, 1, 3, 2, 6, 7, 5, 4)),     # eight: four hops
+    ])
+    def test_ring_reduce_scatter_is_the_sum(self, shape, axis, order):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, PartitionSpec as P
+        from mxnet_tpu.parallel.mesh import _ring_reduce_scatter
+        n = len(order)
+        mesh = Mesh(np.array(jax.devices()[:n]), ("dp",))
+        parts = np.random.RandomState(3).randint(
+            -8, 9, (n,) + shape).astype(np.float32)
+        got = jax.shard_map(
+            lambda p: _ring_reduce_scatter(p[0], axis, "dp", order),
+            mesh=mesh, in_specs=P("dp"),
+            out_specs=P(*("dp" if i == axis else None for i in range(2))),
+            check_vma=False)(jnp.asarray(parts))
+        assert np.array_equal(np.asarray(got), parts.sum(0))
+
+
+# ---------------------------------------------------------------------------
+# the host side of the step: what it asks of every leaf on every step
+# (PR 32: with the exchange beside backward the host's phases set the pace
+# of the four-chip cell, and a run's p95 follows whatever the host does)
+# ---------------------------------------------------------------------------
+class TestHostSideOfTheStep:
+    _step = TestMeshStepTimeline._step
+
+    @pytest.mark.parametrize("ctxs", [[mx.cpu(0)], CTX4],
+                             ids=["one_device", "mesh"])
+    def test_validate_reads_each_state_once(self, monkeypatch, ctxs):
+        """A mesh step's sibling slots hold the device-0 slot's own object:
+        ``Step::validate`` looks at it there and not once a device."""
+        mod = _run(monkeypatch, ctxs, *SGD_MOM, steps=1)
+        fs, ndev = mod._fused_step, len(ctxs)
+        states = mod._updater.states
+        assert len(states) == 4 * ndev
+        real, seen = opt.fused_state_leaves, []
+
+        def counted(st, mp=False):
+            seen.append(st)
+            return real(st, mp)
+
+        monkeypatch.setattr(opt, "fused_state_leaves", counted)
+        assert fs._states_fusable(ndev)
+        assert len(seen) == 4
+        self._step(mod, 3)
+        assert mod._fused_step._unsupported is False
+
+    @pytest.mark.parametrize("slot,fusable", [
+        (0, False),     # the device-0 slot itself
+        (1, False),     # a sibling that is no longer the alias
+        (None, True),
+    ], ids=["base", "sibling", "untouched"])
+    def test_validate_still_sees_a_foreign_state(self, monkeypatch, slot,
+                                                 fusable):
+        mod = _run(monkeypatch, CTX4, *SGD_MOM, steps=1)
+        states = mod._updater.states
+        assert states[1] is states[0]
+        if slot is not None:
+            states[slot] = "not a state"
+        assert mod._fused_step._states_fusable(4) is fusable
+
+    @pytest.mark.parametrize("ctxs", [[mx.cpu(0)], CTX4],
+                             ids=["one_device", "mesh"])
+    def test_small_inputs_are_host_arrays(self, monkeypatch, ctxs):
+        """The per-slot rates, decays and counts and the rescale reach the
+        program as float32 host arrays (the launch copies them; a device
+        array made on one device would be spread by a callback)."""
+        mod = _run(monkeypatch, ctxs, *SGD_MOM, steps=1)
+        ex = mod._exec_group.execs[0]
+        keys = [k for k in ex._jitted
+                if isinstance(k, tuple) and k and k[0] == "step"]
+        assert len(keys) == 1
+        real, got = ex._jitted[keys[0]], []
+
+        def spy(*args):
+            got.append(args[6:])
+            return real(*args)
+
+        ex._jitted[keys[0]] = spy
+        self._step(mod, 5)
+        (lrs, wds, ts, rescale), = got
+        for v, shape in ((lrs, (4,)), (wds, (4,)), (ts, (4,)),
+                         (rescale, ())):
+            assert type(v) is np.ndarray and v.dtype == np.float32
+            assert v.shape == shape
+        assert ts.tolist() == [2.0] * 4 and float(rescale) == 0.125

@@ -502,6 +502,10 @@ class TestEndToEnd:
         monkeypatch.setenv("MXNET_PS_URI", "127.0.0.1")
         monkeypatch.setenv("MXNET_PS_PORT", str(srv.port))
         monkeypatch.setenv("DMLC_NUM_WORKER", "2")
+        # the workers below write their rank into the process's environment:
+        # recorded here so that it is put back, and no later test file on
+        # this xdist worker (test_fleet, test_runlog) reads rank 1
+        monkeypatch.setenv("DMLC_WORKER_ID", "0")
         try:
             errs = []
 

@@ -11,6 +11,7 @@ module-scoped, non-autouse fixture, never at import, in a ``skipif`` or in
 ``parametrize``: only the process that runs these tests loads the TPU
 library, and it compiles in-process.
 """
+import collections
 import functools
 import re
 
@@ -269,7 +270,8 @@ def test_conv3x3_compiles(one_chip):
     assert _custom_calls(grad, x, w) == 3
 
 
-def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
+@pytest.mark.parametrize("exchange", ["row", "async"])
+def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch, exchange):
     """``gpt2m_train_dp4``'s step in small, ahead of time: a two-layer
     transformer's mesh fused step (Adam, bf16 weights with float32
     masters, the batch ``P('dp')``) compiled for the four described chips
@@ -286,7 +288,19 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
     through an all-to-all of the rows' gradients), no all-reduce left in
     the program carries a large gradient, and nothing else is gathered:
     the constraint on the gradient does not pull an activation or a
-    weight-gradient product into another partitioning."""
+    weight-gradient product into another partitioning.
+
+    ``row`` is that program as CPU meshes and meshes with a ``tp`` extent
+    build it (``exchange_path`` answered for here): the gradients'
+    ``all-reduce-scatter`` fusions, which block the core, stand in a row
+    behind the last backward product.  ``async`` is what the described
+    TPUs get by themselves: no such fusion is left for a weight that
+    ``matmul_wt`` multiplies; its gradient goes round the ring as
+    ``collective-permute-start`` / ``-done`` pairs of bf16 eighths (half a
+    quarter a way), six a leaf, nearly all of their bytes with a backward
+    product between start and done, none begun behind the last backward
+    product but the last ring's, and the updates follow their rings in
+    among backward's products."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import mxnet_tpu as mx
@@ -295,7 +309,13 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
     from mxnet_tpu.models.configs import TransformerConfig
     from mxnet_tpu.parallel.mesh import (STATE_SHARD_MIN_ELEMENTS,
                                          state_sharding)
+    from mxnet_tpu.parallel import mesh as pmesh
     monkeypatch.setenv(amp.ENV_FLAG, "1")
+    asked = []
+    real_path = pmesh.exchange_path
+    monkeypatch.setattr(
+        pmesh, "exchange_path",
+        lambda *a: asked.append(real_path(*a)) or exchange)
     B, T, V, D = 4, 128, 1001, 256
     mod = mx.mod.Module(
         transformer_lm(TransformerConfig("small", V, 2, D, 2, 4 * D, T),
@@ -345,6 +365,14 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
             [sds(o.shape, o.dtype) for o in ogs], vec, vec, vec,
             sds((), jnp.float32)).compile()
     hlo = compiled.as_text()
+    bodies = {m.group(1): m.group(0) for m in re.finditer(
+        r"^(%[\w.\-]+) \([^\n]*\) -> [^\n]*\{\n.*?^\}", hlo, re.M | re.S)}
+
+    def called(ln):
+        """The text of the computation a fusion calls."""
+        found = re.search(r"calls=(%[\w.\-]+)", ln)
+        return bodies.get(found.group(1), "") if found else ""
+
     # the new weights leave as they came: the held quarter of a large leaf
     new_p = compiled.output_shardings[0]
     assert [sh.spec for sh in new_p] == [sh.spec for sh in ssh]
@@ -366,6 +394,7 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
     weights = [shape for dt, shape in gathered.values() if dt == "bf16"]
     assert sorted(weights) == sorted(large), weights
     assert {dt for dt, _ in gathered.values()} <= {"bf16", "s32"}, gathered
+    assert asked == ["async"]       # the described chips, by themselves
     # reduce-scatters: each chip's quarter of every large gradient (the
     # token table's may come through the all-to-all instead)
     scattered = sorted(
@@ -373,11 +402,13 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
         if "calls=%all-reduce-scatter" in ln or " reduce-scatter(" in ln
         for _, shape in results(ln))
     quarter = sorted(int(np.prod(s)) // 4 for s in large)
-    if len(scattered) == len(large) - 1:
-        assert " all-to-all(" in hlo
-        quarter.remove(V * D // 4)
-    assert len(scattered) == len(quarter), scattered
-    assert all(q <= g <= 1.1 * q for g, q in zip(scattered, quarter))
+    if exchange == "row":
+        if len(scattered) == len(large) - 1:
+            assert " all-to-all(" in hlo
+            quarter.remove(V * D // 4)
+        assert len(scattered) == len(quarter), scattered
+        assert all(q <= g <= 1.1 * q for g, q in zip(scattered, quarter))
+        assert "collective-permute-start" not in hlo
     # the yardstick's scopes (``optimizer_ms.train`` reads the device time
     # under ``Optimizer::``): every update fusion, which writes a chip's
     # quarter of the float32 master, mean and variance of a large leaf,
@@ -413,11 +444,15 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
         elif ln in updates:
             at["update"].append(i)
         elif re.search(r"transpose\(jvp\((FullyConnected|MultiHead)", ln):
-            at["backward"].append(i)
+            if re.search(r" (fusion|convolution|custom-call)\(", ln) \
+                    and results(ln)[0][0] != "s32":
+                at["backward"].append(i)    # work, not a hoisted index
         elif re.search(r"jvp\((FullyConnected|MultiHeadAttention)", ln):
             at["forward"].append(i)
     assert len(at["gather"]) == len(large), at["gather"]
-    assert max(at["gather"]) < min(at["backward"]) < min(at["update"])
+    assert max(at["gather"]) < min(at["backward"])
+    if exchange == "row":
+        assert min(at["backward"]) < min(at["update"])
     assert max(at["gather"]) < max(at["forward"])
     # every weight the forward products read is a gathered one
     names = {re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = ", entry[i]).group(1)
@@ -437,3 +472,41 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch):
                for r in results(ln)]
     assert reduced and all(int(np.prod(shape)) < STATE_SHARD_MIN_ELEMENTS
                            for _, shape in reduced), reduced
+    if exchange == "row":
+        return
+    # the ring: every weight ``matmul_wt`` multiplies (all but the two
+    # embedding tables) sends and receives half a quarter a hop, bf16 as
+    # the row's fusions exchange it, two hops one way and one the other
+    ringed = [(s, sh) for n, s, sh in zip(pnames, shapes, ssh)
+              if sh is not repl and "embedding" not in n]
+    assert len(ringed) == len(large) - 1        # all but the token table
+    assert len(scattered) <= 1, scattered
+    eighth = collections.Counter()
+    for s, sh in ringed:
+        q = list(sh.shard_shape(s))
+        q[list(sh.spec).index("dp")] //= 2
+        eighth[("bf16", tuple(q))] += 6
+    starts = {re.match(r"\s*%([\w.\-]+) = ", ln).group(1): (i, results(ln)[0])
+              for i, ln in enumerate(entry)
+              if " collective-permute-start(" in ln}
+    assert collections.Counter(r for _, r in starts.values()) == eighth
+    dones = {re.search(r"collective-permute-done\(%([\w.\-]+)\)",
+                       ln).group(1): i
+             for i, ln in enumerate(entry)
+             if " collective-permute-done(" in ln}
+    assert sorted(dones) == sorted(starts)
+    products = [i for i, ln in enumerate(entry)
+                if re.search(r"transpose\(jvp\((FullyConnected|MultiHead)",
+                             ln)
+                and (" custom-call(" in ln or " convolution(" in ln
+                     or "convolution(" in called(ln))]
+    nbytes = covered = late = 0
+    for name, (i, (_, shape)) in starts.items():
+        size = 2 * int(np.prod(shape))
+        nbytes += size
+        covered += size * any(i < j < dones[name] for j in products)
+        late += size * (i > max(products))
+    assert covered >= 0.8 * nbytes, (covered, nbytes)
+    assert late <= 0.1 * nbytes, (late, nbytes)
+    # ... and the updates follow their rings: in among backward's products
+    assert any(min(products) < i < max(products) for i in at["update"])
