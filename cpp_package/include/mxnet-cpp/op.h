@@ -698,6 +698,12 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
     Symbol out_proj_weight,
     Symbol q_norm_gamma,
     Symbol k_norm_gamma,
+    Symbol q_a_weight,
+    Symbol q_a_norm_gamma,
+    Symbol q_b_weight,
+    Symbol kv_a_weight,
+    Symbol kv_a_norm_gamma,
+    Symbol kv_b_weight,
     int num_heads,
     bool causal = true,
     int num_kv_heads = 0,
@@ -706,7 +712,11 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
     double eps = 1e-05,
     int head_dim = 0,
     int window = 0,
-    const std::string &rope_yarn = "()") {
+    const std::string &rope_yarn = "()",
+    int q_lora_rank = 0,
+    int kv_lora_rank = 0,
+    int qk_rope_head_dim = 0,
+    int v_head_dim = 0) {
   SymbolBuilder builder_("MultiHeadAttention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
@@ -717,6 +727,10 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
   builder_.SetParam("head_dim", head_dim);
   builder_.SetParam("window", window);
   builder_.SetParam("rope_yarn", rope_yarn);
+  builder_.SetParam("q_lora_rank", q_lora_rank);
+  builder_.SetParam("kv_lora_rank", kv_lora_rank);
+  builder_.SetParam("qk_rope_head_dim", qk_rope_head_dim);
+  builder_.SetParam("v_head_dim", v_head_dim);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);
@@ -724,6 +738,12 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
   builder_.SetInput("out_proj_weight", out_proj_weight);
   builder_.SetInput("q_norm_gamma", q_norm_gamma);
   builder_.SetInput("k_norm_gamma", k_norm_gamma);
+  builder_.SetInput("q_a_weight", q_a_weight);
+  builder_.SetInput("q_a_norm_gamma", q_a_norm_gamma);
+  builder_.SetInput("q_b_weight", q_b_weight);
+  builder_.SetInput("kv_a_weight", kv_a_weight);
+  builder_.SetInput("kv_a_norm_gamma", kv_a_norm_gamma);
+  builder_.SetInput("kv_b_weight", kv_b_weight);
   return builder_.CreateSymbol(symbol_name);
 }
 
@@ -1131,7 +1151,9 @@ inline Symbol SparseMoE(const std::string &symbol_name,
     int num_hidden,
     int num_held = 0,
     int expert_offset = 0,
-    const std::string &score = "sigmoid") {
+    const std::string &score = "sigmoid",
+    double routed_scaling = 1.0,
+    double weight_eps = 1e-06) {
   SymbolBuilder builder_("SparseMoE");
   builder_.SetParam("num_experts", num_experts);
   builder_.SetParam("num_experts_per_tok", num_experts_per_tok);
@@ -1139,6 +1161,8 @@ inline Symbol SparseMoE(const std::string &symbol_name,
   builder_.SetParam("num_held", num_held);
   builder_.SetParam("expert_offset", expert_offset);
   builder_.SetParam("score", score);
+  builder_.SetParam("routed_scaling", routed_scaling);
+  builder_.SetParam("weight_eps", weight_eps);
   builder_.SetInput("data", data);
   builder_.SetInput("router_weight", router_weight);
   builder_.SetInput("expert_bias", expert_bias);
@@ -4242,6 +4266,12 @@ inline Symbol multiheadattention(const std::string &symbol_name,
     Symbol out_proj_weight,
     Symbol q_norm_gamma,
     Symbol k_norm_gamma,
+    Symbol q_a_weight,
+    Symbol q_a_norm_gamma,
+    Symbol q_b_weight,
+    Symbol kv_a_weight,
+    Symbol kv_a_norm_gamma,
+    Symbol kv_b_weight,
     int num_heads,
     bool causal = true,
     int num_kv_heads = 0,
@@ -4250,7 +4280,11 @@ inline Symbol multiheadattention(const std::string &symbol_name,
     double eps = 1e-05,
     int head_dim = 0,
     int window = 0,
-    const std::string &rope_yarn = "()") {
+    const std::string &rope_yarn = "()",
+    int q_lora_rank = 0,
+    int kv_lora_rank = 0,
+    int qk_rope_head_dim = 0,
+    int v_head_dim = 0) {
   SymbolBuilder builder_("multiheadattention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
@@ -4261,6 +4295,10 @@ inline Symbol multiheadattention(const std::string &symbol_name,
   builder_.SetParam("head_dim", head_dim);
   builder_.SetParam("window", window);
   builder_.SetParam("rope_yarn", rope_yarn);
+  builder_.SetParam("q_lora_rank", q_lora_rank);
+  builder_.SetParam("kv_lora_rank", kv_lora_rank);
+  builder_.SetParam("qk_rope_head_dim", qk_rope_head_dim);
+  builder_.SetParam("v_head_dim", v_head_dim);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);
@@ -4268,6 +4306,12 @@ inline Symbol multiheadattention(const std::string &symbol_name,
   builder_.SetInput("out_proj_weight", out_proj_weight);
   builder_.SetInput("q_norm_gamma", q_norm_gamma);
   builder_.SetInput("k_norm_gamma", k_norm_gamma);
+  builder_.SetInput("q_a_weight", q_a_weight);
+  builder_.SetInput("q_a_norm_gamma", q_a_norm_gamma);
+  builder_.SetInput("q_b_weight", q_b_weight);
+  builder_.SetInput("kv_a_weight", kv_a_weight);
+  builder_.SetInput("kv_a_norm_gamma", kv_a_norm_gamma);
+  builder_.SetInput("kv_b_weight", kv_b_weight);
   return builder_.CreateSymbol(symbol_name);
 }
 
@@ -5247,7 +5291,9 @@ inline Symbol sparsemoe(const std::string &symbol_name,
     int num_hidden,
     int num_held = 0,
     int expert_offset = 0,
-    const std::string &score = "sigmoid") {
+    const std::string &score = "sigmoid",
+    double routed_scaling = 1.0,
+    double weight_eps = 1e-06) {
   SymbolBuilder builder_("sparsemoe");
   builder_.SetParam("num_experts", num_experts);
   builder_.SetParam("num_experts_per_tok", num_experts_per_tok);
@@ -5255,6 +5301,8 @@ inline Symbol sparsemoe(const std::string &symbol_name,
   builder_.SetParam("num_held", num_held);
   builder_.SetParam("expert_offset", expert_offset);
   builder_.SetParam("score", score);
+  builder_.SetParam("routed_scaling", routed_scaling);
+  builder_.SetParam("weight_eps", weight_eps);
   builder_.SetInput("data", data);
   builder_.SetInput("router_weight", router_weight);
   builder_.SetInput("expert_bias", expert_bias);

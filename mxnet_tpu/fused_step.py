@@ -24,7 +24,9 @@ callers (``set_params``, ``__setitem__``, ``set_states``).  ``DonationPool``
 therefore tracks, per logical slot, the exact jax array the fused program
 last produced; anything else found in the handle is defensively copied
 before being donated, so no caller-held buffer is ever invalidated and no
-donated buffer is ever double-used.
+donated buffer is ever double-used.  The copy takes the original's place
+in the handle as it is made, so the original lives only as long as
+whoever else holds it: the first step's peak is the state once and a leaf.
 """
 from __future__ import annotations
 
@@ -121,7 +123,8 @@ class DonationPool:
     current array if this pool produced it (nobody else can hold it — the
     program output went straight into the handle), else a fresh copy
     (externally written handles may share their buffer with caller-held
-    arrays via no-op device_put/astype/broadcast_to).  It has
+    arrays via no-op device_put/astype/broadcast_to), which the handle
+    holds and the pool owns from then on (``_adopt``).  It has
     ``take_sharded``'s signature (its ``sharding`` is None: the array stays
     on its device), so a step binds one of the two once, outside its loops.
     ``give`` writes a
@@ -145,17 +148,30 @@ class DonationPool:
             DONATION_COPIES.labels(path=path).inc()
             DONATION_COPY_BYTES.labels(path=path).inc(nbytes)
 
+    def _adopt(self, slot, handle, copy):
+        """A copy the pool made of a handle it did not own goes into the
+        handle and into the ledger AT ONCE: the original then dies with
+        its last outside holder, leaf by leaf, and not at ``give``, after
+        the step, when every leaf would have been held twice (the first
+        fused step's peak was the weights and the whole optimizer state
+        two times over).  The copy is waited for, so that the original's
+        memory is free before the next leaf's copy asks for its own."""
+        handle._data = self._own[slot] = copy
+        copy.block_until_ready()
+        return copy
+
     def take(self, slot, handle, sharding):
         cur = handle._data
         if self._own.get(slot) is not cur:
             self.count_copy("fused", cur)
-            cur = jnp.array(cur)
+            cur = self._adopt(slot, handle, jnp.array(cur))
         return cur
 
     def take_sharded(self, slot, handle, sharding):
         """Donation-safe buffer for a mesh slot: the handle's array when
         pool-owned AND already laid out as ``sharding``; otherwise a
-        genuine copy placed onto the mesh.  The copy must be
+        genuine copy placed onto the mesh, which the handle holds from
+        then on (``_adopt``).  The copy must be
         ``jnp.array`` — ``jax.device_put`` may alias its input (even with
         ``may_alias=False`` on CPU), and donating an alias would delete
         the caller-held source buffer."""
@@ -164,7 +180,8 @@ class DonationPool:
                 getattr(cur, "sharding", None) == sharding:
             return cur
         self.count_copy("mesh_fused", cur)
-        return jax.device_put(jnp.array(cur), sharding)
+        return self._adopt(slot, handle,
+                           jax.device_put(jnp.array(cur), sharding))
 
     def give(self, slot, handle, new_data):
         self._own[slot] = new_data
@@ -455,6 +472,9 @@ class ModuleFusedStep:
         with _span("Step::slots", {"params": len(self._pnames)}):
             slots = self._slots(ex, len(execs))
         pvals, svals, taken = [], [], []    # taken: (handle, leaves, mp)
+        # a copied leaf goes into its handle at once (``DonationPool._adopt``):
+        # from here on the handles may hold mesh globals
+        self._meshed = self._meshed or place.mesh is not None
         with _gather_span(pool) as args:
             placed = pool.copies
             arg_dict, views = ex.arg_dict, [e.arg_dict for e in rest]
@@ -469,7 +489,12 @@ class ModuleFusedStep:
                         break
                 # the weight is held between steps in its state's layout
                 # (the program gathers it at its top): taken as given
-                pvals.append(take(("w", name), handle, ssh))
+                w = take(("w", name), handle, ssh)
+                if w is not data:
+                    # copied: every exec's view lets go of its original now
+                    for view in views:
+                        view[name]._data = w
+                pvals.append(w)
                 # mp slots: leaf 0 is the master-fp32 copy — same shape as
                 # the param, so it takes the state's layout like every
                 # moment
@@ -478,6 +503,7 @@ class ModuleFusedStep:
                 svals.append(tuple([take(("s", slot, j), leaf, ssh)
                                     for j, leaf in enumerate(leaves)]))
                 taken.append((handle, leaves, mp))
+            data = None     # the last leaf's original dies here too
             if self._split is None or pool.copies != placed:
                 # counted when a leaf was placed (the first step, a state
                 # set from outside), not on every step

@@ -33,7 +33,16 @@ class TransformerConfig:
     (``num_experts`` > 0): ``experts_held`` of them from ``expert_offset``
     live in this graph (0: all), each ``moe_d_ff`` wide, ``experts_per_tok``
     a token, scored by ``moe_score`` (``sigmoid`` under a selection bias |
-    ``softmax``)."""
+    ``softmax``), the weights of the selected over ``sum + moe_weight_eps``
+    times ``routed_scaling``, and beside them ``n_shared_experts`` experts of
+    the same width that every token takes with weight 1.  ``attention`` =
+    ``latent`` gives every attention layer low-rank queries
+    (``q_lora_rank``) and keys/values (``kv_lora_rank``) and heads of
+    ``qk_nope_head_dim`` dims without position and ``qk_rope_head_dim``
+    rotated ones, values of ``v_head_dim`` (``MultiHeadAttention``'s latent
+    form).  ``mtp_layers`` = 1 adds DeepSeek-V3's multi-token-prediction
+    module after the last block (``transformer_lm``), its loss weighted by
+    ``mtp_loss_weight``."""
     name: str
     vocab_size: int
     n_layers: int
@@ -61,15 +70,28 @@ class TransformerConfig:
     window: int = 0
     rope: tuple = ()
     moe_score: str = "sigmoid"
+    attention: str = "full"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    n_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    moe_weight_eps: float = 1e-6
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.0
 
     def __post_init__(self):
-        if not self.head_dim and self.d_model % self.n_heads:
+        if not self.head_dim and self.attention != "latent" \
+                and self.d_model % self.n_heads:
             raise ValueError("d_model %d not divisible by n_heads %d"
                              % (self.d_model, self.n_heads))
         for field, known in (("norm", ("layer", "rms")),
                              ("position", ("learned", "rope")),
                              ("ffn", ("gelu", "swiglu")),
-                             ("moe_score", ("sigmoid", "softmax"))):
+                             ("moe_score", ("sigmoid", "softmax")),
+                             ("attention", ("full", "latent"))):
             if getattr(self, field) not in known:
                 raise ValueError("%s %r is not one of %s"
                                  % (field, getattr(self, field), known))
@@ -86,6 +108,21 @@ class TransformerConfig:
             if not theta > 0 or len(yarn) not in (0, 5):
                 raise ValueError("rope of %r is not a theta and a yarn of "
                                  "none or five: %r, %r" % (kind, theta, yarn))
+
+        if self.attention == "latent" and (
+                self.position != "rope" or self.layer_types
+                or self.n_kv_heads or self.qk_norm or self.head_dim
+                or min(self.q_lora_rank, self.kv_lora_rank,
+                       self.qk_nope_head_dim, self.qk_rope_head_dim) < 1):
+            raise ValueError(
+                "latent attention takes rotary positions, its two ranks and "
+                "the head's two parts, and no layer pattern, grouped heads, "
+                "per-head norms or head_dim (the head is qk_nope_head_dim + "
+                "qk_rope_head_dim)")
+        if self.mtp_layers not in (0, 1) or (
+                self.mtp_layers and self.layer_types):
+            raise ValueError("one multi-token-prediction module or none, "
+                             "and none under a layer pattern")
 
     def rope_of(self, kind: str):
         """(theta, yarn) the layers of ``kind`` turn their heads by."""

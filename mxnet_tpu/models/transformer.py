@@ -43,7 +43,18 @@ import jax
 import jax.numpy as jnp
 
 from .configs import TransformerConfig
+from .. import telemetry as _telemetry
 from ..ops.registry import OPS
+
+# counted where the graph is built (``transformer_lm``): a shared expert and
+# the prediction module are graph structure, no op of their own to count in
+_MOE_SHARED = _telemetry.counter(
+    "moe_shared_experts_total",
+    "Shared experts (a gated feed-forward every token takes beside the "
+    "routed experts) in the transformer graphs built, a layer each")
+_MTP_MODULES = _telemetry.counter(
+    "mtp_modules_total",
+    "Multi-token-prediction modules in the transformer graphs built")
 
 
 # ---------------------------------------------------------------------------
@@ -61,24 +72,42 @@ def _feed_forward(h, cfg: TransformerConfig, idx: int, n: str):
     layer ``num_dense_layers`` on where the config has experts, else the
     dense feed-forward of the config's kind."""
     from .. import symbol as sym
+
+    def swiglu(width, stem):
+        gate = sym.FullyConnected(h, num_hidden=width, flatten=False,
+                                  no_bias=True, name=stem + "gate")
+        up = sym.FullyConnected(h, num_hidden=width, flatten=False,
+                                no_bias=True, name=stem + "up")
+        f = sym.elemwise_mul(
+            sym.Activation(gate, act_type="silu", name=stem + "silu"), up,
+            name=stem + "gated")
+        return sym.FullyConnected(f, num_hidden=cfg.d_model, flatten=False,
+                                  no_bias=True, name=stem + "down")
+
     if cfg.num_experts and idx >= cfg.num_dense_layers:
-        return sym.SparseMoE(
+        variants = {} if cfg.moe_score == "sigmoid" \
+            else {"score": cfg.moe_score}
+        if cfg.routed_scaling != 1.0:
+            variants["routed_scaling"] = cfg.routed_scaling
+        if cfg.moe_weight_eps != 1e-6:
+            variants["weight_eps"] = cfg.moe_weight_eps
+        routed = sym.SparseMoE(
             h, num_experts=cfg.num_experts,
             num_experts_per_tok=cfg.experts_per_tok,
             num_hidden=cfg.moe_d_ff, num_held=cfg.experts_held,
-            expert_offset=cfg.expert_offset, name=n + "moe",
-            **({} if cfg.moe_score == "sigmoid"
-               else {"score": cfg.moe_score}))
+            expert_offset=cfg.expert_offset, name=n + "moe", **variants)
+        if not cfg.n_shared_experts:
+            return routed
+        # the shared experts: one gated feed-forward of their joint width
+        # that every token takes with weight 1, beside the routed sum
+        # (every holder of the layer's experts computes it alike)
+        if _telemetry.enabled:
+            _MOE_SHARED.inc(cfg.n_shared_experts)
+        return sym.elemwise_add(
+            routed, swiglu(cfg.moe_d_ff * cfg.n_shared_experts,
+                           n + "shared_"), name=n + "shared_sum")
     if cfg.ffn == "swiglu":
-        gate = sym.FullyConnected(h, num_hidden=cfg.d_ff, flatten=False,
-                                  no_bias=True, name=n + "ffn_gate")
-        up = sym.FullyConnected(h, num_hidden=cfg.d_ff, flatten=False,
-                                no_bias=True, name=n + "ffn_up")
-        f = sym.elemwise_mul(
-            sym.Activation(gate, act_type="silu", name=n + "ffn_silu"), up,
-            name=n + "ffn_gated")
-        return sym.FullyConnected(f, num_hidden=cfg.d_model, flatten=False,
-                                  no_bias=True, name=n + "ffn_down")
+        return swiglu(cfg.d_ff, n + "ffn_")
     f = sym.FullyConnected(h, num_hidden=cfg.d_ff, flatten=False,
                            no_bias=False, name=n + "ffn_fc1")
     f = sym.Activation(f, act_type="gelu", name=n + "ffn_gelu")
@@ -86,20 +115,33 @@ def _feed_forward(h, cfg: TransformerConfig, idx: int, n: str):
                               no_bias=False, name=n + "ffn_down")
 
 
-def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str):
+def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str,
+                      stem: Optional[str] = None):
     """One pre-norm decoder block: x + Mix(Norm(x)); x + FFN(Norm(x)).
     ``Mix`` is attention, or the gated short convolution where
     ``cfg.layer_types[idx]`` says ``conv``.  A ``sliding_attention``
     layer's node is ``<prefix>l<idx>_swa`` (a device trace's scopes then
     tell the two kinds of attention apart) and its weights keep the
-    ``<prefix>l<idx>_attn_`` names of every attention layer."""
+    ``<prefix>l<idx>_attn_`` names of every attention layer; a latent
+    layer's node is ``<prefix>l<idx>_mla``, its weights ``..._mla_*``.
+    ``stem`` names the block's nodes in place of ``<prefix>l<idx>_`` (the
+    prediction module's block; ``idx`` still says which feed-forward)."""
     from .. import symbol as sym
-    n = "%sl%d_" % (prefix, idx)
+    n = stem or "%sl%d_" % (prefix, idx)
     h = _norm(x, cfg, n + "ln1")
     kind = cfg.layer_types[idx] if cfg.layer_types else "full_attention"
     if kind == "conv":
         a = sym.ShortConv(h, kernel=cfg.conv_kernel, name=n + "conv")
         x = sym.elemwise_add(x, a, name=n + "conv_res")
+    elif cfg.attention == "latent":
+        a = sym.MultiHeadAttention(
+            h, num_heads=cfg.n_heads, causal=True, name=n + "mla",
+            head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+            v_head_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta,
+            eps=cfg.norm_eps)
+        x = sym.elemwise_add(x, a, name=n + "attn_res")
     else:
         variants = {}
         if cfg.n_kv_heads and cfg.n_kv_heads != cfg.n_heads:
@@ -149,8 +191,11 @@ def transformer_lm(cfg: TransformerConfig, prefix: str = "tfm_",
     """
     from .. import symbol as sym
     data = sym.Variable("data")                       # (B, T) token ids
+    mtp = bool(cfg.mtp_layers) and loss
     table = {"weight": sym.Variable(prefix + "tok_embedding_weight")} \
-        if cfg.tie_head else {}
+        if cfg.tie_head or mtp else {}
+    head = table if cfg.tie_head else \
+        {"weight": sym.Variable(prefix + "lm_head_weight")} if mtp else {}
     x = sym.Embedding(data, input_dim=cfg.vocab_size,
                       output_dim=cfg.d_model,
                       name=prefix + "tok_embedding", **table)
@@ -169,16 +214,60 @@ def transformer_lm(cfg: TransformerConfig, prefix: str = "tfm_",
         x = sym.broadcast_add(x, pos, name=prefix + "embed_sum")
     for i in range(cfg.n_layers):
         x = transformer_block(x, cfg, i, prefix)
+    last = x                                  # before the final norm
     x = _norm(x, cfg, prefix + "final_ln")
     logits = sym.FullyConnected(x, num_hidden=cfg.vocab_size,
                                 flatten=False, no_bias=True,
-                                name=prefix + "lm_head", **table)
+                                name=prefix + "lm_head", **head)
     if not loss:
         return logits
     label = sym.Variable("softmax_label")             # (B, T) next ids
     ce = sym.streaming_softmax_ce(logits, label, axis=-1,
                                   name=prefix + "ce")
-    return sym.make_loss(sym.mean(ce), name=prefix + "loss")
+    if not mtp:
+        return sym.make_loss(sym.mean(ce), name=prefix + "loss")
+    ce2 = _prediction_module(last, label, cfg, prefix + "mtp0_", table, head)
+    total = sym.mean(ce) + cfg.mtp_loss_weight * sym.mean(ce2)
+    return sym.make_loss(total, name=prefix + "loss")
+
+
+def _prediction_module(last, label, cfg: TransformerConfig, n: str, table,
+                       head):
+    """DeepSeek-V3's multi-token-prediction module (one depth), every node
+    under ``n`` = ``<prefix>mtp0_``: with ``x_i`` the last block's output
+    before the final norm and ``t_{i+1}`` the step's label at ``i``,
+
+        u_i = M [RMSNorm(x_i; g_h); RMSNorm(E[t_{i+1}]; g_e)]
+        z = Block(u)      (a block of the expert kind, its own weights)
+        logits = W_out RMSNorm(z; g_f')
+
+    with the MAIN embedding ``E`` and head ``W_out`` (``table``, ``head``:
+    one graph variable each, whose gradient is the sum of its uses), against
+    the target ``t_{i+2}``: the label shifted by one inside the graph.  The
+    last position has no such target: the module runs over all ``T``
+    positions (the kernels keep their shapes) and the returned
+    cross-entropies are of the first ``T - 1``."""
+    from .. import symbol as sym
+    if _telemetry.enabled:
+        _MTP_MODULES.inc()
+    e = sym.Embedding(label, input_dim=cfg.vocab_size,
+                      output_dim=cfg.d_model, name=n + "embedding", **table)
+    u = sym.concat(_norm(last, cfg, n + "hnorm"), _norm(e, cfg, n + "enorm"),
+                   dim=2, name=n + "cat")
+    u = sym.FullyConnected(u, num_hidden=cfg.d_model, flatten=False,
+                           no_bias=True, name=n + "proj")
+    z = transformer_block(u, cfg, cfg.n_layers, n, stem=n)
+    logits = sym.FullyConnected(
+        _norm(z, cfg, n + "final_ln"), num_hidden=cfg.vocab_size,
+        flatten=False, no_bias=True, name=n + "head", **head)
+    # position i's target is the label of position i + 1; the last
+    # position's slot takes the first label and is cut off below
+    target = sym.concat(
+        sym.slice_axis(label, axis=1, begin=1, end=None),
+        sym.slice_axis(label, axis=1, begin=0, end=1), dim=1,
+        name=n + "target")
+    ce = sym.streaming_softmax_ce(logits, target, axis=-1, name=n + "ce")
+    return sym.slice_axis(ce, axis=1, begin=0, end=-1, name=n + "ce_seen")
 
 
 # ---------------------------------------------------------------------------

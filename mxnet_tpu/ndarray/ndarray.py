@@ -620,7 +620,14 @@ def imperative_invoke(op_name, *args, **kwargs):
         for k in kw_arrays:
             kwargs.pop(k)
         if op.arg_names:
-            slots = {n: i for i, n in enumerate(op.arg_names)}
+            # the inputs this call's attrs leave switched on, as a Symbol
+            # node of the op has them
+            from ..symbol.symbol import _arg_names
+            names = _arg_names(op, {k: v for k, v in kwargs.items()
+                                    if k not in ("out", "name", "ctx")})
+            if any(k in op.arg_names and k not in names for k in kw_arrays):
+                names = op.arg_names
+            slots = {n: i for i, n in enumerate(names)}
             hi = max((slots.get(k, -1) for k in kw_arrays), default=-1)
             ins = list(inputs) + [None] * max(0, hi + 1 - len(inputs))
             for k, v in kw_arrays.items():
@@ -638,8 +645,8 @@ def imperative_invoke(op_name, *args, **kwargs):
             if any(v is None for v in ins):
                 raise MXNetError(
                     "op %s: missing input(s) %s" % (op_name, [
-                        op.arg_names[i] for i, v in enumerate(ins)
-                        if v is None]))
+                        names[i] if i < len(names) else i
+                        for i, v in enumerate(ins) if v is None]))
             inputs = ins
         else:
             inputs.extend(kw_arrays.values())

@@ -595,6 +595,18 @@ def _kernel_or_reference(q, k, v, causal, scale, interpret, window=None):
     return jax.lax.platform_dependent(q, k, v, tpu=flash, default=reference)
 
 
+#: what a head's keys and values may take of VMEM, double-buffered, for
+#: ``MultiHeadAttention`` to call the flash kernels: 8 MB, 4,096 positions
+#: at a head of 256 (latent attention's) and 8,192 at 128.  The kernels ask
+#: for 32 MB of the v5e's 128 (``pallas_attention._PARALLEL``) and hold, a
+#: program, one head's K and V whole (4 MB at that shape, twice for the
+#: pipeline), a 512-block of q, o and their float32 accumulators; ring
+#: attention, whose competitor is the scan and not a T x T tensor in HBM,
+#: keeps ``kv_fits_vmem``'s own 5 MB.  Measured on the v5e
+#: (``tools/bench_attention_arms.py``, PERF.md, PR 33).
+MHA_KV_VMEM = 8 << 20
+
+
 def mha_uses_kernel(B, H, T, d, dtype):
     """MultiHeadAttention's own shape test: does the flash kernel beat
     ``_mha_reference`` on a device that holds ``B`` rows of ``H`` heads?
@@ -617,7 +629,7 @@ def mha_uses_kernel(B, H, T, d, dtype):
     probabilities of every layer for backward, which no VMEM holds: at the
     GPT-2 cells' shape it cost 0.55 ms a layer there, not 0.35.)"""
     from . import pallas_attention as pa
-    if d % 8 or T % 128 or not pa.kv_fits_vmem(T, d, dtype):
+    if d % 8 or T % 128 or not pa.kv_fits_vmem(T, d, dtype, MHA_KV_VMEM):
         return False
     # pa.INTERPRET is the tests' hook: any shape the kernel can run
     return pa.INTERPRET or B * H * T * T * 4 >= 64 << 20
@@ -632,9 +644,12 @@ def mha_uses_kernel(B, H, T, d, dtype):
                   "eps": param(float, 1e-5),
                   "head_dim": param(int, 0),
                   "window": param(int, 0),
-                  "rope_yarn": param("floats", ())})
-def _multi_head_attention(attrs, data, query_weight, key_weight,
-                          value_weight, out_proj_weight, *qk_gammas):
+                  "rope_yarn": param("floats", ()),
+                  "q_lora_rank": param(int, 0),
+                  "kv_lora_rank": param(int, 0),
+                  "qk_rope_head_dim": param(int, 0),
+                  "v_head_dim": param(int, 0)})
+def _multi_head_attention(attrs, data, *weights):
     """Decoder attention: QKV projections, scaled-dot-product over
     ``num_heads``, output projection.  No reference analog — the
     reference predates transformer first-class ops; the contract follows
@@ -673,12 +688,20 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
     them with zero extra configuration: query/key/value_weight match the
     column-parallel rule (P(t, None)), out_proj_weight the row-parallel
     rule (P(None, t)).
+
+    ``kv_lora_rank`` > 0 is latent attention (DeepSeek-V2's; GLM-4.7-Flash):
+    other inputs and other projections in front of the same dispatch,
+    ``_latent_heads``.
     """
-    from . import pallas_attention as pa
     if data.ndim != 3:
         raise MXNetError(
             "MultiHeadAttention: data must be (batch, time, model_dim), "
             "got %s" % (data.shape,))
+    if attrs.get("kv_lora_rank"):
+        return _attend(attrs, *_latent_heads(attrs, data, *weights[:-1]),
+                       weights[-1], None)
+    query_weight, key_weight, value_weight, out_proj_weight, *qk_gammas = \
+        weights
     B, T, D = data.shape
     H = attrs["num_heads"]
     d = attrs.get("head_dim") or 0
@@ -700,7 +723,6 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
             % len(qk_gammas))
     d = d or D // H
     causal = attrs["causal"]
-    scale = 1.0 / (d ** 0.5)
     window, yarn = attrs.get("window") or None, attrs.get("rope_yarn") or ()
     if window and not causal:
         raise MXNetError("MultiHeadAttention: a window needs causal=True")
@@ -725,8 +747,18 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
         q, k = _rotary(q, theta, yarn), _rotary(k, theta, yarn)
     if Hkv != H:
         k, v = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
+    return _attend(attrs, q, k, v, out_proj_weight, window)
 
-    if mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype):
+
+def _attend(attrs, q, k, v, out_proj_weight, window):
+    """The op's dispatch over finished heads [B,H,T,d] (``v`` may have a
+    size of its own on the XLA arm), its counters, and the output
+    projection."""
+    from . import pallas_attention as pa
+    B, H, T, d = q.shape
+    causal, scale = attrs["causal"], 1.0 / (d ** 0.5)
+    if v.shape[-1] == d and \
+            mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype):
         # test hook (pa.INTERPRET): force the interpreter on CPU
         path = ("flash" if window is None else "flash_window") \
             + ("_interpret" if pa.INTERPRET else "")
@@ -747,8 +779,79 @@ def _multi_head_attention(attrs, data, query_weight, key_weight,
             for fate, count in zip(("visited", "skipped"), blocks):
                 # graftlint: disable=GL002 -- counts compiled variants
                 _ATTN_KV_BLOCKS.labels(kind=kind, fate=fate).inc(count)
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, H * d)  # [B,T,H*d]
+    out = out.transpose(0, 2, 1, 3).reshape(B, T, -1)     # [B,T,H*d]
     return _matmul_wt(out, out_proj_weight)
+
+
+_ATTN_LATENT = _telemetry.counter(
+    "attention_latent_total",
+    "MultiHeadAttention layers compiled as latent attention: low-rank "
+    "queries and keys/values, heads split into a non-rotary and a rotary "
+    "part (trace-time)")
+
+
+def _latent_heads(attrs, data, q_a_weight, q_a_norm_gamma, q_b_weight,
+                  kv_a_weight, kv_a_norm_gamma, kv_b_weight):
+    """Latent attention's heads (DeepSeek-V2, as GLM-4.7-Flash states it)
+    in front of ``_attend``: (q, k, v), each [B,H,T,.].
+
+    With ``r_q`` = ``q_lora_rank``, ``r_kv`` = ``kv_lora_rank``, a head of
+    ``head_dim`` = ``d_n + d_r`` whose last ``d_r`` = ``qk_rope_head_dim``
+    dims carry the position, values of ``v_head_dim`` (0: ``head_dim``):
+
+        c_q = RMSNorm(x · Wqaᵀ; g_q)          q_a_weight (r_q, D)
+        q   = c_q · Wqbᵀ                      q_b_weight (H * head_dim, r_q)
+        [c_kv; k_r] = x · Wkvaᵀ               kv_a_weight (r_kv + d_r, D)
+        c_kv = RMSNorm(c_kv; g_kv)            (k_r is not normalised)
+        [k_n_i; v_i] = c_kv · Wkvbᵀ           kv_b_weight (H * (d_n + v), r_kv)
+        q_i = [q_n_i; rope(q_r_i)],  k_i = [k_n_i; rope(k_r)]
+
+    ``k_r`` is ONE rotated part of ``d_r`` dims that every head shares (its
+    gradient is the sum over the heads, by autodiff through the
+    broadcast); ``_rotary`` is applied to the ``d_r``-wide parts, so the
+    frequencies are ``rope_theta^(-2j / d_r)``.  Scores are over the whole
+    head, ``/ sqrt(head_dim)``.  The kernels take heads of one size:
+    ``v_head_dim`` other than ``head_dim`` keeps the XLA arm.  Under
+    ``megatron_rules`` ``q_b_weight`` and ``kv_b_weight`` split by head
+    (column-parallel), ``out_proj_weight`` (D, H * v_head_dim) by its
+    inputs, the two ``_a_`` matrices and the gains stay whole."""
+    B, T, _ = data.shape
+    H, d, dr = attrs["num_heads"], attrs.get("head_dim") or 0, \
+        attrs.get("qk_rope_head_dim") or 0
+    rq, rkv = attrs.get("q_lora_rank") or 0, attrs["kv_lora_rank"]
+    theta, eps = attrs.get("rope_theta") or 0, attrs.get("eps", 1e-5)
+    if H <= 0 or rq <= 0 or not 0 < dr < d or dr % 2 or not theta > 0 \
+            or not attrs["causal"]:
+        raise MXNetError(
+            "MultiHeadAttention: latent attention takes num_heads, "
+            "q_lora_rank, kv_lora_rank, head_dim (the whole head), an even "
+            "qk_rope_head_dim inside it, rope_theta and causal=True; got "
+            "%r" % ({k: attrs.get(k) for k in (
+                "num_heads", "q_lora_rank", "kv_lora_rank", "head_dim",
+                "qk_rope_head_dim", "rope_theta", "causal")},))
+    if any(attrs.get(k) for k in ("num_kv_heads", "qk_norm", "window",
+                                  "rope_yarn")):
+        raise MXNetError(
+            "MultiHeadAttention: latent attention has no grouped heads, "
+            "per-head norms, window or YaRN frequencies")
+    dn, dv = d - dr, attrs.get("v_head_dim") or d
+
+    def heads(c, w, size):
+        return _matmul_wt(c, w).reshape(B, T, H, size).transpose(0, 2, 1, 3)
+
+    c_q = _rms_norm_last(_matmul_wt(data, q_a_weight), q_a_norm_gamma, eps)
+    q = heads(c_q, q_b_weight, d)
+    kv_a = _matmul_wt(data, kv_a_weight)                  # [B,T,r_kv+d_r]
+    c_kv = _rms_norm_last(kv_a[..., :rkv], kv_a_norm_gamma, eps)
+    kv = heads(c_kv, kv_b_weight, dn + dv)
+    k_r = _rotary(kv_a[:, None, :, rkv:], theta)          # [B,1,T,d_r]
+    q = jnp.concatenate([q[..., :dn], _rotary(q[..., dn:], theta)], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_r, (B, H, T, dr))], axis=-1)
+    if _telemetry.enabled:
+        # graftlint: disable=GL002 -- counts compiled variants, not calls
+        _ATTN_LATENT.inc()
+    return q, k, kv[..., dn:]
 
 
 _SHORTCONV_DISPATCH = _telemetry.counter(
@@ -811,7 +914,9 @@ _MOE_SCORE = _telemetry.counter(
                   "num_hidden": param(int, 0, required=True),
                   "num_held": param(int, 0),
                   "expert_offset": param(int, 0),
-                  "score": param(("sigmoid", "softmax"), "sigmoid")})
+                  "score": param(("sigmoid", "softmax"), "sigmoid"),
+                  "routed_scaling": param(float, 1.0),
+                  "weight_eps": param(float, 1e-6)})
 def _sparse_moe(attrs, data, router_weight, *rest):
     """Sparse mixture of gated (SiLU) experts that is told which experts it
     holds, routed by sigmoid scores under a selection bias (the LFM2 /
@@ -825,9 +930,11 @@ def _sparse_moe(attrs, data, router_weight, *rest):
     Routing runs over all ``num_experts``: ``s = sigmoid(x · W_gᵀ)`` in
     float32, ``sel = top_k(s + expert_bias)`` (the bias, a buffer, takes no
     gradient and only steers the selection), weights ``w_e = s_e`` for
-    ``e`` in ``sel``, divided by ``sum_sel s + 1e-6`` (the source's
-    ``norm_topk_prob`` with a ``routed_scaling_factor`` of 1: the one
-    weighting the op has).  Under ``softmax``: ``s = softmax(x · W_gᵀ)``
+    ``e`` in ``sel``, divided by ``sum_sel s + weight_eps`` (the source's
+    ``norm_topk_prob``; 1e-6 is LFM2's, GLM-4.7-Flash states 1e-20) and
+    multiplied by ``routed_scaling`` (the source's
+    ``routed_scaling_factor``: 1 leaves the program as it was, GLM's is
+    1.8; it scales the softmax weights alike).  Under ``softmax``: ``s = softmax(x · W_gᵀ)``
     over all experts in float32, ``sel = top_k(s)``, ``w_e = s_e / sum_sel
     s`` (softmax, then top-k, then normalised over the selected).  The op
     HOLDS the
@@ -897,7 +1004,10 @@ def _sparse_moe(attrs, data, router_weight, *rest):
         biased = s + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
         _, sel = jax.lax.top_k(biased, k)
         w = jnp.take_along_axis(s, sel, axis=-1)
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True)
+                 + attrs.get("weight_eps", 1e-6))
+    if attrs.get("routed_scaling", 1.0) != 1.0:
+        w = w * attrs["routed_scaling"]
 
     # ---- the held experts over every token; a token's weight for a held
     # expert it did not select is nought (one_hot of an index >= held)

@@ -90,16 +90,19 @@ def flash_attention_available(B, H, Tq, Tk, D, dtype=None) -> bool:
     return kv_fits_vmem(Tk, D, dtype)
 
 
-def kv_fits_vmem(Tk, D, dtype=None) -> bool:
+def kv_fits_vmem(Tk, D, dtype=None, limit=5 * 1024 * 1024) -> bool:
     """K+V resident in VMEM per (b,h) program, double-buffered by the
     pipeline.  Measured crossover (tools/bench_ring_attention.py):
     the kernel wins 1.9x while K/V stream from VMEM comfortably
     (T=4096/D=128), loses once the resident set crowds the 16 MB
     scoped-vmem limit (T=8192: 0.84x; T=16384: compile failure) —
-    larger shapes use the HBM-blocked lax.scan formulation instead."""
+    larger shapes use the HBM-blocked lax.scan formulation instead.
+    (That was against the scan and before the kernels asked for 32 MB:
+    ``MultiHeadAttention``, whose other arm writes T x T scores to HBM,
+    names a ``limit`` of its own, ``ops.nn.MHA_KV_VMEM``.)"""
     esize = jnp.dtype(dtype).itemsize if dtype is not None else 2
     kv_bytes = 2 * Tk * D * esize
-    return 2 * kv_bytes <= 5 * 1024 * 1024
+    return 2 * kv_bytes <= limit
 
 
 _NT = (((1,), (1,)), ((), ()))       # A @ B^T: the MXU loads B transposed

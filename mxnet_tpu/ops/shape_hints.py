@@ -101,6 +101,14 @@ def _mha_hint(attrs, shapes):
     D = data[-1]
     H = attrs["num_heads"]
     hd = attrs.get("head_dim") or D // H
+    if attrs.get("kv_lora_rank"):
+        # latent attention's seven (``ops.nn._latent_heads``)
+        rq, rkv, dr = (attrs["q_lora_rank"], attrs["kv_lora_rank"],
+                       attrs["qk_rope_head_dim"])
+        dv = attrs.get("v_head_dim") or hd
+        return _fill(shapes, [(rq, D), (rq,), (H * hd, rq), (rkv + dr, D),
+                              (rkv,), (H * (hd - dr + dv), rkv),
+                              (D, H * dv)])
     kv = hd * (attrs.get("num_kv_heads") or H)
     return _fill(shapes, [(H * hd, D), (kv, D), (kv, D), (D, H * hd), (hd,),
                           (hd,)])
@@ -187,7 +195,11 @@ def install():
         "Embedding": (("data", "weight"), (), _embedding_hint),
         "MultiHeadAttention": (("data", "query_weight", "key_weight",
                                 "value_weight", "out_proj_weight",
-                                "q_norm_gamma", "k_norm_gamma"), (),
+                                "q_norm_gamma", "k_norm_gamma",
+                                # latent attention's own (kv_lora_rank)
+                                "q_a_weight", "q_a_norm_gamma",
+                                "q_b_weight", "kv_a_weight",
+                                "kv_a_norm_gamma", "kv_b_weight"), (),
                                _mha_hint),
         "RMSNorm": (("data", "gamma"), (), _channel_hint(None, -1)),
         "ShortConv": (("data", "in_proj_weight", "conv_weight",

@@ -392,6 +392,9 @@ def megatron_rules(mesh: Mesh, tp_axis: str = "tp") -> ShardingRules:
         # the row-parallel rule on its (expert, in) axes
         (r"expert_(gate|up|down)_weight$", experts),
         (r"(router_weight|expert_bias)$", P()),
+        # latent attention: the matrices that make the heads split by head
+        # (the low-rank bottlenecks, q_a / kv_a, match nothing: whole)
+        (r"(q_b|kv_b)_weight$", P(t, None)),
         # row-parallel (input-split) rule next: out_proj/fc2/down names
         # also end in proj_weight/fc2_weight, which the column rule below
         # would otherwise claim — first match wins in spec_for

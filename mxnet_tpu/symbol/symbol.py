@@ -454,7 +454,9 @@ def _abstract_node(node: _Node, attrs, in_shapes):
     avals = [jax.ShapeDtypeStruct(s, np.float32) for s in in_shapes]
     if op.needs_rng:
         avals = [jax.ShapeDtypeStruct((2,), np.uint32)] + avals
-    out = jax.eval_shape(lambda *xs: op.fn(attrs, *xs), *avals)
+    from .. import telemetry
+    with telemetry.paused():    # a shape asked about is no compiled variant
+        out = jax.eval_shape(lambda *xs: op.fn(attrs, *xs), *avals)
     if not isinstance(out, (tuple, list)):
         out = (out,)
     return [tuple(o.shape) for o in out]
@@ -477,6 +479,20 @@ def _without_bias(attrs, names):
     return names[:-1] if attrs.get("no_bias") else names
 
 
+_LATENT_INPUTS = ("q_a_weight", "q_a_norm_gamma", "q_b_weight",
+                  "kv_a_weight", "kv_a_norm_gamma", "kv_b_weight")
+
+
+def _attention_inputs(attrs, names):
+    """MultiHeadAttention: the per-head gains exist only under qk_norm;
+    latent attention (kv_lora_rank) takes its own six matrices and gains in
+    place of query / key / value, then out_proj_weight."""
+    if attrs.get("kv_lora_rank"):
+        return ["data", *_LATENT_INPUTS, "out_proj_weight"]
+    plain = [n for n in names if n not in _LATENT_INPUTS]
+    return plain if attrs.get("qk_norm") else plain[:-2]
+
+
 #: ops whose attrs switch declared inputs off: op -> (attrs, names) -> names
 _SWITCHED_ARGS = {
     "Convolution": _without_bias, "Deconvolution": _without_bias,
@@ -484,9 +500,7 @@ _SWITCHED_ARGS = {
     # gamma exists only for the prelu variant
     "LeakyReLU": lambda attrs, names: names if attrs.get(
         "act_type", "leaky") == "prelu" else names[:-1],
-    # the per-head gains exist only under qk_norm
-    "MultiHeadAttention": lambda attrs, names: names if attrs.get(
-        "qk_norm") else names[:-2],
+    "MultiHeadAttention": _attention_inputs,
     # softmax scores are selected as they are: no selection bias to carry
     "SparseMoE": lambda attrs, names: names if attrs.get(
         "score") != "softmax" else [n for n in names if n != "expert_bias"],

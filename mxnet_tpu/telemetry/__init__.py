@@ -29,6 +29,7 @@ attribute :data:`enabled` — a single attribute check on the disabled
 """
 from __future__ import annotations
 
+import contextlib as _contextlib
 import sys as _sys
 
 from ..base import get_env
@@ -88,6 +89,20 @@ def enable():
             _fleet.register_endpoint(bound)
     if get_env("MXNET_TELEMETRY_TS", True, bool):
         timeseries.start()
+
+
+@_contextlib.contextmanager
+def paused():
+    """The built-in counters off inside: for a trace that compiles no
+    program (shape inference runs every op under ``jax.eval_shape`` in
+    float32, and the trace-time dispatch counters count compiled
+    variants, not shapes asked about)."""
+    global enabled
+    was, enabled = enabled, False
+    try:
+        yield
+    finally:
+        enabled = was
 
 
 def disable():

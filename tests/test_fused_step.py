@@ -393,6 +393,79 @@ class TestStepTimeline:
         finally:
             telemetry.disable()
 
+    @pytest.mark.parametrize(
+        "ctxs", [None, [mx.cpu(i) for i in range(4)]],
+        ids=["one_device", "mesh"])
+    def test_first_step_holds_the_state_once(self, monkeypatch, ctxs):
+        """A handle the pool does not own is copied, and the copy takes the
+        handle at once: when the first fused program is launched no
+        pre-step buffer of a weight or of the optimizer's state is alive
+        beside its copy, and what is alive is the state once plus a leaf."""
+        import gc
+        import weakref
+        import jax
+        # one eager step first: the optimizer's state then exists, made
+        # outside the pool, as a checkpoint's set_states would leave it
+        monkeypatch.setenv(fused.ENV_FLAG, "0")
+        mod = _build_module(ctxs=ctxs)
+        mod.init_optimizer(
+            optimizer="adam", optimizer_params={"learning_rate": 0.01})
+        mod.forward_backward(_batch(0))
+        mod.update()
+        monkeypatch.setenv(fused.ENV_FLAG, "1")
+        execs = mod._exec_group.execs
+        names = [n for n in mod._param_names]
+        leaves = [e.arg_dict[n] for e in execs for n in names]
+        leaves += [leaf for st in mod._updater.states.values()
+                   for leaf in st]
+        before = [weakref.ref(h._data) for h in leaves]
+        largest = max(h._data.nbytes for h in leaves)
+        seen = {}
+        program = type(execs[0]).step_program
+
+        def watched(ex, *a, **kw):
+            fn = program(ex, *a, **kw)
+
+            def launch(pvals, svals, *rest):
+                gc.collect()
+                seen["alive"] = sum(r() is not None for r in before)
+                seen["donated"] = sum(
+                    v.nbytes for v in list(pvals)
+                    + [leaf for sv in svals for leaf in sv])
+                seen["live"] = sum(a.nbytes for a in jax.live_arrays())
+                return fn(pvals, svals, *rest)
+            return launch
+
+        monkeypatch.setattr(type(execs[0]), "step_program", watched)
+        del leaves
+        gc.collect()
+        floor = sum(a.nbytes for a in jax.live_arrays())
+        mod.forward_backward(_batch(1))
+        mod.update()
+        assert seen["alive"] == 0, "%d pre-step buffers outlived their " \
+            "copies" % seen["alive"]
+        # what was alive before, with every leaf once (a mesh lays the
+        # per-device copies together: fewer bytes, never more) and the
+        # batch: not the donated leaves a second time
+        assert seen["live"] <= floor + largest + 4096, (seen, floor)
+        assert seen["donated"] > 4 * largest    # the bound says something
+        for e in execs:
+            assert np.isfinite(e.arg_dict["fc1_weight"].asnumpy()).all()
+
+    def test_caller_held_alias_survives_the_first_step(self, monkeypatch):
+        """The buffer a caller still holds is the one thing the copy is
+        for: it keeps its values while the step donates the copy."""
+        mod = self._module(monkeypatch)
+        ex = mod._exec_group.execs[0]
+        alias = ex.arg_dict["fc1_weight"]._data
+        want = np.asarray(alias).copy()
+        for i in range(2):
+            mod.forward_backward(_batch(i))
+            mod.update()
+        assert not alias.is_deleted()
+        np.testing.assert_array_equal(np.asarray(alias), want)
+        assert not np.array_equal(ex.arg_dict["fc1_weight"].asnumpy(), want)
+
     def test_recorder_off_records_nothing_and_trains_alike(
             self, monkeypatch):
         from mxnet_tpu import profiler, tracing

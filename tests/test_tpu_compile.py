@@ -222,6 +222,51 @@ def test_mellum_attention_reaches_its_kernels(one_chip, kind):
     assert found == [kind == "sliding_attention"] * 3
 
 
+def test_latent_attention_reaches_the_kernels_at_a_head_of_256(one_chip):
+    """``glm47flash_train_4k``'s attention layer, ahead of time: 20 heads of
+    192 + 64 (values of 256) under ranks 768 / 512 and a 2048-wide stream at
+    T 4096.  A head's K and V are 4 MB, 8 MB double-buffered: past
+    ``kv_fits_vmem``'s own 5 MB, inside ``MHA_KV_VMEM``.  Forward + backward
+    hold the three Mosaic calls over 20 heads of 256 in 512-blocks, no
+    T x T tensor, and the calls carry the scope
+    ``mla_flash_roofline_pct.train`` finds them by."""
+    import json
+    import os
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops.nn import mha_uses_kernel
+    from mxnet_tpu.ops.registry import OPS
+    assert not pa.kv_fits_vmem(4096, 256, jnp.bfloat16)
+    assert mha_uses_kernel(1, 20, 4096, 256, jnp.bfloat16)
+    assert not mha_uses_kernel(1, 20, 8192, 256, jnp.bfloat16)
+    op = OPS["MultiHeadAttention"]
+    attrs = op.parse_attrs(dict(
+        num_heads=20, head_dim=256, qk_rope_head_dim=64, q_lora_rank=768,
+        kv_lora_rank=512, v_head_dim=256, rope_theta=1e6, eps=1e-5))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, 4096, 2048), sds(768, 2048), sds(768, dtype=jnp.float32),
+            sds(5120, 768), sds(576, 2048), sds(512, dtype=jnp.float32),
+            sds(8960, 512), sds(2048, 5120))
+
+    def loss(*a):
+        with jax.named_scope("MultiHeadAttention:tfm_l1_mla"):
+            return jnp.sum(op.fn(attrs, *a).astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, tuple(range(8)))).lower(
+        *args).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert all("bf16[20,4096,256]" in ln for ln in calls)
+    assert "[1,20,4096,4096]" not in hlo and "[20,4096,4096]" not in hlo
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "metrics",
+                           "mla_flash_roofline_pct.train.json")) as f:
+        reads = re.compile(json.load(f)["params"]["kernel"])
+    assert all(reads.search(ln) for ln in calls)
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_flash_attention_ring_variant_compiles(one_chip, direction):
     """The stats-emitting kernel ring attention runs per shard, and the
