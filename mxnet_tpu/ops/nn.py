@@ -598,12 +598,17 @@ def _kernel_or_reference(q, k, v, causal, scale, interpret, window=None):
 #: what a head's keys and values may take of VMEM, double-buffered, for
 #: ``MultiHeadAttention`` to call the flash kernels: 8 MB, 4,096 positions
 #: at a head of 256 (latent attention's) and 8,192 at 128.  The kernels ask
-#: for 32 MB of the v5e's 128 (``pallas_attention._PARALLEL``) and hold, a
-#: program, one head's K and V whole (4 MB at that shape, twice for the
-#: pipeline), a 512-block of q, o and their float32 accumulators; ring
-#: attention, whose competitor is the scan and not a T x T tensor in HBM,
-#: keeps ``kv_fits_vmem``'s own 5 MB.  Measured on the v5e
-#: (``tools/bench_attention_arms.py``, PERF.md, PR 33).
+#: for 32 MB of the v5e's 128 (``pallas_attention._PARALLEL``).  A forward
+#: program holds one head's K and V whole (4 MB at that shape, twice for
+#: the pipeline), a 512-block of q, o and their float32 accumulators; a
+#: backward program (``flash_dqkv``) holds the head's Q and dO whole, the
+#: same bytes, its dq in float32 (4 MB) and the dq block it writes: 18.5 MB
+#: at a head of 256 by ``pallas_attention._bwd_vmem_bytes``, which raises
+#: the kernel's limit where a shape inside this envelope needs more
+#: (16,384 positions at a head of 64, whose lanes are padded to 128).
+#: Ring attention, whose competitor is the scan and not a T x T tensor in
+#: HBM, keeps ``kv_fits_vmem``'s own 5 MB.  Measured on the v5e
+#: (``tools/bench_attention_arms.py``, PERF.md, PR 33 and PR 34).
 MHA_KV_VMEM = 8 << 20
 
 
@@ -614,13 +619,14 @@ def mha_uses_kernel(B, H, T, d, dtype):
     competitor is the scan.)
 
     Forward + gradient, bf16, causal, device ms from a trace on the v5e
-    (``tools/bench_attention_arms.py``, PR 26), XLA arm / kernel:
+    (``tools/bench_attention_arms.py``; the XLA arm PR 26, the kernels,
+    whose backward is one kernel, PR 34), XLA arm / kernel:
 
         B x H   d     T 256          T 512          T 1024         T 2048
-        16      64    0.012 / 0.018  0.040 / 0.057  0.352 / 0.146  3.40 / 0.50
-        64      64    0.041 / 0.073  0.362 / 0.227  3.57 / 0.584   13.5 / 2.01
-        16      128   0.014 / 0.027  0.044 / 0.068  0.368 / 0.166  3.43 / 0.52
-        64      128   0.048 / 0.091  0.385 / 0.242  3.53 / 0.609   13.6 / 2.03
+        16      64    0.012 / 0.018  0.040 / 0.051  0.352 / 0.125  3.40 / 0.42
+        64      64    0.041 / 0.070  0.362 / 0.203  3.57 / 0.501   13.5 / 1.67
+        16      128   0.014 / 0.022  0.044 / 0.057  0.368 / 0.135  3.43 / 0.43
+        64      128   0.048 / 0.074  0.385 / 0.205  3.53 / 0.499   13.6 / 1.64
 
     The XLA arm is quick while its float32 scores (B x H x T x T) stay in
     VMEM, 16 MB in every cell it wins, and pays HBM for them from 64 MB on,

@@ -12,16 +12,24 @@ max/sum and accumulators are float32, the probabilities are rounded to
 the operands' dtype as the MXU operand of the value product, and the
 logsumexp, T floats a head, is the only residual.
 
-Backward: hand-written Pallas dq and dk/dv kernels — the standard
-two-pass flash backward.  The forward saves the per-row logsumexp
+Backward: ONE hand-written Pallas kernel, ``flash_dqkv`` (the standard
+two-pass flash backward, a dk/dv kernel and a dq kernel, computes every
+tile's scores, exponentials and ``dO v^T`` twice: seven products a tile
+where five are needed).  The forward saves the per-row logsumexp
 ``lse = m + log(l)``; the backward recomputes probabilities on-core as
 ``p = exp(s - lse)``, computes ``delta = rowsum(dO * O)`` once in XLA,
-then:
-  dv_j = sum_i p_ij dO_i          (dk/dv kernel: grid over KV blocks,
-  dk_j = sum_i ds_ij q_i           loop over Q blocks, scores transposed)
-  dq_i = sum_j ds_ij k_j          (dq kernel: grid over Q blocks,
-                                   loop over KV blocks)
-with ``ds = p * (dp - delta) * scale``, ``dp = dO v^T``.
+then, grid over KV blocks, loop over Q blocks, scores transposed:
+  dv_j = sum_i p_ij dO_i
+  dk_j = sum_i ds_ij q_i
+  dq_i += ds_ij k_j               (summed over the KV blocks, which run in
+                                   order, in a float32 VMEM scratch that
+                                   holds the head's whole dq; written at
+                                   the last KV block)
+with ``ds = p * (dp - delta) * scale``, ``dp = dO v^T``.  On the v5e the one
+kernel takes 0.70-0.77 of the pair's time (2.76 against 3.96 ms at
+(1, 20, 4096, 256), 0.072 against 0.093 at (1, 16, 1024, 64); PERF.md,
+PR 34).  The name matters: the benchmark's roofline metrics find the
+kernels by the pattern ``flash_(fwd|dq|dkv)``, which ``flash_dqkv`` matches.
 
 Causal: blocks past the diagonal are never visited, only blocks the
 diagonal crosses are masked, and in square blocks over a self-attention
@@ -29,14 +37,14 @@ the diagonal block is computed in bands with static extents
 (``_bands``).  A ``window`` W on top of it (position t sees the W positions
 t - W < s <= t: sliding-window attention) bounds every loop from the other
 side too: key blocks wholly below the window's lower edge are never visited
-(``_window_bounds``; ``_window_q_bounds`` for the query loop of dk/dv), only
+(``_window_bounds``; ``_window_q_bounds`` for the backward's query loop), only
 the blocks that edge crosses carry its mask, and a window that reaches
 position 0 from every query (W >= T) is plain causal, the same kernels
 (``kv_block_plan`` counts what the forward loop takes in and leaves out).
 Without named blocks a sequence of up to 2,048 positions is
-ONE block (``default_blocks``): at T 1024 / d 64 that is 0.146 ms forward +
-backward for 16 heads on the v5e against 0.266 in 512-blocks and 0.352 for
-the XLA arm (PERF.md, PR 26).
+ONE block (``default_blocks``): at T 1024 / d 64 that is 0.125 ms forward +
+backward for 16 heads on the v5e against 0.177 in 512-blocks (PERF.md,
+PR 34) and 0.352 for the XLA arm (PR 26).
 
 Who reaches it.  ``MultiHeadAttention`` (``ops/nn.py``), on a TPU, at the
 shapes its own test ``mha_uses_kernel`` admits: float32 scores of 64 MB or
@@ -62,6 +70,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
+
+from .. import telemetry as _telemetry
 
 __all__ = ["flash_attention", "flash_attention_available",
            "flash_attention_stats", "flash_attention_bwd", "kv_block_plan"]
@@ -107,6 +117,7 @@ def kv_fits_vmem(Tk, D, dtype=None, limit=5 * 1024 * 1024) -> bool:
 
 _NT = (((1,), (1,)), ((), ()))       # A @ B^T: the MXU loads B transposed
 _NN = (((1,), (0,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))       # A^T @ B: the tile's rows contracted
 
 
 def _fold_scale(scale):
@@ -174,7 +185,7 @@ def _three_loops(lo, a, b, hi, step, init):
 
 
 def _key_block_loop(body, init, qi, TQ, BK, n, square, window):
-    """The causal K-block loop of one Q block, forward and dq alike:
+    """The causal K-block loop of one Q block of the forward:
     ``body(i, carry, masked)`` over the blocks the diagonal and, under a
     ``window``, its lower edge leave to visit, masked only where one of them
     crosses a block.  ``square``: up to the diagonal block, which the caller
@@ -230,9 +241,9 @@ def _bands(T, band):
 
 
 # measured at (1, 16, 1024, 64), one block: the forward (row statistics a
-# band) is fastest in bands of 256, 0.049 against 0.058 ms; dq and dk/dv
-# (no reduction in the loop) in bands of 128, 0.040 / 0.053 against 0.042 /
-# 0.056
+# band) is fastest in bands of 256, 0.049 against 0.058 ms; the backward (no
+# reduction in the loop) in bands of 128 (PR 26, the two kernels it then
+# was: 0.040 / 0.053 against 0.042 / 0.056)
 _BAND_FWD, _BAND_BWD = 256, 128
 
 
@@ -350,16 +361,18 @@ def _out_sds(shape, dtype, like):
 def default_blocks(Tq, Tk, window=None):
     """(block_q, block_k) from the shape, where the caller names none:
     one square block over the whole of a sequence up to 2,048 positions
-    (0.50 ms against 0.69 in 512-blocks at T 2048), 512-blocks beyond, and
+    (0.42 ms against 0.55 in 512-blocks at T 2048), 512-blocks beyond, and
     under a ``window`` narrower than the sequence at any length: one block
     leaves nothing to skip, and its diagonal bands know the causal mask
     alone (not measured; the cell that has a window runs T 4096).
     Measured on the v5e with ``tools/bench_attention_arms.py`` (PERF.md,
-    PR 26) at (1, 16, 1024, 64) bf16 causal: the K-block loop costs about
-    0.3 us a step whatever the block, so forward + backward take 0.65 ms
-    in 128-blocks, 0.34 in 256, 0.22 in 512 and 0.16 in one 1024-block,
-    whose diagonal bands (``_bands``) are straight-line code with static
-    extents and compute 56 % of the square."""
+    PR 34) at (1, 16, 1024, 64) bf16 causal: a loop step costs about the
+    same whatever the block, so forward + backward take 0.31 ms in
+    128-blocks, 0.20 in 256, 0.18 in 512 and 0.125 in one 1024-block, whose
+    diagonal bands (``_bands``) are straight-line code with static extents
+    and compute 56 % of the square.  (At a head of 256 and T 4096
+    1,024-blocks read 3.87 ms against 4.17 in 512-blocks; not taken:
+    PERF.md section 7.)"""
     side = min(Tq, Tk)
     if window is not None and window < Tk:
         side = min(side, 512)
@@ -397,9 +410,13 @@ def _qkv_specs(G, TQ, Tk, D):
 
 # 32 MB of the v5e's 128 MB of VMEM: a 2048-block of f32 operands, double
 # buffered, needs more than the 16 MB a kernel gets unasked
+_VMEM_LIMIT, _VMEM_MOST = 32 << 20, 100 << 20
+# what the 32 MB leave a step's score tiles at the largest shapes the cells
+# run (18.5 MB of buffers at (1, 20, 4096, 256), 16.3 in one 2048-block)
+_TILE_ROOM = 12 << 20
 _PARALLEL = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"),
-    vmem_limit_bytes=32 << 20)
+    vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k, stats,
@@ -476,74 +493,17 @@ def lse_of(m, l):
     return jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), jnp.inf)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *,
-               G, TQ, BK, Tk, causal, scale, square, window=None):
-    """dq for one Q block of ``G`` heads: loop over KV blocks, recompute p
-    from lse, accumulate ds @ K in f32.  Scores lie (TQ, BK) as in the
-    forward; the loop holds no reduction.  Causal bounds, the window's,
-    mask and the banded diagonal block as in ``_online_softmax_loop``."""
-    qi = pl.program_id(1)
-    D = q_ref.shape[-1]
-    fold = _fold_scale(scale)
-    qs = [q_ref[g] * jnp.asarray(scale, q_ref.dtype) if fold else q_ref[g]
-          for g in range(G)]
-    dos = [do_ref[g] for g in range(G)]
-    lses = [_col(lse_ref, g) for g in range(G)]      # (TQ, 1)
-    deltas = [_col(dl_ref, g) for g in range(G)]
-
-    def grad(dq, qb, dob, lse, delta, kblk, vblk, mask):
-        s = jax.lax.dot_general(qb, kblk, _NT,
-                                preferred_element_type=jnp.float32)
-        p = jnp.exp((s if fold else s * scale) - lse)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(dob, vblk, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(kblk.dtype)
-        return dq + jax.lax.dot_general(
-            ds, kblk, _NN, preferred_element_type=jnp.float32)
-
-    def step(g, i, dq, masked):
-        off = pl.multiple_of(i * BK, BK)
-        mask = _visible((TQ, BK), 0, i * BK - qi * TQ, window) \
-            if masked else None
-        return grad(dq, qs[g], dos[g], lses[g], deltas[g],
-                    k_ref[g, pl.ds(off, BK), :], v_ref[g, pl.ds(off, BK), :],
-                    mask)
-
-    def diagonal(g, dq):
-        R, SB = _bands(TQ, _BAND_BWD)
-        base = pl.multiple_of(qi * BK, BK)
-        parts = []
-        for r in range(R):
-            rows, keys = slice(r * SB, (r + 1) * SB), (r + 1) * SB
-            parts.append(grad(
-                dq[rows], qs[g][rows], dos[g][rows], lses[g][rows],
-                deltas[g][rows], k_ref[g, pl.ds(base, keys), :],
-                v_ref[g, pl.ds(base, keys), :],
-                _visible((SB, keys), 0, -r * SB)))
-        return jnp.concatenate(parts, axis=0)
-
-    body = _each_head(G, step)
-    init = (jnp.zeros((TQ, D), jnp.float32),) * G
-    n = Tk // BK
-    if not causal:
-        dqs = jax.lax.fori_loop(0, n, functools.partial(body, masked=False),
-                                init)
-    else:
-        dqs = _key_block_loop(body, init, qi, TQ, BK, n, square, window)
-        if square:
-            dqs = tuple(diagonal(g, dq) for g, dq in enumerate(dqs))
-    for g, dq in enumerate(dqs):
-        dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
-                dv_ref, *, G, TQ, BK, Tq, causal, scale, square, window=None):
-    """dk/dv for one KV block of ``G`` heads: loop over Q blocks.  Scores
-    lie transposed, (BK, TQ) = K Q^T: lse and delta are lane-dense rows as
-    they arrive, and all five products are plain ``A @ B`` or ``A @ B^T``
-    with the score tile as the streamed operand.  Causal: start at the
+def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
+                 dk_ref, dv_ref, dq_acc, *, G, TQ, BK, Tq, causal, scale,
+                 square, window=None):
+    """The whole backward for one KV block of ``G`` heads: loop over Q
+    blocks.  Scores lie transposed, (BK, TQ) = K Q^T: lse and delta are
+    lane-dense rows as they arrive, and the products are plain ``A @ B`` or
+    ``A @ B^T`` with the score tile as the streamed operand; ``dsT`` of a
+    tile serves ``dk`` and, contracted over its keys, the tile's share of
+    ``dq``, which is summed over the KV blocks of the (sequential) second
+    grid axis in ``dq_acc``, the head's whole dq in float32: zeroed at KV
+    block 0, scaled, cast and written at the last.  Causal: start at the
     first Q block that can see this KV block, mask up to the last one the
     diagonal crosses; ``square``: that is block ``ki`` alone, computed in
     bands of keys (band r is seen by the queries from band r on).  Under a
@@ -553,11 +513,23 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
     ki = pl.program_id(1)
     D = k_ref.shape[-1]
     fold = _fold_scale(scale)
-    ks = [k_ref[g] * jnp.asarray(scale, k_ref.dtype) if fold else k_ref[g]
-          for g in range(G)]                         # (BK, D) each
+    raw = [k_ref[g] for g in range(G)]                   # (BK, D) each
+    ks = [kb * jnp.asarray(scale, kb.dtype) for kb in raw] if fold else raw
     vs = [v_ref[g] for g in range(G)]
+    n = Tq // TQ
 
-    def grad(carry, kb, vb, qb, dob, lse, delta, mask):
+    def q_rows(i):
+        return pl.ds(pl.multiple_of(i * TQ, TQ), TQ)
+
+    def clear(i, _):
+        for g in range(G):
+            dq_acc[g, q_rows(i), :] = jnp.zeros((TQ, D), jnp.float32)
+
+    @pl.when(ki == 0)
+    def _():
+        jax.lax.fori_loop(0, n, clear, None)
+
+    def grad(carry, g, rows, kb, vb, kraw, qb, dob, lse, delta, mask):
         dk, dv = carry
         sT = jax.lax.dot_general(kb, qb, _NT,
                                  preferred_element_type=jnp.float32)
@@ -572,26 +544,27 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
         dsT = (pT * (dpT - delta)).astype(qb.dtype)
         dk = dk + jax.lax.dot_general(
             dsT, qb, _NN, preferred_element_type=jnp.float32)
+        dq_acc[g, rows, :] += jax.lax.dot_general(
+            dsT, kraw, _TN, preferred_element_type=jnp.float32)
         return dk, dv
 
     def step(g, i, carry, masked):
-        off = pl.multiple_of(i * TQ, TQ)
+        rows = q_rows(i)
         mask = _visible((BK, TQ), 1, ki * BK - i * TQ, window) \
             if masked else None
-        return grad(carry, ks[g], vs[g], q_ref[g, pl.ds(off, TQ), :],
-                    do_ref[g, pl.ds(off, TQ), :], lse_ref[g, i],
-                    dl_ref[g, i], mask)
+        return grad(carry, g, rows, ks[g], vs[g], raw[g], q_ref[g, rows, :],
+                    do_ref[g, rows, :], lse_ref[g, i], dl_ref[g, i], mask)
 
     def diagonal(g):
         R, SB = _bands(BK, _BAND_BWD)
         zero = jnp.zeros((SB, D), jnp.float32)
         parts = []
         for r in range(R):
-            rows, n_q = slice(r * SB, (r + 1) * SB), TQ - r * SB
-            off = pl.multiple_of(ki * TQ + r * SB, SB)
+            keys, n_q = slice(r * SB, (r + 1) * SB), TQ - r * SB
+            rows = pl.ds(pl.multiple_of(ki * TQ + r * SB, SB), n_q)
             parts.append(grad(
-                (zero, zero), ks[g][rows], vs[g][rows],
-                q_ref[g, pl.ds(off, n_q), :], do_ref[g, pl.ds(off, n_q), :],
+                (zero, zero), g, rows, ks[g][keys], vs[g][keys],
+                raw[g][keys], q_ref[g, rows, :], do_ref[g, rows, :],
                 lse_ref[g, ki, :, r * SB:], dl_ref[g, ki, :, r * SB:],
                 _visible((SB, n_q), 1, 0)))
         return tuple(jnp.concatenate(c, axis=0) for c in zip(*parts))
@@ -599,7 +572,6 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
     body = _each_head(G, step)
     init = ((jnp.zeros((BK, D), jnp.float32),
              jnp.zeros((BK, D), jnp.float32)),) * G
-    n = Tq // TQ
     if not causal:
         grads = jax.lax.fori_loop(
             0, n, functools.partial(body, masked=False), init)
@@ -616,96 +588,108 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
     else:
         lo = (ki * BK) // TQ
         # Q blocks from here on lie wholly under the diagonal
-        clear = jnp.minimum((ki * BK + BK - 1 + TQ - 1) // TQ, n)
+        clear_q = jnp.minimum((ki * BK + BK - 1 + TQ - 1) // TQ, n)
         if window is None:
-            grads = _two_loops(lo, clear, n, body, init, masked_first=True)
+            grads = _two_loops(lo, clear_q, n, body, init, masked_first=True)
         else:
             edge, hi = _window_q_bounds(ki * BK, BK, TQ, window, n)
-            clear = jnp.minimum(clear, hi)
+            clear_q = jnp.minimum(clear_q, hi)
             grads = _three_loops(
-                lo, clear, jnp.maximum(clear, jnp.minimum(edge, hi)), hi,
+                lo, clear_q, jnp.maximum(clear_q, jnp.minimum(edge, hi)), hi,
                 body, init)
     for g, (dk, dv) in enumerate(grads):
         dk_ref[g] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[g] = dv.astype(dv_ref.dtype)
 
+    def write(i, _):
+        for g in range(G):
+            dq_ref[g, q_rows(i), :] = (dq_acc[g, q_rows(i), :]
+                                       * scale).astype(dq_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        jax.lax.fori_loop(0, n, write, None)
+
+
+def _bwd_vmem_bytes(G, Tq, TQ, BK, D, itemsize, out_itemsize):
+    """What one program of the backward keeps in VMEM between steps, the
+    pipeline's second buffers and the lanes' padding counted: the head's
+    whole Q and dO, a block of K, V, dk, dv, the statistics, the dq
+    accumulator and the dq output block.  (The score tiles of a step come
+    on top: ``_TILE_ROOM``.)"""
+    lanes = -(-D // 128) * 128
+    whole, block = G * Tq * lanes, G * BK * lanes
+    stats = 2 * 2 * G * (Tq // TQ) * 8 * TQ * 4
+    return (2 * 2 * whole * itemsize + 2 * 2 * block * itemsize
+            + 2 * 2 * block * out_itemsize + stats + whole * 4
+            + 2 * whole * out_itemsize)
+
+
+_ATTN_BACKWARD = _telemetry.counter(
+    "attention_backward_total",
+    "Flash-attention backward kernel programs built, by form (trace-time): "
+    "fused is one kernel that returns dq, dk and dv, the only form built; "
+    "two_pass would name a shape kept on a dk/dv and a dq kernel",
+    ("form",))
+
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
-def _dq_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
-                out_dtype, interpret, window=None):
+def _dqkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
+                  out_dtype, interpret, window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     BH = B * H
     TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
     nq = Tq // TQ
     G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, G=G, TQ=TQ, BK=BK, Tk=Tk,
-                          causal=causal, scale=scale,
-                          square=_square(TQ, BK, Tq, Tk, window),
-                          window=window),
-        grid=(BH // G, nq),
-        in_specs=_qkv_specs(G, TQ, Tk, D) + [
-            pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0)),
-            _stats_spec(G, TQ), _stats_spec(G, TQ)],
-        out_specs=pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0)),
-        out_shape=_out_sds((BH, Tq, D), out_dtype, q),
-        compiler_params=_PARALLEL,
-        name="flash_dq",
-        interpret=interpret,
-    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D),
-      do.reshape(BH, Tq, D), lse.reshape(BH, nq, 1, TQ),
-      delta.reshape(BH, nq, 1, TQ))
-    return dq.reshape(B, H, Tq, D)
-
-
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
-def _dkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
-                 out_dtype, interpret, window=None):
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
-    nq = Tq // TQ
-    G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
+    if _telemetry.enabled:
+        # graftlint: disable=GL002 -- counts programs built, not calls
+        _ATTN_BACKWARD.labels(form="fused").inc()
     whole_q = pl.BlockSpec((G, Tq, D), lambda b, t: (b, 0, 0))
     kv_blk = pl.BlockSpec((G, BK, D), lambda b, t: (b, t, 0))
     whole_stats = pl.BlockSpec((G, nq, 1, TQ), lambda b, t: (b, 0, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, G=G, TQ=TQ, BK=BK, Tq=Tq,
+    need = _bwd_vmem_bytes(G, Tq, TQ, BK, D, q.dtype.itemsize,
+                           out_dtype.itemsize)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_dqkv_kernel, G=G, TQ=TQ, BK=BK, Tq=Tq,
                           causal=causal, scale=scale,
                           square=_square(TQ, BK, Tq, Tk, window),
                           window=window),
         grid=(BH // G, Tk // BK),
         in_specs=[whole_q, kv_blk, kv_blk, whole_q, whole_stats,
                   whole_stats],
-        out_specs=[kv_blk, kv_blk],
-        out_shape=[_out_sds((BH, Tk, D), out_dtype, q)] * 2,
-        compiler_params=_PARALLEL,
-        name="flash_dkv",
+        out_specs=[whole_q, kv_blk, kv_blk],
+        out_shape=[_out_sds((BH, Tq, D), out_dtype, q)]
+        + [_out_sds((BH, Tk, D), out_dtype, q)] * 2,
+        scratch_shapes=[pltpu.VMEM((G, Tq, D), jnp.float32)],
+        # the KV blocks of a head run in order: they share its dq
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(_VMEM_LIMIT, min(need + _TILE_ROOM,
+                                                  _VMEM_MOST))),
+        name="flash_dqkv",
         interpret=interpret,
     )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D),
       do.reshape(BH, Tq, D), lse.reshape(BH, nq, 1, TQ),
       delta.reshape(BH, nq, 1, TQ))
-    return dk.reshape(B, H, Tk, D), dv.reshape(B, H, Tk, D)
+    return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
+            dv.reshape(B, H, Tk, D))
 
 
 def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
                         block_q=512, block_k=512, out_dtype=jnp.float32,
                         window=None):
-    """Pallas flash backward: (dq, dk, dv), in f32 unless the caller names
-    another ``out_dtype`` (callers accumulating across ring steps keep
-    full precision; the standalone VJP has the kernels cast).
+    """Pallas flash backward, one kernel: (dq, dk, dv), in f32 unless the
+    caller names another ``out_dtype`` (callers accumulating across ring
+    steps keep full precision; the standalone VJP has the kernel cast).
 
     q/k/v/do: [B,H,T,D]; lse/delta: [B,H,Tq] f32 (global logsumexp and
     rowsum(dO*O) — for ring attention these are the FULL-sequence stats,
     making each per-shard call an exact partial contribution).  They
-    enter the kernels lane-dense, T floats a head."""
-    lse = lse.astype(jnp.float32)
-    delta = delta.astype(jnp.float32)
-    args = (q, k, v, do, lse, delta, causal, scale, block_q, block_k,
-            jnp.dtype(out_dtype), INTERPRET, window)
-    return (_dq_program(*args), *_dkv_program(*args))
+    enter the kernel lane-dense, T floats a head."""
+    return _dqkv_program(q, k, v, do, lse.astype(jnp.float32),
+                         delta.astype(jnp.float32), causal, scale, block_q,
+                         block_k, jnp.dtype(out_dtype), INTERPRET, window)
 
 
 # ------------------------------------------------------------ partitioning
@@ -750,9 +734,11 @@ def _on_own_rows(fn, *arrays):
     def spec(x):
         return P(*over, *(None,) * (x.ndim - 2))
 
+    with _telemetry.paused():   # a shape asked about is no program built
+        shapes = jax.eval_shape(fn, *arrays)
     return jax.shard_map(
         fn, in_specs=tuple(spec(a) for a in arrays),
-        out_specs=jax.tree.map(spec, jax.eval_shape(fn, *arrays)),
+        out_specs=jax.tree.map(spec, shapes),
         axis_names={a for a in over if a}, check_vma=False)(*arrays)
 
 

@@ -21,8 +21,10 @@ stack into one grid step (small-spatial shapes keep the MXU fed); the
 input BlockSpec is element-indexed (``pl.Element``) because tap halos
 overlap tiles.
 
-Backward is a ``custom_vjp`` whose both arms are also Pallas kernels
-(mirroring ``flash_attention_bwd``'s two-pass structure):
+Backward is a ``custom_vjp`` whose both arms are also Pallas kernels, one
+a gradient (``flash_attention_bwd`` had that two-pass structure until its
+two kernels became one, which shares the recomputed scores; dgrad and
+wgrad share nothing to recompute):
 
   dgrad: dx = conv_s1(dy, flip(W)^T) — the SAME forward kernel on the
          cotangent with spatially-flipped, io-swapped taps (exact for

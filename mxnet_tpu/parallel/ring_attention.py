@@ -133,9 +133,9 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     and fully-masked shards skip the kernel entirely (the classic ring
     load-saving).  The ring decomposition is also what makes the kernel
     APPLICABLE at long T: the VMEM gate sees the per-shard K/V (T/n),
-    not the full sequence.  Backward (round 5) runs the Pallas dq/dk/dv
-    kernels per shard against the forward's combined full-sequence
-    (out, lse): dq accumulates locally while dk/dv accumulators ride the
+    not the full sequence.  Backward (round 5) runs the Pallas backward
+    kernel (one since PR 34, ``flash_dqkv``) per shard against the
+    forward's combined full-sequence (out, lse): dq accumulates locally while dk/dv accumulators ride the
     ring with their K/V shard — fused kernels in BOTH directions, like
     the reference's cuDNN ops (src/operator/cudnn_rnn-inl.h:1).
     """
@@ -230,7 +230,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         return out.astype(qs.dtype), pa.lse_of(m, l)
 
     def per_shard_flash_bwd(qs, ks, vs, out, lse, g):
-        """Ring backward with the Pallas dq/dk/dv kernels (round 5).
+        """Ring backward with the Pallas backward kernel (round 5; one
+        kernel returns dq, dk and dv since PR 34).
 
         The forward's combined (full-sequence) lse and out make each
         per-shard ``flash_attention_bwd`` call an exact partial: summing

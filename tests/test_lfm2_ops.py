@@ -570,16 +570,18 @@ def test_the_accepted_cells_graphs_are_what_they_were(cell, json_sha1,
 
 
 @pytest.mark.parametrize("shape,jaxpr_sha1", [
-    ((1, 4, 1024, 64), "d836ede8a2abc10d35f0f424b4c6fa9ff63cc8f5"),
-    ((1, 2, 4096, 128), "fa92ff978ffa7e539920ae35d2ec110e71f14358")],
+    ((1, 4, 1024, 64), "e8869a860c1dfe67af21949af06f9b50e76c5723"),
+    ((1, 2, 4096, 128), "491860d3020ed32b4da60ad26e1c6a2fb6cfacae")],
     ids=["one_block", "512_blocks"])
 def test_flash_attention_without_a_window_is_the_kernels_it_was(shape,
                                                                 jaxpr_sha1):
     """``flash_attention(window=None)``, forward and the three gradients,
-    traces to the jaxpr (kernel bodies included) it traced to before the
-    kernels knew a window: in one block as the GPT-2 and LFM2 cells run it,
-    and in 512-blocks (sha1s taken on commit c48259c; the lowered text
-    itself carries the checkout's path and cannot be pinned)."""
+    traces to one pinned jaxpr (kernel bodies included): in one block as the
+    GPT-2 and LFM2 cells run it, and in 512-blocks.  Pinned first on commit
+    c48259c, before the kernels knew a window; taken again in PR 34, whose
+    backward is one kernel where it was two (the forward's part of the text
+    did not change); the lowered text itself carries the checkout's path
+    and cannot be pinned."""
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
 
     def loss(q, k, v):

@@ -98,9 +98,9 @@ WINDOWS = [(256, 64, 64, 96), (256, 64, 64, 64), (256, 64, 64, 40),
 @pytest.mark.parametrize("T,block_q,block_k,window", WINDOWS)
 def test_windowed_kernels_against_the_masked_xla_arm(
         interpret_kernel, T, block_q, block_k, window):
-    """Forward, dq and dk/dv (interpreted) skip and mask to the same result
-    as ``_mha_reference`` under the same window, output and all three
-    gradients.  Tolerance 1e-5 of each tensor's largest entry: both sides
+    """Forward and the one backward kernel (interpreted) skip and mask to the
+    same result as ``_mha_reference`` under the same window, output and all
+    three gradients.  Tolerance 1e-5 of each tensor's largest entry: both sides
     are float32, the kernels sum the keys block by block."""
     q, k, v, probe = (_rand(70 + i, (1, 2, T, 32)) for i in range(4))
     scale = 1.0 / math.sqrt(32)

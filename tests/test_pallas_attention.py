@@ -1,10 +1,11 @@
 """Pallas flash-attention kernels vs the scan blockwise reference.
 
 Interpret mode on CPU (same jaxpr the TPU compiles).  Round 5: both
-directions are hand-written kernels — the backward runs the Pallas
-dq/dk/dv pair (p recomputed from saved lse, delta term, causal loop
-bounds) and must match differentiating the scan formulation and a dense
-XLA softmax reference.
+directions are hand-written kernels — the backward is ONE Pallas kernel
+since PR 34 (``flash_dqkv``: p recomputed from saved lse, delta term,
+causal loop bounds, dq summed over the key blocks in a VMEM scratch) and
+must match differentiating the scan formulation and a dense XLA softmax
+reference.
 """
 import jax
 import jax.numpy as jnp
@@ -362,3 +363,135 @@ def test_mha_op_keeps_each_device_on_its_own_rows():
     for a, b, nme in zip(got[1], want[1], ("x", "wq", "wk", "wv", "wo")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
                                    atol=1e-4, err_msg=nme)
+
+
+def _parts(r, B, H, T, dtype):
+    """A head of 256 put together as GLM-4.7-Flash's latent attention does:
+    192 dims without position and 64 rotary ones, the key's rotary part ONE
+    for all heads."""
+    q_nope, k_nope = (r.standard_normal((B, H, T, 192)) * 0.3
+                      for _ in range(2))
+    q_rope = r.standard_normal((B, H, T, 64)) * 0.3
+    k_rope = r.standard_normal((B, 1, T, 64)) * 0.3
+    return tuple(jnp.asarray(a, dtype)
+                 for a in (q_nope, q_rope, k_nope, k_rope))
+
+
+def _glm_heads(q_nope, q_rope, k_nope, k_rope):
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
+    return q, k
+
+
+# (q shape, key length, blocks, causal, window, dtype, out_dtype)
+_FUSED = {
+    "one-square-block-4-heads": ((1, 4, 256, 16), 256, (None, None), True,
+                                 None, jnp.float32, None),
+    "one-block-bf16": ((1, 4, 256, 16), 256, (None, None), True, None,
+                       jnp.bfloat16, None),
+    "four-blocks-causal": ((1, 2, 512, 16), 512, (128, 128), True, None,
+                           jnp.float32, None),
+    "oblong-blocks-causal": ((1, 2, 512, 16), 512, (128, 256), True, None,
+                             jnp.float32, None),
+    "window-inside-a-block": ((1, 2, 512, 16), 512, (128, 128), True, 96,
+                              jnp.float32, None),
+    "window-of-two-blocks": ((1, 2, 512, 16), 512, (128, 128), True, 256,
+                             jnp.float32, None),
+    "window-to-position-0": ((1, 2, 512, 16), 512, (128, 128), True, 512,
+                             jnp.float32, None),
+    "ring-off-diagonal-Tq-ne-Tk": ((1, 2, 256, 16), 512, (128, 128), False,
+                                   None, jnp.float32, None),
+    "bf16-out-float32": ((1, 2, 256, 16), 256, (128, 128), True, None,
+                         jnp.bfloat16, jnp.float32),
+    "bf16-out-bf16": ((1, 2, 256, 16), 256, (128, 128), True, None,
+                      jnp.bfloat16, jnp.bfloat16),
+    "head-of-256-as-glm": ((1, 2, 256, 256), 256, (128, 128), True, None,
+                           jnp.float32, "glm"),
+}
+
+
+@pytest.mark.parametrize("case", list(_FUSED))
+def test_fused_backward_matches_the_reference(case):
+    """ISSUE 34: ``flash_dqkv``'s dq, dk and dv against the gradients of
+    ``ops.nn._mha_reference`` — one block whose diagonal is all there is
+    (dq from the bands), several blocks (dq summed over the key blocks'
+    programs), the window's three loop shapes, the ring's off-diagonal
+    shard (not causal, Tq != Tk), both result dtypes of
+    ``flash_attention_bwd``, and a head of 256."""
+    from mxnet_tpu.ops.nn import _mha_reference
+    shape, Tk, blocks, causal, window, dtype, out = _FUSED[case]
+    B, H, Tq, D = shape
+    r = np.random.default_rng(34)
+    scale = D ** -0.5
+    f32 = jnp.float32
+
+    def want_of(fn, *xs):
+        return jax.grad(lambda *a: jnp.vdot(
+            fn(*(x.astype(f32) for x in a)), co.astype(f32)),
+            tuple(range(len(xs))))(*xs)
+
+    if out == "glm":
+        parts = _parts(r, B, H, Tq, dtype)
+        v, co = (jnp.asarray(r.standard_normal(shape) * 0.5, dtype)
+                 for _ in range(2))
+        got = jax.grad(lambda *a: jnp.vdot(pa.flash_attention(
+            *_glm_heads(*a[:4]), a[4], causal, None, *blocks), co),
+            tuple(range(5)))(*parts, v)
+        want = want_of(lambda *a: _mha_reference(
+            *_glm_heads(*a[:4]), a[4], causal, scale), *parts, v)
+        names = ("q_nope", "q_rope", "k_nope", "k_rope", "v")
+    else:
+        q, co = (jnp.asarray(r.standard_normal(shape) * 0.5, dtype)
+                 for _ in range(2))
+        k, v = (jnp.asarray(r.standard_normal((B, H, Tk, D)) * 0.5, dtype)
+                for _ in range(2))
+        want = want_of(lambda *a: _mha_reference(*a, causal, scale, window),
+                       q, k, v)
+        names = ("dq", "dk", "dv")
+        if out is None:
+            got = jax.grad(lambda *a: jnp.vdot(pa.flash_attention(
+                *a, causal, None, *blocks, window).astype(f32),
+                co.astype(f32)), (0, 1, 2))(q, k, v)
+            assert all(g.dtype == dtype for g in got)
+        else:       # the ring's call: statistics handed in, dtype named
+            o, lse = pa._flash_fwd_call(q, k, v, causal, scale, *blocks,
+                                        "lse")
+            delta = jnp.sum(co.astype(f32) * o.astype(f32), axis=-1)
+            got = pa.flash_attention_bwd(q, k, v, co, lse, delta, causal,
+                                         scale, *blocks, out_dtype=out)
+            assert all(g.dtype == out for g in got)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for a, b, nme in zip(got, want, names):
+        size = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a, np.float32) / size,
+                                   np.asarray(b, np.float32) / size,
+                                   rtol=0, atol=tol, err_msg=nme)
+
+
+def test_backward_counts_one_fused_program_a_build():
+    """``attention_backward_total{form="fused"}`` rises once where
+    ``flash_attention_bwd`` builds a kernel program, not once a call, and
+    nothing builds the two-pass pair."""
+    from mxnet_tpu import telemetry
+    q, k, v = _case(B=1, H=3, T=128, D=24, seed=34)     # no other test's
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(
+            pa.flash_attention(*a, True, None, 64, 64) ** 2),
+            (0, 1, 2))(q, k, v)
+
+    telemetry.enable()
+    try:
+        fused0 = telemetry.value("attention_backward_total", form="fused")
+        pair0 = telemetry.value("attention_backward_total", form="two_pass")
+        grads()
+        assert telemetry.value("attention_backward_total",
+                               form="fused") == fused0 + 1
+        grads()
+        assert telemetry.value("attention_backward_total",
+                               form="fused") == fused0 + 1
+        assert telemetry.value("attention_backward_total",
+                               form="two_pass") == pair0
+    finally:
+        telemetry.disable()

@@ -84,15 +84,15 @@ def test_flash_attention_compiles(one_chip, direction, shape):
     fn = functools.partial(pa.flash_attention, causal=True)
     if direction == "forward":
         assert _custom_calls(fn, q, q, q) == 1
-    else:       # forward-with-lse, dq, dk/dv
-        assert _custom_calls(jax.grad(_sq(fn), (0, 1, 2)), q, q, q) == 3
+    else:       # forward-with-lse, and the one backward kernel
+        assert _custom_calls(jax.grad(_sq(fn), (0, 1, 2)), q, q, q) == 2
 
 
 def test_mha_op_compiles_for_four_chips_each_on_its_own_sequence(topo):
     """``gpt2m_train_dp4``'s attention, ahead of time: the op over
     (4, 1024, 1024) with the batch sharded over the four chips of the
     described host and the mesh in context, as the mesh fused step traces
-    it.  Forward + backward hold three Mosaic calls, each over ONE
+    it.  Forward + backward hold two Mosaic calls, each over ONE
     sequence's 16 heads, and nothing is gathered: a bare ``pallas_call``
     would have every chip run all four."""
     import numpy as np
@@ -110,7 +110,7 @@ def test_mha_op_compiles_for_four_chips_each_on_its_own_sequence(topo):
         hlo = jax.jit(jax.grad(_sq(fn), (0, 1, 2, 3, 4))).lower(
             x, w, w, w, w).compile().as_text()
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 3
+    assert len(calls) == 2
     for ln in calls:            # results and operands: one sequence, 16 heads
         assert "bf16[16,1024,64]" in ln and "bf16[64,1024,64]" not in ln
     assert "all-gather" not in hlo and "all-to-all" not in hlo
@@ -119,7 +119,7 @@ def test_mha_op_compiles_for_four_chips_each_on_its_own_sequence(topo):
 def test_grouped_rotary_attention_reaches_the_kernels(one_chip):
     """``lfm2moe_train_2k``'s attention layer, ahead of time: 32 query heads
     over 8 key/value heads of 64 at T 2048, per-head norms and rotary
-    positions.  Forward + backward hold the three Mosaic calls over 32
+    positions.  Forward + backward hold the two Mosaic calls over 32
     heads in one 2048-block, and no T x T tensor."""
     from mxnet_tpu.ops.registry import OPS
     op = OPS["MultiHeadAttention"]
@@ -136,7 +136,7 @@ def test_grouped_rotary_attention_reaches_the_kernels(one_chip):
     hlo = jax.jit(jax.grad(_sq(fn), tuple(range(7)))).lower(
         *args).compile().as_text()
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert all("bf16[32,2048,64]" in ln for ln in calls)
     assert "[1,32,2048,2048]" not in hlo and "[32,2048,2048]" not in hlo
 
@@ -185,7 +185,7 @@ def test_mellum_attention_reaches_its_kernels(one_chip, kind):
     """``mellum2moe_train_4k``'s two kinds of attention layer, ahead of time:
     32 query heads of 128 over 4 key/value heads under a 2304-wide stream at
     T 4096, in 512-blocks; a window of 1024 with the default rope, or full
-    attention under YaRN.  Forward + backward hold the three Mosaic calls
+    attention under YaRN.  Forward + backward hold the two Mosaic calls
     over 32 heads, no T x T tensor, and the sliding layer's calls carry the
     scope ``window_flash_roofline_pct.train`` finds them by."""
     import json
@@ -211,7 +211,7 @@ def test_mellum_attention_reaches_its_kernels(one_chip, kind):
     hlo = jax.jit(jax.grad(loss, tuple(range(5)))).lower(
         *args).compile().as_text()
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert all("bf16[32,4096,128]" in ln for ln in calls)
     assert "[1,32,4096,4096]" not in hlo and "[32,4096,4096]" not in hlo
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -219,7 +219,7 @@ def test_mellum_attention_reaches_its_kernels(one_chip, kind):
                            "window_flash_roofline_pct.train.json")) as f:
         reads = re.compile(json.load(f)["params"]["kernel"])
     found = [bool(reads.search(ln)) for ln in calls]
-    assert found == [kind == "sliding_attention"] * 3
+    assert found == [kind == "sliding_attention"] * 2
 
 
 def test_latent_attention_reaches_the_kernels_at_a_head_of_256(one_chip):
@@ -227,7 +227,7 @@ def test_latent_attention_reaches_the_kernels_at_a_head_of_256(one_chip):
     192 + 64 (values of 256) under ranks 768 / 512 and a 2048-wide stream at
     T 4096.  A head's K and V are 4 MB, 8 MB double-buffered: past
     ``kv_fits_vmem``'s own 5 MB, inside ``MHA_KV_VMEM``.  Forward + backward
-    hold the three Mosaic calls over 20 heads of 256 in 512-blocks, no
+    hold the two Mosaic calls over 20 heads of 256 in 512-blocks, no
     T x T tensor, and the calls carry the scope
     ``mla_flash_roofline_pct.train`` finds them by."""
     import json
@@ -257,7 +257,7 @@ def test_latent_attention_reaches_the_kernels_at_a_head_of_256(one_chip):
     hlo = jax.jit(jax.grad(loss, tuple(range(8)))).lower(
         *args).compile().as_text()
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert all("bf16[20,4096,256]" in ln for ln in calls)
     assert "[1,20,4096,4096]" not in hlo and "[20,4096,4096]" not in hlo
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -267,10 +267,13 @@ def test_latent_attention_reaches_the_kernels_at_a_head_of_256(one_chip):
     assert all(reads.search(ln) for ln in calls)
 
 
-@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("direction", ["forward", "backward",
+                                       "backward-diagonal"])
 def test_flash_attention_ring_variant_compiles(one_chip, direction):
     """The stats-emitting kernel ring attention runs per shard, and the
-    backward it feeds with full-sequence stats."""
+    backward it feeds with full-sequence stats: ONE kernel, ``flash_dqkv``,
+    whose three results are float32, off the diagonal shard (not causal)
+    and on it."""
     from mxnet_tpu.ops import pallas_attention as pa
     B, H, T, D = 8, 12, 2048, 64
     q = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
@@ -279,10 +282,39 @@ def test_flash_attention_ring_variant_compiles(one_chip, direction):
         fn = functools.partial(pa.flash_attention_stats, causal=True,
                                scale=D ** -0.5)
         assert _custom_calls(fn, q, q, q) == 1
-    else:
-        fn = functools.partial(pa.flash_attention_bwd, causal=False,
-                               scale=D ** -0.5)
-        assert _custom_calls(fn, q, q, q, q, stat, stat) == 2
+        return
+    fn = functools.partial(pa.flash_attention_bwd, scale=D ** -0.5,
+                           causal=direction == "backward-diagonal")
+    hlo = jax.jit(fn).lower(q, q, q, q, stat, stat).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "flash_dqkv" in calls[0]
+    assert calls[0].count("f32[96,2048,64]") >= 3
+
+
+@pytest.mark.parametrize("shape", [(1, 20, 4096, 256), (1, 4, 8192, 128),
+                                   (1, 4, 16384, 64)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_backward_fits_the_envelope_mha_admits(one_chip, shape):
+    """The largest shapes ``mha_uses_kernel`` lets in (``MHA_KV_VMEM``: a
+    head's K and V of 8 MB double-buffered) build ONE backward kernel that
+    holds the head's Q, dO and float32 dq whole: 18.5 MB of buffers at
+    GLM's head of 256 and at 8,192 x 128, inside the 32 MB the kernels ask
+    for anyway, and 35 MB at 16,384 x 64, whose lanes are padded to 128:
+    there the kernel asks for what ``_bwd_vmem_bytes`` reckons."""
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops.nn import mha_uses_kernel
+    B, H, T, D = shape
+    assert mha_uses_kernel(B, H, T, D, jnp.bfloat16)
+    need = pa._bwd_vmem_bytes(1, T, 512, 512, D, 2, 2)
+    assert (need + pa._TILE_ROOM > pa._VMEM_LIMIT) == (shape[2] == 16384)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((B, H, T), jnp.float32, sharding=one_chip)
+    fn = functools.partial(pa.flash_attention_bwd, causal=True,
+                           scale=D ** -0.5, block_q=None, block_k=None,
+                           out_dtype=jnp.bfloat16)
+    hlo = jax.jit(fn).lower(q, q, q, q, stat, stat).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "flash_dqkv" in calls[0]
 
 
 @pytest.mark.parametrize("direction", ["forward", "forward+backward"])

@@ -4,7 +4,9 @@
     chiprun -- python3 tools/bench_attention_arms.py \
         --shapes 1x16x1024x64 --arms reference,flash,jax [--blocks 256x256]
 
-Forward + gradient (q, k, v) of causal attention at each ``BxHxTxd``, bf16:
+Forward + gradient (q, k, v) of causal attention at each ``BxHxTxd``, bf16
+(``--window W``: sliding-window attention, position t sees the W positions up
+to and with t; the ``jax`` arms have no window and are left out):
 
   reference  ``ops.nn._mha_reference`` (the XLA arm of ``MultiHeadAttention``)
   flash      ``ops.pallas_attention.flash_attention`` (the repo's kernels;
@@ -21,7 +23,10 @@ device operations that carry its scope (``perf/trace.py``'s reader), divided
 by the iterations.  One JSON line per (shape, arm): ``device_ms``, the
 achieved TFLOP/s on the operations a causal forward+backward REQUIRES (6
 half-square products, recomputation not counted), the worst gradient gap to
-the reference arm and the arm's four longest operations.  The table in ``ops/nn.py`` ``mha_uses_kernel`` and ``PERF.md``'s
+the reference arm, the arm's four longest operations and, in ``kernels_ms``,
+the time of each of the repo's kernels by name (``flash_fwd``,
+``flash_dqkv``; ``flash_dq`` and ``flash_dkv`` on a tree from before PR 34).
+The table in ``ops/nn.py`` ``mha_uses_kernel`` and ``PERF.md``'s
 readings come from this script.  Hand-run, not tier-1; without a TPU it
 refuses unless ``--rehearse`` (CPU, kernels interpreted, no ``jax`` arm,
 times meaningless: a control-flow check only).
@@ -29,6 +34,7 @@ times meaningless: a control-flow check only).
 import argparse
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -38,21 +44,26 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-def required_flops(B, H, T, d):
+def required_flops(B, H, T, d, window=None):
     """Causal forward + backward: QK^T, PV forward; dV, dP, dQ, dK backward:
-    six products over the visible half of the square."""
-    return 6 * 2 * B * H * (T * (T + 1) // 2) * d
+    six products over the visible half of the square, under a ``window`` the
+    part of it within ``window`` positions of the diagonal."""
+    w = min(window or T, T)
+    return 6 * 2 * B * H * (w * (w + 1) // 2 + (T - w) * w) * d
 
 
-def build_arm(name, causal, scale, blocks):
+_KERNEL = re.compile(r"%?(flash_[a-z]+)")
+
+
+def build_arm(name, causal, scale, blocks, window=None):
     import jax
     from mxnet_tpu.ops import pallas_attention as pa
     from mxnet_tpu.ops.nn import _mha_reference
     if name == "reference":
-        return lambda q, k, v: _mha_reference(q, k, v, causal, scale)
+        return lambda q, k, v: _mha_reference(q, k, v, causal, scale, window)
     if name == "flash":
-        return lambda q, k, v: pa.flash_attention(q, k, v, causal, scale,
-                                                  *(blocks or ()))
+        return lambda q, k, v: pa.flash_attention(
+            q, k, v, causal, scale, *(blocks or (None, None)), window)
     if name in ("jax", "jax256"):
         from jax.experimental.pallas.ops.tpu import flash_attention as jfa
         bs = None
@@ -84,6 +95,8 @@ def main(argv=None):
                          "_heads_per_program)")
     ap.add_argument("--band", type=int, default=0,
                     help="one band size for all three kernels in this run")
+    ap.add_argument("--window", type=int, default=0,
+                    help="sliding-window attention: positions a query sees")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     if args.rehearse:
@@ -114,7 +127,7 @@ def main(argv=None):
             arms += [("flash" + ("" if b is None else "%dx%d" % b)
                       + ("" if h is None else "g%d" % h), "flash", (b, h))
                      for b in blocks for h in heads]
-        elif not (args.rehearse and a.startswith("jax")):
+        elif not ((args.rehearse or args.window) and a.startswith("jax")):
             arms.append((a, a, None))
     dtype = jnp.dtype(args.dtype)
     rs = np.random.RandomState(args.seed)
@@ -132,7 +145,7 @@ def main(argv=None):
                 if h is not None:       # read when the kernels are traced
                     pa._heads_per_program = lambda BH, T, itemsize, h=h: h
                     jax.clear_caches()
-            fn = build_arm(arm, True, scale, blk)
+            fn = build_arm(arm, True, scale, blk, args.window or None)
 
             def step(q, k, v, co, fn=fn, tag=tag):
                 # graftlint: disable=GL006 -- a bench's own tag, no model
@@ -172,7 +185,8 @@ def main(argv=None):
     lines = []
     if dev.platform == "tpu":
         red = ptrace.reduce(trace_dir, 1)
-        rows = red.devices[min(red.devices)]
+        # no device line: every arm was skipped
+        rows = red.devices[min(red.devices)] if red.devices else []
     else:
         rows = []
     for shape, label, tag, _, _ in jobs:
@@ -183,13 +197,22 @@ def main(argv=None):
         for r in mine:
             by_name[r[0]] = by_name.get(r[0], 0) + r[2]
         top = sorted(by_name.items(), key=lambda kv: -kv[1])[:4]
+        kernels = {}
+        for n, t in by_name.items():
+            m = _KERNEL.match(n)
+            if m:
+                kernels[m.group(1)] = round(
+                    kernels.get(m.group(1), 0) + t * 1e-9 / args.iters, 4)
         ms = ps * 1e-9 / args.iters
         line = {"shape": list(shape), "arm": label, "dtype": args.dtype,
+                "window": args.window or None,
                 "device": dev.device_kind, "iters": args.iters,
                 "device_ms": round(ms, 4) if rows else None,
-                "required_tflops": (round(required_flops(*shape) / ms * 1e-9,
-                                          2) if ms else None),
+                "required_tflops": (round(required_flops(
+                    *shape, args.window or None) / ms * 1e-9, 2)
+                    if ms else None),
                 "grad_gap_to_reference": gaps.get(tag),
+                "kernels_ms": kernels,
                 "top_ops_ms": [[n[:60], round(t * 1e-9 / args.iters, 4)]
                                for n, t in top]}
         lines.append(line)
