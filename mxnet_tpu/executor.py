@@ -67,6 +67,13 @@ _GRAD_EXCHANGE = _telemetry.counter(
     "grad_exchange_total",
     "Mesh step programs built, by the way their split leaves' gradients "
     "are exchanged (trace-time)", ("path",))
+# How a mesh step program reads each table held split over ``dp`` that an
+# ``Embedding`` reads (``parallel.mesh.lookup_path``); one inc a table per
+# program built.
+_EMBEDDING_LOOKUP = _telemetry.counter(
+    "embedding_lookup_total",
+    "Held-split Embedding tables of the mesh step programs built, by how "
+    "the program reads them (trace-time)", ("path",))
 
 
 class _Plan:
@@ -114,6 +121,23 @@ class _Plan:
         for node, _a, _r, _w in self.steps:
             env_union.update(node.op.env_keys)
         self.env_keys = tuple(sorted(env_union))
+        self._uses = None
+
+    def uses(self) -> Dict[str, List[Tuple[str, int]]]:
+        """{variable: the (op, input index) pairs that read it}; a head of
+        the graph reads as ``("output", 0)``."""
+        if self._uses is None:
+            self._uses = {}
+            for node in self.topo:
+                for ii, (src, _) in enumerate(node.inputs):
+                    if src.is_var:
+                        self._uses.setdefault(src.name, []).append(
+                            (node.op.name, ii))
+            heads = {nid for nid, _ in self.out_entries}
+            for node in self.topo:
+                if node.is_var and id(node) in heads:
+                    self._uses.setdefault(node.name, []).append(("output", 0))
+        return self._uses
 
     def execute(self, arg_vals: Dict[str, Any], aux_vals: Dict[str, Any],
                 keys, monitor=None, placements=None):
@@ -547,7 +571,10 @@ class Executor:
         replica updates its part of the leaf from the part of the weight it
         holds, and the new weight and the state leave in the layout they
         were taken in.  A leaf whose two shardings are the same object
-        (small, ``dp`` of 1, a spec that names ``dp``) is untouched.
+        (small, ``dp`` of 1, a spec that names ``dp``) is untouched.  A
+        table that only ``Embedding``s read, split along its columns, is
+        not gathered on the ``async`` exchange: each chip looks it up in
+        its own columns (``lookup_paths``, ``parallel.mesh.lookup``).
 
         ``name`` is the program's: its compile phases are recorded as
         ``XLA::* <name>`` (``program_cache``'s observer).
@@ -566,8 +593,12 @@ class Executor:
         other_names = [n for n in arg_names if n not in pset]
         from .parallel import mesh as _mesh
         exchange = _mesh.exchange_path(param_shardings, state_shardings)
+        lookups = self.lookup_paths(pnames, param_shardings, state_shardings,
+                                    exchange)
         if exchange is not None and _telemetry.enabled:
             _GRAD_EXCHANGE.labels(path=exchange).inc()
+            for how in filter(None, lookups):
+                _EMBEDDING_LOOKUP.labels(path=how).inc()
 
         def fn(pvals, svals, others, auxs, keys, ograds, lrs, wds, ts,
                rescale):
@@ -585,10 +616,13 @@ class Executor:
             if state_shardings is not None:
                 # a weight held as the replica's part of it: gathered here,
                 # ahead of forward, where the products hide the exchange
+                # (a table looked up in its own columns never is)
                 with jax.named_scope(_atlas.GRAD_SYNC):
-                    gathered = [p if ssh is psh else pin(p, psh)
-                                for p, psh, ssh in zip(
-                                    pvals, param_shardings, state_shardings)]
+                    gathered = [p if ssh is psh or how == "own_columns"
+                                else pin(p, psh)
+                                for p, psh, ssh, how in zip(
+                                    pvals, param_shardings, state_shardings,
+                                    lookups)]
             # on TPUs a split weight's gradient leaves the product that
             # makes it around a ring of asynchronous permutes, not through
             # a blocking reduce-scatter behind backward
@@ -628,6 +662,22 @@ class Executor:
         fn = jax.jit(fn, donate_argnums=(0, 1))
         self._jitted[key] = fn
         return fn
+
+    def lookup_paths(self, pnames, param_shardings, state_shardings,
+                     exchange):
+        """[how the mesh step program reads each of ``pnames`` where it is
+        a table held split over ``dp`` that an ``Embedding`` reads:
+        ``"own_columns"`` or ``"gathered"`` (``parallel.mesh.lookup_path``,
+        from its layout, its uses in the graph and the ``exchange``, the
+        layout's ``exchange_path``); None for every other]"""
+        if state_shardings is None:
+            return [None] * len(pnames)
+        from .parallel import mesh as _mesh
+        uses = self._plan(True).uses()
+        return [_mesh.lookup_path(self.arg_dict[n].shape, uses.get(n, ()),
+                                  psh, ssh, exchange)
+                for n, psh, ssh in zip(pnames, param_shardings,
+                                       state_shardings)]
 
     def _gather(self):
         args = [self.arg_dict[n]._data for n in self.arg_names]

@@ -291,6 +291,7 @@ class ModuleFusedStep:
         self._place = None           # _placement()'s (False: none), and
         self._layout = None          # _mesh_layout()'s, of that mesh
         self._exchange = None        # its exchange_path(): a mesh, split
+        self._lookup = None          # how it reads its held-split tables
         self._split = None           # _count_split()'s, of that layout
         self._meshed = False         # handles currently hold mesh globals
         self._mesh_outputs = None    # full-batch outputs of the last step
@@ -521,6 +522,9 @@ class ModuleFusedStep:
         if self._exchange is not None:
             # how the program exchanges its split leaves' gradients
             run["exchange"] = self._exchange
+        if self._lookup is not None:
+            # how it reads the tables of its Embeddings held split over dp
+            run["embedding"] = self._lookup
         with _span("Step::program", run):
             plan = ex._plan(True)
             keys = ex._keys(plan)
@@ -643,6 +647,7 @@ class ModuleFusedStep:
                     setup = None
         self._mesh_cache = (key, setup)
         self._place = self._layout = self._split = self._exchange = None
+        self._lookup = None
         return setup
 
     def _placement(self):
@@ -746,10 +751,11 @@ class ModuleFusedStep:
                 replicated_sharding, state_sharding
             repl = replicated_sharding(mesh)
             ex = self._eg.execs[0]
-            psh, ssh = [], []
+            names, psh, ssh = [], [], []
             for name in self._mod._param_names:
                 if name not in self._pset:
                     continue
+                names.append(name)
                 shape = ex.arg_dict[name].shape
                 sh = repl if rules is None else \
                     rules.sharding_for(name, shape)
@@ -763,6 +769,9 @@ class ModuleFusedStep:
                 sig += (tuple(str(sh.spec) for sh in ssh),)
             self._layout = (psh, ssh, sig)
             self._exchange = exchange_path(psh, ssh)
+            paths = set(ex.lookup_paths(names, psh, ssh, self._exchange))
+            self._lookup = "own_columns" if "own_columns" in paths else \
+                "gathered" if "gathered" in paths else None
         return self._layout
 
     def _count_split(self, svals):

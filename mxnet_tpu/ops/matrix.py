@@ -14,6 +14,7 @@ import numpy as np
 
 from .registry import register, param
 from ..base import MXNetError
+from ..parallel.mesh import lookup as _lookup
 
 
 def infer_reshape(src_shape, target, reverse=False):
@@ -308,8 +309,10 @@ def _scatter_nd(attrs, data, indices):
 def _embedding(attrs, data, weight):
     """Embedding lookup = one_hot @ weight on MXU for tiny vocab, or gather.
     XLA picks the gather path; sparse_grad handled by optimizer-side rowwise
-    updates (ref: src/operator/tensor/indexing_op.cc Embedding)."""
-    return jnp.take(weight, data.astype(jnp.int32), axis=0)
+    updates (ref: src/operator/tensor/indexing_op.cc Embedding).  In the
+    mesh step a table held split along its columns is looked up in each
+    chip's own columns (``parallel.mesh.lookup``)."""
+    return _lookup(weight, data.astype(jnp.int32))
 
 
 @register("_contrib_SparseEmbedding", nin=2,

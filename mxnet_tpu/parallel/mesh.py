@@ -18,7 +18,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["make_mesh", "data_parallel_sharding", "replicated_sharding",
            "state_sharding", "exchange_path", "grad_exchange",
-           "matmul_wt", "ShardingRules", "megatron_rules",
+           "matmul_wt", "lookup", "lookup_path", "ShardingRules",
+           "megatron_rules",
            "host_shard_hint", "P"]
 
 #: a leaf under this many elements keeps its parameter's sharding: a
@@ -154,7 +155,8 @@ def exchange_path(param_shardings, state_shardings) -> Optional[str]:
 def grad_exchange(mesh: Mesh, dp_axis: str):
     """While tracing under it, ``matmul_wt`` makes the gradient of a weight
     that ``state_sharding`` splits over ``dp_axis`` in that layout, through
-    the ring."""
+    the ring, and ``lookup`` reads a table split along its columns in each
+    chip's own columns."""
     was = tuple(getattr(_exchange, k, None) for k in ("on", "last", "ended"))
     _exchange.on = (mesh, dp_axis, _ring_order(mesh, dp_axis))
     # the newest ring's (gradient, split axis), and {id(gradient):
@@ -259,6 +261,31 @@ def _split_axis(shape, on):
     return None if ssh is repl else list(ssh.spec).index(dp)
 
 
+def _by_columns(shape, on):
+    """Whether ``state_sharding`` splits a replicated table of ``shape``
+    along its columns (its output axis: a vocabulary that does not divide
+    by ``dp``), so that a chip holds every row of a part of the columns."""
+    return len(shape) == 2 and _split_axis(shape, on) == 1
+
+
+def lookup_path(shape, uses, param_sharding, state_sharding, exchange):
+    """How the mesh step reads a parameter of ``shape`` that ``uses``, the
+    (op, input) pairs of the graph that read it, name as an ``Embedding``'s
+    table: None where it is not held split over ``dp`` or no ``Embedding``
+    reads it.  ``"own_columns"`` on the ``async`` exchange where it is
+    split along its columns and every use is an ``Embedding``'s table: the
+    program leaves it split and each chip looks up its own columns
+    (``lookup``).  ``"gathered"`` elsewhere: a tied head or a table split
+    along its rows is gathered whole at the top of the step, as every
+    other held-split weight."""
+    if state_sharding is param_sharding or ("Embedding", 1) not in uses:
+        return None
+    if exchange == "async" and set(uses) == {("Embedding", 1)} \
+            and _by_columns(shape, (state_sharding.mesh, "dp", None)):
+        return "own_columns"
+    return "gathered"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _matmul_wt_ring(on, x, w):
     return jnp.matmul(x, w.T)
@@ -340,6 +367,79 @@ def matmul_wt(x, w):
             or x.shape[0] % len(on[2]) or _split_axis(w.shape, on) is None:
         return jnp.matmul(x, w.T)
     return _matmul_wt_ring(on, x, w)
+
+
+# -- a table's lookup on the chip's own columns --------------------------------
+#
+# A token table whose rows do not divide by ``dp`` (GPT-2's 50,257) has its
+# state, and between steps itself, split along its columns.  Read whole, it
+# is gathered at the top of the step, ahead of forward's first op, where
+# nothing hides the gather (103 MB in ``gpt2m_train_dp4``).  Instead each
+# chip looks up its own columns for every chip's ids and one all-to-all
+# hands each chip its own rows' every column (2 MB there); the gradient
+# comes back the same way and is scattered into the chip's own columns.
+
+@functools.lru_cache(maxsize=None)
+def _own_columns(on, rows):
+    """``(forward, backward)`` of ``lookup`` on a table of ``rows`` rows
+    split along its columns: ``forward(ids, table) -> (out, every)``, the
+    batch's rows of ``ids`` split over ``dp`` as the mesh step feeds them
+    and ``every`` every chip's ids, the residual; ``backward(every, dy) ->
+    the table's gradient`` in the table's layout.  ``jax.jit`` callees, as
+    the ring's."""
+    _, dp, _ = on
+
+    def forward(ids, part):
+        every = jax.lax.all_gather(ids, dp)             # [n, *ids]
+        got = jnp.take(part, every, axis=0)             # [n, *ids, d]
+        # chip i's ids leave for chip i; the columns arrive in chip order
+        out = jax.lax.all_to_all(got, dp, 0, got.ndim - 1, tiled=True)
+        return out[0], every
+
+    def backward(every, dy):
+        # the mirror image: the chip's columns of every chip's rows
+        cols = jax.lax.all_to_all(dy[None], dp, dy.ndim, 0, tiled=True)
+        zero = jax.ShapeDtypeStruct((rows, cols.shape[-1]), cols.dtype)
+        return jax.linear_transpose(
+            lambda t: jnp.take(t, every, axis=0), zero)(cols)[0]
+
+    split = P(None, dp)
+    return (jax.jit(jax.shard_map(forward, in_specs=(P(dp), split),
+                                  out_specs=(P(dp), P()), axis_names={dp},
+                                  check_vma=False)),
+            jax.jit(jax.shard_map(backward, in_specs=(P(), P(dp)),
+                                  out_specs=split, axis_names={dp},
+                                  check_vma=False)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _lookup_own_columns(on, rows, ids, table):
+    return _own_columns(on, rows)[0](ids, table)[0]
+
+
+def _lookup_fwd(on, rows, ids, table):
+    return _own_columns(on, rows)[0](ids, table)
+
+
+def _lookup_bwd(on, rows, every, dy):
+    return None, _own_columns(on, rows)[1](every, dy)
+
+
+_lookup_own_columns.defvjp(_lookup_fwd, _lookup_bwd)
+
+
+def lookup(table, ids):
+    """``table``'s rows at the integer ``ids`` (``jnp.take`` along axis 0),
+    as ``Embedding`` reads its table.  Under ``grad_exchange``, where the
+    table's state is split over ``dp`` along its columns, each chip looks
+    up its own columns and the activations cross the chips, not the
+    table, and the gradient is made in the state's layout; anywhere else
+    this IS ``jnp.take(table, ids, axis=0)``."""
+    on = getattr(_exchange, "on", None)
+    if on is None or ids.ndim < 1 or ids.shape[0] % len(on[2]) \
+            or not _by_columns(table.shape, on):
+        return jnp.take(table, ids, axis=0)
+    return _lookup_own_columns(on, table.shape[0], ids, table)
 
 
 class ShardingRules:

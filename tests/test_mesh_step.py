@@ -1279,6 +1279,196 @@ class TestGradExchange:
 
 
 # ---------------------------------------------------------------------------
+# a table split along its columns (a vocabulary that does not divide by dp)
+# is looked up in each chip's own columns on the ring's path: the program
+# gathers it nowhere, and the activations cross the chips in its place
+# ---------------------------------------------------------------------------
+LOOKUP_CASES = {
+    # name: (vocab, use, how the program reads the table)
+    "once": (13, "once", "own_columns"),
+    "twice": (13, "twice", "own_columns"),     # two Embeddings, one table
+    "tied": (13, "tied", "gathered"),          # also the head's weight
+    "rows": (16, "once", "gathered"),          # split along its rows
+}
+
+
+def _lookup_batch(rs, vocab, use, batch=8, seq=2, out=2):
+    label = (batch, seq, vocab) if use == "tied" else (batch, out)
+    return _Batch(rs.randint(0, vocab, (batch, seq)).astype(np.float32),
+                  rs.randint(-1, 2, label).astype(np.float32))
+
+
+def _lookup_module(ctxs, vocab, use, steps, batch=8, seq=2, dim=4, out=2):
+    """ids -> ``Embedding`` of a ``vocab`` x ``dim`` table -> a
+    FullyConnected head, weights from {-1, 0, 1}, ``steps`` steps of SGD
+    with momentum.  ``use``: ``once``; ``twice``, a second ``Embedding``
+    of the same table over the same ids; ``tied``, the table is also the
+    head's weight (logits over the vocabulary)."""
+    data = mx.sym.Variable("data")
+    table = mx.sym.Variable("tok_weight")
+    x = mx.sym.Embedding(data, weight=table, input_dim=vocab,
+                         output_dim=dim, name="tok")
+    if use == "twice":
+        x = x + mx.sym.Embedding(data, weight=table, input_dim=vocab,
+                                 output_dim=dim, name="tok2")
+    head = mx.sym.FullyConnected(x, weight=table, num_hidden=vocab,
+                                 flatten=False, no_bias=True, name="head") \
+        if use == "tied" else mx.sym.FullyConnected(x, num_hidden=out,
+                                                    name="fc")
+    net = mx.sym.LinearRegressionOutput(
+        head, mx.sym.Variable("softmax_label"), name="lin")
+    mod = mx.mod.Module(net, data_names=("data",),
+                        label_names=("softmax_label",), context=ctxs)
+    rs = np.random.RandomState(7)
+    first = _lookup_batch(rs, vocab, use, batch, seq, out)
+    mod.bind(data_shapes=[("data", (batch, seq))],
+             label_shapes=[("softmax_label", first.label[0].shape)])
+    mod.init_params()
+    init = np.random.RandomState(42)
+    mod.set_params({n: mx.nd.array(init.randint(-1, 2, v.shape).astype(
+        np.float32)) for n, v in mod.get_params()[0].items()}, {})
+    mod.init_optimizer(kvstore="local", optimizer=SGD_MOM[0],
+                       optimizer_params=dict(SGD_MOM[1], rescale_grad=0.125))
+    for i in range(steps):
+        mod.forward_backward(first if i == 0 else
+                             _lookup_batch(rs, vocab, use, batch, seq, out))
+        mod.update()
+    return mod
+
+
+class TestLookupOnOwnColumns:
+    @pytest.mark.parametrize("case", sorted(LOOKUP_CASES))
+    def test_lookup_ends_where_one_device_ends(self, monkeypatch,
+                                               split_small, ring_on_cpu,
+                                               case):
+        """The ring's path forced onto the CPU mesh: two steps of SGD with
+        momentum end bit for bit where one device ends, the table held in
+        its state's layout between steps, whichever way the program reads
+        it: in its own columns, once or twice (the gradient is the sum of
+        the two scatters), or gathered (tied to the head, split along its
+        rows)."""
+        monkeypatch.setenv(fused.ENV_FLAG, "1")
+        vocab, use, how = LOOKUP_CASES[case]
+        mod4 = _lookup_module(CTX4, vocab, use, 2)
+        assert mod4._fused_step._exchange == "async"
+        assert mod4._fused_step._lookup == how
+        w = mod4._exec_group.execs[0].arg_dict["tok_weight"]._data
+        assert w.sharding.spec == (("dp",) if vocab % 4 == 0
+                                   else (None, "dp"))
+        mod1 = _lookup_module([mx.cpu(0)], vocab, use, 2)
+        _assert_bitexact(mod4, mod1)
+
+    @pytest.mark.parametrize("case", sorted(LOOKUP_CASES))
+    def test_what_the_program_exchanges(self, monkeypatch, split_small,
+                                        ring_on_cpu, case):
+        """The traced program: a table looked up in its own columns is
+        pinned whole nowhere, each of its lookups gathers the ids once
+        (16 KB at GPT-2's cell) and exchanges the activations by two
+        ``all_to_all``, one a way; a tied table and one split along its
+        rows are gathered at the top as before.  The counter and
+        ``Step::program`` say which, once a program."""
+        import re
+        import jax
+        from mxnet_tpu.executor import Executor
+        from test_fused_step import timeline_of_one_step
+        monkeypatch.setenv(fused.ENV_FLAG, "1")
+        vocab, use, how = LOOKUP_CASES[case]
+        real, seen = Executor.step_program, []
+
+        def step_program(self, *a, **k):
+            fn = real(self, *a, **k)
+
+            def call(*args):
+                seen.append(str(jax.make_jaxpr(fn)(*args)))
+                return fn(*args)
+            return call
+
+        monkeypatch.setattr(Executor, "step_program", step_program)
+        telemetry.enable()
+        try:
+            paths = ("own_columns", "gathered")
+            was = {p: telemetry.value("embedding_lookup_total", path=p)
+                   for p in paths}
+            mod = _lookup_module(CTX4, vocab, use, 1)
+            by = {r.name: r for r in timeline_of_one_step(
+                mod, _lookup_batch(np.random.RandomState(3), vocab, use))}
+            grew = {p: telemetry.value("embedding_lookup_total", path=p)
+                    - was[p] for p in paths}
+        finally:
+            telemetry.disable()
+        assert grew == {p: int(p == how) for p in paths}
+        assert by["Step::program"].args["embedding"] == how
+        text = seen[0]          # one program, traced for each call here
+        pins = re.findall(r"(\w+\[[\d,]*\]) = sharding_constraint\[.*?"
+                          r"spec=(PartitionSpec\([^)]*\))", text, re.S)
+        whole = ("f32[%d,4]" % vocab, "PartitionSpec()")
+        assert (whole in pins) == (how == "gathered"), pins
+        lookups = (how == "own_columns") * (1 + (use == "twice")) or \
+            (use == "tied")     # the gathered table's lookup, sliced
+        assert text.count("all_to_all[") == 2 * lookups
+        assert text.count("all_gather[") == lookups
+
+    @pytest.mark.parametrize("ctxs", [[mx.cpu(0)], CTX4],
+                             ids=["one_device", "cpu_mesh"])
+    def test_off_the_ring_the_lookup_is_the_plain_one(self, monkeypatch,
+                                                      split_small, ctxs):
+        """One device and a CPU mesh (the ``row`` exchange) lower the
+        program they lowered before ``Embedding`` went through
+        ``parallel.mesh.lookup``: the text with the op put back to
+        ``jnp.take`` is the same text, and it holds no all-to-all of the
+        activations."""
+        import hashlib
+        import jax.numpy as jnp
+        from mxnet_tpu.executor import Executor
+        from mxnet_tpu.ops import matrix as ops_matrix
+        monkeypatch.setenv(fused.ENV_FLAG, "1")
+        real, texts = Executor.step_program, []
+
+        def step_program(self, *a, **k):
+            fn = real(self, *a, **k)
+
+            def call(*args):
+                texts.append(fn.lower(*args).as_text())
+                return fn(*args)
+            return call
+
+        monkeypatch.setattr(Executor, "step_program", step_program)
+        _lookup_module(ctxs, 13, "once", 1)
+        monkeypatch.setattr(ops_matrix, "_lookup",
+                            lambda w, ids: jnp.take(w, ids, axis=0))
+        _lookup_module(ctxs, 13, "once", 1)
+        ours, plain = (hashlib.sha1(t.encode()).hexdigest() for t in texts)
+        assert ours == plain
+        assert "all_to_all" not in texts[0]
+
+    def test_lookup_path_reads_the_layout_and_the_uses(self):
+        """``parallel.mesh.lookup_path``: own columns only on the ring's
+        path, for a table split along its columns that nothing but
+        ``Embedding``s read as their table."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from mxnet_tpu.parallel.mesh import lookup_path, make_mesh, \
+            state_sharding
+        mesh = make_mesh({"dp": 4}, devices=[c.jax_device for c in CTX4])
+        repl = NamedSharding(mesh, P())
+        emb = [("Embedding", 1)]
+
+        def how(shape, uses=emb, exchange="async"):
+            return lookup_path(shape, uses, repl,
+                               state_sharding(repl, shape), exchange)
+
+        assert how((50257, 1024)) == "own_columns"
+        assert how((50257, 1024), emb * 2) == "own_columns"
+        assert how((50257, 1024), emb + [("FullyConnected", 1)]) == \
+            "gathered"
+        assert how((50257, 1024), emb + [("output", 0)]) == "gathered"
+        assert how((50257, 1024), exchange="row") == "gathered"
+        assert how((1024, 1024)) == "gathered"          # split by rows
+        assert how((50257, 1024), [("FullyConnected", 1)]) is None
+        assert how((50257, 1024), [("Embedding", 0)]) is None
+        assert how((128, 128)) is None                  # held whole
+
+
+# ---------------------------------------------------------------------------
 # the host side of the step: what it asks of every leaf on every step
 # (PR 32: with the exchange beside backward the host's phases set the pace
 # of the four-chip cell, and a run's p95 follows whatever the host does)

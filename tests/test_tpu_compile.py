@@ -377,7 +377,10 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch, exchange):
     quarter a way), six a leaf, nearly all of their bytes with a backward
     product between start and done, none begun behind the last backward
     product but the last ring's, and the updates follow their rings in
-    among backward's products."""
+    among backward's products.  The token table, whose 1001 rows do not
+    divide by 4 and which only ``Embedding`` reads, is gathered nowhere
+    there: each chip looks up its own columns, one all-to-all a way carries
+    the activations and their gradient (``parallel.mesh.lookup``)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import mxnet_tpu as mx
@@ -469,7 +472,13 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch, exchange):
     gathered = {re.search(r"channel_id=(\d+)", ln).group(1): results(ln)[0]
                 for ln in hlo.splitlines() if " all-gather(" in ln}
     weights = [shape for dt, shape in gathered.values() if dt == "bf16"]
-    assert sorted(weights) == sorted(large), weights
+    # on the ring's path the token table (its 1001 rows do not divide by 4:
+    # split along its columns) is looked up in each chip's own columns and
+    # is gathered nowhere
+    held = list(large)
+    if exchange == "async":
+        held.remove((V, D))     # the table; the head, also (V, D), stays
+    assert sorted(weights) == sorted(held), weights
     assert {dt for dt, _ in gathered.values()} <= {"bf16", "s32"}, gathered
     assert asked == ["async"]       # the described chips, by themselves
     # reduce-scatters: each chip's quarter of every large gradient (the
@@ -526,7 +535,7 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch, exchange):
                 at["backward"].append(i)    # work, not a hoisted index
         elif re.search(r"jvp\((FullyConnected|MultiHeadAttention)", ln):
             at["forward"].append(i)
-    assert len(at["gather"]) == len(large), at["gather"]
+    assert len(at["gather"]) == len(held), at["gather"]
     assert max(at["gather"]) < min(at["backward"])
     if exchange == "row":
         assert min(at["backward"]) < min(at["update"])
@@ -551,6 +560,15 @@ def test_mesh_step_splits_the_update_over_dp(topo, monkeypatch, exchange):
                            for _, shape in reduced), reduced
     if exchange == "row":
         return
+    # the table's lookup (``gathered`` above holds one whole [V, D], the
+    # head's): one all-to-all of the activations each way under the op's
+    # scope; its update writes the chip's quarter, as every other
+    table = [ln for ln in entry if " all-to-all(" in ln
+             and "Embedding:tfm_tok_embedding" in scope(ln)]
+    assert sorted("transpose(" in scope(ln) for ln in table) == \
+        [False, True], [scope(ln) for ln in table]
+    assert [ln for ln in updates
+            if ("f32", (V, D // 4)) in results(ln)], "the table's update"
     # the ring: every weight ``matmul_wt`` multiplies (all but the two
     # embedding tables) sends and receives half a quarter a hop, bf16 as
     # the row's fusions exchange it, two hops one way and one the other
