@@ -375,6 +375,51 @@ inline Symbol DeformablePSROIPooling(const std::string &symbol_name,
   return builder_.CreateSymbol(symbol_name);
 }
 
+inline Symbol DeltaAttention(const std::string &symbol_name,
+    Symbol data,
+    Symbol query_weight,
+    Symbol key_weight,
+    Symbol value_weight,
+    Symbol q_conv_weight,
+    Symbol k_conv_weight,
+    Symbol v_conv_weight,
+    Symbol f_a_weight,
+    Symbol f_b_weight,
+    Symbol A_log,
+    Symbol dt_bias,
+    Symbol beta_weight,
+    Symbol g_a_weight,
+    Symbol g_b_weight,
+    Symbol o_norm_gamma,
+    Symbol out_proj_weight,
+    int num_heads,
+    int head_dim,
+    int conv_kernel = 4,
+    double eps = 1e-05) {
+  SymbolBuilder builder_("DeltaAttention");
+  builder_.SetParam("num_heads", num_heads);
+  builder_.SetParam("head_dim", head_dim);
+  builder_.SetParam("conv_kernel", conv_kernel);
+  builder_.SetParam("eps", eps);
+  builder_.SetInput("data", data);
+  builder_.SetInput("query_weight", query_weight);
+  builder_.SetInput("key_weight", key_weight);
+  builder_.SetInput("value_weight", value_weight);
+  builder_.SetInput("q_conv_weight", q_conv_weight);
+  builder_.SetInput("k_conv_weight", k_conv_weight);
+  builder_.SetInput("v_conv_weight", v_conv_weight);
+  builder_.SetInput("f_a_weight", f_a_weight);
+  builder_.SetInput("f_b_weight", f_b_weight);
+  builder_.SetInput("A_log", A_log);
+  builder_.SetInput("dt_bias", dt_bias);
+  builder_.SetInput("beta_weight", beta_weight);
+  builder_.SetInput("g_a_weight", g_a_weight);
+  builder_.SetInput("g_b_weight", g_b_weight);
+  builder_.SetInput("o_norm_gamma", o_norm_gamma);
+  builder_.SetInput("out_proj_weight", out_proj_weight);
+  return builder_.CreateSymbol(symbol_name);
+}
+
 inline Symbol Dropout(const std::string &symbol_name,
     Symbol data,
     double p = 0.5,
@@ -3513,6 +3558,51 @@ inline Symbol degrees(const std::string &symbol_name,
     Symbol data) {
   SymbolBuilder builder_("degrees");
   builder_.SetInput("data", data);
+  return builder_.CreateSymbol(symbol_name);
+}
+
+inline Symbol deltaattention(const std::string &symbol_name,
+    Symbol data,
+    Symbol query_weight,
+    Symbol key_weight,
+    Symbol value_weight,
+    Symbol q_conv_weight,
+    Symbol k_conv_weight,
+    Symbol v_conv_weight,
+    Symbol f_a_weight,
+    Symbol f_b_weight,
+    Symbol A_log,
+    Symbol dt_bias,
+    Symbol beta_weight,
+    Symbol g_a_weight,
+    Symbol g_b_weight,
+    Symbol o_norm_gamma,
+    Symbol out_proj_weight,
+    int num_heads,
+    int head_dim,
+    int conv_kernel = 4,
+    double eps = 1e-05) {
+  SymbolBuilder builder_("deltaattention");
+  builder_.SetParam("num_heads", num_heads);
+  builder_.SetParam("head_dim", head_dim);
+  builder_.SetParam("conv_kernel", conv_kernel);
+  builder_.SetParam("eps", eps);
+  builder_.SetInput("data", data);
+  builder_.SetInput("query_weight", query_weight);
+  builder_.SetInput("key_weight", key_weight);
+  builder_.SetInput("value_weight", value_weight);
+  builder_.SetInput("q_conv_weight", q_conv_weight);
+  builder_.SetInput("k_conv_weight", k_conv_weight);
+  builder_.SetInput("v_conv_weight", v_conv_weight);
+  builder_.SetInput("f_a_weight", f_a_weight);
+  builder_.SetInput("f_b_weight", f_b_weight);
+  builder_.SetInput("A_log", A_log);
+  builder_.SetInput("dt_bias", dt_bias);
+  builder_.SetInput("beta_weight", beta_weight);
+  builder_.SetInput("g_a_weight", g_a_weight);
+  builder_.SetInput("g_b_weight", g_b_weight);
+  builder_.SetInput("o_norm_gamma", o_norm_gamma);
+  builder_.SetInput("out_proj_weight", out_proj_weight);
   return builder_.CreateSymbol(symbol_name);
 }
 

@@ -15,7 +15,9 @@ chips.  This module is the single source of the dtype policy, gated by
 - loss reduction, softmax, batchnorm statistics and normalization
   scale/shift (``*_gamma``/``*_beta``) stay fp32, and so does what decides
   a sparse expert layer's routing (``*_router_weight``, ``*_expert_bias``):
-  a selection that flips with bf16 rounding sends a token to other experts.
+  a selection that flips with bf16 rounding sends a token to other experts;
+  so do a delta rule's decay rates (``*_A_log``, ``*_dt_bias``), which
+  enter an exponential.
 
 The flag is read at BIND time (it decides array dtypes) and joins every
 fused-program jit-cache key through ``Executor.STEP_ENV_KEYS`` (GL001),
@@ -41,8 +43,10 @@ ENV_FLAG = "MXNET_TPU_BF16"
 _LOW_PRECISION = ("bfloat16", "float16")
 
 # arguments that stay fp32 under the policy, by the end of their name:
-# normalization gains/shifts, SparseMoE's router and selection bias
-_FP32_SUFFIXES = ("_gamma", "_beta", "_router_weight", "_expert_bias")
+# normalization gains/shifts, SparseMoE's router and selection bias,
+# DeltaAttention's decay rates and their bias
+_FP32_SUFFIXES = ("_gamma", "_beta", "_router_weight", "_expert_bias",
+                  "_A_log", "_dt_bias")
 
 
 def enabled():

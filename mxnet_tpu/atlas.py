@@ -95,6 +95,18 @@ def scope_name(op_type, node_name="~"):
     return "%s:%s" % (_sanitize(op_type), _sanitize(node_name))
 
 
+def part_scope(name):
+    """A named part INSIDE one op's ``<OpType>:<node>`` scope (e.g.
+    ``DeltaAttention``'s core, ``delta_rule``): a word without a colon, so
+    the atlas still attributes the part's instructions to the op's node
+    (a scope token has a colon), while a device trace can read the part
+    alone by its name."""
+    import jax
+    if ":" in name:
+        raise ValueError("a part's scope has no colon: %r" % (name,))
+    return jax.named_scope(_sanitize(name))
+
+
 def optimizer_scope(update_fn):
     """``Optimizer::<Name>`` scope of a (bound) fused_update stage."""
     owner = getattr(update_fn, "__self__", update_fn)

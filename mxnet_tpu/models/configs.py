@@ -18,12 +18,14 @@ class TransformerConfig:
     GPT-2-style decoder; the fields after them, all with defaults that
     leave that graph as it is, select the block variants ``transformer_lm``
     can build: the norm (``layer`` | ``rms``), positions (``learned`` |
-    ``rope``), the feed-forward (``gelu``: fc1/gelu/down with
+    ``rope`` | ``none``), the feed-forward (``gelu``: fc1/gelu/down with
     biases; ``swiglu``: down(silu(gate) * up), no bias), grouped key/value
     heads and per-head query/key normalisation, a pattern of sequence
     mixers per layer (``full_attention`` | ``sliding_attention``, which sees
-    the last ``window`` positions | ``conv``, the gated short convolution;
-    empty: full attention everywhere), heads of a size of their own
+    the last ``window`` positions | ``conv``, the gated short convolution |
+    ``kda``, Kimi Delta Attention of ``n_heads`` heads of ``kda_head_dim``;
+    both convolutions take ``conv_kernel`` taps; empty: full attention
+    everywhere), heads of a size of their own
     (``head_dim``; 0: ``d_model / n_heads``), rotary parameters of a layer
     kind's own (``rope``: triples ``(kind, theta, yarn)`` with ``yarn``
     empty or ``(factor, original positions, beta_fast, beta_slow,
@@ -36,11 +38,13 @@ class TransformerConfig:
     ``softmax``), the weights of the selected over ``sum + moe_weight_eps``
     times ``routed_scaling``, and beside them ``n_shared_experts`` experts of
     the same width that every token takes with weight 1.  ``attention`` =
-    ``latent`` gives every attention layer low-rank queries
-    (``q_lora_rank``) and keys/values (``kv_lora_rank``) and heads of
+    ``latent`` gives every attention layer (those of a pattern's
+    ``full_attention`` kind) low-rank queries (``q_lora_rank``; 0: one
+    full-rank product) and keys/values (``kv_lora_rank``) and heads of
     ``qk_nope_head_dim`` dims without position and ``qk_rope_head_dim``
-    rotated ones, values of ``v_head_dim`` (``MultiHeadAttention``'s latent
-    form).  ``mtp_layers`` = 1 adds DeepSeek-V3's multi-token-prediction
+    rotated ones (``position`` ``none``: turned by nothing), values of
+    ``v_head_dim`` (``MultiHeadAttention``'s latent form).  ``mtp_layers``
+    = 1 adds DeepSeek-V3's multi-token-prediction
     module after the last block (``transformer_lm``), its loss weighted by
     ``mtp_loss_weight``."""
     name: str
@@ -81,6 +85,7 @@ class TransformerConfig:
     moe_weight_eps: float = 1e-6
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.0
+    kda_head_dim: int = 0
 
     def __post_init__(self):
         if not self.head_dim and self.attention != "latent" \
@@ -88,7 +93,7 @@ class TransformerConfig:
             raise ValueError("d_model %d not divisible by n_heads %d"
                              % (self.d_model, self.n_heads))
         for field, known in (("norm", ("layer", "rms")),
-                             ("position", ("learned", "rope")),
+                             ("position", ("learned", "rope", "none")),
                              ("ffn", ("gelu", "swiglu")),
                              ("moe_score", ("sigmoid", "softmax")),
                              ("attention", ("full", "latent"))):
@@ -99,9 +104,12 @@ class TransformerConfig:
             raise ValueError("layer_types names %d layers of %d"
                              % (len(self.layer_types), self.n_layers))
         for kind in self.layer_types:
-            if kind not in ("full_attention", "sliding_attention", "conv"):
+            if kind not in ("full_attention", "sliding_attention", "conv",
+                            "kda"):
                 raise ValueError("layer type %r is not full_attention, "
-                                 "sliding_attention or conv" % (kind,))
+                                 "sliding_attention, conv or kda" % (kind,))
+        if "kda" in self.layer_types and self.kda_head_dim < 1:
+            raise ValueError("kda layers need kda_head_dim")
         if "sliding_attention" in self.layer_types and self.window < 1:
             raise ValueError("sliding_attention layers need a window")
         for kind, theta, yarn in self.rope:
@@ -110,15 +118,18 @@ class TransformerConfig:
                                  "none or five: %r, %r" % (kind, theta, yarn))
 
         if self.attention == "latent" and (
-                self.position != "rope" or self.layer_types
+                self.position not in ("rope", "none")
+                or set(self.layer_types) - {"full_attention", "kda"}
                 or self.n_kv_heads or self.qk_norm or self.head_dim
-                or min(self.q_lora_rank, self.kv_lora_rank,
-                       self.qk_nope_head_dim, self.qk_rope_head_dim) < 1):
+                or self.q_lora_rank < 0
+                or min(self.kv_lora_rank, self.qk_nope_head_dim,
+                       self.qk_rope_head_dim) < 1):
             raise ValueError(
-                "latent attention takes rotary positions, its two ranks and "
-                "the head's two parts, and no layer pattern, grouped heads, "
-                "per-head norms or head_dim (the head is qk_nope_head_dim + "
-                "qk_rope_head_dim)")
+                "latent attention takes rotary positions or none, its "
+                "key/value rank (and a query rank or none) and the head's "
+                "two parts, and no layer kinds but full_attention and kda, "
+                "grouped heads, per-head norms or head_dim (the head is "
+                "qk_nope_head_dim + qk_rope_head_dim)")
         if self.mtp_layers not in (0, 1) or (
                 self.mtp_layers and self.layer_types):
             raise ValueError("one multi-token-prediction module or none, "

@@ -16,8 +16,9 @@ names.  Two layers of API:
   layer pattern, ``SparseMoE`` experts after leading dense layers, a head
   tied to the embedding; sliding-window layers among full ones with a rope
   of each kind's own, heads of a size of their own, softmax-scored
-  experts): an LFM2-class hybrid or a Mellum-class mixture is a config,
-  not a second model.
+  experts; Kimi Delta Attention layers among latent ones with no
+  positions): an LFM2-class hybrid, a Mellum-class mixture or a
+  Kimi-Linear-class hybrid is a config, not a second model.
   Parameter names are chosen so ``parallel.mesh.megatron_rules`` shards
   a DP×TP mesh with zero configuration: ``*_query/key/value_weight`` and
   ``*_fc1_weight`` column-parallel, ``*_out_proj_weight`` and
@@ -118,8 +119,9 @@ def _feed_forward(h, cfg: TransformerConfig, idx: int, n: str):
 def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str,
                       stem: Optional[str] = None):
     """One pre-norm decoder block: x + Mix(Norm(x)); x + FFN(Norm(x)).
-    ``Mix`` is attention, or the gated short convolution where
-    ``cfg.layer_types[idx]`` says ``conv``.  A ``sliding_attention``
+    ``Mix`` is attention, the gated short convolution where
+    ``cfg.layer_types[idx]`` says ``conv``, or Kimi Delta Attention where it
+    says ``kda`` (node ``<prefix>l<idx>_kda``).  A ``sliding_attention``
     layer's node is ``<prefix>l<idx>_swa`` (a device trace's scopes then
     tell the two kinds of attention apart) and its weights keep the
     ``<prefix>l<idx>_attn_`` names of every attention layer; a latent
@@ -133,13 +135,20 @@ def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str,
     if kind == "conv":
         a = sym.ShortConv(h, kernel=cfg.conv_kernel, name=n + "conv")
         x = sym.elemwise_add(x, a, name=n + "conv_res")
+    elif kind == "kda":
+        a = sym.DeltaAttention(h, num_heads=cfg.n_heads,
+                               head_dim=cfg.kda_head_dim,
+                               conv_kernel=cfg.conv_kernel,
+                               eps=cfg.norm_eps, name=n + "kda")
+        x = sym.elemwise_add(x, a, name=n + "kda_res")
     elif cfg.attention == "latent":
         a = sym.MultiHeadAttention(
             h, num_heads=cfg.n_heads, causal=True, name=n + "mla",
             head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
             qk_rope_head_dim=cfg.qk_rope_head_dim,
             q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
-            v_head_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta,
+            v_head_dim=cfg.v_head_dim,
+            rope_theta=cfg.rope_theta if cfg.position == "rope" else 0.0,
             eps=cfg.norm_eps)
         x = sym.elemwise_add(x, a, name=n + "attn_res")
     else:
@@ -185,7 +194,9 @@ def transformer_lm(cfg: TransformerConfig, prefix: str = "tfm_",
     Data is ``(B, T)`` token ids, label ``(B, T)`` next tokens.  Positions
     (``cfg.position``): ``learned`` adds a learned table post-embedding
     (gpt2 style), ``rope`` turns queries and keys inside every attention
-    layer.  ``cfg.tie_head`` computes the logits on
+    layer, ``none`` has no positions at all (the order comes from the
+    causal masks and the recurrent mixers alone).  ``cfg.tie_head``
+    computes the logits on
     the embedding's own rows: one argument, ``<prefix>tok_embedding_weight``,
     whose gradient is the sum of its two uses.
     """

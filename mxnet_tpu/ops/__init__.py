@@ -12,6 +12,7 @@ from . import elemwise      # noqa: F401
 from . import reduce        # noqa: F401
 from . import matrix        # noqa: F401
 from . import nn            # noqa: F401
+from . import delta_rule    # noqa: F401  (Kimi Delta Attention)
 from . import rnn           # noqa: F401
 from . import init_random   # noqa: F401
 from . import optimizer_ops # noqa: F401

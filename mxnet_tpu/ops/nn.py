@@ -612,7 +612,7 @@ def _kernel_or_reference(q, k, v, causal, scale, interpret, window=None):
 MHA_KV_VMEM = 8 << 20
 
 
-def mha_uses_kernel(B, H, T, d, dtype):
+def mha_uses_kernel(B, H, T, d, dtype, dv=None):
     """MultiHeadAttention's own shape test: does the flash kernel beat
     ``_mha_reference`` on a device that holds ``B`` rows of ``H`` heads?
     (``pa.flash_attention_available`` answers for ring attention, whose
@@ -633,9 +633,13 @@ def mha_uses_kernel(B, H, T, d, dtype):
     every cell it loses, whatever ``d``: the kernel takes the shapes whose
     scores reach 64 MB.  (Inside a training step the XLA arm also keeps the
     probabilities of every layer for backward, which no VMEM holds: at the
-    GPT-2 cells' shape it cost 0.55 ms a layer there, not 0.35.)"""
+    GPT-2 cells' shape it cost 0.55 ms a layer there, not 0.35.)  ``dv``:
+    values of a width of their own (0 or None: ``d``), counted apart in the
+    keys' and values' VMEM."""
     from . import pallas_attention as pa
-    if d % 8 or T % 128 or not pa.kv_fits_vmem(T, d, dtype, MHA_KV_VMEM):
+    dv = dv or d
+    if d % 8 or dv % 8 or T % 128 \
+            or not pa.kv_fits_vmem(T, d, dtype, MHA_KV_VMEM, dv=dv):
         return False
     # pa.INTERPRET is the tests' hook: any shape the kernel can run
     return pa.INTERPRET or B * H * T * T * 4 >= 64 << 20
@@ -758,13 +762,13 @@ def _multi_head_attention(attrs, data, *weights):
 
 def _attend(attrs, q, k, v, out_proj_weight, window):
     """The op's dispatch over finished heads [B,H,T,d] (``v`` may have a
-    size of its own on the XLA arm), its counters, and the output
+    size of its own, on either arm), its counters, and the output
     projection."""
     from . import pallas_attention as pa
     B, H, T, d = q.shape
     causal, scale = attrs["causal"], 1.0 / (d ** 0.5)
-    if v.shape[-1] == d and \
-            mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype):
+    if mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype,
+                       dv=v.shape[-1]):
         # test hook (pa.INTERPRET): force the interpreter on CPU
         path = ("flash" if window is None else "flash_window") \
             + ("_interpret" if pa.INTERPRET else "")
@@ -796,8 +800,7 @@ _ATTN_LATENT = _telemetry.counter(
     "part (trace-time)")
 
 
-def _latent_heads(attrs, data, q_a_weight, q_a_norm_gamma, q_b_weight,
-                  kv_a_weight, kv_a_norm_gamma, kv_b_weight):
+def _latent_heads(attrs, data, *weights):
     """Latent attention's heads (DeepSeek-V2, as GLM-4.7-Flash states it)
     in front of ``_attend``: (q, k, v), each [B,H,T,.].
 
@@ -816,48 +819,80 @@ def _latent_heads(attrs, data, q_a_weight, q_a_norm_gamma, q_b_weight,
     gradient is the sum over the heads, by autodiff through the
     broadcast); ``_rotary`` is applied to the ``d_r``-wide parts, so the
     frequencies are ``rope_theta^(-2j / d_r)``.  Scores are over the whole
-    head, ``/ sqrt(head_dim)``.  The kernels take heads of one size:
-    ``v_head_dim`` other than ``head_dim`` keeps the XLA arm.  Under
-    ``megatron_rules`` ``q_b_weight`` and ``kv_b_weight`` split by head
-    (column-parallel), ``out_proj_weight`` (D, H * v_head_dim) by its
-    inputs, the two ``_a_`` matrices and the gains stay whole."""
+    head, ``/ sqrt(head_dim)``.  Two other forms (Kimi Linear's latent
+    layer): ``q_lora_rank`` 0 takes the queries from one full-rank product,
+    ``q = x · Wqᵀ`` with ``query_weight`` (H * head_dim, D), in place of
+    the first two lines and their three inputs; ``rope_theta`` 0 turns
+    nothing (the ``d_r``-wide parts, the shared ``k_r`` among them, stay as
+    they are).  Inputs, in order: ``q_a_weight, q_a_norm_gamma,
+    q_b_weight`` (or ``query_weight``), ``kv_a_weight, kv_a_norm_gamma,
+    kv_b_weight``.  Values of their own width go to the kernels as heads of
+    any width do (``_attend``).  Under ``megatron_rules`` ``q_b_weight``,
+    ``query_weight`` and ``kv_b_weight`` split by head (column-parallel),
+    ``out_proj_weight`` (D, H * v_head_dim) by its inputs, the two ``_a_``
+    matrices and the gains stay whole."""
     B, T, _ = data.shape
     H, d, dr = attrs["num_heads"], attrs.get("head_dim") or 0, \
         attrs.get("qk_rope_head_dim") or 0
     rq, rkv = attrs.get("q_lora_rank") or 0, attrs["kv_lora_rank"]
     theta, eps = attrs.get("rope_theta") or 0, attrs.get("eps", 1e-5)
-    if H <= 0 or rq <= 0 or not 0 < dr < d or dr % 2 or not theta > 0 \
-            or not attrs["causal"]:
+    if H <= 0 or rq < 0 or not 0 < dr < d or dr % 2 or theta < 0 \
+            or not attrs["causal"] or len(weights) != (6 if rq else 4):
         raise MXNetError(
             "MultiHeadAttention: latent attention takes num_heads, "
-            "q_lora_rank, kv_lora_rank, head_dim (the whole head), an even "
-            "qk_rope_head_dim inside it, rope_theta and causal=True; got "
-            "%r" % ({k: attrs.get(k) for k in (
-                "num_heads", "q_lora_rank", "kv_lora_rank", "head_dim",
-                "qk_rope_head_dim", "rope_theta", "causal")},))
+            "kv_lora_rank, head_dim (the whole head), an even "
+            "qk_rope_head_dim inside it and causal=True, q_lora_rank and "
+            "rope_theta of 0 or more, and its six (q_lora_rank 0: four) "
+            "matrices and gains; got %r and %d inputs" % (
+                {k: attrs.get(k) for k in (
+                    "num_heads", "q_lora_rank", "kv_lora_rank", "head_dim",
+                    "qk_rope_head_dim", "rope_theta", "causal")},
+                len(weights)))
     if any(attrs.get(k) for k in ("num_kv_heads", "qk_norm", "window",
                                   "rope_yarn")):
         raise MXNetError(
             "MultiHeadAttention: latent attention has no grouped heads, "
             "per-head norms, window or YaRN frequencies")
     dn, dv = d - dr, attrs.get("v_head_dim") or d
+    kv_a_weight, kv_a_norm_gamma, kv_b_weight = weights[-3:]
 
     def heads(c, w, size):
         return _matmul_wt(c, w).reshape(B, T, H, size).transpose(0, 2, 1, 3)
 
-    c_q = _rms_norm_last(_matmul_wt(data, q_a_weight), q_a_norm_gamma, eps)
-    q = heads(c_q, q_b_weight, d)
+    if rq:
+        q_a_weight, q_a_norm_gamma, q_b_weight = weights[:3]
+        q = heads(_rms_norm_last(_matmul_wt(data, q_a_weight),
+                                 q_a_norm_gamma, eps), q_b_weight, d)
+    else:
+        q = heads(data, weights[0], d)
     kv_a = _matmul_wt(data, kv_a_weight)                  # [B,T,r_kv+d_r]
     c_kv = _rms_norm_last(kv_a[..., :rkv], kv_a_norm_gamma, eps)
     kv = heads(c_kv, kv_b_weight, dn + dv)
-    k_r = _rotary(kv_a[:, None, :, rkv:], theta)          # [B,1,T,d_r]
-    q = jnp.concatenate([q[..., :dn], _rotary(q[..., dn:], theta)], axis=-1)
+    k_r = kv_a[:, None, :, rkv:]                          # [B,1,T,d_r]
+    if theta > 0:
+        k_r = _rotary(k_r, theta)
+        q = jnp.concatenate([q[..., :dn], _rotary(q[..., dn:], theta)],
+                            axis=-1)
     k = jnp.concatenate(
         [kv[..., :dn], jnp.broadcast_to(k_r, (B, H, T, dr))], axis=-1)
     if _telemetry.enabled:
         # graftlint: disable=GL002 -- counts compiled variants, not calls
         _ATTN_LATENT.inc()
     return q, k, kv[..., dn:]
+
+
+def causal_depthwise_conv(u, taps):
+    """``conv_t = sum_j taps[:, j] * u_{t-(L-1)+j}`` over ``u`` (batch, time,
+    channels) with ``u`` zero before position 0: depthwise, causal, ``L``
+    taps a channel (``taps`` (channels, L), the last on the current
+    position), no bias.  ``L`` shifted multiply-adds that XLA fuses with
+    what surrounds them, in ``u``'s type (callers pass float32; the taps
+    are cast to it).  Shared by
+    ``ShortConv`` and ``DeltaAttention``'s three convolutions."""
+    T, L = u.shape[1], taps.shape[-1]
+    u = jnp.pad(u, ((0, 0), (L - 1, 0), (0, 0)))
+    taps = taps.astype(u.dtype)
+    return sum(u[:, j:j + T, :] * taps[:, j] for j in range(L))
 
 
 _SHORTCONV_DISPATCH = _telemetry.counter(
@@ -880,22 +915,20 @@ def _short_conv(attrs, data, in_proj_weight, conv_weight, out_proj_weight):
     first column-parallel, the second row-parallel under
     ``megatron_rules``); ``conv_weight`` is (dim, kernel), one row of taps a
     channel, the last tap on the current position.  The ``L`` taps are
-    ``L`` shifted multiply-adds that XLA fuses with the two gates (path
+    ``L`` shifted multiply-adds (``causal_depthwise_conv``) that XLA fuses
+    with the two gates (path
     ``shift`` of ``shortconv_dispatch_total``), accumulated in float32.  No
     reference analog."""
     if data.ndim != 3:
         raise MXNetError(
             "ShortConv: data must be (batch, time, dim), got %s"
             % (data.shape,))
-    T = data.shape[1]
     L = attrs["kernel"]
     if conv_weight.shape[-1] != L:
         raise MXNetError("ShortConv: conv_weight %s has not kernel=%d taps"
                          % (conv_weight.shape, L))
     b, c, x = jnp.split(jnp.matmul(data, in_proj_weight.T), 3, axis=-1)
-    u = jnp.pad((b * x).astype(jnp.float32), ((0, 0), (L - 1, 0), (0, 0)))
-    taps = conv_weight.astype(jnp.float32)
-    conv = sum(u[:, j:j + T, :] * taps[:, j] for j in range(L))
+    conv = causal_depthwise_conv((b * x).astype(jnp.float32), conv_weight)
     if _telemetry.enabled:
         # graftlint: disable=GL002 -- counts compiled variants, not calls
         _SHORTCONV_DISPATCH.labels(path="shift").inc()

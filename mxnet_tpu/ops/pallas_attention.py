@@ -100,7 +100,7 @@ def flash_attention_available(B, H, Tq, Tk, D, dtype=None) -> bool:
     return kv_fits_vmem(Tk, D, dtype)
 
 
-def kv_fits_vmem(Tk, D, dtype=None, limit=5 * 1024 * 1024) -> bool:
+def kv_fits_vmem(Tk, D, dtype=None, limit=5 * 1024 * 1024, dv=None) -> bool:
     """K+V resident in VMEM per (b,h) program, double-buffered by the
     pipeline.  Measured crossover (tools/bench_ring_attention.py):
     the kernel wins 1.9x while K/V stream from VMEM comfortably
@@ -109,9 +109,10 @@ def kv_fits_vmem(Tk, D, dtype=None, limit=5 * 1024 * 1024) -> bool:
     larger shapes use the HBM-blocked lax.scan formulation instead.
     (That was against the scan and before the kernels asked for 32 MB:
     ``MultiHeadAttention``, whose other arm writes T x T scores to HBM,
-    names a ``limit`` of its own, ``ops.nn.MHA_KV_VMEM``.)"""
+    names a ``limit`` of its own, ``ops.nn.MHA_KV_VMEM``.)  ``dv``: values
+    of a width of their own (None: ``D``)."""
     esize = jnp.dtype(dtype).itemsize if dtype is not None else 2
-    kv_bytes = 2 * Tk * D * esize
+    kv_bytes = Tk * (D + (dv or D)) * esize
     return 2 * kv_bytes <= limit
 
 
@@ -278,7 +279,7 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
     of the first blocks visited, so a masked step under a window keeps
     ``exp`` off ``-inf - -inf``."""
     qi = pl.program_id(1)
-    D = q_ref.shape[-1]
+    DV = v_ref.shape[-1]
     fold = _fold_scale(scale)
     qs = [q_ref[g] * jnp.asarray(scale, q_ref.dtype) if fold else q_ref[g]
           for g in range(G)]                         # (TQ, D) each
@@ -325,7 +326,7 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
     body = _each_head(G, step)
     init = ((jnp.full((TQ, 1), -jnp.inf, jnp.float32),
              jnp.zeros((TQ, 1), jnp.float32),
-             jnp.zeros((TQ, D), jnp.float32)),) * G
+             jnp.zeros((TQ, DV), jnp.float32)),) * G
     n = Tk // BK
     if not causal:
         return jax.lax.fori_loop(0, n, functools.partial(body, masked=False),
@@ -402,10 +403,10 @@ def _stats_spec(G, TQ):
     return pl.BlockSpec((G, 1, 1, TQ), lambda b, t: (b, t, 0, 0))
 
 
-def _qkv_specs(G, TQ, Tk, D):
+def _qkv_specs(G, TQ, Tk, D, DV):
     return [pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0)),
             pl.BlockSpec((G, Tk, D), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((G, Tk, D), lambda b, t: (b, 0, 0))]
+            pl.BlockSpec((G, Tk, DV), lambda b, t: (b, 0, 0))]
 
 
 # 32 MB of the v5e's 128 MB of VMEM: a 2048-block of f32 operands, double
@@ -435,7 +436,7 @@ def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k, stats,
 def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret,
                  window=None):
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, DV = k.shape[2], v.shape[-1]
     BH = B * H
     TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
     nq = Tq // TQ
@@ -461,17 +462,17 @@ def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret,
     outs = pl.pallas_call(
         kern,
         grid=(BH // G, nq),
-        in_specs=_qkv_specs(G, TQ, Tk, D),
-        out_specs=[pl.BlockSpec((G, TQ, D), lambda b, t: (b, t, 0))]
+        in_specs=_qkv_specs(G, TQ, Tk, D, DV),
+        out_specs=[pl.BlockSpec((G, TQ, DV), lambda b, t: (b, t, 0))]
         + [_stats_spec(G, TQ)] * n_stats,
-        out_shape=[_out_sds((BH, Tq, D),
+        out_shape=[_out_sds((BH, Tq, DV),
                             jnp.float32 if stats == "ml" else q.dtype, q)]
         + [_out_sds((BH, nq, 1, TQ), jnp.float32, q)] * n_stats,
         compiler_params=_PARALLEL,
         name="flash_fwd",
         interpret=interpret,
-    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D))
-    return (outs[0].reshape(B, H, Tq, D),
+    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, DV))
+    return (outs[0].reshape(B, H, Tq, DV),
             *(s.reshape(B, H, Tq) for s in outs[1:]))
 
 
@@ -511,7 +512,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     and masks from the first one with a position past it
     (``_window_q_bounds``)."""
     ki = pl.program_id(1)
-    D = k_ref.shape[-1]
+    D, DV = k_ref.shape[-1], v_ref.shape[-1]
     fold = _fold_scale(scale)
     raw = [k_ref[g] for g in range(G)]                   # (BK, D) each
     ks = [kb * jnp.asarray(scale, kb.dtype) for kb in raw] if fold else raw
@@ -558,12 +559,13 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     def diagonal(g):
         R, SB = _bands(BK, _BAND_BWD)
         zero = jnp.zeros((SB, D), jnp.float32)
+        zero = (zero, zero if DV == D else jnp.zeros((SB, DV), jnp.float32))
         parts = []
         for r in range(R):
             keys, n_q = slice(r * SB, (r + 1) * SB), TQ - r * SB
             rows = pl.ds(pl.multiple_of(ki * TQ + r * SB, SB), n_q)
             parts.append(grad(
-                (zero, zero), g, rows, ks[g][keys], vs[g][keys],
+                zero, g, rows, ks[g][keys], vs[g][keys],
                 raw[g][keys], q_ref[g, rows, :], do_ref[g, rows, :],
                 lse_ref[g, ki, :, r * SB:], dl_ref[g, ki, :, r * SB:],
                 _visible((SB, n_q), 1, 0)))
@@ -571,7 +573,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
     body = _each_head(G, step)
     init = ((jnp.zeros((BK, D), jnp.float32),
-             jnp.zeros((BK, D), jnp.float32)),) * G
+             jnp.zeros((BK, DV), jnp.float32)),) * G
     if not causal:
         grads = jax.lax.fori_loop(
             0, n, functools.partial(body, masked=False), init)
@@ -611,25 +613,29 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         jax.lax.fori_loop(0, n, write, None)
 
 
-def _bwd_vmem_bytes(G, Tq, TQ, BK, D, itemsize, out_itemsize):
+def _bwd_vmem_bytes(G, Tq, TQ, BK, D, itemsize, out_itemsize, DV=None):
     """What one program of the backward keeps in VMEM between steps, the
     pipeline's second buffers and the lanes' padding counted: the head's
-    whole Q and dO, a block of K, V, dk, dv, the statistics, the dq
-    accumulator and the dq output block.  (The score tiles of a step come
-    on top: ``_TILE_ROOM``.)"""
-    lanes = -(-D // 128) * 128
-    whole, block = G * Tq * lanes, G * BK * lanes
+    whole Q (``D`` wide) and dO (``DV``, None: ``D``), a block of K and dk
+    (``D``), V and dv (``DV``), the statistics, the dq accumulator and the
+    dq output block.  (The score tiles of a step come on top:
+    ``_TILE_ROOM``.)"""
+    def lanes(width):
+        return -(-width // 128) * 128
+    k_lanes, v_lanes = lanes(D), lanes(DV or D)
+    whole = G * Tq * k_lanes
+    whole_kv, block_kv = G * Tq * (k_lanes + v_lanes), G * BK * (
+        k_lanes + v_lanes)
     stats = 2 * 2 * G * (Tq // TQ) * 8 * TQ * 4
-    return (2 * 2 * whole * itemsize + 2 * 2 * block * itemsize
-            + 2 * 2 * block * out_itemsize + stats + whole * 4
+    return (2 * whole_kv * itemsize + 2 * block_kv * itemsize
+            + 2 * block_kv * out_itemsize + stats + whole * 4
             + 2 * whole * out_itemsize)
 
 
 _ATTN_BACKWARD = _telemetry.counter(
     "attention_backward_total",
     "Flash-attention backward kernel programs built, by form (trace-time): "
-    "fused is one kernel that returns dq, dk and dv, the only form built; "
-    "two_pass would name a shape kept on a dk/dv and a dq kernel",
+    "fused, one kernel that returns dq, dk and dv",
     ("form",))
 
 
@@ -637,7 +643,7 @@ _ATTN_BACKWARD = _telemetry.counter(
 def _dqkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
                   out_dtype, interpret, window=None):
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, DV = k.shape[2], v.shape[-1]
     BH = B * H
     TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
     nq = Tq // TQ
@@ -646,21 +652,24 @@ def _dqkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
         # graftlint: disable=GL002 -- counts programs built, not calls
         _ATTN_BACKWARD.labels(form="fused").inc()
     whole_q = pl.BlockSpec((G, Tq, D), lambda b, t: (b, 0, 0))
-    kv_blk = pl.BlockSpec((G, BK, D), lambda b, t: (b, t, 0))
+    whole_do = pl.BlockSpec((G, Tq, DV), lambda b, t: (b, 0, 0))
+    k_blk = pl.BlockSpec((G, BK, D), lambda b, t: (b, t, 0))
+    v_blk = pl.BlockSpec((G, BK, DV), lambda b, t: (b, t, 0))
     whole_stats = pl.BlockSpec((G, nq, 1, TQ), lambda b, t: (b, 0, 0, 0))
     need = _bwd_vmem_bytes(G, Tq, TQ, BK, D, q.dtype.itemsize,
-                           out_dtype.itemsize)
+                           out_dtype.itemsize, DV)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_dqkv_kernel, G=G, TQ=TQ, BK=BK, Tq=Tq,
                           causal=causal, scale=scale,
                           square=_square(TQ, BK, Tq, Tk, window),
                           window=window),
         grid=(BH // G, Tk // BK),
-        in_specs=[whole_q, kv_blk, kv_blk, whole_q, whole_stats,
+        in_specs=[whole_q, k_blk, v_blk, whole_do, whole_stats,
                   whole_stats],
-        out_specs=[whole_q, kv_blk, kv_blk],
-        out_shape=[_out_sds((BH, Tq, D), out_dtype, q)]
-        + [_out_sds((BH, Tk, D), out_dtype, q)] * 2,
+        out_specs=[whole_q, k_blk, v_blk],
+        out_shape=[_out_sds((BH, Tq, D), out_dtype, q),
+                   _out_sds((BH, Tk, D), out_dtype, q),
+                   _out_sds((BH, Tk, DV), out_dtype, q)],
         scratch_shapes=[pltpu.VMEM((G, Tq, D), jnp.float32)],
         # the KV blocks of a head run in order: they share its dq
         compiler_params=pltpu.CompilerParams(
@@ -669,11 +678,11 @@ def _dqkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
                                                   _VMEM_MOST))),
         name="flash_dqkv",
         interpret=interpret,
-    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, D),
-      do.reshape(BH, Tq, D), lse.reshape(BH, nq, 1, TQ),
+    )(q.reshape(BH, Tq, D), k.reshape(BH, Tk, D), v.reshape(BH, Tk, DV),
+      do.reshape(BH, Tq, DV), lse.reshape(BH, nq, 1, TQ),
       delta.reshape(BH, nq, 1, TQ))
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
-            dv.reshape(B, H, Tk, D))
+            dv.reshape(B, H, Tk, DV))
 
 
 def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
@@ -683,7 +692,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
     caller names another ``out_dtype`` (callers accumulating across ring
     steps keep full precision; the standalone VJP has the kernel cast).
 
-    q/k/v/do: [B,H,T,D]; lse/delta: [B,H,Tq] f32 (global logsumexp and
+    q/k: [B,H,T,D], v/do: [B,H,T,DV] (values may be a width of their
+    own); lse/delta: [B,H,Tq] f32 (global logsumexp and
     rowsum(dO*O) — for ring attention these are the FULL-sequence stats,
     making each per-shard call an exact partial contribution).  They
     enter the kernel lane-dense, T floats a head."""
@@ -777,7 +787,8 @@ def kv_block_plan(Tq, Tk, causal=True, window=None, block_q=None,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, window=None):
-    """[B,H,T,D] attention; Pallas kernels both directions, each device
+    """[B,H,T,D] attention (values and the output may be a width of their
+    own, [B,H,T,DV]); Pallas kernels both directions, each device
     of an ambient mesh on its own batch rows and heads (``_on_own_rows``).
     Blocks default to ``default_blocks`` of the shape.  ``window`` (causal
     only): position t sees the ``window`` positions up to and with t."""

@@ -102,13 +102,15 @@ def _mha_hint(attrs, shapes):
     H = attrs["num_heads"]
     hd = attrs.get("head_dim") or D // H
     if attrs.get("kv_lora_rank"):
-        # latent attention's seven (``ops.nn._latent_heads``)
+        # latent attention's seven, or five without a query rank
+        # (``ops.nn._latent_heads``)
         rq, rkv, dr = (attrs["q_lora_rank"], attrs["kv_lora_rank"],
                        attrs["qk_rope_head_dim"])
         dv = attrs.get("v_head_dim") or hd
-        return _fill(shapes, [(rq, D), (rq,), (H * hd, rq), (rkv + dr, D),
-                              (rkv,), (H * (hd - dr + dv), rkv),
-                              (D, H * dv)])
+        query = [(rq, D), (rq,), (H * hd, rq)] if rq else [(H * hd, D)]
+        return _fill(shapes, query + [(rkv + dr, D), (rkv,),
+                                      (H * (hd - dr + dv), rkv),
+                                      (D, H * dv)])
     kv = hd * (attrs.get("num_kv_heads") or H)
     return _fill(shapes, [(H * hd, D), (kv, D), (kv, D), (D, H * hd), (hd,),
                           (hd,)])
@@ -122,6 +124,21 @@ def _short_conv_hint(attrs, shapes):
         return shapes
     D = data[-1]
     return _fill(shapes, [(3 * D, D), (D, attrs["kernel"]), (D, D)])
+
+
+def _delta_attention_hint(attrs, shapes):
+    """DeltaAttention: q/k/v projections (H d, dim) and their taps (H d,
+    conv_kernel), the forget gate's two low-rank factors, ``A_log`` (H,),
+    ``dt_bias`` (H d,), beta (H, dim), the output gate's two factors, the
+    output norm's gain (d,), out_proj (dim, H d)."""
+    data = shapes[0]
+    if data is None:
+        return shapes
+    D, H, d = data[-1], attrs["num_heads"], attrs["head_dim"]
+    HD, L = H * d, attrs["conv_kernel"]
+    return _fill(shapes, [(HD, D)] * 3 + [(HD, L)] * 3
+                 + [(d, D), (HD, d), (H,), (HD,), (H, D), (d, D), (HD, d),
+                    (d,), (D, HD)])
 
 
 def _sparse_moe_hint(attrs, shapes):
@@ -204,6 +221,12 @@ def install():
         "RMSNorm": (("data", "gamma"), (), _channel_hint(None, -1)),
         "ShortConv": (("data", "in_proj_weight", "conv_weight",
                        "out_proj_weight"), (), _short_conv_hint),
+        "DeltaAttention": (("data", "query_weight", "key_weight",
+                            "value_weight", "q_conv_weight", "k_conv_weight",
+                            "v_conv_weight", "f_a_weight", "f_b_weight",
+                            "A_log", "dt_bias", "beta_weight", "g_a_weight",
+                            "g_b_weight", "o_norm_gamma", "out_proj_weight"),
+                           (), _delta_attention_hint),
         "SparseMoE": (("data", "router_weight", "expert_bias",
                        "expert_gate_weight", "expert_up_weight",
                        "expert_down_weight", "expert_load"), (-1,),

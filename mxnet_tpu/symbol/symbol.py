@@ -486,9 +486,12 @@ _LATENT_INPUTS = ("q_a_weight", "q_a_norm_gamma", "q_b_weight",
 def _attention_inputs(attrs, names):
     """MultiHeadAttention: the per-head gains exist only under qk_norm;
     latent attention (kv_lora_rank) takes its own six matrices and gains in
-    place of query / key / value, then out_proj_weight."""
+    place of query / key / value, then out_proj_weight; without a query
+    rank (q_lora_rank 0) one query_weight in place of the query's three."""
     if attrs.get("kv_lora_rank"):
-        return ["data", *_LATENT_INPUTS, "out_proj_weight"]
+        query = _LATENT_INPUTS[:3] if attrs.get("q_lora_rank") \
+            else ("query_weight",)
+        return ["data", *query, *_LATENT_INPUTS[3:], "out_proj_weight"]
     plain = [n for n in names if n not in _LATENT_INPUTS]
     return plain if attrs.get("qk_norm") else plain[:-2]
 

@@ -153,8 +153,11 @@ def test_the_shared_key_parts_gradient_is_the_sum_over_heads():
 
 def test_latent_attention_refuses_what_it_cannot_build():
     args = _latent_args(16)
+    # (q_lora_rank 0 is the full-rank query's form, which takes one query
+    # weight where these are three; rope_theta 0 is the form that turns
+    # nothing)
     for bad in (dict(qk_rope_head_dim=0), dict(q_lora_rank=0),
-                dict(rope_theta=0.0), dict(window=4), dict(num_kv_heads=1),
+                dict(rope_theta=-1.0), dict(window=4), dict(num_kv_heads=1),
                 dict(qk_rope_head_dim=HD)):
         with pytest.raises(mx.base.MXNetError):
             _op("MultiHeadAttention", **{**LATENT, **bad})(*args)

@@ -472,7 +472,7 @@ def test_fused_backward_matches_the_reference(case):
 def test_backward_counts_one_fused_program_a_build():
     """``attention_backward_total{form="fused"}`` rises once where
     ``flash_attention_bwd`` builds a kernel program, not once a call, and
-    nothing builds the two-pass pair."""
+    the family has no other form."""
     from mxnet_tpu import telemetry
     q, k, v = _case(B=1, H=3, T=128, D=24, seed=34)     # no other test's
 
@@ -484,14 +484,14 @@ def test_backward_counts_one_fused_program_a_build():
     telemetry.enable()
     try:
         fused0 = telemetry.value("attention_backward_total", form="fused")
-        pair0 = telemetry.value("attention_backward_total", form="two_pass")
         grads()
         assert telemetry.value("attention_backward_total",
                                form="fused") == fused0 + 1
         grads()
         assert telemetry.value("attention_backward_total",
                                form="fused") == fused0 + 1
-        assert telemetry.value("attention_backward_total",
-                               form="two_pass") == pair0
+        forms = {row["labels"]["form"] for row in telemetry.snapshot()[
+            "attention_backward_total"]["samples"]}
+        assert forms == {"fused"}
     finally:
         telemetry.disable()

@@ -267,6 +267,86 @@ def test_latent_attention_reaches_the_kernels_at_a_head_of_256(one_chip):
     assert all(reads.search(ln) for ln in calls)
 
 
+def test_kimi_latent_attention_reaches_the_kernels_at_192_and_128(one_chip):
+    """``kimilinear_train_4k``'s latent layer, ahead of time: 32 heads of
+    128 + 64 with values of 128, full-rank queries, no positions, under a
+    2304-wide stream at T 4096.  Forward + backward hold the two Mosaic
+    calls, keys and queries 192 wide and values and outputs 128, no T x T
+    tensor."""
+    from mxnet_tpu.ops.nn import mha_uses_kernel
+    from mxnet_tpu.ops.registry import OPS
+    assert mha_uses_kernel(1, 32, 4096, 192, jnp.bfloat16, dv=128)
+    op = OPS["MultiHeadAttention"]
+    attrs = op.parse_attrs(dict(
+        num_heads=32, head_dim=192, qk_rope_head_dim=64, q_lora_rank=0,
+        kv_lora_rank=512, v_head_dim=128, rope_theta=0.0, eps=1e-5))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, 4096, 2304), sds(6144, 2304), sds(576, 2304),
+            sds(512, dtype=jnp.float32), sds(8192, 512), sds(2304, 4096))
+
+    def loss(*a):
+        with jax.named_scope("MultiHeadAttention:tfm_l3_mla"):
+            return jnp.sum(op.fn(attrs, *a).astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, tuple(range(6)))).lower(
+        *args).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert all("bf16[32,4096,192]" in ln and "bf16[32,4096,128]" in ln
+               for ln in calls)
+    assert "[1,32,4096,4096]" not in hlo and "[32,4096,4096]" not in hlo
+
+
+def test_kda_layer_compiles_at_the_cells_widths(one_chip):
+    """``kimilinear_train_4k``'s KDA layer, ahead of time: 32 heads of 128
+    under a 2304-wide stream at T 4096, forward + backward.  No kernel (the
+    chunked scan is XLA's), no tensor anywhere near T x H x 64 x dk (the
+    pairwise decays a chunk at a time: the largest array is the states the
+    chunks start from, 64 x 32 x 128 x 128, a 32nd of it), under 1.5 GB of
+    temporaries, and
+    the core's operations, forward and backward, carry the scope
+    ``kda_core_roofline_pct.train`` finds them by."""
+    import json
+    import os
+    from mxnet_tpu.ops.registry import OPS
+    op = OPS["DeltaAttention"]
+    attrs = op.parse_attrs(dict(num_heads=32, head_dim=128))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    D, HD, d, f32 = 2304, 4096, 128, jnp.float32
+    args = (sds(1, 4096, D), sds(HD, D), sds(HD, D), sds(HD, D),
+            sds(HD, 4), sds(HD, 4), sds(HD, 4), sds(d, D), sds(HD, d),
+            sds(32, dtype=f32), sds(HD, dtype=f32), sds(32, D), sds(d, D),
+            sds(HD, d), sds(d, dtype=f32), sds(D, HD))
+
+    def loss(*a):
+        with jax.named_scope("DeltaAttention:tfm_l0_kda"):
+            return jnp.sum(op.fn(attrs, *a).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, tuple(range(16)))).lower(
+        *args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo and " while(" in hlo
+    largest = max(functools.reduce(lambda a, b: a * b, map(int, dims), 1)
+                  for dims in (m.split(",") for m in re.findall(
+                      r"[a-z]+\d*\[([\d,]+)\]", hlo)))
+    assert largest <= 4096 * 32 * 64 * 128 // 32
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "metrics",
+                           "kda_core_roofline_pct.train.json")) as f:
+        reads = re.compile(json.load(f)["params"]["kernel"])
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    core = [n for n in names if reads.search(n)]
+    assert any("transpose(" not in n for n in core)
+    assert any("transpose(" in n for n in core)
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward",
                                        "backward-diagonal"])
 def test_flash_attention_ring_variant_compiles(one_chip, direction):
