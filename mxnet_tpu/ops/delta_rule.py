@@ -41,10 +41,18 @@ and a chunk's -700.
 Precision: gates, decays, the state and the solve are float32; every
 product takes float32 operands at jax's default precision, under which the
 TPU's compiler may take bfloat16 passes (float32 accumulation); on the CPU
-they are float32.  Nothing here
-is a kernel: the chunks are XLA's own products in a ``while`` loop, under
-the op's part scope ``delta_rule`` forward and backward (``atlas.part_scope``;
-``linear_attention_dispatch_total{path="chunked"}``).
+they are float32.
+
+Two forms, one contract.  On a TPU, where a device's heads come in pairs
+and ``dk`` and ``dv`` are multiples of 128, the chunks run in the Pallas
+kernels of ``ops/pallas_delta_rule.py`` (``_kernel_rule``: each chunk's
+whole body in one kernel step, each head's state in VMEM, the same
+numerics; ``linear_attention_dispatch_total{path="kernel"}``).  Everywhere
+else, the CPU and heads of 8 or 16 among them, the chunks are XLA's own
+products in a ``while`` loop (``_chunked_rule``; ``{path="chunked"}``),
+which is also the kernels' second reference in the tests.  Either runs
+under the op's part scope ``delta_rule``, forward and backward
+(``atlas.part_scope``).
 """
 from __future__ import annotations
 
@@ -168,27 +176,60 @@ def gated_delta_rule(q, k, v, g, beta, scale=None):
     ``CHUNK``: the tail is padded with positions that neither decay nor
     write (g, k, v, beta nought), which leaves every output before them as
     it is."""
+    from . import pallas_attention as pa, pallas_delta_rule as pdr
     B, T, H, dk = q.shape
-    scale = dk ** -0.5 if scale is None else scale
-    chunk = CHUNK
-    n = -(-T // chunk)
-    pad = n * chunk - T
-
-    def chunks(x):
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape((B, n, chunk, H) + x.shape[3:])
-        x = x.transpose((1, 0, 3, 2) + tuple(range(4, x.ndim)))
-        return x.reshape((n, B * H, chunk) + x.shape[4:])
-
+    dv = v.shape[-1]
+    scale = float(dk ** -0.5 if scale is None else scale)
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    BH = int(np.prod(pa.rows_per_device(B, H)))
+    kernel = pdr.available(BH, dk, dv)
     if _telemetry.enabled:
         # graftlint: disable=GL002 -- counts compiled variants, not calls
-        _LINEAR_DISPATCH.labels(path="chunked").inc()
+        _LINEAR_DISPATCH.labels(path="kernel" if kernel else "chunked").inc()
     with _atlas.part_scope(SCOPE):
-        o = _chunked(chunks(q), chunks(k), chunks(v),
-                     chunks(g.astype(jnp.float32)),
-                     chunks(beta.astype(jnp.float32)), float(scale))
-        o = o.reshape(n, B, H, chunk, -1).transpose(1, 0, 3, 2, 4)
-        return o.reshape(B, n * chunk, H, -1)[:, :T]
+        if not kernel:
+            return _chunked_rule(q, k, v, g, beta, scale)
+        fast = jax.config.jax_default_matmul_precision in (
+            None, "default", "bfloat16", "fastest")
+        def on_kernel(*xs):
+            return _kernel_rule(*xs, scale, fast)
+        if pdr.INTERPRET:
+            return on_kernel(q, k, v, g, beta)
+        return jax.lax.platform_dependent(
+            q, k, v, g, beta, tpu=on_kernel,
+            default=lambda *xs: _chunked_rule(*xs, scale))
+
+
+def _chunked_rule(q, k, v, g, beta, scale):
+    """``gated_delta_rule`` as XLA's ``while`` over chunk-major heads."""
+    B, T, H, _ = q.shape
+    n = -(-T // CHUNK)
+
+    def chunks(x):
+        x = jnp.pad(x, ((0, 0), (0, n * CHUNK - T)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((B, n, CHUNK, H) + x.shape[3:])
+        x = x.transpose((1, 0, 3, 2) + tuple(range(4, x.ndim)))
+        return x.reshape((n, B * H, CHUNK) + x.shape[4:])
+
+    o = _chunked(*(chunks(x) for x in (q, k, v, g, beta)), scale)
+    o = o.reshape(n, B, H, CHUNK, -1).transpose(1, 0, 3, 2, 4)
+    return o.reshape(B, n * CHUNK, H, -1)[:, :T]
+
+
+def _kernel_rule(q, k, v, g, beta, scale, fast):
+    """``gated_delta_rule`` on the Pallas kernels over head-major heads."""
+    from .pallas_delta_rule import delta_rule
+    T = q.shape[1]
+    pad = -(-T // CHUNK) * CHUNK - T
+
+    def heads(x):
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x, 2, 1)
+
+    B, _, H = beta.shape
+    o = delta_rule(*(heads(x) for x in (q, k, v, g)),
+                   heads(beta).reshape(B, H, -1, CHUNK), scale, fast)
+    return jnp.moveaxis(o, 1, 2)[:, :T]
 
 
 def delta_rule_reference(q, k, v, g, beta, scale=None):

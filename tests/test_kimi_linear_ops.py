@@ -31,6 +31,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.models.configs import TransformerConfig
 from mxnet_tpu.ops import delta_rule as dr
 from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import pallas_delta_rule as pdr
 from mxnet_tpu.ops.nn import _mha_reference, mha_uses_kernel
 from mxnet_tpu.ops.registry import OPS
 
@@ -100,11 +101,11 @@ def _agree(fn, ref_fn, args, tol=2e-5):
 
 
 # ------------------------------------------------------ the chunked rule
-def _rule_args(decay, T=150, dv=8):
-    """q, k (unit per head), v, g, beta over B 2, H 2, dk 16: ``decay`` is
-    the log-decay's scale a step (``A_log = log 16`` and gate inputs up to
+def _rule_args(decay, T=150, dv=8, dk=16):
+    """q, k (unit per head), v, g, beta over B 2, H 2: ``decay`` is the
+    log-decay's scale a step (``A_log = log 16`` and gate inputs up to
     about 3 give 16 x softplus(3) = 48)."""
-    B, Hh, dk = 2, 2, 16
+    B, Hh = 2, 2
     q, k = (_rand(s, (B, T, Hh, dk)) for s in (1, 2))
     q, k = (a / jnp.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
     v = _rand(3, (B, T, Hh, dv))
@@ -115,17 +116,117 @@ def _rule_args(decay, T=150, dv=8):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("decay", [0.05, 16.0, 1000.0],
-                         ids=["mild", "A_log_log16", "extreme"])
-def test_chunked_rule_agrees_with_the_recurrence(decay):
+@pytest.mark.parametrize("decay,form", [
+    (0.05, "chunked"), (16.0, "chunked"), (1000.0, "chunked"),
+    (0.05, "kernel"), (16.0, "kernel"), (1000.0, "kernel")],
+    ids=["mild", "A_log_log16", "extreme", "kernel_mild",
+         "kernel_A_log_log16", "kernel_extreme"])
+def test_chunked_rule_agrees_with_the_recurrence(decay, form):
     """Over 150 positions (three chunks, the last short), B 2, values
     narrower than keys; at decays as strong as ``A_log = log 16`` with large
     gate inputs and far past them (a chunk's log-decay reaches -64,000:
     any ``exp(-G)`` would overflow), the outputs and the gradients of q, k,
-    v, g and beta are the recurrence's, finite."""
+    v, g and beta are the recurrence's, finite.  ``kernel``: the Pallas
+    kernels (interpreted) at heads of 128, against the recurrence and
+    against the chunked form."""
     with jax.default_matmul_precision("highest"):
-        _agree(dr.gated_delta_rule, dr.delta_rule_reference,
-               _rule_args(decay), tol=1e-4)
+        if form == "chunked":
+            _agree(dr.gated_delta_rule, dr.delta_rule_reference,
+                   _rule_args(decay), tol=1e-4)
+            return
+        args = _rule_args(decay, dv=128, dk=128)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(pdr, "INTERPRET", True)
+            _agree(dr.gated_delta_rule, dr.delta_rule_reference, args,
+                   tol=1e-4)
+            _agree(dr.gated_delta_rule,
+                   lambda *a: dr._chunked_rule(*a, 128 ** -0.5), args,
+                   tol=1e-4)
+
+
+def _kernels_in(jaxpr, prefix=""):
+    """(name stack, kernel) of every ``pallas_call`` in a jaxpr, the name
+    stacks of the equations around it joined in front."""
+    for eqn in jaxpr.eqns:
+        stack = "/".join(s for s in (prefix, str(eqn.source_info.name_stack))
+                         if s)
+        if eqn.primitive.name == "pallas_call":
+            yield stack
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernels_in(sub, stack)
+
+
+def test_kernel_rule_counts_its_path_and_carries_its_scope():
+    """At heads of 128 one ``linear_attention_dispatch_total{path="kernel"}``
+    a core traced and none ``chunked``; the forward and the backward kernel
+    are the two ``pallas_call``s of the gradient, each under the op's
+    ``DeltaAttention:<node>`` scope and the part ``delta_rule``, so that
+    ``kda_core_roofline_pct.train``'s pattern finds both.  Heads that do not
+    fill the lanes, or an odd number of them, keep the chunked form."""
+    q = jax.ShapeDtypeStruct((1, 150, 2, 128), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((1, 150, 2, 128), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, 150, 2), jnp.float32)
+
+    def loss(*a):
+        with jax.named_scope("DeltaAttention:tfm_l0_kda"):
+            return jnp.sum(dr.gated_delta_rule(*a).astype(jnp.float32))
+
+    telemetry.enable()
+    try:
+        n0 = {p: telemetry.value("linear_attention_dispatch_total", path=p)
+              for p in ("kernel", "chunked")}
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2, 3, 4)))(
+            q, q, q, g, beta).jaxpr
+        assert telemetry.value("linear_attention_dispatch_total",
+                               path="kernel") == n0["kernel"] + 1
+        assert telemetry.value("linear_attention_dispatch_total",
+                               path="chunked") == n0["chunked"]
+    finally:
+        telemetry.disable()
+    stacks = list(_kernels_in(jaxpr))
+    assert [s.rsplit("/", 1)[-1] for s in stacks] == ["delta_rule_fwd",
+                                                     "delta_rule_bwd"]
+    with open(os.path.join(ROOT, "perf", "metrics",
+                           "kda_core_roofline_pct.train.json")) as f:
+        reads = re.compile(json.load(f)["params"]["kernel"])
+    assert all(reads.search(s) for s in stacks)
+    assert stacks[1].startswith("transpose(")
+    assert pdr.available(2, 128, 128) and pdr.available(64, 256, 128)
+    assert not pdr.available(3, 128, 128)
+    assert not pdr.available(2, 16, 8) and not pdr.available(2, 128, 64)
+
+
+def test_kernel_rule_runs_each_device_on_its_own_rows():
+    """Under a mesh in context the kernels run under ``shard_map``, each
+    device on its own batch rows (``dp``): the output and the gradients are
+    those of the call without a mesh, and the kernel path is counted."""
+    from jax.sharding import Mesh
+    args = _rule_args(16.0, T=64, dv=128, dk=128)
+    probe = _rand(99, args[2].shape)
+
+    def run(*a):
+        return jax.grad(lambda *x: jnp.sum(dr.gated_delta_rule(*x) * probe),
+                        (0, 1, 2, 3, 4))(*a)
+
+    with pytest.MonkeyPatch.context() as mp, \
+            jax.default_matmul_precision("highest"):
+        mp.setattr(pdr, "INTERPRET", True)
+        want = jax.jit(run)(*args)
+        telemetry.enable()
+        try:
+            n0 = telemetry.value("linear_attention_dispatch_total",
+                                 path="kernel")
+            with jax.set_mesh(Mesh(np.array(jax.devices()[:2]), ("dp",))):
+                got = jax.jit(run)(*args)
+            assert telemetry.value("linear_attention_dispatch_total",
+                                   path="kernel") == n0 + 1
+        finally:
+            telemetry.disable()
+    for a, b in zip(got, want):
+        _close(a, b, 1e-5)
 
 
 def test_chunked_rule_counts_its_path_and_carries_its_scope():

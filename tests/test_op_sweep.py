@@ -375,8 +375,8 @@ EXEMPT = {
     "MultiHeadAttention": "flash-vs-reference parity + op-level grads in "
                           "tests/test_pallas_attention.py",
     "DeltaAttention": "tests/test_kimi_linear_ops.py (the op and its "
-                      "chunked core against the recurrence, values and "
-                      "every input's gradient)",
+                      "chunked core and Pallas kernels against the "
+                      "recurrence, values and every input's gradient)",
     "SparseMoE": "top-k routing is piecewise constant in the router; "
                  "forward + every gradient against the plain reference in "
                  "tests/test_lfm2_ops.py",

@@ -302,13 +302,12 @@ def test_kimi_latent_attention_reaches_the_kernels_at_192_and_128(one_chip):
 
 def test_kda_layer_compiles_at_the_cells_widths(one_chip):
     """``kimilinear_train_4k``'s KDA layer, ahead of time: 32 heads of 128
-    under a 2304-wide stream at T 4096, forward + backward.  No kernel (the
-    chunked scan is XLA's), no tensor anywhere near T x H x 64 x dk (the
-    pairwise decays a chunk at a time: the largest array is the states the
-    chunks start from, 64 x 32 x 128 x 128, a 32nd of it), under 1.5 GB of
-    temporaries, and
-    the core's operations, forward and backward, carry the scope
-    ``kda_core_roofline_pct.train`` finds them by."""
+    under a 2304-wide stream at T 4096, forward + backward.  The core is the
+    two Pallas kernels (``delta_rule_fwd``, ``delta_rule_bwd``) and no XLA
+    ``while``, no tensor anywhere near T x H x 64 x dk (the largest array is
+    the states the chunks start from, 64 x 32 x 128 x 128, a 32nd of it),
+    under 1.5 GB of temporaries, and both kernels, forward and backward,
+    carry the scope ``kda_core_roofline_pct.train`` finds them by."""
     import json
     import os
     from mxnet_tpu.ops.registry import OPS
@@ -331,7 +330,8 @@ def test_kda_layer_compiles_at_the_cells_widths(one_chip):
     compiled = jax.jit(jax.grad(loss, tuple(range(16)))).lower(
         *args).compile()
     hlo = compiled.as_text()
-    assert "tpu_custom_call" not in hlo and " while(" in hlo
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2 and " while(" not in hlo
     largest = max(functools.reduce(lambda a, b: a * b, map(int, dims), 1)
                   for dims in (m.split(",") for m in re.findall(
                       r"[a-z]+\d*\[([\d,]+)\]", hlo)))
@@ -341,10 +341,10 @@ def test_kda_layer_compiles_at_the_cells_widths(one_chip):
     with open(os.path.join(root, "perf", "metrics",
                            "kda_core_roofline_pct.train.json")) as f:
         reads = re.compile(json.load(f)["params"]["kernel"])
-    names = re.findall(r'op_name="([^"]*)"', hlo)
-    core = [n for n in names if reads.search(n)]
-    assert any("transpose(" not in n for n in core)
-    assert any("transpose(" in n for n in core)
+    names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+    assert all(reads.search(n) for n in names)
+    assert ["transpose(" in n for n in names] == [False, True]
+    assert ["delta_rule_fwd" in n for n in names] == [True, False]
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward",
