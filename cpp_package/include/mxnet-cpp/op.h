@@ -761,7 +761,8 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
     int q_lora_rank = 0,
     int kv_lora_rank = 0,
     int qk_rope_head_dim = 0,
-    int v_head_dim = 0) {
+    int v_head_dim = 0,
+    int diffusion_block = 0) {
   SymbolBuilder builder_("MultiHeadAttention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
@@ -776,6 +777,7 @@ inline Symbol MultiHeadAttention(const std::string &symbol_name,
   builder_.SetParam("kv_lora_rank", kv_lora_rank);
   builder_.SetParam("qk_rope_head_dim", qk_rope_head_dim);
   builder_.SetParam("v_head_dim", v_head_dim);
+  builder_.SetParam("diffusion_block", diffusion_block);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);
@@ -3111,6 +3113,21 @@ inline Symbol bipartite_matching(const std::string &symbol_name,
   return builder_.CreateSymbol(symbol_name);
 }
 
+inline Symbol block_diffusion_ce(const std::string &symbol_name,
+    Symbol data0,
+    Symbol data1,
+    Symbol data2,
+    int block,
+    int mask_id) {
+  SymbolBuilder builder_("block_diffusion_ce");
+  builder_.SetParam("block", block);
+  builder_.SetParam("mask_id", mask_id);
+  builder_.SetInput("data0", data0);
+  builder_.SetInput("data1", data1);
+  builder_.SetInput("data2", data2);
+  return builder_.CreateSymbol(symbol_name);
+}
+
 inline Symbol box_iou(const std::string &symbol_name,
     Symbol data0,
     Symbol data1,
@@ -4374,7 +4391,8 @@ inline Symbol multiheadattention(const std::string &symbol_name,
     int q_lora_rank = 0,
     int kv_lora_rank = 0,
     int qk_rope_head_dim = 0,
-    int v_head_dim = 0) {
+    int v_head_dim = 0,
+    int diffusion_block = 0) {
   SymbolBuilder builder_("multiheadattention");
   builder_.SetParam("num_heads", num_heads);
   builder_.SetParam("causal", causal ? 1 : 0);
@@ -4389,6 +4407,7 @@ inline Symbol multiheadattention(const std::string &symbol_name,
   builder_.SetParam("kv_lora_rank", kv_lora_rank);
   builder_.SetParam("qk_rope_head_dim", qk_rope_head_dim);
   builder_.SetParam("v_head_dim", v_head_dim);
+  builder_.SetParam("diffusion_block", diffusion_block);
   builder_.SetInput("data", data);
   builder_.SetInput("query_weight", query_weight);
   builder_.SetInput("key_weight", key_weight);

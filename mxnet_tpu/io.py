@@ -25,6 +25,7 @@ from .base import MXNetError
 from . import ndarray as nd
 from . import telemetry as _telemetry
 from . import health as _health
+from . import profiler as _profiler
 from .ndarray.ndarray import NDArray
 
 _IO_BATCHES = _telemetry.counter(
@@ -48,7 +49,8 @@ _IO_WORKERS = _telemetry.gauge(
     "Worker threads producing batches for the pipeline", ("iter",))
 
 __all__ = ["DataDesc", "DataBatch", "DataIter", "NDArrayIter",
-           "SyntheticLMIter", "CSVIter", "MNISTIter", "PrefetchingIter",
+           "SyntheticLMIter", "BlockDiffusionIter", "block_diffusion_noise",
+           "CSVIter", "MNISTIter", "PrefetchingIter",
            "ResizeIter", "ImageRecordIter", "LibSVMIter",
            "ImageDetRecordIter"]
 
@@ -313,6 +315,80 @@ class SyntheticLMIter(DataIter):
 
     def getlabel(self):
         return [self._batch_array(self._label)]
+
+
+def block_diffusion_noise(x0, block, mask_id, seed):
+    """x_t of the clean documents ``x0`` (B, L), noised by blocks as block
+    diffusion trains (BD3-LM, arXiv:2503.09573; SDAR): for every block of
+    ``block`` positions a count m is drawn uniformly from 1 .. block, and m
+    positions of the block, chosen uniformly, take ``mask_id`` (the block's
+    rate t = m / block, exactly).  Deterministic under ``seed`` (anything
+    ``numpy.random.default_rng`` takes: a whole number of any size, or a
+    list of them).  ``x0`` keeps its dtype; ``mask_id`` must not be one of
+    its tokens."""
+    x0 = np.asarray(x0)
+    if x0.ndim != 2 or block < 1 or x0.shape[1] % block:
+        raise MXNetError("block_diffusion_noise: documents (B, L) of whole "
+                         "blocks of %d, got %s" % (block, x0.shape))
+    if np.any(x0 == mask_id):
+        raise MXNetError("block_diffusion_noise: the mask id %r is a token "
+                         "of the documents" % (mask_id,))
+    B, L = x0.shape
+    rng = np.random.default_rng(seed)
+    m = rng.integers(1, block + 1, size=(B, L // block, 1))
+    rank = np.argsort(np.argsort(rng.random((B, L // block, block)), -1), -1)
+    masked = (rank < m).reshape(B, L)
+    return np.where(masked, np.asarray(mask_id, x0.dtype), x0)
+
+
+class BlockDiffusionIter(DataIter):
+    """Block-diffusion training batches from a token iterator: each batch of
+    ``source`` gives its first data array as the clean documents x_0 (B, L),
+    and this iterator yields ``data`` = x_t (``block_diffusion_noise``, under
+    the seed ``[seed, batch number]``) and ``label`` = x_0, as
+    ``models.transformer_lm`` takes them under ``diffusion_block``.  The
+    noising is the host span ``IO::block_diffusion_noise``."""
+
+    def __init__(self, source, block, mask_id, seed=0,
+                 label_name="softmax_label"):
+        super().__init__(source.batch_size)
+        self.source, self.block, self.mask_id = source, int(block), mask_id
+        self.seed, self.label_name = int(seed), label_name
+        self._count = 0
+        self._batch = None
+
+    @property
+    def provide_data(self):
+        return self.source.provide_data[:1]
+
+    @property
+    def provide_label(self):
+        desc = self.source.provide_data[0]
+        return [DataDesc(self.label_name, desc.shape, desc.dtype,
+                         layout=desc.layout)]
+
+    def reset(self):
+        self.source.reset()
+        self._count = 0
+
+    def iter_next(self):
+        try:
+            batch = self.source.next()
+        except StopIteration:
+            return False
+        x0 = batch.data[0]
+        with _profiler.span("IO::block_diffusion_noise", "io"):
+            xt = block_diffusion_noise(x0.asnumpy(), self.block, self.mask_id,
+                                       [self.seed, self._count])
+        self._count += 1
+        self._batch = ([nd.array(xt, dtype=xt.dtype)], [x0])
+        return True
+
+    def getdata(self):
+        return self._batch[0]
+
+    def getlabel(self):
+        return self._batch[1]
 
 
 def _part_slice(batch, rank, nranks):

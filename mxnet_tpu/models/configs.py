@@ -46,7 +46,13 @@ class TransformerConfig:
     ``v_head_dim`` (``MultiHeadAttention``'s latent form).  ``mtp_layers``
     = 1 adds DeepSeek-V3's multi-token-prediction
     module after the last block (``transformer_lm``), its loss weighted by
-    ``mtp_loss_weight``."""
+    ``mtp_loss_weight``.  ``diffusion_block`` = b > 0 trains by block
+    diffusion (BD3-LM's objective, SDAR's): the data is a document noised by
+    blocks of b (``io.block_diffusion_noise``: ``diffusion_mask_id`` in
+    place of the masked tokens), the label the clean document, both go
+    through the layers as one sequence of ``2 seq_len`` positions under the
+    block-diffusion mask, and the loss is ``block_diffusion_ce`` over the
+    noised half (``transformer_lm``)."""
     name: str
     vocab_size: int
     n_layers: int
@@ -86,6 +92,8 @@ class TransformerConfig:
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.0
     kda_head_dim: int = 0
+    diffusion_block: int = 0
+    diffusion_mask_id: int = -1
 
     def __post_init__(self):
         if not self.head_dim and self.attention != "latent" \
@@ -134,6 +142,18 @@ class TransformerConfig:
                 self.mtp_layers and self.layer_types):
             raise ValueError("one multi-token-prediction module or none, "
                              "and none under a layer pattern")
+        b = self.diffusion_block
+        if b and (b & (b - 1) or self.seq_len % b
+                  or not 0 <= self.diffusion_mask_id < self.vocab_size
+                  or self.position == "learned" or self.attention != "full"
+                  or set(self.layer_types) - {"full_attention"}
+                  or self.mtp_layers):
+            raise ValueError(
+                "block diffusion takes blocks of a power of two that divide "
+                "seq_len, a mask id inside the vocabulary, rotary positions "
+                "or none, full attention in every layer and no prediction "
+                "module; got diffusion_block=%d, diffusion_mask_id=%d"
+                % (b, self.diffusion_mask_id))
 
     def rope_of(self, kind: str):
         """(theta, yarn) the layers of ``kind`` turn their heads by."""

@@ -124,7 +124,9 @@ def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str,
     says ``kda`` (node ``<prefix>l<idx>_kda``).  A ``sliding_attention``
     layer's node is ``<prefix>l<idx>_swa`` (a device trace's scopes then
     tell the two kinds of attention apart) and its weights keep the
-    ``<prefix>l<idx>_attn_`` names of every attention layer; a latent
+    ``<prefix>l<idx>_attn_`` names of every attention layer, as do those of
+    a layer under the block-diffusion mask (``cfg.diffusion_block``), node
+    ``<prefix>l<idx>_bd``; a latent
     layer's node is ``<prefix>l<idx>_mla``, its weights ``..._mla_*``.
     ``stem`` names the block's nodes in place of ``<prefix>l<idx>_`` (the
     prediction module's block; ``idx`` still says which feed-forward)."""
@@ -163,17 +165,20 @@ def transformer_block(x, cfg: TransformerConfig, idx: int, prefix: str,
                 variants["rope_yarn"] = yarn
         if cfg.head_dim:
             variants["head_dim"] = cfg.head_dim
-        name = n + "attn"
-        if kind == "sliding_attention":
+        name, causal = n + "attn", True
+        if kind == "sliding_attention" or cfg.diffusion_block:
             # the weights under the names every attention layer gives them
             # (megatron_rules, checkpoints), the node under its own kind's
-            variants.update(window=cfg.window, **{
+            mask = {"diffusion_block": cfg.diffusion_block} \
+                if cfg.diffusion_block else {"window": cfg.window}
+            variants.update(mask, **{
                 w: sym.Variable("%s_%s" % (name, w))
                 for w in ("query_weight", "key_weight", "value_weight",
                           "out_proj_weight")
                 + (("q_norm_gamma", "k_norm_gamma") if cfg.qk_norm else ())})
-            name = n + "swa"
-        a = sym.MultiHeadAttention(h, num_heads=cfg.n_heads, causal=True,
+            name, causal = (n + "bd", False) if cfg.diffusion_block \
+                else (n + "swa", True)
+        a = sym.MultiHeadAttention(h, num_heads=cfg.n_heads, causal=causal,
                                    name=name, **variants)
         x = sym.elemwise_add(x, a, name=n + "attn_res")
     h = _norm(x, cfg, n + "ln2")
@@ -199,12 +204,24 @@ def transformer_lm(cfg: TransformerConfig, prefix: str = "tfm_",
     computes the logits on
     the embedding's own rows: one argument, ``<prefix>tok_embedding_weight``,
     whose gradient is the sum of its two uses.
+
+    ``cfg.diffusion_block`` (training only): data is the noised document
+    x_t (B, T) and label the clean one x_0; both are embedded by the one
+    table and concatenated along time, every layer attends under the
+    block-diffusion mask over the 2T positions, the final norm and the head
+    see the noised half only, and the loss is the mean of
+    ``block_diffusion_ce`` (node ``<prefix>diffusion_ce``): each masked
+    position's cross-entropy against x_0 times b over its block's masked
+    count.
     """
     from .. import symbol as sym
     data = sym.Variable("data")                       # (B, T) token ids
     mtp = bool(cfg.mtp_layers) and loss
+    if cfg.diffusion_block and not loss:
+        raise ValueError("block diffusion builds its training graph: the "
+                         "clean copy comes in as the label")
     table = {"weight": sym.Variable(prefix + "tok_embedding_weight")} \
-        if cfg.tie_head or mtp else {}
+        if cfg.tie_head or mtp or cfg.diffusion_block else {}
     head = table if cfg.tie_head else \
         {"weight": sym.Variable(prefix + "lm_head_weight")} if mtp else {}
     x = sym.Embedding(data, input_dim=cfg.vocab_size,
@@ -223,15 +240,30 @@ def transformer_lm(cfg: TransformerConfig, prefix: str = "tfm_",
                             output_dim=cfg.d_model,
                             name=prefix + "pos_embedding")
         x = sym.broadcast_add(x, pos, name=prefix + "embed_sum")
+    if cfg.diffusion_block:
+        label = sym.Variable("softmax_label")         # (B, T) clean ids
+        clean = sym.Embedding(label, input_dim=cfg.vocab_size,
+                              output_dim=cfg.d_model,
+                              name=prefix + "clean_embedding", **table)
+        x = sym.concat(x, clean, dim=1, name=prefix + "both")
     for i in range(cfg.n_layers):
         x = transformer_block(x, cfg, i, prefix)
     last = x                                  # before the final norm
+    if cfg.diffusion_block:
+        x = sym.slice_axis(x, axis=1, begin=0, end=cfg.seq_len,
+                           name=prefix + "noised")
     x = _norm(x, cfg, prefix + "final_ln")
     logits = sym.FullyConnected(x, num_hidden=cfg.vocab_size,
                                 flatten=False, no_bias=True,
                                 name=prefix + "lm_head", **head)
     if not loss:
         return logits
+    if cfg.diffusion_block:
+        ce = sym.block_diffusion_ce(logits, label, data,
+                                    block=cfg.diffusion_block,
+                                    mask_id=cfg.diffusion_mask_id,
+                                    name=prefix + "diffusion_ce")
+        return sym.make_loss(sym.mean(ce), name=prefix + "loss")
     label = sym.Variable("softmax_label")             # (B, T) next ids
     ce = sym.streaming_softmax_ce(logits, label, axis=-1,
                                   name=prefix + "ce")

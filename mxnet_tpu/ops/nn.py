@@ -520,20 +520,23 @@ def yarn_frequencies(d, theta, yarn):
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def _rotary(x, theta, yarn=()):
+def _rotary(x, theta, yarn=(), period=0):
     """Rotary positions on [B,H,T,d] in the rotate-half pairing (dimension
     i turns with dimension i + d/2 by ``t * theta^(-2i/d)``), computed in
     float32 and stored in ``x``'s type.  ``yarn`` (factor, original
     positions, beta_fast, beta_slow, attention_factor): the frequencies are
     ``yarn_frequencies`` and cos and sin are both multiplied by the
-    attention factor."""
+    attention factor.  ``period`` > 0: position ``t`` turns as ``t mod
+    period`` (the two copies of a block-diffusion sequence)."""
     T, d = x.shape[-2:]
     half = d // 2
     if yarn:
         inv = jnp.asarray(yarn_frequencies(d, theta, yarn), jnp.float32)
     else:
         inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    pos = (jnp.arange(T) % period).astype(jnp.float32) if period \
+        else jnp.arange(T, dtype=jnp.float32)
+    ang = pos[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)               # [T, d/2]
     if yarn:
         cos, sin = cos * yarn[4], sin * yarn[4]
@@ -551,18 +554,26 @@ _ATTN_KV_BLOCKS = _telemetry.counter(
     "attention_kv_blocks_total",
     "Key blocks at or under the diagonal that the flash forward kernel's "
     "loop bounds take in (visited) and a window lets them leave out "
-    "(skipped), a head, a compiled attention variant (trace-time)",
+    "(skipped), a head, a compiled attention variant; under the "
+    "block-diffusion mask the tiles that hold a visible pair and the rest "
+    "(trace-time)",
     ("kind", "fate"))
 
 
-def _mha_reference(q, k, v, causal, scale, window=None):
+def _mha_reference(q, k, v, causal, scale, window=None, diffusion=None):
     """XLA reference attention, [B,H,T,d].  Same math contract as the
     Pallas flash kernel: f32 score/softmax/accumulate regardless of the
     input dtype, the causal mask admits position j<=i exactly, and a
-    ``window`` on top of it the ``window`` positions i - window < j <= i."""
+    ``window`` on top of it the ``window`` positions i - window < j <= i;
+    ``diffusion`` = (half, block) masks as the kernels' tiles do
+    (``pallas_attention._bd_visible``, the whole square one tile)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
+    if diffusion is not None:
+        from . import pallas_attention as pa
+        s = jnp.where(pa._bd_visible(s.shape[-2:], 0, 0, 0, *diffusion), s,
+                      -jnp.inf)
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         keep = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
@@ -575,8 +586,9 @@ def _mha_reference(q, k, v, causal, scale, window=None):
     return o.astype(q.dtype)
 
 
-@partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def _kernel_or_reference(q, k, v, causal, scale, interpret, window=None):
+@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _kernel_or_reference(q, k, v, causal, scale, interpret, window=None,
+                         diffusion=None):
     """The kernel arm of ``MultiHeadAttention``, [B,H,T,d].  The platform
     is resolved at LOWERING time (advisor r03): of the two branches the one
     that does not match the target is pruned.  Jitted on its own because
@@ -589,6 +601,9 @@ def _kernel_or_reference(q, k, v, causal, scale, interpret, window=None):
     reference = partial(_mha_reference, causal=causal, scale=scale)
     if window is not None:
         flash, reference = (partial(f, window=window)
+                            for f in (flash, reference))
+    if diffusion is not None:
+        flash, reference = (partial(f, diffusion=diffusion)
                             for f in (flash, reference))
     if interpret:
         return flash(q, k, v)
@@ -658,7 +673,8 @@ def mha_uses_kernel(B, H, T, d, dtype, dv=None):
                   "q_lora_rank": param(int, 0),
                   "kv_lora_rank": param(int, 0),
                   "qk_rope_head_dim": param(int, 0),
-                  "v_head_dim": param(int, 0)})
+                  "v_head_dim": param(int, 0),
+                  "diffusion_block": param(int, 0)})
 def _multi_head_attention(attrs, data, *weights):
     """Decoder attention: QKV projections, scaled-dot-product over
     ``num_heads``, output projection.  No reference analog — the
@@ -687,6 +703,13 @@ def _multi_head_attention(attrs, data, *weights):
     XLA arm masks them.  ``rope_yarn`` = (factor, original positions,
     beta_fast, beta_slow, attention_factor) turns the rotary frequencies
     into YaRN's (``yarn_frequencies``; needs ``rope_theta``).
+    ``diffusion_block`` = b > 0 (``causal=False``) is block-diffusion
+    attention (BD3-LM's, SDAR's): the T positions are a noised copy of a
+    document in [0, T/2) and its clean copy in [T/2, T), both rotated by
+    the positions 0 .. T/2 - 1, and the mask ``pallas_attention`` states
+    says who sees whom in blocks of b (a power of two that divides T/2); the kernels
+    visit only the tiles that hold a visible pair
+    (``attention_kv_blocks_total{kind="block_diffusion"}``).
 
     Dispatch: the Pallas flash kernel (ops/pallas_attention.py) wherever
     ``mha_uses_kernel`` says the kernel beats the XLA arm at this shape;
@@ -743,6 +766,14 @@ def _multi_head_attention(attrs, data, *weights):
             "got %r with rope_theta=%r" % (yarn, theta))
     if window is not None and window >= T:
         window = None           # every earlier position: plain causal
+    block = attrs.get("diffusion_block") or 0
+    if block and (causal or window or T % 2 or (T // 2) % block
+                  or block & (block - 1)):
+        raise MXNetError(
+            "MultiHeadAttention: diffusion_block=%d is its own mask (causal="
+            "False, no window) over an even T whose half it divides, a "
+            "power of two; got causal=%r, window=%r, T=%d"
+            % (block, causal, window, T))
 
     def proj(w, heads):
         y = _matmul_wt(data, w)                       # [B,T,heads*d]
@@ -754,7 +785,8 @@ def _multi_head_attention(attrs, data, *weights):
         q = _rms_norm_last(q, qk_gammas[0], attrs.get("eps", 1e-5))
         k = _rms_norm_last(k, qk_gammas[1], attrs.get("eps", 1e-5))
     if theta > 0:
-        q, k = _rotary(q, theta, yarn), _rotary(k, theta, yarn)
+        period = T // 2 if block else 0
+        q, k = (_rotary(a, theta, yarn, period) for a in (q, k))
     if Hkv != H:
         k, v = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
     return _attend(attrs, q, k, v, out_proj_weight, window)
@@ -767,16 +799,19 @@ def _attend(attrs, q, k, v, out_proj_weight, window):
     from . import pallas_attention as pa
     B, H, T, d = q.shape
     causal, scale = attrs["causal"], 1.0 / (d ** 0.5)
+    block = attrs.get("diffusion_block") or 0
+    diffusion = (T // 2, block) if block else None
     if mha_uses_kernel(*pa.rows_per_device(B, H), T, d, q.dtype,
                        dv=v.shape[-1]):
         # test hook (pa.INTERPRET): force the interpreter on CPU
-        path = ("flash" if window is None else "flash_window") \
+        path = ("flash_block_diffusion" if block else "flash"
+                if window is None else "flash_window") \
             + ("_interpret" if pa.INTERPRET else "")
         out = _kernel_or_reference(q, k, v, causal, scale, pa.INTERPRET,
-                                   window)
-        blocks = pa.kv_block_plan(T, T, causal, window)
+                                   window, diffusion)
+        blocks = pa.kv_block_plan(T, T, causal, window, diffusion=diffusion)
     else:
-        out = _mha_reference(q, k, v, causal, scale, window)
+        out = _mha_reference(q, k, v, causal, scale, window, diffusion)
         path, blocks = "reference", None
     if _telemetry.enabled:
         # one inc per compiled attention variant, not per step — the
@@ -784,8 +819,8 @@ def _attend(attrs, q, k, v, out_proj_weight, window):
         # graftlint: disable=GL002 -- counts compiled variants, not calls
         _ATTN_DISPATCH.labels(path=path).inc()
         if blocks is not None:
-            kind = "sliding_attention" if attrs.get("window") \
-                else "full_attention"
+            kind = "block_diffusion" if block else "sliding_attention" \
+                if attrs.get("window") else "full_attention"
             for fate, count in zip(("visited", "skipped"), blocks):
                 # graftlint: disable=GL002 -- counts compiled variants
                 _ATTN_KV_BLOCKS.labels(kind=kind, fate=fate).inc(count)
@@ -849,10 +884,11 @@ def _latent_heads(attrs, data, *weights):
                     "qk_rope_head_dim", "rope_theta", "causal")},
                 len(weights)))
     if any(attrs.get(k) for k in ("num_kv_heads", "qk_norm", "window",
-                                  "rope_yarn")):
+                                  "rope_yarn", "diffusion_block")):
         raise MXNetError(
             "MultiHeadAttention: latent attention has no grouped heads, "
-            "per-head norms, window or YaRN frequencies")
+            "per-head norms, window, YaRN frequencies or block-diffusion "
+            "mask")
     dn, dv = d - dr, attrs.get("v_head_dim") or d
     kv_a_weight, kv_a_norm_gamma, kv_b_weight = weights[-3:]
 
@@ -1283,6 +1319,36 @@ def _streaming_softmax_ce_op(attrs, data, label):
     out = streaming_ce(data, label, attrs["axis"])
     return jnp.expand_dims(out, attrs["axis"] % data.ndim) \
         if attrs["keepdims"] else out
+
+
+@register("block_diffusion_ce", nin=3,
+          params={"block": param(int, 0, required=True),
+                  "mask_id": param(int, 0, required=True)})
+def _block_diffusion_ce(attrs, data, label, noised):
+    """The masked-diffusion objective over blocks (BD3-LM's; SDAR's), per
+    token: with ``data`` the logits of the noised copy (B, L, V), ``label``
+    the clean ids x_0 (B, L) and ``noised`` the noised ids x_t (B, L),
+
+        out_i = (b / m) * CE(data_i, x_0[i])   where x_t[i] is ``mask_id``
+        out_i = 0                              elsewhere
+
+    with ``m`` the masked positions of i's block of ``block`` = b: the
+    ELBO's 1 / t of a block masked at the rate t = m / b.  The mean of the
+    result over the B x L positions is the loss.  The cross-entropy is
+    ``streaming_ce``'s; the weights read ``noised`` and carry no
+    gradient."""
+    b = attrs["block"]
+    B, L = noised.shape
+    if b < 1 or L % b or label.shape != noised.shape \
+            or data.shape[:2] != noised.shape:
+        raise MXNetError(
+            "block_diffusion_ce: logits (B, L, V), clean and noised ids "
+            "(B, L), L whole blocks of block=%d; got %s, %s, %s"
+            % (b, data.shape, label.shape, noised.shape))
+    masked = (noised == attrs["mask_id"]).astype(jnp.float32)
+    m = jnp.sum(masked.reshape(B, L // b, b), axis=-1, keepdims=True)
+    weight = (masked.reshape(B, L // b, b) * (b / jnp.maximum(m, 1.0)))
+    return streaming_ce(data, label, -1) * weight.reshape(B, L)
 
 
 @register("softmax_cross_entropy", nin=2)

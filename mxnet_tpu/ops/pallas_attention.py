@@ -41,6 +41,13 @@ side too: key blocks wholly below the window's lower edge are never visited
 the blocks that edge crosses carry its mask, and a window that reaches
 position 0 from every query (W >= T) is plain causal, the same kernels
 (``kv_block_plan`` counts what the forward loop takes in and leaves out).
+Block diffusion (``diffusion`` = (half, block), in place of the causal
+mask: a noised copy of a document and its clean copy as one sequence,
+the mask stated beside ``_bd_key_segments``): the forward's key loop and
+the backward's query loop run over segments that cover exactly the tiles
+holding a visible pair, masked only where one of the mask's edges crosses
+a tile (80 of 256 tiles a head at T 8192 in 512-blocks, 24 of them
+masked), the boundary between the copies anywhere.
 Without named blocks a sequence of up to 2,048 positions is
 ONE block (``default_blocks``): at T 1024 / d 64 that is 0.125 ms forward +
 backward for 16 heads on the v5e against 0.177 in 512-blocks (PERF.md,
@@ -168,6 +175,119 @@ def _window_q_bounds(col0, cols, rows, window, n_rows):
     return edge, hi
 
 
+# --------------------------------------------------------- block diffusion
+# The block-diffusion mask (BD3-LM, arXiv:2503.09573; SDAR trains by it) over
+# a sequence of 2L positions, the noised copy of a document in [0, L) and the
+# clean copy in [L, 2L), both cut into blocks of ``block`` positions.  With
+# n(i) = i < L and blk(i) = (i mod L) // block, query i sees key j when
+#   n(i) and n(j) and blk(j) == blk(i)        its own noised block, both ways
+#   n(i) and not n(j) and blk(j) < blk(i)     the clean blocks before it
+#   not n(i) and not n(j) and blk(j) <= blk(i)   the clean copy, block-causal
+# and no query sees a noised key outside its own block.  The bounds below
+# are worked on python ints (``kv_block_plan``) or on a program's traced
+# block index alike; a stretch of blocks carries the mask only where the
+# rows of the tile see some of its keys and not others.
+def _pick(cond, a, b):
+    """``a`` where ``cond`` else ``b``, on python ints or traced ones."""
+    if isinstance(cond, bool):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+def _ceil_div(x, n):
+    return -(-x // n)
+
+
+def _bd_key_segments(row0, rows, cols, half, block):
+    """The forward's key-block loop of the ``rows`` query positions from
+    ``row0`` under the block-diffusion mask: [(lo, hi, masked)], in order,
+    disjoint, covering exactly the key blocks of ``cols`` that hold a
+    visible pair.  The noised keys of the rows' own blocks come first, every
+    row sees one of them (its own), then the clean keys from position L on:
+    whole where every row of the tile sees the whole block, masked where an
+    edge crosses it."""
+    lesser, greater = _min_max(row0)
+    r1 = row0 + rows
+    noisy, clean = row0 < half, r1 > half
+    last = lesser(r1, half) - 1                 # the last noised row
+    # noised keys: the blocks of the noised rows, none past L
+    n_lo = _pick(noisy, row0 // block * block // cols, 0)
+    n_hi = _pick(noisy, _ceil_div(
+        lesser(last // block * block + block, half), cols), 0)
+    # clean keys [L, L + x): a noised row sees the blocks before its own, a
+    # clean row its own and those before
+    x = greater(_pick(noisy, last // block * block, 0),
+                _pick(clean, (r1 - 1 - half) // block * block + block, 0))
+    c_lo = greater(half // cols, n_hi)
+    c_hi = _pick(x > 0, greater(c_lo, _ceil_div(half + x, cols)), c_lo)
+    # [L, L + f): what every row of the tile sees of the clean keys
+    big = 2 * half
+    f = lesser(_pick(noisy, row0 // block * block, big),
+               _pick(clean, (greater(row0, half) - half) // block * block
+                     + block, big))
+    f_lo = lesser(greater(_ceil_div(half, cols), c_lo), c_hi)
+    f_hi = lesser(greater((half + f) // cols, f_lo), c_hi)
+    return [(n_lo, n_hi, True), (c_lo, f_lo, True), (f_lo, f_hi, False),
+            (f_hi, c_hi, True)]
+
+
+def _bd_query_segments(col0, cols, rows, half, block, n_rows):
+    """The backward's query-block loop for the ``cols`` keys from ``col0``
+    under the block-diffusion mask: [(lo, hi, masked)] over query blocks of
+    ``rows``, in order, disjoint, covering exactly those that see one of the
+    keys.  A noised key is seen by the rows of its own block; a clean key of
+    block c by the noised rows past block c and the clean rows from block c
+    on."""
+    lesser, greater = _min_max(col0)
+    c1 = col0 + cols
+    noisy, clean = col0 < half, c1 > half
+    first = greater(col0, half) - half          # the first clean key, from L
+    last = c1 - 1 - half                        # the last clean key, from L
+    # noised rows [a, L): those of the noised keys' blocks, and those past
+    # the block of the first clean key (both reach L where both are there)
+    a = lesser(_pick(noisy, col0 // block * block, half),
+               _pick(clean, first // block * block + block, half))
+    a_hi = _pick(noisy, _ceil_div(lesser(
+        (lesser(c1, half) - 1) // block * block + block, half), rows), 0)
+    a_hi = _pick(clean, _pick(a < half, _ceil_div(half, rows), 0), a_hi)
+    a_lo = lesser(a // rows, a_hi)
+    # clean rows from L + the first clean key's block on, to the end
+    b_lo = _pick(clean, greater((half + first // block * block) // rows,
+                                a_hi), n_rows)
+    # rows that see every key of the block: the block all clean, and a
+    # noised row past (a clean row at or past) the last key's block
+    whole = col0 >= half
+    fa_lo = lesser(greater(_pick(whole, _ceil_div(
+        last // block * block + block, rows), a_hi), a_lo), a_hi)
+    fa_hi = lesser(greater(half // rows, fa_lo), a_hi)
+    fb_lo = lesser(greater(_pick(whole, _ceil_div(
+        half + last // block * block, rows), n_rows), b_lo), n_rows)
+    return [(a_lo, fa_lo, True), (fa_lo, fa_hi, False), (fa_hi, a_hi, True),
+            (b_lo, fb_lo, True), (fb_lo, n_rows, False)]
+
+
+def _segment_loops(segments, step, init):
+    """``step(i, carry, masked)`` over each (lo, hi, masked) in turn."""
+    carry = init
+    for lo, hi, masked in segments:
+        carry = jax.lax.fori_loop(lo, hi, functools.partial(
+            step, masked=masked), carry)
+    return carry
+
+
+def _bd_visible(shape, q_axis, q0, k0, half, block):
+    """The block-diffusion mask of one tile, queries from position ``q0``
+    along ``q_axis``, keys from ``k0`` along the other: ``block`` a power of
+    two that divides ``half``, so a position's block starts at ``p &
+    -block`` in either copy."""
+    q = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) + q0
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) + k0
+    qn, kn, qc, kc = q < half, k < half, q >= half, k >= half
+    qs, ks = q & -block, k & -block
+    return (qn & kn & (qs == ks)) | (qn & kc & (ks - half < qs)) \
+        | (qc & kc & (ks <= qs))
+
+
 def _two_loops(lo, mid, hi, step, init, masked_first):
     """``step(i, carry, masked)`` over [lo, hi): one stretch masked, the
     other not, split at ``mid`` — the mask costs three VPU passes a score
@@ -260,7 +380,7 @@ def _visible(shape, row_axis, shift, window=None):
 
 
 def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
-                         scale, square=False, window=None):
+                         scale, square=False, window=None, diffusion=None):
     """Shared kernel body: the online-softmax K-block loop over the ``G``
     heads of a program, returning a running (m, l, acc) a head with m, l
     of shape (TQ, 1) — finalized differently by the normalized-output
@@ -277,7 +397,12 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
     first block the window's lower edge reaches and masks up to the first
     block every row sees whole (``_window_bounds``).  A row may see nothing
     of the first blocks visited, so a masked step under a window keeps
-    ``exp`` off ``-inf - -inf``."""
+    ``exp`` off ``-inf - -inf``.
+
+    ``diffusion`` (half, block): the block-diffusion mask in place of the
+    causal one, the loop over the segments ``_bd_key_segments`` gives; a
+    clean row of a tile that straddles the two copies sees nothing of the
+    noised blocks visited first, so a masked step keeps the same guard."""
     qi = pl.program_id(1)
     DV = v_ref.shape[-1]
     fold = _fold_scale(scale)
@@ -305,11 +430,15 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
     def step(g, i, carry, masked):
         off = pl.multiple_of(i * BK, BK)
         s = scores(qs[g], k_ref[g, pl.ds(off, BK), :])       # (TQ, BK)
-        if masked:
+        if masked and diffusion is not None:
+            s = jnp.where(_bd_visible((TQ, BK), 0, qi * TQ, i * BK,
+                                      *diffusion), s, -jnp.inf)
+        elif masked:
             s = jnp.where(_visible((TQ, BK), 0, i * BK - qi * TQ, window), s,
                           -jnp.inf)
         return update(carry, s, v_ref[g, pl.ds(off, BK), :],
-                      guard=masked and window is not None)
+                      guard=masked and (window is not None
+                                        or diffusion is not None))
 
     def diagonal(g, carry):
         R, SB = _bands(TQ, _BAND_FWD)
@@ -328,6 +457,9 @@ def _online_softmax_loop(q_ref, k_ref, v_ref, *, G, TQ, BK, Tk, causal,
              jnp.zeros((TQ, 1), jnp.float32),
              jnp.zeros((TQ, DV), jnp.float32)),) * G
     n = Tk // BK
+    if diffusion is not None:
+        return _segment_loops(_bd_key_segments(qi * TQ, TQ, BK, *diffusion),
+                              body, init)
     if not causal:
         return jax.lax.fori_loop(0, n, functools.partial(body, masked=False),
                                  init)
@@ -359,7 +491,7 @@ def _out_sds(shape, dtype, like):
         return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def default_blocks(Tq, Tk, window=None):
+def default_blocks(Tq, Tk, window=None, diffusion=None):
     """(block_q, block_k) from the shape, where the caller names none:
     one square block over the whole of a sequence up to 2,048 positions
     (0.42 ms against 0.55 in 512-blocks at T 2048), 512-blocks beyond, and
@@ -373,16 +505,18 @@ def default_blocks(Tq, Tk, window=None):
     diagonal bands (``_bands``) are straight-line code with static extents
     and compute 56 % of the square.  (At a head of 256 and T 4096
     1,024-blocks read 3.87 ms against 4.17 in 512-blocks; not taken:
-    PERF.md section 7.)"""
+    PERF.md section 7.)  Under the block-diffusion mask (``diffusion``)
+    too: its tiles are skipped in 512-blocks, which the cell that has it
+    runs (T 8192)."""
     side = min(Tq, Tk)
-    if window is not None and window < Tk:
+    if window is not None and window < Tk or diffusion is not None:
         side = min(side, 512)
     return (side, side) if side <= 2048 else (512, 512)
 
 
-def _pick_blocks(Tq, Tk, block_q, block_k, window=None):
+def _pick_blocks(Tq, Tk, block_q, block_k, window=None, diffusion=None):
     if block_q is None or block_k is None:
-        own_q, own_k = default_blocks(Tq, Tk, window)
+        own_q, own_k = default_blocks(Tq, Tk, window, diffusion)
         block_q, block_k = block_q or own_q, block_k or own_k
     TQ = min(block_q, Tq)
     while Tq % TQ:
@@ -421,24 +555,24 @@ _PARALLEL = pltpu.CompilerParams(
 
 
 def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k, stats,
-                    window=None):
+                    window=None, diffusion=None):
     """One forward ``pallas_call`` over [B,H,T,D]: ``stats`` None -> out;
     "lse" -> (out, lse[B,H,Tq]); "ml" -> (acc f32, m, l) for the ring."""
     return _fwd_program(q, k, v, causal, scale, block_q, block_k, stats,
-                        INTERPRET, window)
+                        INTERPRET, window, diffusion)
 
 
 # The three programs are jitted on their own: a model calls them once a
 # layer with the same shapes, and a jitted callee is traced and lowered to
 # Mosaic once a program, not once a layer (GPT-2-medium's 72 kernel
 # instances took 180 s of every start-up otherwise, compile cache or not).
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret,
-                 window=None):
+                 window=None, diffusion=None):
     B, H, Tq, D = q.shape
     Tk, DV = k.shape[2], v.shape[-1]
     BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
+    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window, diffusion)
     nq = Tq // TQ
 
     G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
@@ -446,8 +580,9 @@ def _fwd_program(q, k, v, causal, scale, block_q, block_k, stats, interpret,
     def kern(q_ref, k_ref, v_ref, o_ref, *stat_refs):
         heads = _online_softmax_loop(
             q_ref, k_ref, v_ref, G=G, TQ=TQ, BK=BK, Tk=Tk, causal=causal,
-            scale=scale, square=_square(TQ, BK, Tq, Tk, window),
-            window=window)
+            scale=scale, square=diffusion is None
+            and _square(TQ, BK, Tq, Tk, window), window=window,
+            diffusion=diffusion)
         for g, (m, l, acc) in enumerate(heads):
             if stats == "ml":
                 o_ref[g] = acc
@@ -496,7 +631,7 @@ def lse_of(m, l):
 
 def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                  dk_ref, dv_ref, dq_acc, *, G, TQ, BK, Tq, causal, scale,
-                 square, window=None):
+                 square, window=None, diffusion=None):
     """The whole backward for one KV block of ``G`` heads: loop over Q
     blocks.  Scores lie transposed, (BK, TQ) = K Q^T: lse and delta are
     lane-dense rows as they arrive, and the products are plain ``A @ B`` or
@@ -510,7 +645,8 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     bands of keys (band r is seen by the queries from band r on).  Under a
     ``window`` the loop ends at the last Q block that reaches this KV block
     and masks from the first one with a position past it
-    (``_window_q_bounds``)."""
+    (``_window_q_bounds``).  Under the block-diffusion mask (``diffusion``)
+    the loop runs over the segments ``_bd_query_segments`` gives."""
     ki = pl.program_id(1)
     D, DV = k_ref.shape[-1], v_ref.shape[-1]
     fold = _fold_scale(scale)
@@ -551,8 +687,11 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
     def step(g, i, carry, masked):
         rows = q_rows(i)
-        mask = _visible((BK, TQ), 1, ki * BK - i * TQ, window) \
-            if masked else None
+        if masked and diffusion is not None:
+            mask = _bd_visible((BK, TQ), 1, i * TQ, ki * BK, *diffusion)
+        else:
+            mask = _visible((BK, TQ), 1, ki * BK - i * TQ, window) \
+                if masked else None
         return grad(carry, g, rows, ks[g], vs[g], raw[g], q_ref[g, rows, :],
                     do_ref[g, rows, :], lse_ref[g, i], dl_ref[g, i], mask)
 
@@ -574,7 +713,10 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     body = _each_head(G, step)
     init = ((jnp.zeros((BK, D), jnp.float32),
              jnp.zeros((BK, DV), jnp.float32)),) * G
-    if not causal:
+    if diffusion is not None:
+        grads = _segment_loops(_bd_query_segments(ki * BK, BK, TQ,
+                                                  *diffusion, n), body, init)
+    elif not causal:
         grads = jax.lax.fori_loop(
             0, n, functools.partial(body, masked=False), init)
     elif square:
@@ -639,13 +781,13 @@ _ATTN_BACKWARD = _telemetry.counter(
     ("form",))
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 def _dqkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
-                  out_dtype, interpret, window=None):
+                  out_dtype, interpret, window=None, diffusion=None):
     B, H, Tq, D = q.shape
     Tk, DV = k.shape[2], v.shape[-1]
     BH = B * H
-    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
+    TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window, diffusion)
     nq = Tq // TQ
     G = _heads_per_program(BH, max(Tq, Tk), q.dtype.itemsize)
     if _telemetry.enabled:
@@ -661,8 +803,9 @@ def _dqkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
     dq, dk, dv = pl.pallas_call(
         functools.partial(_dqkv_kernel, G=G, TQ=TQ, BK=BK, Tq=Tq,
                           causal=causal, scale=scale,
-                          square=_square(TQ, BK, Tq, Tk, window),
-                          window=window),
+                          square=diffusion is None
+                          and _square(TQ, BK, Tq, Tk, window),
+                          window=window, diffusion=diffusion),
         grid=(BH // G, Tk // BK),
         in_specs=[whole_q, k_blk, v_blk, whole_do, whole_stats,
                   whole_stats],
@@ -687,7 +830,7 @@ def _dqkv_program(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
 
 def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
                         block_q=512, block_k=512, out_dtype=jnp.float32,
-                        window=None):
+                        window=None, diffusion=None):
     """Pallas flash backward, one kernel: (dq, dk, dv), in f32 unless the
     caller names another ``out_dtype`` (callers accumulating across ring
     steps keep full precision; the standalone VJP has the kernel cast).
@@ -699,7 +842,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, causal, scale,
     enter the kernel lane-dense, T floats a head."""
     return _dqkv_program(q, k, v, do, lse.astype(jnp.float32),
                          delta.astype(jnp.float32), causal, scale, block_q,
-                         block_k, jnp.dtype(out_dtype), INTERPRET, window)
+                         block_k, jnp.dtype(out_dtype), INTERPRET, window,
+                         diffusion)
 
 
 # ------------------------------------------------------------ partitioning
@@ -767,12 +911,37 @@ def _window_of(window, causal, Tk):
     return None if window >= Tk else int(window)
 
 
+def _diffusion_of(diffusion, causal, window, Tq, Tk):
+    """The block-diffusion mask the kernels are built for: (half, block)
+    as python ints, or None."""
+    if diffusion is None:
+        return None
+    half, block = (int(x) for x in diffusion)
+    if causal or window is not None or Tq != Tk or Tq != 2 * half \
+            or block < 1 or block & (block - 1) or half % block:
+        raise ValueError(
+            "the block-diffusion mask is its own mask (no causal, no "
+            "window) over 2 x half positions in blocks of a power of two "
+            "that divides half; got %r over %d x %d" % (diffusion, Tq, Tk))
+    return half, block
+
+
 def kv_block_plan(Tq, Tk, causal=True, window=None, block_q=None,
-                  block_k=None):
+                  block_k=None, diffusion=None):
     """(visited, skipped): of the key blocks at or under the diagonal, a
     head, how many the forward kernel's loop bounds take in and how many a
     ``window`` lets them leave out.  The bounds are the kernel's own, on
-    python ints; nothing runs."""
+    python ints; nothing runs.  Under the block-diffusion mask
+    (``diffusion`` = (half, block)), of all the tiles of a head: those that
+    hold a visible pair and the rest (at T 8192, half 4096, in 512-blocks:
+    80 and 176)."""
+    diffusion = _diffusion_of(diffusion, causal, window, Tq, Tk)
+    if diffusion is not None:
+        TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, None, diffusion)
+        visited = sum(hi - lo for row0 in range(0, Tq, TQ)
+                      for lo, hi, _ in _bd_key_segments(row0, TQ, BK,
+                                                        *diffusion))
+        return visited, (Tq // TQ) * (Tk // BK) - visited
     window = _window_of(window, causal, Tk)
     TQ, BK = _pick_blocks(Tq, Tk, block_q, block_k, window)
     visited = skipped = 0
@@ -784,39 +953,47 @@ def kv_block_plan(Tq, Tk, causal=True, window=None, block_q=None,
     return visited, skipped
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, window=None):
+                    block_k=None, window=None, diffusion=None):
     """[B,H,T,D] attention (values and the output may be a width of their
     own, [B,H,T,DV]); Pallas kernels both directions, each device
     of an ambient mesh on its own batch rows and heads (``_on_own_rows``).
     Blocks default to ``default_blocks`` of the shape.  ``window`` (causal
-    only): position t sees the ``window`` positions up to and with t."""
+    only): position t sees the ``window`` positions up to and with t.
+    ``diffusion`` = (half, block), not causal: the block-diffusion mask over
+    a noised copy in [0, half) and a clean one in [half, 2 half)."""
     sc = _scale_of(q, scale)
     window = _window_of(window, causal, k.shape[2])
+    diffusion = _diffusion_of(diffusion, causal, window, q.shape[2],
+                              k.shape[2])
     return _on_own_rows(
         lambda *qkv: _flash_fwd_call(*qkv, causal, sc, block_q, block_k,
-                                     None, window)[0], q, k, v)
+                                     None, window, diffusion)[0], q, k, v)
 
 
-def _fa_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window):
+def _fa_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window, diffusion):
     sc = _scale_of(q, scale)
     window = _window_of(window, causal, k.shape[2])
+    diffusion = _diffusion_of(diffusion, causal, window, q.shape[2],
+                              k.shape[2])
     out, lse = _on_own_rows(
         lambda *qkv: _flash_fwd_call(*qkv, causal, sc, block_q, block_k,
-                                     "lse", window), q, k, v)
+                                     "lse", window, diffusion), q, k, v)
     return out, (q, k, v, out, lse)
 
 
-def _fa_vjp_bwd(causal, scale, block_q, block_k, window, res, g):
+def _fa_vjp_bwd(causal, scale, block_q, block_k, window, diffusion, res, g):
     q, k, v, out, lse = res
     sc = _scale_of(q, scale)
     window = _window_of(window, causal, k.shape[2])
+    diffusion = _diffusion_of(diffusion, causal, window, q.shape[2],
+                              k.shape[2])
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     return _on_own_rows(
         lambda *xs: flash_attention_bwd(*xs, causal, sc, block_q, block_k,
-                                        q.dtype, window),
+                                        q.dtype, window, diffusion),
         q, k, v, g, lse, delta)
 
 

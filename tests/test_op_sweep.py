@@ -371,6 +371,9 @@ EXEMPT = {
                               "not d(out); tests/test_module.py",
     "softmax_cross_entropy": "loss op: scalar loss + implicit grad; "
                              "tests/test_fused.py",
+    "block_diffusion_ce": "loss op: the weighted cross-entropy and the "
+                          "logits' gradient against it written out in "
+                          "tests/test_sdar_ops.py; the ids take none",
     "RNN": "tests/test_gluon_rnn.py + tests/test_pallas_rnn.py",
     "MultiHeadAttention": "flash-vs-reference parity + op-level grads in "
                           "tests/test_pallas_attention.py",

@@ -222,6 +222,45 @@ def test_mellum_attention_reaches_its_kernels(one_chip, kind):
     assert found == [kind == "sliding_attention"] * 2
 
 
+def test_block_diffusion_attention_reaches_its_kernels(one_chip):
+    """``sdarmoe_train_4k``'s attention layer, ahead of time: 32 query heads
+    of 128 over 4 key/value heads with per-head norms under a 2048-wide
+    stream, the noised and clean copies of a 4096-token document as 8192
+    positions under the block-diffusion mask in blocks of 4.  Forward +
+    backward hold the two Mosaic calls over 32 heads, no T x T tensor, and
+    both carry the scope ``bd_flash_roofline_pct.train`` finds them by."""
+    import json
+    import os
+    from mxnet_tpu.ops.registry import OPS
+    op = OPS["MultiHeadAttention"]
+    attrs = op.parse_attrs(dict(num_heads=32, num_kv_heads=4, head_dim=128,
+                                qk_norm=True, eps=1e-6, rope_theta=1e6,
+                                causal=False, diffusion_block=4))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, 8192, 2048), sds(4096, 2048), sds(512, 2048),
+            sds(512, 2048), sds(2048, 4096), sds(128, dtype=jnp.float32),
+            sds(128, dtype=jnp.float32))
+
+    def loss(*a):
+        with jax.named_scope("MultiHeadAttention:tfm_l0_bd"):
+            return jnp.sum(op.fn(attrs, *a).astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
+        *args).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert all("bf16[32,8192,128]" in ln for ln in calls)
+    assert "[1,32,8192,8192]" not in hlo and "[32,8192,8192]" not in hlo
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "metrics",
+                           "bd_flash_roofline_pct.train.json")) as f:
+        reads = re.compile(json.load(f)["params"]["kernel"])
+    assert all(reads.search(ln) for ln in calls)
+
+
 def test_latent_attention_reaches_the_kernels_at_a_head_of_256(one_chip):
     """``glm47flash_train_4k``'s attention layer, ahead of time: 20 heads of
     192 + 64 (values of 256) under ranks 768 / 512 and a 2048-wide stream at
